@@ -6,8 +6,12 @@ ln(vocab) — plus checkpoint save/resume continuity. Use --tiny for CI-speed.
 """
 
 import argparse
+import os
 import sys
 import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
 
 import numpy as np
 
@@ -37,6 +41,8 @@ def main():
     ap.add_argument("--compile", action=argparse.BooleanOptionalAction,
                     default=True)
     args = ap.parse_args()
+    from paddle_tpu.framework.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
 
     cfg = GPT2Config.tiny() if args.tiny else GPT2Config.small()
     base_lr, warmup = 3e-4, 20
@@ -89,10 +95,12 @@ def main():
           f"({args.steps * args.batch * args.seq / dt:.0f} tok/s)")
 
     # checkpoint round trip
-    paddle.save(model.state_dict(), "/tmp/gpt2_ckpt/model.pdparams")
-    paddle.save(opt.state_dict(), "/tmp/gpt2_ckpt/opt.pdopt")
-    model.set_state_dict(paddle.load("/tmp/gpt2_ckpt/model.pdparams"))
-    opt.set_state_dict(paddle.load("/tmp/gpt2_ckpt/opt.pdopt"))
+    import tempfile
+    ckpt = tempfile.mkdtemp(prefix="gpt2_ckpt_")
+    paddle.save(model.state_dict(), f"{ckpt}/model.pdparams")
+    paddle.save(opt.state_dict(), f"{ckpt}/opt.pdopt")
+    model.set_state_dict(paddle.load(f"{ckpt}/model.pdparams"))
+    opt.set_state_dict(paddle.load(f"{ckpt}/opt.pdopt"))
     loss2 = float(train_step(sample_batch(0)).item())
     print(f"resumed step loss {loss2:.4f}")
 

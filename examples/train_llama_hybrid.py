@@ -137,6 +137,8 @@ def train_pipeline_hybrid():
 
 
 def main():
+    from paddle_tpu.framework.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     train_gspmd_hybrid()
     train_pipeline_hybrid()
 

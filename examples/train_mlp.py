@@ -6,12 +6,18 @@ import os
 import sys
 import time
 
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
+
 import numpy as np
 
 import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.io import DataLoader, TensorDataset
 
+from paddle_tpu.framework.compile_cache import ensure_compile_cache
+
+ensure_compile_cache()
 paddle.seed(42)
 
 # synthetic regression task
@@ -67,10 +73,13 @@ print(f"compiled 5 epochs in {time.perf_counter() - t0:.2f}s, "
 assert losses[-1] <= last + 1e-3, "compiled step regressed the loss"
 
 print("== checkpoint save / resume ==")
-paddle.save(model.state_dict(), "/tmp/verify_mlp/model.pdparams")
-paddle.save(opt.state_dict(), "/tmp/verify_mlp/opt.pdopt")
+import tempfile
+
+_ckpt = tempfile.mkdtemp(prefix="verify_mlp_")
+paddle.save(model.state_dict(), f"{_ckpt}/model.pdparams")
+paddle.save(opt.state_dict(), f"{_ckpt}/opt.pdopt")
 model2 = nn.Sequential(nn.Linear(D, 64), nn.GELU(), nn.Linear(64, 1))
-model2.set_state_dict(paddle.load("/tmp/verify_mlp/model.pdparams"))
+model2.set_state_dict(paddle.load(f"{_ckpt}/model.pdparams"))
 pred1 = model(paddle.to_tensor(X[:4])).numpy()
 pred2 = model2(paddle.to_tensor(X[:4])).numpy()
 np.testing.assert_allclose(pred1, pred2, rtol=1e-6)

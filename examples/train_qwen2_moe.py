@@ -99,5 +99,7 @@ if __name__ == "__main__":
                     choices=["single", "ep", "ep_pp"])
     ap.add_argument("--steps", type=int, default=3)
     args = ap.parse_args()
+    from paddle_tpu.framework.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     {"single": run_single, "ep": run_ep,
      "ep_pp": run_ep_pp}[args.mode](args.steps)

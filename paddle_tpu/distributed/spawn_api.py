@@ -2,9 +2,11 @@
 (upstream python/paddle/distributed/spawn.py, UNVERIFIED).
 
 Spawns ``nprocs`` python processes running ``func(*args)`` with the
-paddle rank env set, CPU-pinned jax (the launcher's simulation mode —
-one process drives all TPU chips in real runs, so multi-proc spawn is
-the CPU/Gloo-role path)."""
+paddle rank env set. The children are pinned to the CPU backend
+UNCONDITIONALLY (``JAX_PLATFORMS=cpu``), also on a TPU host: this is a
+simulation of a multi-process job (the CPU/Gloo-role path), never a way
+onto the accelerator — a chip belongs to one process, and in real runs
+ONE process drives all the chips of a host."""
 
 from __future__ import annotations
 
@@ -20,14 +22,8 @@ def _entry(func, rank, nprocs, args):
         "PADDLE_RANK": str(rank),
         "PADDLE_TRAINERS_NUM": str(nprocs),
         "PADDLE_WORLD_SIZE": str(nprocs),
-        "JAX_PLATFORMS": "cpu",
+        "JAX_PLATFORMS": "cpu",     # read by jax at backend init
     })
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
     func(*args)
 
 

@@ -5,8 +5,7 @@ Inside a shard_map region, jax tracks which named axes a value is
 device-varying over; freshly created constants (zeros carries) start
 invariant and must be explicitly marked before a ``lax.scan`` whose
 outputs vary — otherwise the carry types mismatch. This helper is the
-one place that knows the pcast/pvary API difference and how to read a
-value's current vma."""
+one place that knows how to read a value's current vma and cast it."""
 
 from __future__ import annotations
 
@@ -31,9 +30,4 @@ def pvary_missing(x, axes=(), like=None):
         pass
     if not want:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, tuple(want), to="varying")
-    try:
-        return lax.pvary(x, tuple(want))
-    except (AttributeError, TypeError):
-        return x
+    return lax.pcast(x, tuple(want), to="varying")

@@ -84,11 +84,8 @@ def dtype_name(dtype) -> str:
 def trace_clean() -> bool:
     """True when no jax trace is in progress (i.e. eager host execution).
     Single wrapper around the unstable jax internal so a jax upgrade has
-    one place to fix; falls back to 'clean' if the symbol moves."""
-    try:
-        from jax._src.core import trace_state_clean
-    except ImportError:  # jax moved the symbol; assume eager
-        return True
+    one place to fix (pyproject.toml pins the jax minor)."""
+    from jax._src.core import trace_state_clean
     return trace_state_clean()
 
 

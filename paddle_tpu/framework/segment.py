@@ -63,28 +63,13 @@ def _ensure_compile_cache():
     """Segmented flushes re-trace fresh closures every call; without the
     persistent (HLO-keyed) compilation cache, every flush of a LARGE
     segment would also pay a full XLA compile. Configure the cache once
-    if — and only if — the app has not set one itself. Entries need
-    >0.1s of compile time to persist, so the directory holds only
-    programs worth caching even though the setting is process-global;
-    genuinely tiny segments re-compile in milliseconds and stay out."""
+    (``framework.compile_cache`` — a directory placed from outside
+    wins)."""
     if _cache_checked[0]:
         return
     _cache_checked[0] = True
-    if jax.config.jax_compilation_cache_dir:
-        return
-    import os
-    import tempfile
-    user = os.environ.get("USER") or os.environ.get("LOGNAME") or (
-        str(os.getuid()) if hasattr(os, "getuid") else "anon")
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(tempfile.gettempdir(),
-                     f"paddle_tpu_segment_xla_cache_{user}"))
-    # jax's default persistence threshold is a full SECOND of compile
-    # time — a segment compiling in 0.9s would re-pay that every call.
-    # Persist anything over 0.1s; only genuinely tiny programs (which
-    # re-compile in milliseconds) stay out of the cache.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    from .compile_cache import ensure_compile_cache
+    ensure_compile_cache()
 
 
 class SegValue:

@@ -89,6 +89,32 @@ _ERROR_TYPES = {c.__name__: c for c in
                  RequestQuarantined, Overloaded, ReplicaFailed)}
 
 
+def _child_jax_env():
+    """The jax settings a worker is spawned with, stated explicitly.
+
+    Platform: the parent's own pin (``JAX_PLATFORMS`` / ``jax.config``)
+    if there is one. With none — a TPU host — a parent whose backend is
+    already up HOLDS the device it reports, and the child is told that
+    platform by name, so a worker that cannot share the parent's chip
+    exits at start-up with a message (``worker._acquire_device``)
+    instead of inheriting nothing and hanging. A parent that has not
+    touched jax holds no device and pins nothing."""
+    import jax
+    from jax._src import xla_bridge
+    env = {}
+    plat = jax.config.jax_platforms
+    if not plat and xla_bridge.backends_are_initialized():
+        plat = jax.default_backend()
+    if plat:
+        env["JAX_PLATFORMS"] = plat
+    cache = jax.config.jax_compilation_cache_dir
+    if cache:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache
+    if jax.config.read("jax_disable_most_optimizations"):
+        env["PADDLE_TPU_WORKER_DISOPT"] = "1"
+    return env
+
+
 def _rebuild_error(type_name, msg):
     cls = _ERROR_TYPES.get(type_name, ServingError)
     err = cls.__new__(cls)
@@ -314,18 +340,7 @@ class ProcReplica(FleetReplica):
                 os.path.dirname(os.path.abspath(paddle_tpu.__file__)))
             env["PYTHONPATH"] = pkg_root + os.pathsep \
                 + env.get("PYTHONPATH", "")
-            try:
-                import jax
-                plat = jax.config.jax_platforms
-                if plat:
-                    env.setdefault("JAX_PLATFORMS", plat)
-                cache = jax.config.jax_compilation_cache_dir
-                if cache:
-                    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache)
-                if jax.config.jax_disable_most_optimizations:
-                    env.setdefault("PADDLE_TPU_WORKER_DISOPT", "1")
-            except Exception:  # noqa: BLE001 — env passthrough only
-                pass
+            env.update(_child_jax_env())
             child_fd = child_sock.fileno()
             self._proc = subprocess.Popen(
                 [sys.executable, "-m", "paddle_tpu.inference.worker",

@@ -111,13 +111,15 @@ def _signature_key(leaves):
 
 
 class _CompiledGraph:
-    __slots__ = ("state_list", "jitted", "pure_fn", "guard_log")
+    __slots__ = ("state_list", "jitted", "pure_fn", "guard_log",
+                 "call_avals")
 
     def __init__(self, state_list, jitted, pure_fn, guard_log):
         self.state_list = state_list
         self.jitted = jitted
         self.pure_fn = pure_fn
         self.guard_log = guard_log   # [(kind, value)] from discovery
+        self.call_avals = None       # shapes of the first compiled run
 
 
 class _SigEntry:
@@ -190,6 +192,16 @@ class StaticFunction:
     @property
     def function(self):
         return self._fn
+
+    def program_texts(self) -> list[str]:
+        """The lowered (StableHLO) text of every program this function
+        has RUN compiled — what a check reads to see that a kernel is
+        really in the program (a Pallas call is a ``tpu_custom_call``).
+        Re-lowering hits jit's trace cache; nothing executes."""
+        return [g.jitted.lower(*g.call_avals).as_text()
+                for entry in self._graphs.values()
+                for g in entry.by_key.values()
+                if g.call_avals is not None]
 
     def rollback(self):
         return self._fn
@@ -465,6 +477,15 @@ class StaticFunction:
         arg_arrays = tuple(leaf._data for leaf in leaves
                            if isinstance(leaf, Tensor))
         state_arrays = tuple(t._data for t in graph.state_list)
+        if graph.call_avals is None:
+            # keep a sharding only where it spans devices: one-device
+            # arrays are uncommitted and follow the others, as in a call
+            graph.call_avals = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=a.sharding
+                    if len(a.sharding.device_set) > 1 else None),
+                (state_arrays, arg_arrays))
         new_state, out_arrays, guard_vec = graph.jitted(state_arrays,
                                                         arg_arrays)
         holder = graph.pure_fn._holder

@@ -27,24 +27,42 @@ __all__ = ["available", "parallel_stack", "shuffle_indices", "TCPStore",
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "native.cc")
-_LIB = os.path.join(_HERE, "_paddle_tpu_native.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
+def _lib_path() -> str:
+    """The library's name carries a hash of ``src/native.cc``: the .so
+    is gitignored and travels by copy, which keeps no mtimes, so only
+    the source's CONTENT can say whether a binary on disk was built
+    from the committed source."""
+    import hashlib
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"_paddle_tpu_native_{digest}.so")
+
+
 def _build() -> str | None:
-    if os.path.exists(_LIB) and \
-            os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return _LIB
+    lib = _lib_path()
+    if os.path.exists(lib):
+        return lib
+    tmp = f"{lib}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-pthread", "-std=c++17",
-           _SRC, "-o", _LIB + ".tmp"]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_LIB + ".tmp", _LIB)
-        return _LIB
+        os.replace(tmp, lib)
     except Exception:
         return None
+    import glob
+    for old in glob.glob(os.path.join(_HERE, "_paddle_tpu_native*.so")):
+        if old != lib:          # built from a source that is gone
+            try:
+                os.remove(old)
+            except OSError:
+                pass
+    return lib
 
 
 def _load():

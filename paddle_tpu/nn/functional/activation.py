@@ -184,8 +184,14 @@ def swiglu(x, y=None, name=None):
             # one VMEM pass + fused dgate/dup backward, no silu
             # intermediate saved (ops/pallas/swiglu.py)
             from ...ops.pallas import swiglu as pallas_sw
-            return apply(pallas_sw.swiglu_fused, as_tensor(x),
-                         as_tensor(y), name="fused_swiglu")
+            from ...ops.pallas._mesh import (kernel_placement,
+                                             sharded_cols)
+            use_kernel, mesh = kernel_placement()
+            if use_kernel:      # per shard (rows x MLP columns) on a mesh
+                return apply(
+                    lambda a, b: sharded_cols(pallas_sw.swiglu_fused,
+                                              mesh, a, b),
+                    as_tensor(x), as_tensor(y), name="fused_swiglu")
         return apply(lambda a, b: jax.nn.silu(a) * b, as_tensor(x),
                      as_tensor(y), name="swiglu")
 

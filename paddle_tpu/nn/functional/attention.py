@@ -75,12 +75,19 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     use_pallas = (_use_pallas() and attn_mask is None and dropout_p == 0.0
                   and q.shape[1] == k.shape[1])
     if use_pallas:
+        from ...ops.pallas._mesh import kernel_placement, sharded_heads
+        use_pallas, mesh = kernel_placement()
+    if use_pallas:
         from jax import ad_checkpoint
 
         from ...ops.pallas import flash_attention as fa
 
         def fn(qq, kk, vv):
-            out = fa.flash_attention(qq, kk, vv, causal=is_causal)
+            # per shard under a fleet mesh (ops/pallas/_mesh.py)
+            out = sharded_heads(
+                lambda a, b, c: fa.flash_attention(a, b, c,
+                                                   causal=is_causal),
+                mesh, qq, kk, vv)
             # name the kernel output so the opt-in remat policy
             # FLAGS_recompute_policy='dots_and_flash_saveable' can save
             # it (under dots_saveable a checkpointed layer re-runs the

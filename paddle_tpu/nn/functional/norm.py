@@ -16,10 +16,14 @@ __all__ = ["layer_norm", "batch_norm", "instance_norm", "group_norm",
            "local_response_norm", "rms_norm", "fused_rms_norm_residual"]
 
 
-def _use_pallas() -> bool:
-    if not flags.flag("FLAGS_enable_pallas_kernels"):
-        return False
-    return jax.default_backend() == "tpu"
+def _pallas_mesh():
+    """``(use the Pallas kernel, fleet mesh to shard it over or None)``
+    — platform + flag, then the placement rule of ops/pallas/_mesh.py."""
+    if not (flags.flag("FLAGS_enable_pallas_kernels")
+            and jax.default_backend() == "tpu"):
+        return False, None
+    from ...ops.pallas._mesh import kernel_placement
+    return kernel_placement()
 
 
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
@@ -56,10 +60,15 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     x = as_tensor(x)
     if weight is not None:
         w = as_tensor(weight)
-        if _use_pallas():
+        use_pallas, mesh = _pallas_mesh()
+        if use_pallas:
             from ...ops.pallas import rms_norm as pallas_rms
-            return apply(lambda a, ww: pallas_rms.rms_norm(a, ww, epsilon),
-                         x, w, name="rms_norm")
+            from ...ops.pallas._mesh import sharded_rows
+            return apply(
+                lambda a, ww: sharded_rows(
+                    lambda a_, w_: pallas_rms.rms_norm(a_, w_, epsilon),
+                    mesh, a, replicated=(ww,)),
+                x, w, name="rms_norm")
 
         def fn(a, ww):
             dt = a.dtype
@@ -86,10 +95,14 @@ def fused_rms_norm_residual(x, residual, weight, epsilon=1e-6, name=None):
     ``x + residual`` followed by :func:`rms_norm`)."""
     x, r, w = as_tensor(x), as_tensor(residual), as_tensor(weight)
     from ...ops.pallas import rms_norm as pallas_rms
-    if _use_pallas():
+    use_pallas, mesh = _pallas_mesh()
+    if use_pallas:
+        from ...ops.pallas._mesh import sharded_rows
         return apply(
-            lambda a, b, ww: pallas_rms.rms_norm_residual(a, b, ww,
-                                                          epsilon),
+            lambda a, b, ww: sharded_rows(
+                lambda a_, b_, w_: pallas_rms.rms_norm_residual(
+                    a_, b_, w_, epsilon),
+                mesh, a, b, replicated=(ww,), n_out=2),
             x, r, w, n_outputs=2, name="fused_rms_norm_residual")
     # the SAME oracle the interpret-mode parity tests pin the kernel to
     # — one source of truth for the fallback math

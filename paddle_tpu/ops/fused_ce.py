@@ -120,7 +120,14 @@ def _use_pallas_inner() -> bool:
             return False
     except KeyError:
         pass
-    return jax.default_backend() == "tpu"
+    if jax.default_backend() != "tpu":
+        return False
+    # under a fleet mesh the inner kernels are not wrapped in a
+    # shard_map (the [N, vc] block is column-sharded with a
+    # vocab-parallel head): by rule, the XLA inner path there
+    from .pallas._mesh import kernel_placement
+    use_kernel, mesh = kernel_placement()
+    return use_kernel and mesh is None
 
 
 def _chunk_grid(v, chunk_v):

@@ -371,8 +371,9 @@ def put_along_axis(arr, indices, values, axis, reduce="assign",
     def fn(a, i, v):
         v = jnp.broadcast_to(v, i.shape) if broadcast else v
         if reduce == "add":
-            return jnp.put_along_axis(a, i, jnp.take_along_axis(a, i, axis=int(axis)) + v, axis=int(axis), inplace=False) \
-                if hasattr(jnp, "put_along_axis") else _pala(a, i, v, int(axis), "add")
+            return jnp.put_along_axis(
+                a, i, jnp.take_along_axis(a, i, axis=int(axis)) + v,
+                axis=int(axis), inplace=False)
         if reduce in ("mul", "multiply"):
             return _pala(a, i, jnp.take_along_axis(a, i, axis=int(axis)) * v,
                          int(axis), "assign")

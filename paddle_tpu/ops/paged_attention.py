@@ -107,40 +107,33 @@ def paged_attention(q, key_pages, value_pages, block_tables, context_lens,
                     scale=None):
     """Decode-step paged attention; Pallas kernel on TPU, jnp oracle
     elsewhere (flag ``FLAGS_use_pallas_paged_attention`` forces the
-    reference path off TPU too)."""
+    reference path on TPU too). The path is a rule on platform + flag:
+    a kernel that fails on TPU raises, it is never swapped for the
+    oracle."""
     from ..framework import flags
     platform = jax.devices()[0].platform
     use_kernel = (platform == "tpu"
                   and bool(int(flags.flag(
                       "FLAGS_use_pallas_paged_attention"))))
     if use_kernel:
-        import warnings
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as _kernel)
-            s = scale if scale is not None else 1.0 / math.sqrt(
-                q.shape[-1])
-            pages_per_seq = block_tables.shape[1]
-            ppcb = next(c for c in (8, 4, 2, 1)
-                        if pages_per_seq % c == 0)
-            # the kernel applies no softmax scale — fold it into q; it
-            # also indexes with int32 internally, so int64 tables/lens
-            # (the paddle default int dtype) must be cast AND the trace
-            # must run with x64 promotion off (kernel-internal python
-            # ints otherwise promote to i64 and its lax.div mixes
-            # dtypes) — same contract as the other pallas kernels
-            from .pallas._utils import no_x64
-            with no_x64():
-                return _kernel(q * jnp.asarray(s, q.dtype), key_pages,
-                               value_pages,
-                               context_lens.astype(jnp.int32),
-                               block_tables.astype(jnp.int32),
-                               pages_per_compute_block=ppcb)
-        except Exception as e:
-            warnings.warn(
-                f"Pallas paged-attention kernel unavailable "
-                f"({type(e).__name__}: {e}); using the jnp reference "
-                f"path", RuntimeWarning)
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention as _kernel)
+        s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+        pages_per_seq = block_tables.shape[1]
+        ppcb = next(c for c in (8, 4, 2, 1) if pages_per_seq % c == 0)
+        # the kernel applies no softmax scale — fold it into q; it
+        # also indexes with int32 internally, so int64 tables/lens
+        # (the paddle default int dtype) must be cast AND the trace
+        # must run with x64 promotion off (kernel-internal python
+        # ints otherwise promote to i64 and its lax.div mixes
+        # dtypes) — same contract as the other pallas kernels
+        from .pallas._utils import no_x64
+        with no_x64():
+            return _kernel(q * jnp.asarray(s, q.dtype), key_pages,
+                           value_pages,
+                           context_lens.astype(jnp.int32),
+                           block_tables.astype(jnp.int32),
+                           pages_per_compute_block=ppcb)
     return paged_attention_reference(q, key_pages, value_pages,
                                      block_tables, context_lens, scale)
 
@@ -244,25 +237,20 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
     attention entry point (PAPERS.md ragged-paged-attention). Pallas
     kernel on TPU (``FLAGS_use_pallas_ragged_attention``), jnp oracle
     elsewhere; the kernel module itself always runs (interpret mode)
-    in the parity tests, the flash_attention discipline."""
+    in the parity tests, the flash_attention discipline. The path is a
+    rule on platform + flag: a kernel that fails on TPU raises, it is
+    never swapped for the oracle."""
     from ..framework import flags
     platform = jax.devices()[0].platform
     use_kernel = (platform == "tpu"
                   and bool(int(flags.flag(
                       "FLAGS_use_pallas_ragged_attention"))))
     if use_kernel:
-        import warnings
-        try:
-            from .pallas.ragged_paged_attention import (
-                ragged_paged_attention as _kernel)
-            return _kernel(q, key_pages, value_pages, block_tables,
-                           ctx_lens, lengths, scale,
-                           k_scales=k_scales, v_scales=v_scales)
-        except Exception as e:
-            warnings.warn(
-                f"Pallas ragged paged-attention kernel unavailable "
-                f"({type(e).__name__}: {e}); using the jnp reference "
-                f"path", RuntimeWarning)
+        from .pallas.ragged_paged_attention import (
+            ragged_paged_attention as _kernel)
+        return _kernel(q, key_pages, value_pages, block_tables,
+                       ctx_lens, lengths, scale,
+                       k_scales=k_scales, v_scales=v_scales)
     return ragged_paged_attention_reference(
         q, key_pages, value_pages, block_tables, ctx_lens, lengths,
         scale, k_scales=k_scales, v_scales=v_scales)

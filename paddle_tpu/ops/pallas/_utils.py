@@ -19,7 +19,4 @@ def no_x64():
     lowering's dtype promotion). Kernel inputs carry explicit dtypes,
     so disabling x64 for the trace changes nothing semantically."""
     import jax
-    if hasattr(jax, "enable_x64"):     # removed from the jax root
-        return jax.enable_x64(False)   # namespace in newer releases
-    from jax.experimental import enable_x64
-    return enable_x64(False)
+    return jax.enable_x64(False)

@@ -34,9 +34,13 @@ from ._utils import interpret_mode as _interpret, no_x64 as _no_x64
 
 __all__ = ["chunk_stats", "chunk_dlogits"]
 
-#: rows per program — the logits block is f32: 256 x 4096 x 4B = 4MB
-#: per input block, well inside VMEM next to the [blk, 1] vectors
+#: rows per program at the default 1024-column chunk. The f32 logits
+#: block, its double buffer and the kernels' elementwise temporaries
+#: (iota, mask, exp, one-hot) must fit 16 MB of scoped VMEM: 256 x 1024
+#: does, 256 x 8192 does not (compiled for a described v5e) — so the
+#: row block shrinks as the chunk widens, holding rows x columns.
 _BLOCK_ROWS = 256
+_BLOCK_ELEMS = _BLOCK_ROWS * 1024
 
 
 def _stats_kernel(lo_ref, logits_ref, local_ref, m_ref, s_ref, t_ref):
@@ -78,8 +82,9 @@ def _dlogits_kernel(lo_ref, logits_ref, lse_ref, local_ref, scale_ref,
                 * scale_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _row_blk(n):
-    return min(_BLOCK_ROWS, -(-n // 8) * 8)
+def _row_blk(n, vc):
+    rows = min(_BLOCK_ROWS, max(8, _BLOCK_ELEMS // vc // 8 * 8))
+    return min(rows, -(-n // 8) * 8)
 
 
 def _pad_rows(a, n_pad):
@@ -98,7 +103,7 @@ def chunk_stats(logits, local, lo):
     int32 (columns before it belong to the previous chunk — tail-
     overlap masking). Returns ``(m, s, t)`` f32 ``[N]`` vectors."""
     n, vc = logits.shape
-    blk = _row_blk(n)
+    blk = _row_blk(n, vc)
     n_p = -(-n // blk) * blk
     lo_arr = jnp.reshape(jnp.asarray(lo, jnp.int32), (1,))
     col2 = pl.BlockSpec((blk, 1), lambda i: (i, 0))
@@ -125,7 +130,7 @@ def chunk_dlogits(logits, lse, local, scale, lo, out_dtype=None):
     Returns ``[N, vc]`` in ``out_dtype`` (default: logits dtype)."""
     n, vc = logits.shape
     out_dtype = logits.dtype if out_dtype is None else out_dtype
-    blk = _row_blk(n)
+    blk = _row_blk(n, vc)
     n_p = -(-n // blk) * blk
     lo_arr = jnp.reshape(jnp.asarray(lo, jnp.int32), (1,))
     col2 = pl.BlockSpec((blk, 1), lambda i: (i, 0))

@@ -24,6 +24,10 @@ prefetch idiom, generalized to ragged multi-token queries):
 
 - grid ``(kv_head, sequence, q_block)`` — one program per kv head per
   sequence-block of the token stream;
+- the wrapper lays q out kv-head-major, ``[B, KVH, C * rep, D]`` with
+  ``row = token * rep + head-in-group``, so a q/out block is the 2-D
+  ``[rows, D]`` tile the TPU lowering requires (``rows`` a multiple of
+  8, or the whole row-padded chunk) whatever the GQA ratio ``rep``;
 - block tables / context lens / lengths ride scalar prefetch, so only
   the pages a sequence actually owns are streamed;
 - K/V pools stay in HBM (``ANY`` memory space); each grid step DMAs
@@ -85,7 +89,9 @@ def _resolve_blocks(c, pages_per_seq, page, d, dtype, quant=False):
     Host-side at trace time — static ints selecting the compiled
     grid. Quantized pools add a ``kvq`` component to the shape sig so
     bf16 cache entries can't poison quantized configs (and vice versa);
-    bf16 shapes keep the historical sig."""
+    bf16 shapes keep the historical sig. The wrapper then rounds
+    q_block up to one the TPU lowering accepts (:func:`_row_blocking`;
+    the tuner surface only offers such blocks, a flag may not)."""
     from ...framework import flags
     forced = getattr(_forced_tls, "blocks", None)
     if forced is not None:
@@ -118,16 +124,32 @@ def _resolve_blocks(c, pages_per_seq, page, d, dtype, quant=False):
 
 
 def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
-                   v_hbm_ref, o_ref, k_buf, v_buf, sem, *, scale,
-                   page, q_block, g_pages, pages_per_seq):
+                   v_hbm_ref, *rest, scale, page, q_block, rep, g_pages,
+                   pages_per_seq, quant):
     """One program: (kv head h, sequence b, q block qi). Streams the
     sequence's pages through the double-buffered VMEM scratch and
-    accumulates an online softmax over them."""
+    accumulates an online softmax over them.
+
+    The q/out block is 2-D ``[rows, d]`` with ``row = token * rep +
+    head-in-group`` (the wrapper lays the stream out kv-head-major), so
+    its last two dims are the tile-aligned ones Mosaic requires and the
+    body never reshapes across the sublane/lane boundary.
+
+    ``quant``: the data pools are int8 (or fp8); the per-token scales of
+    the sequence's pages arrive lane-dense as ``[n_kv_blocks, bk]`` rows
+    (gathered through the SAME block table by the wrapper) and are
+    applied on the key axis of the scores / probabilities —
+    ``(q . code_j) * ks_j`` and ``(p_j * vs_j) . code_j`` — which equals
+    dequantize-then-dot in exact arithmetic and keeps the softmax in
+    fp32."""
+    if quant:
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sem = rest
+    else:
+        o_ref, k_buf, v_buf, sem = rest
     h = pl.program_id(0)
     b = pl.program_id(1)
     qi = pl.program_id(2)
-    rep = q_ref.shape[1]           # q heads per kv head
-    d = q_ref.shape[2]
+    rows, d = q_ref.shape          # q rows of this block (padded)
     bk = g_pages * page            # keys per kv block
     ctx = ctx_ref[b]
     length = len_ref[b]
@@ -167,8 +189,14 @@ def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
         for c in dma_block(0, 0):
             c.start()
 
-        q = q_ref[...].astype(jnp.float32) * scale  # [q_block, rep, d]
-        q2 = q.reshape(q_block * rep, d)
+        q2 = q_ref[...].astype(jnp.float32) * scale      # [rows, d]
+        # row r is chunk token q_start + r // rep. The masks below are
+        # the division-free forms of
+        #   k_pos <= ctx + q_tok   and   q_tok < length
+        # (x <= r // rep  <=>  x * rep <= r for integer x, rep > 0).
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
+        row_ok = row < (length - q_start) * rep
 
         def body(i, carry):
             acc, m_prev, l_prev = carry
@@ -182,137 +210,68 @@ def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
 
             for c in dma_block(i, slot):
                 c.wait()
-            k = k_buf[slot].reshape(bk, d).astype(jnp.float32)
-            v = v_buf[slot].reshape(bk, d).astype(jnp.float32)
+            # widen BEFORE collapsing (g, page) -> bk: f32 tiles are 8
+            # sublanes, so the collapse is layout-free for any page
+            # size that is a multiple of 8 (int8/bf16 tiles are not)
+            k = k_buf[slot].astype(jnp.float32).reshape(bk, d)
+            v = v_buf[slot].astype(jnp.float32).reshape(bk, d)
             s = jax.lax.dot_general(
                 q2, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [qb*rep, bk]
-            k_pos = i * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (q_block * rep, bk), 1)
-            q_tok = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (q_block * rep, bk), 0) // rep
+                preferred_element_type=jnp.float32)      # [rows, bk]
+            if quant:
+                s = s * ks_ref[pl.ds(i, 1), :]
             # causal over the paged history + the row-validity mask
             # (rows past `length` stay fully masked -> zero output)
-            valid = (k_pos <= ctx + q_tok) & (q_tok < length)
+            valid = ((col + (i * bk - ctx - q_start)) * rep <= row) & row_ok
             s = jnp.where(valid, s, _NEG_INF)
-            m_cur = jnp.max(s, axis=-1)
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
             alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-            acc = acc * alpha[:, None] + jax.lax.dot_general(
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quant:
+                p = p * vs_ref[pl.ds(i, 1), :]
+            acc = acc * alpha + jax.lax.dot_general(
                 p, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             return acc, m_new, l_new
 
-        acc0 = jnp.zeros((q_block * rep, d), jnp.float32)
-        m0 = jnp.full((q_block * rep,), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((q_block * rep,), jnp.float32)
+        acc0 = jnp.zeros((rows, d), jnp.float32)
+        m0 = jnp.full((rows, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((rows, 1), jnp.float32)
         acc, m, l = jax.lax.fori_loop(0, n_blocks, body, (acc0, m0, l0))
-        l = jnp.maximum(l, 1e-30)
-        o_ref[...] = (acc / l[:, None]).reshape(
-            q_block, rep, d).astype(o_ref.dtype)
+        o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _ragged_quant_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
-                         v_hbm_ref, ks_hbm_ref, vs_hbm_ref, o_ref,
-                         k_buf, v_buf, ks_buf, vs_buf, sem, sem_s, *,
-                         scale, page, q_block, g_pages, pages_per_seq):
-    """Quantized-pool variant of :func:`_ragged_kernel`: the data pools
-    are int8 (or fp8) and a page-parallel f32 scales pool rides the
-    SAME block-table indirection — each grid step DMAs the scale pages
-    alongside the data pages and dequantizes in VMEM right after the
-    wait (``k = q_codes.astype(f32) * scale``), so the softmax body is
-    numerically identical to the bf16 kernel's fp32 accumulation. Scale
-    copies have a different byte count than data copies, so they ride
-    their OWN per-slot semaphore (the shared-counter hazard in
-    ``_ragged_kernel.dma_block`` applies per byte-count class)."""
-    h = pl.program_id(0)
-    b = pl.program_id(1)
-    qi = pl.program_id(2)
-    rep = q_ref.shape[1]           # q heads per kv head
-    d = q_ref.shape[2]
-    bk = g_pages * page            # keys per kv block
-    ctx = ctx_ref[b]
-    length = len_ref[b]
-    q_start = qi * q_block         # first chunk token of this q block
+_SUBLANES = 8    # Mosaic: second-to-last block dim % 8, or the whole dim
 
-    o_ref[...] = jnp.zeros_like(o_ref)
 
-    def dma_block(i, slot):
-        copies = []
-        for gidx in range(g_pages):
-            pidx = jnp.minimum(i * g_pages + gidx, pages_per_seq - 1)
-            pid = tbl_ref[b * pages_per_seq + pidx]
-            copies.append(pltpu.make_async_copy(
-                k_hbm_ref.at[h, pid], k_buf.at[slot, gidx],
-                sem.at[slot]))
-            copies.append(pltpu.make_async_copy(
-                v_hbm_ref.at[h, pid], v_buf.at[slot, gidx],
-                sem.at[slot]))
-            copies.append(pltpu.make_async_copy(
-                ks_hbm_ref.at[h, pid], ks_buf.at[slot, gidx],
-                sem_s.at[slot]))
-            copies.append(pltpu.make_async_copy(
-                vs_hbm_ref.at[h, pid], vs_buf.at[slot, gidx],
-                sem_s.at[slot]))
-        return copies
+def _row_blocking(c, qb, rep):
+    """(q_block, padded chunk, rows per block) such that the q/out
+    block ``[rows, d]`` is one the TPU lowering accepts: ``rows =
+    q_block * rep`` a multiple of 8, or — when one block covers the
+    chunk — the whole (row-padded) array."""
+    step = _SUBLANES // math.gcd(rep, _SUBLANES)
+    qb = -(-qb // step) * step
+    if qb >= c:                     # one block: pad ROWS, not tokens
+        return c, c, -(-c * rep // _SUBLANES) * _SUBLANES
+    return qb, -(-c // qb) * qb, qb * rep
 
-    @pl.when(q_start < length)
-    def compute():  # noqa: ANN001 — pl.when body
-        n_kv = ctx + jnp.minimum(q_start + q_block, length)
-        n_blocks = (n_kv + bk - 1) // bk
 
-        for c in dma_block(0, 0):
-            c.start()
-
-        q = q_ref[...].astype(jnp.float32) * scale  # [q_block, rep, d]
-        q2 = q.reshape(q_block * rep, d)
-
-        def body(i, carry):
-            acc, m_prev, l_prev = carry
-            slot = jax.lax.rem(i, 2)
-            nslot = jax.lax.rem(i + 1, 2)
-
-            @pl.when(i + 1 < n_blocks)
-            def _():
-                for c in dma_block(i + 1, nslot):
-                    c.start()
-
-            for c in dma_block(i, slot):
-                c.wait()
-            # dequant in VMEM, right after the DMA: one f32 scale per
-            # (token, kv head) broadcast over the head dim
-            k = (k_buf[slot].reshape(bk, d).astype(jnp.float32)
-                 * ks_buf[slot].reshape(bk, 1))
-            v = (v_buf[slot].reshape(bk, d).astype(jnp.float32)
-                 * vs_buf[slot].reshape(bk, 1))
-            s = jax.lax.dot_general(
-                q2, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [qb*rep, bk]
-            k_pos = i * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (q_block * rep, bk), 1)
-            q_tok = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (q_block * rep, bk), 0) // rep
-            valid = (k_pos <= ctx + q_tok) & (q_tok < length)
-            s = jnp.where(valid, s, _NEG_INF)
-            m_cur = jnp.max(s, axis=-1)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-            acc = acc * alpha[:, None] + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return acc, m_new, l_new
-
-        acc0 = jnp.zeros((q_block * rep, d), jnp.float32)
-        m0 = jnp.full((q_block * rep,), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((q_block * rep,), jnp.float32)
-        acc, m, l = jax.lax.fori_loop(0, n_blocks, body, (acc0, m0, l0))
-        l = jnp.maximum(l, 1e-30)
-        o_ref[...] = (acc / l[:, None]).reshape(
-            q_block, rep, d).astype(o_ref.dtype)
+def _block_scales(scales, block_tables, g, page):
+    """Page-parallel scales pool [KVH, P, page] -> the sequences' own
+    scales, lane-dense per kv block: [B, KVH, n_kv_blocks, g * page].
+    One XLA gather through the block table (the kernel DMAs only the
+    data pages; a per-page scale row is 16-32 lanes, which neither the
+    DMA engine nor an in-kernel (g, page) -> (1, bk) relayout
+    handles)."""
+    kvh = scales.shape[0]
+    b, pps = block_tables.shape
+    nb = -(-pps // g)
+    sc = scales[:, block_tables]                      # [KVH, B, pps, page]
+    sc = jnp.pad(sc, ((0, 0), (0, 0), (0, nb * g - pps), (0, 0)))
+    return jnp.swapaxes(sc, 0, 1).reshape(b, kvh, nb, g * page).astype(
+        jnp.float32)
 
 
 def ragged_paged_attention(q, key_pages, value_pages, block_tables,
@@ -333,7 +292,7 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
                  1 = decode step, >1 = prefill chunk)
     k_scales /   optional [KVH, num_pages, page_size] f32 page-parallel
     v_scales     scales pools — when given, the data pools are int8/fp8
-                 and the kernel dequantizes pages in VMEM after the DMA
+                 and the kernel applies the scales in VMEM
     Returns [B, C, H, D].
     """
     b, c, h, d = q.shape
@@ -348,56 +307,56 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
         qb = max(1, min(int(q_block), c))
     if kv_pages_per_block is not None:
         g = max(1, min(int(kv_pages_per_block), pages_per_seq))
-    c_p = -(-c // qb) * qb
-    if c_p != c:
-        q = jnp.pad(q, ((0, 0), (0, c_p - c), (0, 0), (0, 0)))
-    grid = (kvh, b, c_p // qb)
-    kern = _ragged_quant_kernel if quant else _ragged_kernel
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    in_specs = [
-        # q: (slot, q block, kv-head group, head_dim)
-        pl.BlockSpec((None, qb, rep, d),
-                     lambda hh, bb, qq, *_: (bb, qq, hh, 0)),
-        any_spec,       # key pages stay in HBM
-        any_spec,       # value pages
-    ]
+    qb, c_p, rows = _row_blocking(c, qb, rep)
+    n_q = c_p // qb
+    # kv-head-major rows: [B, C, KVH, rep, D] -> [B, KVH, C * rep, D]
+    qr = jnp.pad(q, ((0, 0), (0, c_p - c), (0, 0), (0, 0)))
+    qr = qr.reshape(b, c_p, kvh, rep, d).transpose(0, 2, 1, 3, 4)
+    qr = qr.reshape(b, kvh, c_p * rep, d)
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, n_q * rows - c_p * rep),
+                      (0, 0)))
+    grid = (kvh, b, n_q)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    q_spec = pl.BlockSpec((None, None, rows, d),
+                          lambda hh, bb, qq, *_: (bb, hh, qq, 0))
+    in_specs = [q_spec,
+                any_spec,       # key pages stay in HBM
+                any_spec]       # value pages
+    operands = [qr, key_pages, value_pages]
+    if quant:
+        nb = -(-pages_per_seq // g)
+        sc_spec = pl.BlockSpec((None, None, nb, g * page),
+                               lambda hh, bb, qq, *_: (bb, hh, 0, 0))
+        in_specs += [sc_spec, sc_spec]
+        operands += [_block_scales(k_scales, block_tables, g, page),
+                     _block_scales(v_scales, block_tables, g, page)]
     scratch = [
         pltpu.VMEM((2, g, page, d), key_pages.dtype),
         pltpu.VMEM((2, g, page, d), value_pages.dtype),
+        pltpu.SemaphoreType.DMA((2,)),              # one per slot
     ]
-    operands = [q, key_pages, value_pages]
-    if quant:
-        in_specs += [any_spec, any_spec]            # scales pools
-        scratch += [pltpu.VMEM((2, g, page), k_scales.dtype),
-                    pltpu.VMEM((2, g, page), v_scales.dtype)]
-        operands += [k_scales, v_scales]
-    scratch.append(pltpu.SemaphoreType.DMA((2,)))   # one per slot
-    if quant:
-        # scale copies are a different byte count than page copies —
-        # they need their own per-slot counter (see kernel docstring)
-        scratch.append(pltpu.SemaphoreType.DMA((2,)))
     with _no_x64():
         out = pl.pallas_call(
             functools.partial(
-                kern, scale=s, page=page, q_block=qb,
-                g_pages=g, pages_per_seq=pages_per_seq),
+                _ragged_kernel, scale=s, page=page, q_block=qb, rep=rep,
+                g_pages=g, pages_per_seq=pages_per_seq, quant=quant),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,   # ctx, lengths, block tables
                 grid=grid,
                 in_specs=in_specs,
-                out_specs=pl.BlockSpec(
-                    (None, qb, rep, d),
-                    lambda hh, bb, qq, *_: (bb, qq, hh, 0)),
+                out_specs=q_spec,
                 scratch_shapes=scratch,
             ),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary",
                                      "arbitrary")),
-            out_shape=jax.ShapeDtypeStruct((b, c_p, h, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
             interpret=_interpret(),
+            name="ragged_paged_attention",
         )(ctx_lens.astype(jnp.int32), lengths.astype(jnp.int32),
           block_tables.astype(jnp.int32).reshape(-1), *operands)
-    return out[:, :c]
+    out = out[:, :, :c_p * rep].reshape(b, kvh, c_p, rep, d)
+    return out.transpose(0, 2, 1, 3, 4).reshape(b, c_p, h, d)[:, :c]
 
 
 # -- tunable surface ---------------------------------------------------------
@@ -406,26 +365,33 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
 # whole page list, so byte traffic scales with the q-block COUNT — the
 # trial engine times every valid candidate rather than trusting a
 # first-order roofline that would mispredict the DMA-overlap win of
-# larger page blocks. Shape key: (c, pages, page, d).
+# larger page blocks. Shape key: (c, pages, page, d). Only blocks the
+# TPU lowering takes as given are offered: q_block a multiple of 8 (so
+# q_block * rep rows are tile-aligned for every GQA ratio) or the whole
+# chunk — anything else _row_blocking would round, and a trial would
+# time a block it did not ask for.
+
+def _q_block_accepted(qb, c):
+    return qb >= c or qb % _SUBLANES == 0
+
 
 def _register_ragged_surface():
     from ...tuner.surface import TunableSurface, register_surface
 
     def _candidates(shape):
-        c = int(shape.get("c", 16))
-        pages = int(shape.get("pages", 8))
-        qbs = sorted({min(qb, c) for qb in (1, 8, 16, 32, 64, 128)
-                      if qb <= max(c, 1)})
-        gs = sorted({min(g, pages) for g in (1, 2, 4, 8, 16)
-                     if g <= max(pages, 1)})
+        c = max(int(shape.get("c", 16)), 1)
+        pages = max(int(shape.get("pages", 8)), 1)
+        qbs = sorted({qb for qb in (8, 16, 32, 64, 128) if qb < c} | {c})
+        gs = sorted({g for g in (1, 2, 4, 8, 16) if g <= pages})
         return [{"q_block": qb, "kv_pages_per_block": g}
                 for qb in qbs for g in gs]
 
     def _is_valid(config, shape):
-        c = int(shape.get("c", 16))
-        pages = int(shape.get("pages", 8))
-        return (1 <= config["q_block"] <= max(c, 1)
-                and 1 <= config["kv_pages_per_block"] <= max(pages, 1))
+        c = max(int(shape.get("c", 16)), 1)
+        pages = max(int(shape.get("pages", 8)), 1)
+        return (1 <= config["q_block"] <= c
+                and _q_block_accepted(config["q_block"], c)
+                and 1 <= config["kv_pages_per_block"] <= pages)
 
     register_surface(TunableSurface(
         name="ragged_paged_attention",

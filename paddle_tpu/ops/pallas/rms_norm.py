@@ -80,10 +80,38 @@ class force_rows_block:
         return False
 
 
+#: elements of one (rows, d) stream block. The residual backward keeps
+#: five such streams double-buffered next to its f32 temporaries, and
+#: the TPU compiler gives a kernel 16 MB of scoped VMEM: 256 rows fit
+#: up to d = 2560, and overflow at d = 4096 (16.0 MB fwd / 16.8 MB bwd,
+#: compiled for a described v5e). Half a million elements per stream
+#: keeps every width inside it.
+_BLOCK_ELEMS = 512 * 1024
+
+
+def _max_rows(d) -> int:
+    """Largest power-of-two row block the scoped-VMEM budget allows at
+    feature dim ``d`` (128 rows at 3584/4096, 256 at 2048, never < 8)."""
+    cap = max(8, _BLOCK_ELEMS // max(int(d), 1))
+    return 1 << (cap.bit_length() - 1)
+
+
+def _rows_valid(config, shape) -> bool:
+    b = config["block_rows"]
+    return b > 0 and b % 8 == 0 and b <= _max_rows(shape.get("d", 1))
+
+
+def _clamp_rows(want, n_rows, d, forced) -> int:
+    if d is not None and not forced:
+        want = min(want, _max_rows(d))   # a trial's block is its own
+    return min(want, -(-n_rows // 8) * 8)
+
+
 def _rows_block(n_rows: int, d: int | None = None, dtype=None) -> int:
-    """Rows per program, clamped to the (8-aligned) row count. The 256
-    default is the static pick; the tuner cache ("rms_norm" surface,
-    keyed by feature dim) overrides it when a sweep recorded a winner."""
+    """Rows per program, clamped to the (8-aligned) row count and to
+    what scoped VMEM holds at this width. 256 is the static pick; the
+    tuner cache ("rms_norm" surface, keyed by feature dim) overrides it
+    when a sweep recorded a winner."""
     want = 256
     forced = getattr(_forced_tls, "rows_block", None)
     if forced is not None:
@@ -93,7 +121,7 @@ def _rows_block(n_rows: int, d: int | None = None, dtype=None) -> int:
         cfg = lookup("rms_norm", {"d": int(d)}, str(dtype))
         if cfg:
             want = int(cfg.get("block_rows", want))
-    return min(want, -(-n_rows // 8) * 8)
+    return _clamp_rows(want, n_rows, d, forced is not None)
 
 
 def _pad_rows(a, n_pad):
@@ -177,8 +205,7 @@ def _register_rms_surface():
         default={"block_rows": 256},
         candidates=lambda shape: [{"block_rows": b}
                                   for b in (64, 128, 256, 512, 1024)],
-        is_valid=lambda config, shape: (config["block_rows"] % 8 == 0
-                                        and config["block_rows"] > 0),
+        is_valid=_rows_valid,
         describe="Rows per program of the fused RMSNorm fwd/dx kernels "
                  "(bandwidth-bound VMEM pass). Shape key: feature dim."))
 
@@ -259,7 +286,7 @@ def _res_rows_block(n_rows: int, d: int | None = None, dtype=None) -> int:
         cfg = lookup("rms_norm_residual", {"d": int(d)}, str(dtype))
         if cfg:
             want = int(cfg.get("block_rows", want))
-    return min(want, -(-n_rows // 8) * 8)
+    return _clamp_rows(want, n_rows, d, forced is not None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -346,8 +373,7 @@ def _register_rms_residual_surface():
         default={"block_rows": 256},
         candidates=lambda shape: [{"block_rows": b}
                                   for b in (64, 128, 256, 512, 1024)],
-        is_valid=lambda config, shape: (config["block_rows"] % 8 == 0
-                                        and config["block_rows"] > 0),
+        is_valid=_rows_valid,
         describe="Rows per program of the fused RMSNorm+residual "
                  "fwd/dh kernels (two streams in, two out — tuned "
                  "separately from plain rms_norm). Shape key: feature "

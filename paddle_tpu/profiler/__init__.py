@@ -321,8 +321,9 @@ class Profiler:
         avg = sum(self._step_times) / n
         print(f"steps: {n}  avg step time: {avg * 1e3:.3f} ms  "
               f"throughput: {1.0 / avg:.2f} steps/s")
-        sections = get_tracer().section_summary(
-            peak_flops=cost.device_peaks().flops)
+        # MFU/roofline against the device's table entry; on a device
+        # the table does not know the columns are left out
+        sections = get_tracer().section_summary()
         for name, a in sorted(sections.items(),
                               key=lambda kv: -kv[1]["total_ms"]):
             mfu_s = f"  MFU {a['mfu'] * 100:.1f}%" if "mfu" in a else ""

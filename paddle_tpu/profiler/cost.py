@@ -25,7 +25,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-__all__ = ["SectionCost", "Peaks", "device_peaks", "peak_flops",
+__all__ = ["SectionCost", "Peaks", "UnknownDeviceError", "device_peaks",
+           "known_peaks", "peak_flops",
            "matmul_cost", "attention_cost", "grouped_matmul_cost",
            "transformer_step_flops", "moe_section_costs", "mfu",
            "roofline", "rms_norm_cost", "swiglu_cost",
@@ -77,23 +78,41 @@ _PEAK_TABLE = (
     ("lite", Peaks(197e12, 819e9, "v5e")),
     ("v4", Peaks(275e12, 1228e9, "v4")),
 )
-_FALLBACK = Peaks(50e12, 100e9, "unknown")   # CPU/unknown: line still prints
+
+
+class UnknownDeviceError(LookupError):
+    """The device's kind is not in the peak table. An MFU or roofline
+    share against an invented peak would be a made-up number, so
+    callers either pass ``peak=``/``peaks=`` explicitly or leave the
+    column out."""
 
 
 def device_peaks(device=None) -> Peaks:
-    """Peaks for a jax device (default: first visible device). Unknown
-    kinds (CPU smoke runs) get a fallback so records still emit."""
+    """Peaks for a jax device (default: first visible device). A kind
+    the table does not know (CPU included) raises
+    :class:`UnknownDeviceError` — never a default."""
     if device is None:
-        try:
-            import jax
-            device = jax.devices()[0]
-        except Exception:
-            return _FALLBACK
+        import jax
+        device = jax.devices()[0]
     kind = getattr(device, "device_kind", "").lower()
-    for key, peaks in _PEAK_TABLE:
-        if key in kind:
-            return peaks
-    return _FALLBACK
+    if device.platform == "tpu":
+        for key, peaks in _PEAK_TABLE:
+            if key in kind:
+                return peaks
+    raise UnknownDeviceError(
+        f"no published peak for device kind {kind!r} "
+        f"(platform {device.platform!r}); pass the "
+        f"peak explicitly")
+
+
+def known_peaks(device=None) -> Peaks | None:
+    """:func:`device_peaks`, or None where the table has no entry — for
+    reports that leave their MFU/roofline columns out on such a device
+    instead of failing."""
+    try:
+        return device_peaks(device)
+    except UnknownDeviceError:
+        return None
 
 
 def peak_flops(device=None) -> float:

@@ -245,10 +245,16 @@ class Tracer:
 
     # -- summaries --------------------------------------------------------
 
-    def section_summary(self, peak_flops=None):
+    def section_summary(self, peak_flops=None, peaks=None):
         """Aggregate X events by name: count, total/mean ms, and — for
         spans annotated with ``flops``/``bytes`` — achieved FLOP/s, MFU
-        against ``peak_flops`` and the roofline classification."""
+        against ``peak_flops`` and the roofline classification against
+        ``peaks`` (default: the device's table entry; on a device the
+        table does not know, no roofline is reported)."""
+        from .cost import known_peaks, roofline
+        peaks = peaks or known_peaks()
+        if peak_flops is None and peaks is not None:
+            peak_flops = peaks.flops
         agg: dict[str, dict] = {}
         with self._lock:
             events = list(self.events)
@@ -267,9 +273,8 @@ class Tracer:
                 a["flops_per_s"] = a["flops"] / (a["total_ms"] / 1e3)
                 if peak_flops:
                     a["mfu"] = a["flops_per_s"] / peak_flops
-            if a["flops"] and a["bytes"]:
-                from .cost import roofline
-                a["roofline"] = roofline(a["flops"], a["bytes"])
+            if a["flops"] and a["bytes"] and peaks is not None:
+                a["roofline"] = roofline(a["flops"], a["bytes"], peaks)
         return agg
 
     # -- export -----------------------------------------------------------

@@ -20,7 +20,7 @@ both proven under ``paddle_tpu.testing.FaultInjector``
   starts empty — the sweep re-tunes, it does not traceback.
 
 Backend namespacing (the non-TPU-poisoning rule): the key's last
-component is ``backend:chip`` (e.g. ``tpu:v5e``, ``cpu:unknown``), so
+component is ``backend:chip`` (e.g. ``tpu:v5e``, ``cpu:cpu``), so
 configs timed under ``JAX_PLATFORMS=cpu`` land in a ``cpu:*`` namespace
 a TPU process never reads.
 """
@@ -57,33 +57,30 @@ _backend_memo: str | None = None
 
 
 def backend_signature(device=None) -> str:
-    """``backend:chip`` namespace component (``tpu:v5e``,
-    ``cpu:unknown``). jax is imported lazily and absence tolerated so
-    the cache stays usable from stdlib-only tooling. The default-
-    device answer is memoized — it is immutable for the process and
-    this runs on every trace-time kernel lookup."""
+    """``backend:chip`` namespace component (``tpu:v5e``, ``cpu:cpu``).
+    The default-device answer is memoized — it is immutable for the
+    process and this runs on every trace-time kernel lookup. A device
+    query that fails, or a TPU kind the peak table does not know,
+    raises: an entry is never filed under a made-up backend."""
     global _backend_memo
     if device is None and _backend_memo is not None:
         return _backend_memo
     memoize = device is None
-    try:
-        import jax
-        if device is None:
-            device = jax.devices()[0]
-        platform = str(getattr(device, "platform", "unknown")).lower()
-        kind = str(getattr(device, "device_kind", "") or "unknown")
-        kind = kind.lower().replace(" ", "_")
-        if platform == "tpu":
-            # normalize marketing names to the generation tag the
-            # profiler peak table keys on (profiler/cost.py)
-            from ..profiler.cost import device_peaks
-            kind = device_peaks(device).kind
-        sig = f"{platform}:{kind}"
-        if memoize:
-            _backend_memo = sig
-        return sig
-    except Exception:
-        return "cpu:unknown"  # NOT memoized: backend may init later
+    import jax
+    if device is None:
+        device = jax.devices()[0]
+    platform = str(device.platform).lower()
+    kind = str(getattr(device, "device_kind", "") or "unknown")
+    kind = kind.lower().replace(" ", "_")
+    if platform == "tpu":
+        # normalize marketing names to the generation tag the
+        # profiler peak table keys on (profiler/cost.py)
+        from ..profiler.cost import device_peaks
+        kind = device_peaks(device).kind
+    sig = f"{platform}:{kind}"
+    if memoize:
+        _backend_memo = sig
+    return sig
 
 
 def make_key(surface: str, shape_sig: str, dtype, backend: str) -> str:

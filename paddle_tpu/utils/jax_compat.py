@@ -1,50 +1,23 @@
-"""jax API compatibility shims.
+"""The one import site of ``shard_map``.
 
-``shard_map`` moved namespaces and grew a new partial-manual spelling
-across jax releases: newer jax exposes ``jax.shard_map`` at the root
-with an ``axis_names=`` parameter (the axes the region binds
-manually), while 0.4.x only has
-``jax.experimental.shard_map.shard_map`` whose partial-manual knob is
-the COMPLEMENT set ``auto=`` (the axes left automatic). Every call
-site imports :func:`shard_map` from here and writes the new-style
-``axis_names=``; the shim translates for old jax.
-
-tests/test_context_parallel.py carried the namespace fallback locally
-since PR 7; the library modules (distributed/zero_bubble.py,
-distributed/pipeline.py, fleet context_parallel, the EP MoE layer)
-hit the root-attribute AttributeError at runtime, which was 2 of the
-6 pre-existing tier-1 failures (test_zero_bubble).
+Every call site (distributed/zero_bubble.py, distributed/pipeline.py,
+fleet context_parallel, the EP MoE layer, the kernel wrappers under a
+fleet mesh) imports :func:`shard_map` from here and writes
+``axis_names=`` for a partial-manual region. pyproject.toml pins the
+jax minor; there is no branch for another release.
 """
 
 from __future__ import annotations
 
-import inspect
-
 __all__ = ["shard_map"]
 
-try:
-    from jax import shard_map as _impl  # type: ignore[attr-defined]
-except (ImportError, AttributeError):
-    from jax.experimental.shard_map import shard_map as _impl
-
-_HAS_AXIS_NAMES = "axis_names" in inspect.signature(_impl).parameters
+from jax import shard_map as _impl
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, **kw):
-    """``jax.shard_map`` with new-style ``axis_names`` on every jax.
-
-    ``axis_names=None`` (bind every mesh axis) passes straight
-    through. On old jax a partial set becomes ``auto = mesh axes -
-    axis_names``."""
-    if axis_names is None:
-        return _impl(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kw)
-    if _HAS_AXIS_NAMES:
-        return _impl(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, axis_names=set(axis_names),
-                     **kw)
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    if auto:
-        kw["auto"] = auto
+    """``jax.shard_map``; ``axis_names=None`` binds every mesh axis, a
+    partial set leaves the other axes to the compiler."""
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
     return _impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                  **kw)

@@ -1,0 +1,253 @@
+"""The Pallas kernels of the main paths, compiled for a DESCRIBED v5e.
+
+Interpret mode (every other kernel test) never checks what the TPU
+compiler checks: block shapes against the (8, 128) tiling, in-kernel
+relayouts, scoped VMEM, and that a Mosaic call under a mesh sits inside
+a ``shard_map``. The TPU compiler is installed here and compiles for a
+chip that is described, not attached — no chip time, about two seconds
+a case. Nothing runs, so this says nothing about results or speed;
+``chip_smoke.py`` is the run.
+
+Rules this file keeps (the ``on-chip-measurement`` guide, section 2):
+the topology is described inside a module-scoped, non-autouse fixture
+(never at import time, never in ``skipif``/``parametrize``), compiles
+happen in the test's own process, the persistent compilation cache is
+off around them, and all such tests live in this ONE file (only one
+process may load the TPU library).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "no compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip (the next one warns
+    and recompiles): keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def native(monkeypatch, no_persistent_cache):
+    """Compile the kernels natively: off-TPU each kernel module asks
+    ``_interpret()`` and would lower the interpreter instead. Steered
+    here, in the test — the program has no option for it."""
+    from paddle_tpu.ops.pallas import (ce_chunk, flash_attention,
+                                       grouped_matmul,
+                                       ragged_paged_attention, rms_norm,
+                                       swiglu)
+    for mod in (ce_chunk, flash_attention, grouped_matmul,
+                ragged_paged_attention, rms_norm, swiglu):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _compile(fn, *args):
+    """Lower + compile ``fn`` for the described chip; the compiled text
+    must hold the Mosaic custom call (i.e. the kernel, not a jnp
+    path)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _sds(sharding):
+    return lambda shape, dtype=BF16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+
+
+# ---- serving: ragged paged attention -------------------------------------
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("rep", [1, 4, 7])
+@pytest.mark.parametrize("c,page", [(1, 16), (16, 16), (64, 32)],
+                         ids=["decode", "chunk16", "chunk64_page32"])
+def test_ragged_paged_attention(native, one_chip, quant, rep, c, page):
+    """GQA ratios 1 (MHA), 4 (Llama-3-8B) and 7 (Qwen2-7B), d128, pure
+    decode and a prefill chunk, plain and quantized pools."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    s = _sds(one_chip)
+    kvh, b, d, pps, n_pages = 4, 8, 128, 64, 512
+    pool = s((kvh, n_pages, page, d), jnp.int8 if quant else BF16)
+    args = [s((b, c, kvh * rep, d)), pool, pool,
+            s((b, pps), jnp.int32), s((b,), jnp.int32),
+            s((b,), jnp.int32)]
+    if quant:
+        scales = s((kvh, n_pages, page), jnp.float32)
+        args += [scales, scales]
+
+        def fn(q, k, v, t, ctx, ln, ks, vs):
+            return ragged_paged_attention(q, k, v, t, ctx, ln,
+                                          k_scales=ks, v_scales=vs)
+    else:
+        fn = ragged_paged_attention
+    _compile(fn, *args)
+
+
+def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):
+    """Every block the tuner surface offers compiles AS GIVEN (a q
+    block the wrapper would have to round is not a candidate)."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+    from paddle_tpu.tuner.surface import get_surface
+    surf = get_surface("ragged_paged_attention")
+    shape = {"c": 48, "pages": 4, "page": 16, "d": 128}
+    cands = [c for c in surf.candidates(shape) if surf.is_valid(c, shape)]
+    assert cands and not surf.is_valid(
+        {"q_block": 4, "kv_pages_per_block": 1}, shape)
+    for cand in cands:
+        assert rpa._row_blocking(48, cand["q_block"], 7)[0] \
+            == cand["q_block"]
+    s = _sds(one_chip)
+    pool = s((4, 64, 16, 128))
+    for cand in (cands[0], cands[-1]):
+        _compile(lambda q, k, v, t, ctx, ln, _c=cand:
+                 rpa.ragged_paged_attention(
+                     q, k, v, t, ctx, ln, q_block=_c["q_block"],
+                     kv_pages_per_block=_c["kv_pages_per_block"]),
+                 s((2, 48, 28, 128)), pool, pool,
+                 s((2, 4), jnp.int32), s((2,), jnp.int32),
+                 s((2,), jnp.int32))
+
+
+# ---- training kernels ----------------------------------------------------
+
+def _grad_sum(fn):
+    """fwd+bwd of ``fn`` w.r.t. every argument, reduced to a scalar."""
+    def loss(*args):
+        out = fn(*args)
+        outs = out if isinstance(out, (tuple, list)) else (out,)
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in outs)
+    return lambda *args: jax.grad(loss, argnums=tuple(
+        range(len(args))))(*args)
+
+
+@pytest.mark.parametrize("d,heads", [(128, 28), (64, 12)],
+                         ids=["d128", "d64"])
+def test_flash_attention_fwd_bwd(native, one_chip, d, heads):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    s = _sds(one_chip)
+    q = s((2, 1024, heads, d))
+    text = _compile(_grad_sum(
+        lambda q, k, v: flash_attention(q, k, v, True, None)), q, q, q)
+    assert text.count("tpu_custom_call") >= 3       # fwd, dkv, dq
+
+
+@pytest.mark.parametrize("h", [3584, 4096])
+def test_rms_norm_residual_fwd_bwd(native, one_chip, h):
+    """Default row block at the widths where 256 rows overflow scoped
+    VMEM (16.0 MB fwd / 16.8 MB bwd at h4096): the block is derived
+    from the width."""
+    from paddle_tpu.ops.pallas.rms_norm import (rms_norm,
+                                                rms_norm_residual)
+    s = _sds(one_chip)
+    x = s((4096, h))
+    _compile(_grad_sum(lambda x, r, w: rms_norm_residual(x, r, w, 1e-6)),
+             x, x, s((h,)))
+    _compile(_grad_sum(lambda x, w: rms_norm(x, w, 1e-6)), x, s((h,)))
+
+
+def test_rms_surfaces_offer_only_blocks_that_fit():
+    from paddle_tpu.ops.pallas.rms_norm import _max_rows
+    from paddle_tpu.tuner.surface import get_surface
+    assert (_max_rows(2048), _max_rows(3584), _max_rows(4096)) \
+        == (256, 128, 128)
+    for name in ("rms_norm", "rms_norm_residual"):
+        surf = get_surface(name)
+        ok = [c["block_rows"] for c in surf.candidates({"d": 4096})
+              if surf.is_valid(c, {"d": 4096})]
+        assert ok == [64, 128]
+
+
+def test_swiglu_fwd_bwd(native, one_chip):
+    from paddle_tpu.ops.pallas.swiglu import swiglu_fused
+    g = _sds(one_chip)((2048, 18944))
+    _compile(_grad_sum(swiglu_fused), g, g)
+
+
+@pytest.mark.parametrize("chunk", [1024, 8192])
+def test_ce_chunk_pair(native, one_chip, chunk):
+    """The stats/dlogits pair at the default chunk and at the widest
+    one the ``fused_ce`` surface can select."""
+    from paddle_tpu.ops.pallas.ce_chunk import chunk_dlogits, chunk_stats
+    s = _sds(one_chip)
+    n = 8192
+    logits = s((n, chunk), jnp.float32)
+    vec_i, vec_f = s((n,), jnp.int32), s((n,), jnp.float32)
+    lo = s((), jnp.int32)
+    _compile(chunk_stats, logits, vec_i, lo)
+    _compile(lambda lg, lse, loc, sc, lo_: chunk_dlogits(
+        lg, lse, loc, sc, lo_, out_dtype=BF16),
+        logits, vec_f, vec_i, vec_f, lo)
+
+
+def test_grouped_matmul_fwd_bwd(native, one_chip):
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    s = _sds(one_chip)
+    e, d, h, bm, nr = 8, 2048, 1408, 128, 24
+    _compile(
+        lambda x, w, gid: jax.grad(
+            lambda x_, w_: jnp.sum(
+                grouped_matmul(x_, w_, gid).astype(jnp.float32)),
+            argnums=(0, 1))(x, w),
+        s((nr * bm, d)), s((e, d, h)), s((nr,), jnp.int32))
+
+
+# ---- under a mesh --------------------------------------------------------
+
+def test_kernels_under_a_2x2_mesh(native, topo):
+    """Mosaic kernels cannot be partitioned automatically. Under a
+    fleet mesh (sharding=2 x model=2, the ``--chips 4`` phase) the call
+    sites wrap them in ``shard_map`` over the axes their operands are
+    sharded on: flash attention with batch over ``sharding`` and heads
+    over ``model``, rms_norm with rows over ``sharding``."""
+    from paddle_tpu.ops.pallas._mesh import sharded_heads, sharded_rows
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    from paddle_tpu.ops.pallas.rms_norm import rms_norm
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2),
+                ("sharding", "model"))
+
+    def s(shape, spec, dtype=BF16):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    qkv = s((4, 1024, 16, 128), P("sharding", None, "model", None))
+    text = _compile(
+        _grad_sum(lambda q, k, v: sharded_heads(
+            lambda a, b, c: flash_attention(a, b, c, True, None),
+            mesh, q, k, v)), qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3
+    x = s((4, 1024, 2048), P("sharding", None, None))
+    _compile(_grad_sum(lambda x_, w: sharded_rows(
+        lambda a, b: rms_norm(a, b, 1e-6), mesh, x_, replicated=(w,))),
+        x, s((2048,), P()))

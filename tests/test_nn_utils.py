@@ -127,11 +127,6 @@ class TestSpectralNorm:
         g = dict(lin.named_parameters())
         assert g["weight_orig"].grad is not None
 
-    @pytest.mark.xfail(
-        reason="pre-existing: seed-3's 6x8 matrix has a slow eigengap — "
-               "30 single-iteration power steps converge sigma only to "
-               "~3%, outside the 1e-2 bar (u-persistence itself is "
-               "covered by the 20-iteration test above)", strict=False)
     def test_default_iterations_converge_across_forwards(self):
         # u must persist between calls: with n_power_iterations=1, sigma
         # converges over repeated forwards (torch/paddle semantics)

@@ -41,7 +41,11 @@ class TestMoeStepBreakdown:
         sort / a2a / expert-matmul / other rows summing to ~100% of the
         step, each with MFU + roofline columns where costed."""
         model, ids = self._model_and_ids()
-        bd = profiler.moe_step_breakdown(model, ids, steps=2, warmup=1)
+        # the CPU has no published peak: the MFU/roofline columns need
+        # one passed explicitly
+        bd = profiler.moe_step_breakdown(
+            model, ids, steps=2, warmup=1,
+            peaks=profiler.cost.Peaks(197e12, 819e9, "v5e"))
         d = bd.to_dict()
         assert d["step_ms"] > 0
         names = [r["section"] for r in d["sections"]]

@@ -60,11 +60,6 @@ class TestStaticNN:
         assert h.shape == (2, 2, 8)
         np.testing.assert_allclose(h.mean(-1), 0.0, atol=1e-5)
 
-    @pytest.mark.xfail(
-        reason="pre-existing: 25 SGD steps land at 0.503x of the "
-               "initial loss vs the 0.5x bar on this jax/seed — "
-               "marginal threshold miss, training itself works",
-        strict=False)
     def test_training_via_program_parameters(self):
         paddle.seed(0)
         prog = static.Program()

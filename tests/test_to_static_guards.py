@@ -350,11 +350,6 @@ def test_segmented_outputs_are_plain_arrays():
         else np.asarray(cmp._data).dtype == np.bool_
 
 
-@pytest.mark.xfail(
-    reason="pre-existing: jax<0.9 still accepts __jax_array__ coercion, "
-           "so paddle.any silently carries the lazy segment (correct "
-           "results, no 'eagerly' warning); the guarded path is "
-           "jax>=0.9 semantics", strict=False)
 def test_segment_unsafe_op_retries_eager():
     """A broken signature whose function uses an op that consumes raw
     arrays outside the apply() funnel (paddle.any here) cannot carry

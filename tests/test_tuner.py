@@ -237,7 +237,9 @@ def test_engine_roofline_pruning_skips_provably_worse(gcache):
         measured.append(cfg["a"])
         return 1.0
 
-    res = tengine.TrialEngine(gcache).search(
+    from paddle_tpu.profiler.cost import Peaks
+    res = tengine.TrialEngine(      # the CPU has no published peak
+        gcache, peaks=Peaks(197e12, 819e9, "v5e")).search(
         "syn_prune", {"n": 8}, measure_fn=measure)
     assert 3 not in measured                # pruned before measuring
     assert sorted(measured) == [1, 2]

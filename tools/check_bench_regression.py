@@ -328,6 +328,10 @@ def main(argv=None) -> int:
         return self_test()
 
     trajectory = load_trajectory(args.glob)
+    if not trajectory:
+        print(f"[bench-regr] no parsed trajectory record matches "
+              f"{args.glob} — nothing to compare", file=sys.stderr)
+        return 0
     if args.fresh is not None:
         try:
             fresh, flabel = load_record(args.fresh)

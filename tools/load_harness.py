@@ -20,7 +20,7 @@ what the CLIENT measured — the numbers the server cannot see:
   mid-stream after the first token (the cancel/reclaim path) and/or
   time out client-side;
 - **JSON report**: goodput, client-measured p50/p99 TTFT and
-  inter-token latency, delivered tok/s, bytes, and an error taxonomy
+  inter-token latency, delivered tok/s, bytes, and an error classes
   (HTTP status x typed SSE error), written to ``--report`` and echoed
   on stdout.
 
@@ -73,7 +73,7 @@ async def _read_body(reader, headers):
 async def do_request(host, port, payload, headers=None, stream=False,
                      disconnect_after_tokens=None, timeout_s=120.0):
     """One ``POST /v1/completions`` over a fresh connection. Returns a
-    result dict: ok, status, text, finish_reason, error (taxonomy
+    result dict: ok, status, text, finish_reason, error (class
     key), ttft_s, itl samples, bytes, trace_id."""
     t_send = time.perf_counter()
     res = {"ok": False, "status": 0, "text": "", "finish_reason": None,
@@ -594,7 +594,7 @@ def _pct(xs, q):
 
 def summarize(results, wall_s):
     """The JSON report: goodput + client-measured latency + error
-    taxonomy. ``goodput_frac`` counts streams that completed clean
+    classification. ``goodput_frac`` counts streams that completed clean
     over streams that were supposed to (injected disconnects are the
     CLIENT's fault and excluded from the denominator)."""
     ok = [r for r in results if r and r["ok"]]
@@ -602,10 +602,10 @@ def summarize(results, wall_s):
                 if r and r["error"] == "injected_disconnect"]
     failed = [r for r in results if r and not r["ok"]
               and r["error"] != "injected_disconnect"]
-    taxonomy = {}
+    err_classes = {}
     for r in failed:
         key = r["error"] or f"http_{r['status']}"
-        taxonomy[key] = taxonomy.get(key, 0) + 1
+        err_classes[key] = err_classes.get(key, 0) + 1
     ttfts = [r["ttft_s"] * 1e3 for r in ok if r["ttft_s"] is not None]
     itls = [v * 1e3 for r in ok for v in r["itls_s"]]
     toks = sum(len(r["text"].split()) for r in ok)
@@ -624,7 +624,7 @@ def summarize(results, wall_s):
         "itl_ms_p50": round(_pct(itls, 0.50), 3),
         "itl_ms_p99": round(_pct(itls, 0.99), 3),
         "bytes": sum(r["bytes"] for r in results if r),
-        "errors": taxonomy,
+        "errors": err_classes,
     }
 
 
