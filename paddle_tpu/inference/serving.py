@@ -90,6 +90,7 @@ Shared structure:
 
 from __future__ import annotations
 
+import contextlib
 import time
 import zlib
 from collections import deque
@@ -103,6 +104,7 @@ from ..framework.core import Tensor, no_grad
 from ..profiler import flight_recorder as _frec
 from ..profiler import metrics as _pmetrics
 from ..profiler.trace import get_trace_log as _get_trace_log
+from ..profiler.trace import trace_span as _span
 from .reliability import (MAX_HOPS as _MAX_HOPS, DeadlineExceeded,
                           RequestCancelled, RequestQuarantined,
                           record_hop)
@@ -141,7 +143,15 @@ _pmetrics.declare("serving/unified_steps", "counter",
 _pmetrics.declare("serving/requests_completed", "counter",
                   "requests finished (eos or length)")
 _pmetrics.declare("serving/run_seconds", "counter",
-                  "wall seconds spent inside run()")
+                  "wall seconds spent inside scheduler turns (step() "
+                  "calls and run()'s loop iterations)")
+_pmetrics.declare("serving/prefill_tokens", "counter",
+                  "prompt tokens carried by dispatched programs (sum "
+                  "of the per-slot chunk lengths)")
+_pmetrics.declare("serving/prefill_positions", "counter",
+                  "prompt positions computed by dispatched programs "
+                  "(num_slots x prefill_chunk per mixed pass or "
+                  "prefill wave, filled or not)")
 _pmetrics.declare("serving/ttft_ms", "histogram",
                   "request arrival -> first token on host, ms (bounded "
                   "reservoir; p50/p99 exposed via gauges())")
@@ -267,6 +277,7 @@ _STAT_KEYS = ("chunks", "chunk_slot_steps", "active_slot_steps",
               "tokens_emitted", "prefills", "prefills_overlapped",
               "prefill_waves", "chunks_empty", "unified_steps",
               "requests_completed", "run_seconds",
+              "prefill_tokens", "prefill_positions",
               # ISSUE-10 reliability counters ride the same view so
               # reset_gauges()/as_dict() cover them uniformly
               "preempt_evictions", "preempt_pages_reclaimed",
@@ -825,21 +836,24 @@ class ContinuousBatchingEngine:
                     ttft_deadline_s=None, deadline_s=None,
                     tenant=None) -> int:
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        self._check_fits(prompt.size, int(max_new_tokens))
-        req = ServedRequest(self._next_id, prompt, int(max_new_tokens),
-                            eos_token_id if eos_token_id is not None
-                            else (self.eos if self.eos >= 0 else None),
-                            priority=int(priority),
-                            ttft_deadline_s=ttft_deadline_s,
-                            deadline_s=deadline_s,
-                            tenant=tenant)
-        req.t_arrive = time.perf_counter()
-        self._next_id += 1
-        if req.priority:
-            self._has_priorities = True
-        if ttft_deadline_s is not None or deadline_s is not None:
-            self._lifecycle_seen = True
-        self.queue.append(req)
+        with _span("serving/add_request", request_id=self._next_id,
+                   prompt_len=prompt.size):
+            self._check_fits(prompt.size, int(max_new_tokens))
+            req = ServedRequest(
+                self._next_id, prompt, int(max_new_tokens),
+                eos_token_id if eos_token_id is not None
+                else (self.eos if self.eos >= 0 else None),
+                priority=int(priority),
+                ttft_deadline_s=ttft_deadline_s,
+                deadline_s=deadline_s,
+                tenant=tenant)
+            req.t_arrive = time.perf_counter()
+            self._next_id += 1
+            if req.priority:
+                self._has_priorities = True
+            if ttft_deadline_s is not None or deadline_s is not None:
+                self._lifecycle_seen = True
+            self.queue.append(req)
         return req.request_id
 
     def _check_fits(self, prompt_len, max_new):
@@ -1156,24 +1170,40 @@ class ContinuousBatchingEngine:
         chunk in legacy mode), drain finished slots. Returns the
         requests completed by this step. Step failures hit the same
         containment boundary as :meth:`run`."""
-        self._admit()
-        try:
-            if self._unified:
-                if self._worth_step():
-                    # spec engines speculate in step()-pumped drivers
-                    # too (ApiServer, fleet replicas), not just run()
-                    self._harvest_step(self._dispatch_spec_step()
-                                       if self._spec else
-                                       self._dispatch_step())
-            else:
-                self._pump_prefill()
-                if self.active.any():
-                    self._decode_chunk()
-        except Exception as exc:  # noqa: BLE001 — containment boundary
-            if not self._containable(exc):
-                raise
-            return self._contain_step_failure(exc) + self._drain()
-        return self._drain()
+        with self._turn():
+            self._admit()
+            try:
+                if self._unified:
+                    if self._worth_step():
+                        # spec engines speculate in step()-pumped
+                        # drivers too (ApiServer, fleet replicas), not
+                        # just run()
+                        self._harvest_step(self._dispatch_spec_step()
+                                           if self._spec else
+                                           self._dispatch_step())
+                else:
+                    self._pump_prefill()
+                    if self.active.any():
+                        self._decode_chunk()
+            except Exception as exc:  # noqa: BLE001 — containment boundary
+                if not self._containable(exc):
+                    raise
+                return self._contain_step_failure(exc) + self._drain()
+            return self._drain()
+
+    @contextlib.contextmanager
+    def _turn(self):
+        """One scheduler turn — a ``step()`` call or one iteration of
+        :meth:`_run_driver`'s loop: the ``serving/step`` span (``seq``:
+        the newest dispatched program when the turn closed) and the
+        wall seconds ``gauges()`` divides by, whoever pumps."""
+        t0 = time.perf_counter()
+        with _span("serving/step") as sp:
+            try:
+                yield
+            finally:
+                sp.set_args(seq=self._seq)
+                self._stats["run_seconds"] += time.perf_counter() - t0
 
     def run(self):
         """Drive until every queued request completes; returns them in
@@ -1291,24 +1321,69 @@ class ContinuousBatchingEngine:
                 return None
             return self._contain_step_failure(exc, cohort=cohort)
 
-        t_run0 = time.perf_counter()
         _wd_token = _frec.arm("serving run loop")
         try:
             while True:
-                # watchdog progress mark: a hung device fetch or a
-                # scheduler livelock stops the beats and the flight
-                # recorder dumps a diagnosable bundle (owner-token
-                # scoped: another component's beats cannot mask us)
-                _frec.beat(_wd_token)
-                if inflight is not None:
-                    # speculative successor first: device never
-                    # idles while the host harvests/drains/admits.
-                    # Containment wraps ONLY the compiled dispatch/
-                    # harvest — a host-side scheduler bug in
-                    # _admit/_drain/_reap is not a per-request fault
-                    # and must surface, not be laundered into strikes
+                with self._turn():
+                    # watchdog progress mark: a hung device fetch or a
+                    # scheduler livelock stops the beats and the flight
+                    # recorder dumps a diagnosable bundle (owner-token
+                    # scoped: another component's beats cannot mask us)
+                    _frec.beat(_wd_token)
+                    if inflight is not None:
+                        # speculative successor first: device never
+                        # idles while the host harvests/drains/admits.
+                        # Containment wraps ONLY the compiled dispatch/
+                        # harvest — a host-side scheduler bug in
+                        # _admit/_drain/_reap is not a per-request fault
+                        # and must surface, not be laundered into strikes
+                        try:
+                            nxt = spec_dispatch()
+                        except Exception as exc:  # noqa: BLE001
+                            extra = contained(exc)
+                            if extra is None:
+                                raise
+                            inflight = None
+                            done.extend(extra)
+                            continue
+                        try:
+                            harvest(inflight)
+                        except Exception as exc:  # noqa: BLE001
+                            # blame the HARVESTED program's dispatch-time
+                            # cohort (rec[1]), not whoever occupies the
+                            # slots now
+                            extra = contained(exc, cohort=inflight[1])
+                            if extra is None:
+                                raise
+                            inflight = None
+                            done.extend(extra)
+                            continue
+                        done.extend(self._drain())
+                        # admissions overlap nxt's on-device run — the
+                        # gauge distinguishing overlapped / serialized
+                        self._overlap_admission = nxt is not None
+                        try:
+                            self._admit()
+                            try:
+                                # legacy prefill waves ARE compiled
+                                # dispatches — containable; nxt is
+                                # abandoned with the rest of device state
+                                after_admit()
+                            except Exception as exc:  # noqa: BLE001
+                                extra = contained(exc)
+                                if extra is None:
+                                    raise
+                                nxt = None
+                                done.extend(extra)
+                        finally:
+                            self._overlap_admission = False
+                        inflight = nxt
+                        continue
+                    n_before = len(done)
+                    self._admit()
+                    done.extend(self._drain())
                     try:
-                        nxt = spec_dispatch()
+                        progressed, inflight = idle_turn()
                     except Exception as exc:  # noqa: BLE001
                         extra = contained(exc)
                         if extra is None:
@@ -1316,97 +1391,51 @@ class ContinuousBatchingEngine:
                         inflight = None
                         done.extend(extra)
                         continue
-                    try:
-                        harvest(inflight)
-                    except Exception as exc:  # noqa: BLE001
-                        # blame the HARVESTED program's dispatch-time
-                        # cohort (rec[1]), not whoever occupies the
-                        # slots now
-                        extra = contained(exc, cohort=inflight[1])
-                        if extra is None:
-                            raise
-                        inflight = None
-                        done.extend(extra)
+                    if progressed or len(done) > n_before:
+                        # a recovered wedge must not eat the deadlock
+                        # budget forever: the cap bounds CONSECUTIVE
+                        # fruitless evictions, not a run's lifetime total
+                        deadlock_evictions = 0
                         continue
-                    done.extend(self._drain())
-                    # admissions overlap nxt's on-device run — the
-                    # gauge distinguishing overlapped / serialized
-                    self._overlap_admission = nxt is not None
-                    try:
-                        self._admit()
+                    if not self.queue:
+                        break
+                    # nothing dispatched, harvested, drained or admitted
+                    # this turn, but requests still queued: overload always
+                    # progresses (slots drain -> pages free -> admission),
+                    # so something undrainable holds the pool
+                    occupied = [s for s in range(self.num_slots)
+                                if self.slot_req[s] is not None]
+                    if occupied and deadlock_evictions < max_deadlock:
+                        victim = min(occupied, key=lambda s: (
+                            self.slot_req[s].priority,
+                            -self.slot_req[s].t_admit))
+                        deadlock_evictions += 1
+                        self._evict_slot(victim, requeue=True,
+                                         reason="deadlock")
+                        continue
+                    # pool exhausted with no evictable occupant (or the
+                    # eviction budget burned without progress): a true
+                    # leak/deadlock. Dump a flight-recorder bundle first:
+                    # the ring's recent scheduler turns + pool state are
+                    # the post-mortem
+                    rec = _frec.get_recorder()
+                    if rec is not None:
+                        _frec.record_event(
+                            "serving_stall", queued=len(self.queue),
+                            free_pages=len(self._free_pages),
+                            occupied=len(occupied))
                         try:
-                            # legacy prefill waves ARE compiled
-                            # dispatches — containable; nxt is
-                            # abandoned with the rest of device state
-                            after_admit()
-                        except Exception as exc:  # noqa: BLE001
-                            extra = contained(exc)
-                            if extra is None:
-                                raise
-                            nxt = None
-                            done.extend(extra)
-                    finally:
-                        self._overlap_admission = False
-                    inflight = nxt
-                    continue
-                n_before = len(done)
-                self._admit()
-                done.extend(self._drain())
-                try:
-                    progressed, inflight = idle_turn()
-                except Exception as exc:  # noqa: BLE001
-                    extra = contained(exc)
-                    if extra is None:
-                        raise
-                    inflight = None
-                    done.extend(extra)
-                    continue
-                if progressed or len(done) > n_before:
-                    # a recovered wedge must not eat the deadlock
-                    # budget forever: the cap bounds CONSECUTIVE
-                    # fruitless evictions, not a run's lifetime total
-                    deadlock_evictions = 0
-                    continue
-                if not self.queue:
-                    break
-                # nothing dispatched, harvested, drained or admitted
-                # this turn, but requests still queued: overload always
-                # progresses (slots drain -> pages free -> admission),
-                # so something undrainable holds the pool
-                occupied = [s for s in range(self.num_slots)
-                            if self.slot_req[s] is not None]
-                if occupied and deadlock_evictions < max_deadlock:
-                    victim = min(occupied, key=lambda s: (
-                        self.slot_req[s].priority,
-                        -self.slot_req[s].t_admit))
-                    deadlock_evictions += 1
-                    self._evict_slot(victim, requeue=True,
-                                     reason="deadlock")
-                    continue
-                # pool exhausted with no evictable occupant (or the
-                # eviction budget burned without progress): a true
-                # leak/deadlock. Dump a flight-recorder bundle first:
-                # the ring's recent scheduler turns + pool state are
-                # the post-mortem
-                rec = _frec.get_recorder()
-                if rec is not None:
-                    _frec.record_event(
-                        "serving_stall", queued=len(self.queue),
-                        free_pages=len(self._free_pages),
-                        occupied=len(occupied))
-                    try:
-                        rec.dump("serving engine stalled: queued "
-                                 "request cannot be admitted")
-                    except OSError:
-                        pass    # the diagnostic RuntimeError below
-                                # must not be replaced by a failed
-                                # bundle write
-                raise RuntimeError(
-                    "serving engine stalled: queued request cannot "
-                    "be admitted (page pool exhausted?)")
+                            rec.dump("serving engine stalled: queued "
+                                     "request cannot be admitted")
+                        except OSError:
+                            pass    # the diagnostic RuntimeError below
+                                    # must not be replaced by a failed
+                                    # bundle write
+                    raise RuntimeError(
+                        "serving engine stalled: queued request cannot "
+                        "be admitted (page pool exhausted?)")
         finally:
             _frec.disarm(_wd_token)
-            self._stats["run_seconds"] += time.perf_counter() - t_run0
             self._emit_gauges()
         return done
 
@@ -1658,11 +1687,12 @@ class ContinuousBatchingEngine:
         self._compiled.add(("unified", C, 1 + n_dec))
         return self._unified_fn
 
-    def _dispatch_step(self):
-        """Launch one unified step (async) and chain the device state.
-        Returns an in-flight record for :meth:`_harvest_step` — the
-        packed output is NOT fetched here, so a caller may overlap the
-        fetch with the next step's on-device compute."""
+    def _stage_prompt_chunks(self):
+        """The next ``prefill_chunk`` prompt tokens of every prefilling
+        slot (at most ``admit_batch`` of them), as the step programs
+        take them: ``ids [B, C]``, ``nq`` (tokens staged per slot),
+        ``last`` (the prompt ends in this chunk), ``tgt`` (the slot
+        decodes afterwards), and how many slots carry a chunk."""
         B, C = self.num_slots, self.prefill_chunk
         ids = np.zeros((B, C), np.int32)
         nq = np.zeros((B,), np.int32)
@@ -1680,16 +1710,14 @@ class ContinuousBatchingEngine:
             last[slot] = off + v == len(prm)
             tgt[slot] = self._act_target[slot]
             n_pre += 1
-        fn = self._unified_static()
-        self._seq += 1
-        self._last_fetch_dispatch_seq = self._seq
-        n_steps = 1 + self._n_decode
-        # a slot advances this step if it decodes with budget left OR
-        # streams prompt tokens (a completing prompt decodes the
-        # in-program tail too, so its tokens must be credited here)
-        n_active = int(np.sum((self.active
-                               & (self.limits > self._pred_ctx))
-                              | (nq > 0)))
+        return ids, nq, last, tgt, n_pre
+
+    def _count_dispatch(self, sp, mode, n_steps, n_active, n_pre,
+                        n_tok):
+        """The counters, the flight-recorder turn and the
+        ``serving/dispatch`` span's args of one dispatched unified
+        step (plain or speculative)."""
+        B = self.num_slots
         _t_obs = time.perf_counter()
         self._stats.inc("chunks")
         self._stats.inc("unified_steps")
@@ -1697,116 +1725,149 @@ class ContinuousBatchingEngine:
         if n_pre:
             self._stats.inc("prefill_waves")
         self._stats.inc("active_slot_steps", n_active * n_steps)
+        # how full the mixed pass is: it computes every slot's
+        # prefill_chunk positions whether or not they hold a token
+        self._stats.inc("prefill_tokens", n_tok)
+        self._stats.inc("prefill_positions", B * self.prefill_chunk)
         from ..profiler.trace import get_tracer
         _tr = get_tracer()
         if _tr.enabled:
             _tr.counter("serving/active_slots", n_active,
                         queued=len(self.queue), chunk_len=n_steps,
                         prefilling=n_pre)
-        _frec.record_event("sched_turn", seq=self._seq, mode="unified",
+        _frec.record_event("sched_turn", seq=self._seq, mode=mode,
                            active=n_active, queued=len(self.queue),
                            prefilling=n_pre, chunk_len=n_steps)
+        sp.set_args(seq=self._seq, active=n_active, prefilling=n_pre,
+                    prefill_tokens=n_tok, chunk_len=n_steps)
         self._obs_s += time.perf_counter() - _t_obs
-        res = fn(Tensor(jnp.asarray(ids)), Tensor(jnp.asarray(nq)),
-                 Tensor(jnp.asarray(last)), Tensor(jnp.asarray(tgt)),
-                 Tensor(self._dev_tok), Tensor(self._dev_ctx),
-                 Tensor(self._dev_act), Tensor(self._dev_tbl),
-                 Tensor(self._dev_lim), Tensor(self._dev_eos),
-                 Tensor(self._key), *self.pools)
-        packed, tok_f, ctx_f, act_f, key_f = res[:5]
-        self.pools = list(res[5:])
-        self._dev_tok = tok_f._data
-        self._dev_ctx = ctx_f._data
-        self._dev_act = act_f._data
-        self._key = key_f._data
-        # host bookkeeping: prompt-stream progress is exact; decode
-        # activity is a prediction refined by the harvested mirrors
-        emits = np.zeros((B,), bool)
-        for slot in range(B):
-            if nq[slot] > 0:
-                self._prefill_off[slot] += nq[slot]
-                if last[slot]:
-                    req = self.slot_req[slot]
-                    tl = len(self._slot_prompt[slot])
-                    req.t_prefill_done = time.perf_counter()
-                    self._prefilling[slot] = False
-                    self.ctx[slot] = tl
-                    # the first token + in-program decode tail land in
-                    # THIS step; mirrors from any EARLIER in-flight
-                    # step must not clobber the activation
-                    self.active[slot] = bool(tgt[slot])
-                    self._act_since[slot] = self._seq
+
+    def _dispatch_step(self):
+        """Launch one unified step (async) and chain the device state.
+        Returns an in-flight record for :meth:`_harvest_step` — the
+        packed output is NOT fetched here, so a caller may overlap the
+        fetch with the next step's on-device compute."""
+        with _span("serving/dispatch") as sp:
+            B = self.num_slots
+            with _span("serving/dispatch.stage"):
+                ids, nq, last, tgt, n_pre = self._stage_prompt_chunks()
+                staged = [Tensor(jnp.asarray(a))
+                          for a in (ids, nq, last, tgt)]
+            fn = self._unified_static()
+            self._seq += 1
+            self._last_fetch_dispatch_seq = self._seq
+            n_steps = 1 + self._n_decode
+            # a slot advances this step if it decodes with budget left OR
+            # streams prompt tokens (a completing prompt decodes the
+            # in-program tail too, so its tokens must be credited here)
+            n_active = int(np.sum((self.active
+                                   & (self.limits > self._pred_ctx))
+                                  | (nq > 0)))
+            self._count_dispatch(sp, "unified", n_steps, n_active, n_pre,
+                                 int(nq.sum()))
+            with _span("serving/dispatch.launch"):
+                res = fn(*staged,
+                         Tensor(self._dev_tok), Tensor(self._dev_ctx),
+                         Tensor(self._dev_act), Tensor(self._dev_tbl),
+                         Tensor(self._dev_lim), Tensor(self._dev_eos),
+                         Tensor(self._key), *self.pools)
+            packed, tok_f, ctx_f, act_f, key_f = res[:5]
+            self.pools = list(res[5:])
+            self._dev_tok = tok_f._data
+            self._dev_ctx = ctx_f._data
+            self._dev_act = act_f._data
+            self._key = key_f._data
+            # host bookkeeping: prompt-stream progress is exact; decode
+            # activity is a prediction refined by the harvested mirrors
+            emits = np.zeros((B,), bool)
+            for slot in range(B):
+                if nq[slot] > 0:
+                    self._prefill_off[slot] += nq[slot]
+                    if last[slot]:
+                        req = self.slot_req[slot]
+                        tl = len(self._slot_prompt[slot])
+                        req.t_prefill_done = time.perf_counter()
+                        self._prefilling[slot] = False
+                        self.ctx[slot] = tl
+                        # the first token + in-program decode tail land in
+                        # THIS step; mirrors from any EARLIER in-flight
+                        # step must not clobber the activation
+                        self.active[slot] = bool(tgt[slot])
+                        self._act_since[slot] = self._seq
+                        self._pred_ctx[slot] = min(
+                            int(self.limits[slot]), tl + self._n_decode)
+                        # the prompt's full pages are final now (decode
+                        # writes land past tl): publish them for sharing
+                        self._pc_insert(slot)
+                        emits[slot] = True
+                elif self.active[slot] \
+                        and self.limits[slot] > self._pred_ctx[slot]:
                     self._pred_ctx[slot] = min(
-                        int(self.limits[slot]), tl + self._n_decode)
-                    # the prompt's full pages are final now (decode
-                    # writes land past tl): publish them for sharing
-                    self._pc_insert(slot)
+                        int(self.limits[slot]),
+                        int(self._pred_ctx[slot]) + n_steps)
                     emits[slot] = True
-            elif self.active[slot] \
-                    and self.limits[slot] > self._pred_ctx[slot]:
-                self._pred_ctx[slot] = min(
-                    int(self.limits[slot]),
-                    int(self._pred_ctx[slot]) + n_steps)
-                emits[slot] = True
-        self._emits_inflight += emits.astype(np.int32)
-        return (packed, list(self.slot_req), emits, n_steps, self._seq)
+            self._emits_inflight += emits.astype(np.int32)
+            return (packed, list(self.slot_req), emits, n_steps, self._seq)
 
     def _harvest_step(self, rec):
         """Fetch one in-flight unified step's packed output and apply
         it: append emitted tokens, refresh the ctx/active mirrors
         (unless the slot was re-admitted, or activated by a LATER
         dispatch, since this step went out)."""
-        packed, snap_req, emits, n_steps, seq = rec
-        arr = np.asarray(packed._data)            # the ONE fetch
-        self._last_harvest_seq = max(self._last_harvest_seq, seq)
-        self._release_deferred()
-        toks_np = arr[:, :n_steps]
-        emitted_np = arr[:, n_steps:2 * n_steps].astype(bool)
-        ctx_m = arr[:, 2 * n_steps].astype(np.int32)
-        act_m = arr[:, 2 * n_steps + 1].astype(bool)
-        t_now = time.perf_counter()
-        appended = 0
-        for slot in range(self.num_slots):
-            req = snap_req[slot]
-            if req is not self.slot_req[slot]:
-                continue      # slot re-admitted since this dispatch
-            if emits[slot]:
-                self._emits_inflight[slot] -= 1
-            if self._act_since[slot] <= seq:
-                self.ctx[slot] = ctx_m[slot]
-                self.active[slot] = act_m[slot]
-                self._pred_ctx[slot] = max(int(self._pred_ctx[slot]),
-                                           int(ctx_m[slot]))
-            if req is None or req.finished:
-                continue
-            # a clean harvest exonerates its riders: one solo step
-            # clears a suspect, so a containment cannot serialize the
-            # whole batch into solo-to-completion replays
-            req.strikes = 0
-            for j in range(n_steps):
-                if emitted_np[slot, j]:
-                    if not req.tokens:
-                        req.t_first = t_now
-                    req.tokens.append(int(toks_np[slot, j]))
-                    appended += 1
-        _t_obs = time.perf_counter()
-        self._stats.inc("tokens_emitted", appended)
-        if appended == 0:
-            self._stats.inc("chunks_empty")
-        # a SPEC step's packed output carries two extra accounting
-        # columns (committed-draft and drafted counts per slot) past
-        # the layout this method parses — fold them into the spec
-        # economics counters
-        if arr.shape[1] > 2 * n_steps + 2:
-            nds = arr[:, 2 * n_steps + 3]
-            accs = arr[:, 2 * n_steps + 2]
-            drafted = int(nds.sum())
-            if drafted:
-                committed = int(accs.sum())
-                self._c_spec_drafted.inc(drafted)
-                self._c_spec_accepted.inc(committed)
-                self._c_spec_rejected.inc(drafted - committed)
-        self._obs_s += time.perf_counter() - _t_obs
+        with _span("serving/harvest") as sp:
+            packed, snap_req, emits, n_steps, seq = rec
+            with _span("serving/harvest.fetch"):
+                arr = np.asarray(packed._data)        # the ONE fetch
+            self._last_harvest_seq = max(self._last_harvest_seq, seq)
+            self._release_deferred()
+            toks_np = arr[:, :n_steps]
+            emitted_np = arr[:, n_steps:2 * n_steps].astype(bool)
+            ctx_m = arr[:, 2 * n_steps].astype(np.int32)
+            act_m = arr[:, 2 * n_steps + 1].astype(bool)
+            t_now = time.perf_counter()
+            appended = 0
+            for slot in range(self.num_slots):
+                req = snap_req[slot]
+                if req is not self.slot_req[slot]:
+                    continue      # slot re-admitted since this dispatch
+                if emits[slot]:
+                    self._emits_inflight[slot] -= 1
+                if self._act_since[slot] <= seq:
+                    self.ctx[slot] = ctx_m[slot]
+                    self.active[slot] = act_m[slot]
+                    self._pred_ctx[slot] = max(int(self._pred_ctx[slot]),
+                                               int(ctx_m[slot]))
+                if req is None or req.finished:
+                    continue
+                # a clean harvest exonerates its riders: one solo step
+                # clears a suspect, so a containment cannot serialize the
+                # whole batch into solo-to-completion replays
+                req.strikes = 0
+                for j in range(n_steps):
+                    if emitted_np[slot, j]:
+                        if not req.tokens:
+                            req.t_first = t_now
+                        req.tokens.append(int(toks_np[slot, j]))
+                        appended += 1
+            _t_obs = time.perf_counter()
+            self._stats.inc("tokens_emitted", appended)
+            if appended == 0:
+                self._stats.inc("chunks_empty")
+            # a SPEC step's packed output carries two extra accounting
+            # columns (committed-draft and drafted counts per slot) past
+            # the layout this method parses — fold them into the spec
+            # economics counters
+            if arr.shape[1] > 2 * n_steps + 2:
+                nds = arr[:, 2 * n_steps + 3]
+                accs = arr[:, 2 * n_steps + 2]
+                drafted = int(nds.sum())
+                if drafted:
+                    committed = int(accs.sum())
+                    self._c_spec_drafted.inc(drafted)
+                    self._c_spec_accepted.inc(committed)
+                    self._c_spec_rejected.inc(drafted - committed)
+            sp.set_args(seq=seq, appended=appended)
+            self._obs_s += time.perf_counter() - _t_obs
 
     # ---- speculative decoding (ISSUE 18) ---------------------------------
 
@@ -1989,102 +2050,75 @@ class ContinuousBatchingEngine:
         ``limits - ctx - 1`` so every verify write stays inside the
         slot's allocated table row. Runs serially (dispatch → harvest)
         — see :meth:`run`."""
-        B, C, K = self.num_slots, self.prefill_chunk, self._spec_k
-        ids = np.zeros((B, C), np.int32)
-        nq = np.zeros((B,), np.int32)
-        last = np.zeros((B,), bool)
-        tgt = np.zeros((B,), bool)
-        nd = np.zeros((B,), np.int32)
-        n_pre = 0
-        for slot in range(B):
-            if not self._prefilling[slot] or n_pre >= self.admit_batch:
-                continue
-            prm = self._slot_prompt[slot]
-            off = int(self._prefill_off[slot])
-            v = min(C, len(prm) - off)
-            ids[slot, :v] = prm[off:off + v]
-            nq[slot] = v
-            last[slot] = off + v == len(prm)
-            tgt[slot] = self._act_target[slot]
-            n_pre += 1
-        drafting = [s for s in range(B)
-                    if self.active[s] and not self._prefilling[s]
-                    and self.slot_req[s] is not None
-                    and int(self.limits[s]) - int(self.ctx[s]) > 1]
-        if drafting:
-            drafts, counts = self._spec_source.propose(
-                self, drafting, K)
-            for s in drafting:
-                c = min(int(counts[s]), K,
-                        int(self.limits[s]) - int(self.ctx[s]) - 1)
-                if c > 0:
-                    ids[s, 1:1 + c] = drafts[s, :c]
-                    nd[s] = c
-        fn = self._unified_spec_static()
-        self._seq += 1
-        self._last_fetch_dispatch_seq = self._seq
-        n_steps = 1 + K
-        n_active = int(np.sum((self.active
-                               & (self.limits > self._pred_ctx))
-                              | (nq > 0)))
-        _t_obs = time.perf_counter()
-        self._stats.inc("chunks")
-        self._stats.inc("unified_steps")
-        self._stats.inc("chunk_slot_steps", B * n_steps)
-        if n_pre:
-            self._stats.inc("prefill_waves")
-        self._stats.inc("active_slot_steps", n_active * n_steps)
-        self._c_spec_steps.inc()
-        from ..profiler.trace import get_tracer
-        _tr = get_tracer()
-        if _tr.enabled:
-            _tr.counter("serving/active_slots", n_active,
-                        queued=len(self.queue), chunk_len=n_steps,
-                        prefilling=n_pre)
-        _frec.record_event("sched_turn", seq=self._seq, mode="spec",
-                           active=n_active, queued=len(self.queue),
-                           prefilling=n_pre, chunk_len=n_steps)
-        self._obs_s += time.perf_counter() - _t_obs
-        res = fn(Tensor(jnp.asarray(ids)), Tensor(jnp.asarray(nq)),
-                 Tensor(jnp.asarray(last)), Tensor(jnp.asarray(tgt)),
-                 Tensor(jnp.asarray(nd)),
-                 Tensor(self._dev_tok), Tensor(self._dev_ctx),
-                 Tensor(self._dev_act), Tensor(self._dev_tbl),
-                 Tensor(self._dev_lim), Tensor(self._dev_eos),
-                 Tensor(self._key), *self.pools)
-        packed, tok_f, ctx_f, act_f, key_f = res[:5]
-        self.pools = list(res[5:])
-        self._dev_tok = tok_f._data
-        self._dev_ctx = ctx_f._data
-        self._dev_act = act_f._data
-        self._key = key_f._data
-        emits = np.zeros((B,), bool)
-        for slot in range(B):
-            if nq[slot] > 0:
-                self._prefill_off[slot] += nq[slot]
-                if last[slot]:
-                    req = self.slot_req[slot]
-                    tl = len(self._slot_prompt[slot])
-                    req.t_prefill_done = time.perf_counter()
-                    self._prefilling[slot] = False
-                    self.ctx[slot] = tl
-                    self.active[slot] = bool(tgt[slot])
-                    self._act_since[slot] = self._seq
-                    # the spec step has NO in-program decode tail:
-                    # exactly the first token lands this turn
-                    self._pred_ctx[slot] = tl
-                    self._pc_insert(slot)
+        with _span("serving/dispatch") as sp:
+            B, K = self.num_slots, self._spec_k
+            with _span("serving/dispatch.stage"):
+                ids, nq, last, tgt, n_pre = self._stage_prompt_chunks()
+                nd = np.zeros((B,), np.int32)
+                drafting = [s for s in range(B)
+                            if self.active[s] and not self._prefilling[s]
+                            and self.slot_req[s] is not None
+                            and int(self.limits[s]) - int(self.ctx[s]) > 1]
+                if drafting:
+                    drafts, counts = self._spec_source.propose(
+                        self, drafting, K)
+                    for s in drafting:
+                        c = min(int(counts[s]), K,
+                                int(self.limits[s]) - int(self.ctx[s]) - 1)
+                        if c > 0:
+                            ids[s, 1:1 + c] = drafts[s, :c]
+                            nd[s] = c
+                staged = [Tensor(jnp.asarray(a))
+                          for a in (ids, nq, last, tgt, nd)]
+            fn = self._unified_spec_static()
+            self._seq += 1
+            self._last_fetch_dispatch_seq = self._seq
+            n_steps = 1 + K
+            n_active = int(np.sum((self.active
+                                   & (self.limits > self._pred_ctx))
+                                  | (nq > 0)))
+            self._c_spec_steps.inc()
+            self._count_dispatch(sp, "spec", n_steps, n_active, n_pre,
+                                 int(nq.sum()))
+            with _span("serving/dispatch.launch"):
+                res = fn(*staged,
+                         Tensor(self._dev_tok), Tensor(self._dev_ctx),
+                         Tensor(self._dev_act), Tensor(self._dev_tbl),
+                         Tensor(self._dev_lim), Tensor(self._dev_eos),
+                         Tensor(self._key), *self.pools)
+            packed, tok_f, ctx_f, act_f, key_f = res[:5]
+            self.pools = list(res[5:])
+            self._dev_tok = tok_f._data
+            self._dev_ctx = ctx_f._data
+            self._dev_act = act_f._data
+            self._key = key_f._data
+            emits = np.zeros((B,), bool)
+            for slot in range(B):
+                if nq[slot] > 0:
+                    self._prefill_off[slot] += nq[slot]
+                    if last[slot]:
+                        req = self.slot_req[slot]
+                        tl = len(self._slot_prompt[slot])
+                        req.t_prefill_done = time.perf_counter()
+                        self._prefilling[slot] = False
+                        self.ctx[slot] = tl
+                        self.active[slot] = bool(tgt[slot])
+                        self._act_since[slot] = self._seq
+                        # the spec step has NO in-program decode tail:
+                        # exactly the first token lands this turn
+                        self._pred_ctx[slot] = tl
+                        self._pc_insert(slot)
+                        emits[slot] = True
+                elif self.active[slot] \
+                        and self.limits[slot] > self._pred_ctx[slot]:
+                    # at least the target sample always lands; the exact
+                    # accepted length arrives with the harvest mirrors
+                    self._pred_ctx[slot] = min(
+                        int(self.limits[slot]),
+                        int(self._pred_ctx[slot]) + 1)
                     emits[slot] = True
-            elif self.active[slot] \
-                    and self.limits[slot] > self._pred_ctx[slot]:
-                # at least the target sample always lands; the exact
-                # accepted length arrives with the harvest mirrors
-                self._pred_ctx[slot] = min(
-                    int(self.limits[slot]),
-                    int(self._pred_ctx[slot]) + 1)
-                emits[slot] = True
-        self._emits_inflight += emits.astype(np.int32)
-        return (packed, list(self.slot_req), emits, n_steps, self._seq)
+            self._emits_inflight += emits.astype(np.int32)
+            return (packed, list(self.slot_req), emits, n_steps, self._seq)
 
     def gauges(self) -> dict:
         """Serving observability surface (profiler subsystem):
@@ -2095,11 +2129,21 @@ class ContinuousBatchingEngine:
           the drain/re-admit idle share specifically.
         - ``prefill_overlap_frac``: admissions made while a decode chunk
           was in flight (prefill waves then overlap its on-device run).
-        - ``tokens_per_s``: emitted tokens / wall seconds inside run().
-        - ``ttft_ms_p50/p99``: request-arrival → first-token-on-host
-          percentiles (completed requests).
-        - ``itl_ms_p50/p99``: smoothed inter-token latency percentiles —
-          (t_done - t_first) / (tokens - 1) per request with ≥2 tokens.
+        - ``tokens_per_s``: emitted tokens / wall seconds inside
+          scheduler turns (``serving/step`` spans), whoever pumps them:
+          ``run()``, or ``step()`` from an ApiServer / fleet replica.
+        - ``ttft_ms_p50/p99``: first-token-on-host percentiles of
+          completed requests. The clock starts at ``add_request``, not
+          when the request was due at the caller.
+        - ``itl_ms_p50/p99``: percentiles of a PER-REQUEST MEAN —
+          (t_done - t_first) / (tokens - 1) of each request with ≥2
+          tokens — not of single token gaps: a request's longest gap
+          is averaged away.
+        - ``prefill_tokens`` / ``prefill_positions`` / ``prefill_fill``:
+          prompt tokens carried by the dispatched programs, the prompt
+          positions those programs computed (``num_slots x
+          prefill_chunk`` per mixed pass, filled or not), and their
+          ratio — how full the padded prefill compute is.
         - ``compiled_programs``: distinct compiled signatures this
           engine built — steady-state 1 in unified mode (the single
           batching-step program); 1 prefill + the decode-chunk-length
@@ -2139,6 +2183,11 @@ class ContinuousBatchingEngine:
             "requests_completed": s["requests_completed"],
             "obs_overhead_frac": (self._obs_s / s["run_seconds"])
             if s["run_seconds"] else 0.0,
+            "prefill_tokens": s["prefill_tokens"],
+            "prefill_positions": s["prefill_positions"],
+            "prefill_fill": (s["prefill_tokens"]
+                             / s["prefill_positions"])
+            if s["prefill_positions"] else 0.0,
             # reliability surface (ISSUE 10): overload economics
             "preempt_evictions": s["preempt_evictions"],
             "preempt_recompute_tokens": s["preempt_recompute_tokens"],
@@ -2695,6 +2744,15 @@ class ContinuousBatchingEngine:
         return done
 
     def _admit(self):
+        """One admission pass (:meth:`_admit_queued`) under the
+        ``serving/admit`` span."""
+        with _span("serving/admit") as sp:
+            n0 = self._stats["prefills"]
+            self._admit_queued()
+            sp.set_args(admitted=self._stats["prefills"] - n0,
+                        queued=len(self.queue))
+
+    def _admit_queued(self):
         """Move queued requests into free slots: allocate pages, stage
         per-slot state, and mark the slot PREFILLING — the prompt itself
         streams through the batched prefill-chunk program in
@@ -2944,12 +3002,19 @@ class ContinuousBatchingEngine:
             fn = self._prefill_static()
             self._seq += 1
             self._stats["prefill_waves"] += 1
-            res = fn(Tensor(jnp.asarray(ids)), Tensor(jnp.asarray(pstart)),
-                     Tensor(jnp.asarray(valid)), Tensor(jnp.asarray(last)),
-                     Tensor(jnp.asarray(tgt)), Tensor(self._dev_tok),
-                     Tensor(self._dev_ctx), Tensor(self._dev_act),
-                     Tensor(self._dev_tbl), Tensor(self._key),
-                     *self.pools)
+            n_tok = int(valid.sum())
+            self._stats.inc("prefill_tokens", n_tok)
+            self._stats.inc("prefill_positions", B * C)
+            with _span("serving/dispatch", seq=self._seq,
+                       prefilling=len(batched), prefill_tokens=n_tok):
+                res = fn(Tensor(jnp.asarray(ids)),
+                         Tensor(jnp.asarray(pstart)),
+                         Tensor(jnp.asarray(valid)),
+                         Tensor(jnp.asarray(last)),
+                         Tensor(jnp.asarray(tgt)), Tensor(self._dev_tok),
+                         Tensor(self._dev_ctx), Tensor(self._dev_act),
+                         Tensor(self._dev_tbl), Tensor(self._key),
+                         *self.pools)
             tok2, ctx2, act2, key2 = res[:4]
             self.pools = list(res[4:])
             self._dev_tok = tok2._data
@@ -3107,10 +3172,12 @@ class ContinuousBatchingEngine:
                            active=n_active, queued=len(self.queue),
                            chunk_len=n)
         self._obs_s += time.perf_counter() - _t_obs
-        res = fn(Tensor(self._dev_tok), Tensor(self._dev_ctx),
-                 Tensor(self._dev_act), Tensor(self._dev_tbl),
-                 Tensor(self._dev_lim), Tensor(self._dev_eos),
-                 Tensor(self._key), *self.pools)
+        with _span("serving/dispatch", seq=self._seq, active=n_active,
+                   chunk_len=n):
+            res = fn(Tensor(self._dev_tok), Tensor(self._dev_ctx),
+                     Tensor(self._dev_act), Tensor(self._dev_tbl),
+                     Tensor(self._dev_lim), Tensor(self._dev_eos),
+                     Tensor(self._key), *self.pools)
         packed, tok_f, ctx_f, act_f, key_f = res[:5]
         self.pools = list(res[5:])
         self._dev_tok = tok_f._data
@@ -3133,56 +3200,59 @@ class ContinuousBatchingEngine:
 
     def _harvest_chunk(self, rec):
         """Fetch one in-flight chunk's packed output and apply it."""
-        packed, snap_req, pending, n, seq = rec
-        arr = np.asarray(packed._data)            # the ONE fetch
-        self._last_harvest_seq = max(self._last_harvest_seq, seq)
-        self._release_deferred()
-        toks_np = arr[:, :n]
-        emitted_np = arr[:, n:2 * n].astype(bool)
-        init_tok = arr[:, 2 * n]
-        ctx_m = arr[:, 2 * n + 1].astype(np.int32)
-        act_m = arr[:, 2 * n + 2].astype(bool)
-        t_now = time.perf_counter()
-        appended = 0
-        for slot in range(self.num_slots):
-            req = snap_req[slot]
-            if req is not self.slot_req[slot]:
-                # slot evicted (its echo flag was reset by the
-                # eviction) or re-admitted since this dispatch: the
-                # stale pending snapshot must not clear the NEW
-                # occupant's first-token guard — its token rides a
-                # later, unharvested program
-                continue
-            if pending[slot]:
-                # this harvest delivers the slot's first-token echo;
-                # _drain may finish the slot again from here on
-                self._echo_inflight[slot] = False
-            if self._act_since[slot] <= seq:
-                # the chunk's view of this slot is current (it was not
-                # re-activated by a prefill wave after this dispatch)
-                self.ctx[slot] = ctx_m[slot]
-                self.active[slot] = act_m[slot]
-            if req is None:
-                continue
-            if pending[slot]:
-                if not req.tokens:
-                    req.t_first = t_now
-                req.tokens.append(int(init_tok[slot]))
-                appended += 1
-            if req.finished:
-                continue
-            req.strikes = 0        # clean harvest exonerates (above)
-            for j in range(n):
-                if emitted_np[slot, j]:
+        with _span("serving/harvest") as sp:
+            packed, snap_req, pending, n, seq = rec
+            with _span("serving/harvest.fetch"):
+                arr = np.asarray(packed._data)        # the ONE fetch
+            self._last_harvest_seq = max(self._last_harvest_seq, seq)
+            self._release_deferred()
+            toks_np = arr[:, :n]
+            emitted_np = arr[:, n:2 * n].astype(bool)
+            init_tok = arr[:, 2 * n]
+            ctx_m = arr[:, 2 * n + 1].astype(np.int32)
+            act_m = arr[:, 2 * n + 2].astype(bool)
+            t_now = time.perf_counter()
+            appended = 0
+            for slot in range(self.num_slots):
+                req = snap_req[slot]
+                if req is not self.slot_req[slot]:
+                    # slot evicted (its echo flag was reset by the
+                    # eviction) or re-admitted since this dispatch: the
+                    # stale pending snapshot must not clear the NEW
+                    # occupant's first-token guard — its token rides a
+                    # later, unharvested program
+                    continue
+                if pending[slot]:
+                    # this harvest delivers the slot's first-token echo;
+                    # _drain may finish the slot again from here on
+                    self._echo_inflight[slot] = False
+                if self._act_since[slot] <= seq:
+                    # the chunk's view of this slot is current (it was not
+                    # re-activated by a prefill wave after this dispatch)
+                    self.ctx[slot] = ctx_m[slot]
+                    self.active[slot] = act_m[slot]
+                if req is None:
+                    continue
+                if pending[slot]:
                     if not req.tokens:
                         req.t_first = t_now
-                    req.tokens.append(int(toks_np[slot, j]))
+                    req.tokens.append(int(init_tok[slot]))
                     appended += 1
-        _t_obs = time.perf_counter()
-        self._stats.inc("tokens_emitted", appended)
-        if appended == 0:
-            self._stats.inc("chunks_empty")
-        self._obs_s += time.perf_counter() - _t_obs
+                if req.finished:
+                    continue
+                req.strikes = 0        # clean harvest exonerates (above)
+                for j in range(n):
+                    if emitted_np[slot, j]:
+                        if not req.tokens:
+                            req.t_first = t_now
+                        req.tokens.append(int(toks_np[slot, j]))
+                        appended += 1
+            _t_obs = time.perf_counter()
+            self._stats.inc("tokens_emitted", appended)
+            if appended == 0:
+                self._stats.inc("chunks_empty")
+            sp.set_args(seq=seq, appended=appended)
+            self._obs_s += time.perf_counter() - _t_obs
 
     def _decode_chunk(self):
         self._harvest_chunk(self._dispatch_chunk())
@@ -3262,6 +3332,14 @@ class ContinuousBatchingEngine:
                    tokens=len(req.tokens))
 
     def _drain(self):
+        """Finished slots out (:meth:`_drain_slots`) under the
+        ``serving/drain`` span."""
+        with _span("serving/drain") as sp:
+            done = self._drain_slots()
+            sp.set_args(finished=len(done))
+        return done
+
+    def _drain_slots(self):
         # lifecycle first: cancellations and deadline expiries free
         # their pages and complete with typed errors at this turn
         done = self._reap()

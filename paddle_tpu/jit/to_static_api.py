@@ -53,10 +53,27 @@ from ..framework.core import (GraphBreak, ObservedFloat, Tensor,
                               StateTracking, guardable_concretization,
                               record_concretizations, replay_concretizations,
                               track_state)
+from ..profiler import metrics as _pmetrics
+from ..profiler.trace import trace_span as _span
 
 __all__ = ["to_static", "StaticFunction", "not_to_static", "ignore_module"]
 
 logger = logging.getLogger(__name__)
+
+# what one compiled call hands back, on the process registry: a step
+# whose state comes back in fresh buffers shows as outputs far above
+# donated_inputs (the host allocates each one before launch)
+_pmetrics.declare("jit/compiled_calls", "counter",
+                  "to_static calls that ran the compiled program")
+_pmetrics.declare("jit/outputs", "counter",
+                  "device buffers compiled to_static calls returned "
+                  "(reassigned state + outputs)")
+_pmetrics.declare("jit/donated_inputs", "counter",
+                  "state buffers compiled to_static calls donated to "
+                  "their program (0 without donate_state)")
+_c_calls = _pmetrics.get_registry().counter("jit/compiled_calls")
+_c_outputs = _pmetrics.get_registry().counter("jit/outputs")
+_c_donated = _pmetrics.get_registry().counter("jit/donated_inputs")
 
 
 def not_to_static(fn):
@@ -112,13 +129,14 @@ def _signature_key(leaves):
 
 class _CompiledGraph:
     __slots__ = ("state_list", "jitted", "pure_fn", "guard_log",
-                 "call_avals")
+                 "donates", "call_avals")
 
-    def __init__(self, state_list, jitted, pure_fn, guard_log):
+    def __init__(self, state_list, jitted, pure_fn, guard_log, donates):
         self.state_list = state_list
         self.jitted = jitted
         self.pure_fn = pure_fn
         self.guard_log = guard_log   # [(kind, value)] from discovery
+        self.donates = donates       # the state buffers are donated
         self.call_avals = None       # shapes of the first compiled run
 
 
@@ -157,6 +175,7 @@ class StaticFunction:
                  donate_state: bool = False):
         functools.update_wrapper(self, function)
         self._fn = function
+        self._name = getattr(function, "__name__", "fn")
         self._input_spec = input_spec
         self._graphs: dict[str, _SigEntry] = {}
         self._fallback_sigs: set[str] = set()
@@ -216,22 +235,38 @@ class StaticFunction:
     _globally_enabled = True
 
     def __call__(self, *args, **kwargs):
+        with _span("to_static/call", fn=self._name) as call:
+            return self._call(call, args, kwargs)
+
+    def _call(self, call, args, kwargs):
+        """One call under its ``to_static/call`` span; ``mode`` says how
+        it ran: compiled | discover | eager | segmented."""
         if not self._enabled or not StaticFunction._globally_enabled:
+            call.set_args(mode="eager")
             self.n_eager_runs += 1
             return self._call_fn(*args, **kwargs)
-        leaves: list = []
-        spec = _tree_flatten((args, kwargs), leaves)
-        sig = _signature_key(leaves)
-        if sig in self._fallback_sigs:
+        with _span("to_static/bind"):
+            leaves: list = []
+            spec = _tree_flatten((args, kwargs), leaves)
+            sig = _signature_key(leaves)
+            broken = sig in self._fallback_sigs
+            entry = self._graphs.get(sig)
+            graph = bound = None
+            if not broken and entry is not None \
+                    and entry.latest_key is not None:
+                graph = entry.by_key[entry.latest_key]
+                bound = self._bind(graph, leaves)
+        if broken:
+            call.set_args(mode="segmented")
             self.n_eager_runs += 1
             return self._call_segmented(sig, args, kwargs)
-        entry = self._graphs.get(sig)
-        if entry is None or entry.latest_key is None:
+        if graph is None:
+            call.set_args(mode="discover")
             self.n_eager_runs += 1
             return self._discover(sig, spec, leaves, args, kwargs)
-        graph = entry.by_key[entry.latest_key]
+        call.set_args(mode="compiled")
         try:
-            result = self._run_compiled(graph, leaves)
+            result = self._run_compiled(graph, *bound)
             self.n_compiled_runs += 1
             entry.mispredicts = 0   # guard-hit run: healthy specialization
             return result
@@ -245,10 +280,12 @@ class StaticFunction:
                     "eager for this signature")
                 self._fallback_sigs.add(sig)
                 self._graphs.pop(sig, None)
+                call.set_args(mode="eager")
                 self.n_eager_runs += 1
                 return self._call_fn(*args, **kwargs)
             # the discarded run committed nothing; re-run eagerly (correct
             # for the new branch pattern) and re-specialize
+            call.set_args(mode="discover")
             self.n_eager_runs += 1
             return self._discover(sig, spec, leaves, args, kwargs)
         except _TRACE_ERRORS as e:
@@ -259,6 +296,7 @@ class StaticFunction:
                 "for this signature")
             self._fallback_sigs.add(sig)
             self._graphs.pop(sig, None)
+            call.set_args(mode="eager")
             self.n_eager_runs += 1
             return self._call_fn(*args, **kwargs)
 
@@ -317,7 +355,9 @@ class StaticFunction:
     def _discover(self, sig, spec, leaves, args, kwargs):
         _t0 = _time.perf_counter()
         try:
-            return self._discover_inner(sig, spec, leaves, args, kwargs)
+            with _span("to_static/discover"):
+                return self._discover_inner(sig, spec, leaves, args,
+                                            kwargs)
         finally:
             self.compile_seconds += _time.perf_counter() - _t0
 
@@ -369,7 +409,8 @@ class StaticFunction:
             # on guard-free graphs
             donate = (0,) if self._donate and not log else ()
             jitted = jax.jit(pure_fn, donate_argnums=donate)
-            entry.by_key[key] = _CompiledGraph(state, jitted, pure_fn, log)
+            entry.by_key[key] = _CompiledGraph(state, jitted, pure_fn, log,
+                                               bool(donate))
         entry.latest_key = key
         return outputs
 
@@ -471,12 +512,21 @@ class StaticFunction:
                     t._grad_value = g
 
         pure_fn._holder = holder
+        # host and module events of the program read jit_to_static_<fn>
+        pure_fn.__name__ = pure_fn.__qualname__ = \
+            f"to_static_{self._name}"
         return pure_fn
 
-    def _run_compiled(self, graph: _CompiledGraph, leaves):
+    @staticmethod
+    def _bind(graph: _CompiledGraph, leaves):
+        """The arrays a compiled call hands its program."""
         arg_arrays = tuple(leaf._data for leaf in leaves
                            if isinstance(leaf, Tensor))
         state_arrays = tuple(t._data for t in graph.state_list)
+        return state_arrays, arg_arrays
+
+    def _run_compiled(self, graph: _CompiledGraph, state_arrays,
+                      arg_arrays):
         if graph.call_avals is None:
             # keep a sharding only where it spans devices: one-device
             # arrays are uncommitted and follow the others, as in a call
@@ -486,8 +536,18 @@ class StaticFunction:
                     sharding=a.sharding
                     if len(a.sharding.device_set) > 1 else None),
                 (state_arrays, arg_arrays))
-        new_state, out_arrays, guard_vec = graph.jitted(state_arrays,
-                                                        arg_arrays)
+        with _span("to_static/execute"):
+            new_state, out_arrays, guard_vec = graph.jitted(state_arrays,
+                                                            arg_arrays)
+        _c_calls.inc()
+        _c_outputs.inc(len(new_state) + len(out_arrays))
+        if graph.donates:
+            _c_donated.inc(len(state_arrays))
+        with _span("to_static/commit"):
+            return self._commit(graph, new_state, out_arrays, guard_vec)
+
+    @staticmethod
+    def _commit(graph, new_state, out_arrays, guard_vec):
         holder = graph.pure_fn._holder
         # verify the guarded branch decisions BEFORE committing state —
         # a mismatched run must leave no trace (its outputs followed the

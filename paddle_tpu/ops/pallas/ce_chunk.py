@@ -116,6 +116,7 @@ def chunk_stats(logits, local, lo):
                       col2],
             out_specs=[col2, col2, col2],
             out_shape=[jax.ShapeDtypeStruct((n_p, 1), jnp.float32)] * 3,
+            name="ce_chunk_stats",
             interpret=_interpret(),
         )(lo_arr, _pad_rows(logits, n_p),
           _pad_rows(local.astype(jnp.int32).reshape(-1, 1), n_p))
@@ -143,6 +144,7 @@ def chunk_dlogits(logits, lse, local, scale, lo, out_dtype=None):
                       col2, col2, col2],
             out_specs=pl.BlockSpec((blk, vc), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n_p, vc), out_dtype),
+            name="ce_chunk_dlogits",
             interpret=_interpret(),
         )(lo_arr, _pad_rows(logits, n_p),
           _pad_rows(lse.astype(jnp.float32).reshape(-1, 1), n_p),

@@ -212,6 +212,7 @@ def _flash_fwd(q, k, v, causal, scale):
                 jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
                 jax.ShapeDtypeStruct((b * h, sq_p, 128), jnp.float32),
             ],
+            name="flash_attention_fwd",
             interpret=_interpret(),
         )(qh, kh, vh)
     out4 = out[:, :sq].reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -387,6 +388,7 @@ def _flash_bwd_pallas(q, k_full, v_full, out, lse, g, causal, s):
                 jax.ShapeDtypeStruct((bh, sk_p, d), k_full.dtype),
                 jax.ShapeDtypeStruct((bh, sk_p, d), v_full.dtype),
             ],
+            name="flash_attention_dkv",
             interpret=_interpret(),
         )(qh, kh, vh, gh, lse_p, delta)
         dq = pl.pallas_call(
@@ -408,6 +410,7 @@ def _flash_bwd_pallas(q, k_full, v_full, out, lse, g, causal, s):
             out_shape=[
                 jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype),
             ],
+            name="flash_attention_dq",
             interpret=_interpret(),
         )(qh, kh, vh, gh, lse_p, delta)[0]
     dq4 = dq[:, :sq].reshape(b, h, sq, d).transpose(0, 2, 1, 3)

@@ -106,6 +106,7 @@ def _gmm_call(x, w, tile_gid, transpose_rhs, bn):
             functools.partial(_fwd_kernel, transpose_rhs=transpose_rhs),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((P, out_dim), x.dtype),
+            name="grouped_matmul",
             interpret=_interpret(),
         )(tile_gid, x, w)
 
@@ -163,6 +164,7 @@ def _dw_call(x, dy, tile_gid, n_experts, bd, bh):
             functools.partial(_dw_kernel, nr=nr),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n_experts, d, h), x.dtype),
+            name="grouped_matmul_dw",
             interpret=_interpret(),
         )(tile_gid, x, dy)
 

@@ -153,6 +153,7 @@ def _rms_fwd_impl(x, w, eps):
                       pl.BlockSpec((d,), lambda i: (0,))],
             out_specs=pl.BlockSpec((blk, d), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n_p, d), x.dtype),
+            name="rms_norm_fwd",
             interpret=_interpret(),
         )(_pad_rows(x2, n_p), w)
     return out[:n].reshape(orig_shape)
@@ -180,6 +181,7 @@ def _rms_bwd(eps, res, g):
                       pl.BlockSpec((blk, d), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((blk, d), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n_p, d), x.dtype),
+            name="rms_norm_bwd",
             interpret=_interpret(),
         )(_pad_rows(x2, n_p), w, _pad_rows(g2, n_p))
     dx = dx[:n]
@@ -317,6 +319,7 @@ def _rms_res_fwd_impl(x, res, w, eps):
                        pl.BlockSpec((blk, d), lambda i: (i, 0))],
             out_shape=[jax.ShapeDtypeStruct((n_p, d), x.dtype),
                        jax.ShapeDtypeStruct((n_p, d), x.dtype)],
+            name="rms_norm_residual_fwd",
             interpret=_interpret(),
         )(_pad_rows(x2, n_p), _pad_rows(r2, n_p), w)
     return (y[:n].reshape(orig_shape), r[:n].reshape(orig_shape))
@@ -349,6 +352,7 @@ def _rms_res_bwd(eps, resids, gs):
                       pl.BlockSpec((blk, d), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((blk, d), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((n_p, d), x.dtype),
+            name="rms_norm_residual_bwd",
             interpret=_interpret(),
         )(_pad_rows(x2, n_p), _pad_rows(r2, n_p), w,
           _pad_rows(gy2, n_p), _pad_rows(gr2, n_p))

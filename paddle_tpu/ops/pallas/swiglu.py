@@ -130,6 +130,7 @@ def _swiglu_fwd_impl(gate, up):
             in_specs=[spec, spec],
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct((n_p, h_p), gate.dtype),
+            name="swiglu_fwd",
             interpret=_interpret(),
         )(_pad2(g2, n_p, h_p), _pad2(u2, n_p, h_p))
     return out[:n, :h].reshape(orig_shape)
@@ -159,6 +160,7 @@ def _swiglu_bwd(resids, go):
             out_specs=[spec, spec],
             out_shape=[jax.ShapeDtypeStruct((n_p, h_p), gate.dtype),
                        jax.ShapeDtypeStruct((n_p, h_p), up.dtype)],
+            name="swiglu_bwd",
             interpret=_interpret(),
         )(_pad2(g2, n_p, h_p), _pad2(u2, n_p, h_p),
           _pad2(go2, n_p, h_p))

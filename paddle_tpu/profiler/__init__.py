@@ -74,7 +74,6 @@ class ProfilerOptions:
     output_dir: str = "./profiler_log"          # PADDLE_PROFILER_LOG_DIR
     trace_enabled: bool = False                 # PADDLE_PROFILER_TRACE
     with_flops: bool = False                    # PADDLE_PROFILER_WITH_FLOPS
-    sync_spans: bool = False                    # PADDLE_PROFILER_SYNC
     export_on_disable: bool = True
 
     @classmethod
@@ -83,8 +82,7 @@ class ProfilerOptions:
             output_dir=os.environ.get("PADDLE_PROFILER_LOG_DIR",
                                       "./profiler_log"),
             trace_enabled=_env_bool("PADDLE_PROFILER_TRACE"),
-            with_flops=_env_bool("PADDLE_PROFILER_WITH_FLOPS"),
-            sync_spans=_env_bool("PADDLE_PROFILER_SYNC"))
+            with_flops=_env_bool("PADDLE_PROFILER_WITH_FLOPS"))
 
 
 def enable(options: ProfilerOptions | None = None) -> Tracer:
@@ -193,32 +191,25 @@ def load_profiler_result(path):
 
 
 class RecordEvent:
-    """User range annotation; shows up in the jax/Perfetto trace AND —
-    when the structured tracer is enabled — as a ``trace_span`` in the
-    chrome-trace/JSON export."""
+    """User range annotation: one ``trace_span`` held open between
+    ``begin()`` and ``end()`` — in the jax/Perfetto trace whenever a
+    profiler session is live, and in the chrome-trace/JSON export while
+    the structured tracer is enabled."""
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ann = None
         self._span = None
         self.begin_ts = None
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-        tr = get_tracer()
-        if tr.enabled:
-            self._span = tr.span(self.name, cat="record_event")
-            self._span.__enter__()
+        self._span = trace_span(self.name, cat="record_event")
+        self._span.__enter__()
         self.begin_ts = time.perf_counter()
 
     def end(self):
         if self._span is not None:
             self._span.__exit__(None, None, None)
             self._span = None
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
 
     def __enter__(self):
         self.begin()

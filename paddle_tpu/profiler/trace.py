@@ -10,14 +10,21 @@ trace-event schema (load in Perfetto / chrome://tracing) and raw JSON.
 
 Deliberately stdlib-only at import time (no jax): it is imported from
 hot paths (``nn/scan.py``, ``inference/serving.py``, ``hapi``) and must
-never add import weight or create cycles. jax is imported lazily inside
-:func:`block_on` only when a span actually requests a device sync.
+never add import weight or create cycles. jax is imported lazily, when
+the first span opens (:func:`_annotation`) or a span requests a device
+sync (:func:`block_on`).
 
 Design notes:
 
-- A DISABLED tracer costs one attribute read per span — instrumentation
-  stays in production code paths (the Paddle profiler contract:
-  ``RecordEvent`` is free unless a profiler is recording).
+- ONE span primitive, two sinks. :func:`trace_span` always opens a
+  ``jax.profiler.TraceAnnotation``: whenever a jax profiler session is
+  live the span lands in the ``.xplane.pb`` host plane, on the clock the
+  device trace uses, so a device gap can be laid over the program's own
+  layers; with no session it is a sub-microsecond no-op, so
+  instrumentation stays in production code paths. Only while the
+  structured :class:`Tracer` is enabled does the same span ALSO record
+  the chrome event (host clock, this module's export). "Tracing on"
+  means "a profiler session is running" — there is no flag of its own.
 - Spans are exception-safe: the event is recorded (with an ``error``
   arg) even when the body raises, so a trace of a crashed step still
   shows where the time went.
@@ -97,13 +104,53 @@ def block_on(value):
     return time.perf_counter() - t0
 
 
+_TraceAnnotation = None
+
+
+def _annotation(name, args):
+    """A ``jax.profiler.TraceAnnotation`` (jax imported on first use: this
+    module stays importable without it)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **args)
+
+
 class _Span:
-    """Context manager recording one X event. Exception-safe: records
-    even when the body raises (annotating ``args['error']``)."""
+    """The one span primitive: a ``TraceAnnotation`` on the profiler
+    session's clock, always (a no-op with no session live). Parent by
+    nesting; ``set_args`` maps to ``TraceMe.set_metadata``."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name, args):
+        self._ann = _annotation(name, args)
+
+    def set_args(self, **kw):
+        """Attach metadata mid-span (e.g. flops discovered after shapes
+        are known, a count known only once the body has run)."""
+        self._ann.set_metadata(**kw)
+        return self
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
+        return False                         # never swallow exceptions
+
+
+class _RecordedSpan(_Span):
+    """The same span while the structured tracer is enabled: it also
+    records one chrome X event. Exception-safe: records even when the
+    body raises (annotating ``args['error']``)."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "sync", "_t0", "_depth")
 
     def __init__(self, tracer, name, cat, sync, args):
+        super().__init__(name, args)
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -111,12 +158,11 @@ class _Span:
         self.args = args
 
     def set_args(self, **kw):
-        """Attach/override metadata mid-span (e.g. flops discovered
-        after shapes are known)."""
         self.args.update(kw)
-        return self
+        return super().set_args(**kw)
 
     def __enter__(self):
+        super().__enter__()
         tl = self._tracer._tl
         self._depth = getattr(tl, "depth", 0)
         tl.depth = self._depth + 1
@@ -139,26 +185,8 @@ class _Span:
                 args=self.args))
         finally:
             self._tracer._tl.depth = self._depth
-        return False                         # never swallow exceptions
-
-
-class _NullSpan:
-    """Shared no-op span for the disabled tracer (one object, no
-    allocation per call)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
+            super().__exit__(exc_type, exc, tb)
         return False
-
-    def set_args(self, **kw):
-        return self
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -179,14 +207,16 @@ class Tracer:
             self.events.append(ev)
 
     def span(self, name, cat="user", sync=None, **args):
-        """Nestable timed span. ``sync`` (Tensor/array/pytree/callable)
-        inserts a device-sync point before the span closes, so the
+        """Nestable timed span: always a ``TraceAnnotation`` (see the
+        module docstring), and a chrome event too while this tracer is
+        enabled. ``sync`` (Tensor/array/pytree/callable) makes a
+        RECORDED span block on the device before it closes, so its
         duration covers device work, not just dispatch. Extra kwargs
-        become event args (``flops=``/``bytes=`` feed the per-section
-        MFU/roofline summary, profiler.cost)."""
+        become the span's args in both sinks (``flops=``/``bytes=`` feed
+        the per-section MFU/roofline summary, profiler.cost)."""
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, sync, dict(args))
+            return _Span(name, args)
+        return _RecordedSpan(self, name, cat, sync, args)
 
     def counter(self, name, value=None, cat="gauge", **values):
         """Record a gauge sample (chrome counter event)."""
@@ -397,7 +427,7 @@ def get_tracer() -> Tracer:
 
 
 def trace_span(name, cat="user", sync=None, **args):
-    """Module-level convenience: a span on the global tracer."""
+    """THE way to open a span (``Tracer.span`` on the global tracer)."""
     return _tracer.span(name, cat=cat, sync=sync, **args)
 
 
