@@ -259,7 +259,7 @@ def _fit_e2e_bench(on_tpu, dev, autotune=False):
     # what fit reuses.
     from paddle_tpu.framework import flags as _flags
     x0 = paddle.to_tensor(ids_np[:batch])
-    step_fn = m._static_train_step(donate=True)
+    step_fn = m._static_train_step()
     with _flags.scoped_default("FLAGS_fused_linear_cross_entropy", True):
         loss = step_fn(x0, x0)            # discovery
         loss = step_fn(x0, x0)            # compile+run

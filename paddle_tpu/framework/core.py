@@ -743,8 +743,11 @@ class Tensor:
     # -- autograd ----------------------------------------------------------
 
     def detach(self) -> "Tensor":
-        t = Tensor(self._data, stop_gradient=True)
-        return t
+        """A tensor off the tape that SHARES this one's buffer (as in
+        Paddle, where it aliases the live parameter): a compiled step
+        that reassigns this tensor donates that buffer, and the detached
+        view is then deleted. A snapshot is ``clone()`` or ``numpy()``."""
+        return Tensor(self._data, stop_gradient=True)
 
     def detach_(self) -> "Tensor":
         self._node = None

@@ -54,12 +54,16 @@ class Generator:
         self._state.set_data(new_state)
         return sub
 
+    # both copy: the generator's key is state a compiled function
+    # reassigns, so to_static donates (deletes) the buffer it held — a
+    # saved state must be restorable after that, and more than once
+
     def get_state(self) -> Tensor:
-        return Tensor(self._state.jax())
+        return Tensor(jnp.array(self._state.jax()))
 
     def set_state(self, state) -> None:
-        data = state.jax() if isinstance(state, Tensor) else jnp.asarray(state)
-        self._state.set_data(data)
+        self._state.set_data(jnp.array(
+            state.jax() if isinstance(state, Tensor) else state))
 
 
 default_generator = Generator(0, "default")

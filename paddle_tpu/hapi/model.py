@@ -219,18 +219,15 @@ class Model:
 
     # ---- compiled steps (the fit hot path) -------------------------------
 
-    def _static_train_step(self, donate: bool = True):
+    def _static_train_step(self):
         """The jitted train step: forward + loss + backward + optimizer
         update functionalized into ONE compiled program via the
-        to_static machinery, with params and optimizer slots donated
-        (``donate_state``) so XLA updates state in place instead of
-        allocating a fresh copy per step. Returns the loss TENSOR — no
-        host fetch; the fit loop resolves values at log boundaries.
+        to_static machinery, which donates the params and optimizer
+        slots the step reassigns, so XLA updates state in place instead
+        of allocating a fresh copy per step. Returns the loss TENSOR —
+        no host fetch; the fit loop resolves values at log boundaries.
         ``train_batch`` stays the eager parity oracle."""
         sf = getattr(self, "_compiled_train_step", None)
-        if sf is not None and \
-                getattr(self, "_compiled_train_donate", None) != donate:
-            sf = None    # donation setting changed: rebuild
         # the fused-loss branch is decided at TRACE time; if the flag
         # state changed since this step was built (e.g. an explicit
         # set_flags OFF after a fused fit), the cached program is stale
@@ -261,9 +258,8 @@ class Model:
                 return loss
 
             from ..jit.to_static_api import StaticFunction
-            sf = StaticFunction(train_step, donate_state=donate)
+            sf = StaticFunction(train_step)
             self._compiled_train_step = sf
-            self._compiled_train_donate = donate
             self._compiled_train_fused = fused_now
         return sf
 
@@ -467,7 +463,7 @@ class Model:
             eval_freq=1, log_freq=10, save_dir=None, save_freq=1,
             verbose=2, drop_last=False, shuffle=True, num_workers=0,
             callbacks=None, resume=None, keep_last_n=None,
-            legacy_save=True, compiled=True, donate=True,
+            legacy_save=True, compiled=True,
             prefetch_depth=None, steps_in_flight=None,
             device_sharding=None, preemptible=None):
         """Train. ``save_dir`` writes a committed ``step_N``
@@ -498,9 +494,8 @@ class Model:
         or ``False`` to opt out.
 
         Hot-path knobs (module docstring, docs/data_pipeline.md):
-        ``compiled=True`` runs the jitted train step (``donate``
-        controls state-buffer donation); ``prefetch_depth`` /
-        ``steps_in_flight`` override the pipeline depths (default:
+        ``compiled=True`` runs the jitted train step; ``prefetch_depth``
+        / ``steps_in_flight`` override the pipeline depths (default:
         tuning cache, then 2/2); ``device_sharding`` (a jax Sharding,
         e.g. NamedSharding over a dp mesh axis) device-places each
         global batch sharded across the mesh."""
@@ -602,8 +597,7 @@ class Model:
             if compiled:
                 _scope.enter_context(_flags.scoped_default(
                     "FLAGS_fused_linear_cross_entropy", True))
-            step_fn = self._static_train_step(donate) if compiled \
-                else None
+            step_fn = self._static_train_step() if compiled else None
             for epoch in range(start_epoch, epochs):
                 epoch_t0 = time.perf_counter()
                 skip_to = resume_skip if epoch == start_epoch else 0

@@ -82,15 +82,19 @@ class ModelAverage(Optimizer):
 
     def step(self):
         with no_grad():
+            # the sums are buffers of their own, never a parameter's
+            # (astype to the same dtype aliases): a compiled step donates
+            # the parameter's
             for p in self._parameter_list:
                 acc = self._sum.get(id(p))
-                v = p._data.astype(jnp.float32)
-                self._sum[id(p)] = v if acc is None else acc + v
+                self._sum[id(p)] = \
+                    jnp.array(p._data, jnp.float32) if acc is None \
+                    else acc + p._data.astype(jnp.float32)
         self._num += 1
         # restart the window once it outgrows max_average_window
         if self._num > self.max_w and self._num > self.min_w:
             for p in self._parameter_list:
-                self._sum[id(p)] = p._data.astype(jnp.float32)
+                self._sum[id(p)] = jnp.array(p._data, jnp.float32)
             self._num = 1
 
     def apply(self, executor=None, need_restore=True):

@@ -12,12 +12,16 @@ How functionalization works (this replaces SOT's bytecode interception):
    *persistable* tensor (parameters, buffers, optimizer accumulators, RNG
    key) funnels through ``core.apply`` / ``Tensor.set_data``, so we learn
    exactly which state the function touches.
-2. **Pure wrapper** — ``(state_arrays, arg_arrays) -> (new_state, outputs)``
-   temporarily rebinds the tracked tensors to tracer arrays, replays the
-   user function (the autograd tape runs on tracers, so ``.backward()``
-   lowers into the same XLA program), and reads back mutated state.
+2. **Pure wrapper** — ``(written state, read-only state, args) ->
+   (new_state, outputs)`` temporarily rebinds the tracked tensors to
+   tracer arrays, replays the user function (the autograd tape runs on
+   tracers, so ``.backward()`` lowers into the same XLA program), and
+   reads back mutated state.
 3. ``jax.jit`` compiles it; python scalars in the signature are baked in as
-   constants (they're part of the cache key, like SOT guards).
+   constants (they're part of the cache key, like SOT guards). A
+   guard-free graph DONATES the written state: the program updates it in
+   place, and the arrays those tensors held before the call are deleted
+   (``to_static``'s docstring says what that means for a caller).
 
 Graph breaks and guarded specialization (the SOT role): data-dependent
 Python control flow on SCALARS (``if loss_improved:``, ``int(idx)``) does
@@ -39,7 +43,9 @@ supported; reading ``.grad`` after a compiled step warns.
 
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
 import logging
 import time as _time
 import warnings
@@ -70,7 +76,7 @@ _pmetrics.declare("jit/outputs", "counter",
                   "(reassigned state + outputs)")
 _pmetrics.declare("jit/donated_inputs", "counter",
                   "state buffers compiled to_static calls donated to "
-                  "their program (0 without donate_state)")
+                  "their program (the state a guard-free step reassigns)")
 _c_calls = _pmetrics.get_registry().counter("jit/compiled_calls")
 _c_outputs = _pmetrics.get_registry().counter("jit/outputs")
 _c_donated = _pmetrics.get_registry().counter("jit/donated_inputs")
@@ -128,15 +134,15 @@ def _signature_key(leaves):
 
 
 class _CompiledGraph:
-    __slots__ = ("state_list", "jitted", "pure_fn", "guard_log",
-                 "donates", "call_avals")
+    __slots__ = ("written", "read_only", "jitted", "pure_fn", "guard_log",
+                 "call_avals")
 
-    def __init__(self, state_list, jitted, pure_fn, guard_log, donates):
-        self.state_list = state_list
+    def __init__(self, written, read_only, jitted, pure_fn, guard_log):
+        self.written = written       # state the discovery run reassigned
+        self.read_only = read_only   # state it only read
         self.jitted = jitted
         self.pure_fn = pure_fn
         self.guard_log = guard_log   # [(kind, value)] from discovery
-        self.donates = donates       # the state buffers are donated
         self.call_avals = None       # shapes of the first compiled run
 
 
@@ -171,8 +177,7 @@ _TRACE_ERRORS = (jax.errors.TracerBoolConversionError,
 
 class StaticFunction:
     def __init__(self, function: Callable, input_spec=None,
-                 build_strategy=None, backend=None, full_graph=False,
-                 donate_state: bool = False):
+                 build_strategy=None, backend=None, full_graph=False):
         functools.update_wrapper(self, function)
         self._fn = function
         self._name = getattr(function, "__name__", "fn")
@@ -180,7 +185,6 @@ class StaticFunction:
         self._graphs: dict[str, _SigEntry] = {}
         self._fallback_sigs: set[str] = set()
         self._instance = None
-        self._donate = donate_state
         self._enabled = not getattr(function,
                                     "_paddle_tpu_not_to_static", False)
         # run-mode telemetry (hapi fit attribution + tests): how many
@@ -202,8 +206,7 @@ class StaticFunction:
         cache_name = f"__static_fn_{id(self)}"
         bound = instance.__dict__.get(cache_name)
         if bound is None:
-            bound = StaticFunction(self._fn, self._input_spec,
-                                   donate_state=self._donate)
+            bound = StaticFunction(self._fn, self._input_spec)
             bound._instance = instance
             instance.__dict__[cache_name] = bound
         return bound
@@ -388,12 +391,9 @@ class StaticFunction:
             self._fallback_sigs.add(sig)
             self._graphs.pop(sig, None)
             return outputs
-        state, seen = [], set()
-        for d in (tracking.read, tracking.written):
-            for tid, t in d.items():
-                if tid not in seen:
-                    seen.add(tid)
-                    state.append(t)
+        written = list(tracking.written.values())
+        read_only = [t for tid, t in tracking.read.items()
+                     if tid not in tracking.written]
         entry = self._graphs.get(sig)
         if entry is None:
             entry = self._graphs[sig] = _SigEntry()
@@ -403,29 +403,35 @@ class StaticFunction:
         key = tuple((e[0], e[1]) if len(e) == 2 else (e[0], "<obs>")
                     for e in log)
         if key not in entry.by_key:
-            pure_fn = self._make_pure_fn(spec, leaves, state, log)
-            # guards require the ability to DISCARD a run on mismatch, so
-            # donation (which invalidates the input buffers) is only safe
-            # on guard-free graphs
-            donate = (0,) if self._donate and not log else ()
-            jitted = jax.jit(pure_fn, donate_argnums=donate)
-            entry.by_key[key] = _CompiledGraph(state, jitted, pure_fn, log,
-                                               bool(donate))
+            pure_fn = self._make_pure_fn(spec, leaves, written, read_only,
+                                         log)
+            # argument 0 is the program's to overwrite; _bind decides
+            # what goes into it
+            jitted = jax.jit(pure_fn, donate_argnums=(0,))
+            entry.by_key[key] = _CompiledGraph(written, read_only, jitted,
+                                               pure_fn, log)
         entry.latest_key = key
         return outputs
 
     # ---- the pure function ----------------------------------------------
 
-    def _make_pure_fn(self, spec, proto_leaves, state_list, guard_log):
-        donate = self._donate and not guard_log
+    def _make_pure_fn(self, spec, proto_leaves, written, read_only,
+                      guard_log):
         fn = self._call_fn
+        state_list = written + read_only
         # leaf prototypes: for tensors remember stop_gradient; for python
         # values bake in the discovery-call value (sig key guards equality)
         protos = [(True, leaf.stop_gradient) if isinstance(leaf, Tensor)
                   else (False, leaf) for leaf in proto_leaves]
         holder = {}
 
-        def pure_fn(state_arrays, arg_arrays):
+        def pure_fn(donated, kept, read_arrays, arg_arrays):
+            # the written group arrives split by _bind: donated[i] is
+            # None where written[i]'s buffer stays the caller's, and
+            # those come in kept, in order
+            kept = iter(kept)
+            state_arrays = [next(kept) if a is None else a
+                            for a in donated] + list(read_arrays)
             # _grad_value (not .grad): internal save/restore must neither
             # trigger nor clear the stale-grad warning
             originals = [(t, t._data, t._node, t._grad_value)
@@ -468,20 +474,19 @@ class StaticFunction:
                 holder["out_spec"] = out_spec
                 holder["out_is_tensor"] = [isinstance(o, Tensor)
                                            for o in out_leaves]
-                # only state actually REASSIGNED during the trace is an
-                # output (identity check against the input tracer):
-                # returning untouched params would force fresh device
-                # buffers for the whole model every step
-                if donate:
-                    # donated input buffers are invalidated unless
-                    # aliased to an output — must return full state
-                    changed = list(range(len(state_list)))
-                else:
-                    changed = [i for i, (t, a) in
-                               enumerate(zip(state_list, state_arrays))
-                               if t._data is not a]
+                # every written tensor is an output: a donated buffer
+                # that no output aliases is lost. Read-only state is one
+                # only if the trace REASSIGNED it after all (identity
+                # check against the input tracer): returning untouched
+                # weights would force fresh device buffers for the whole
+                # model every call
+                changed = [i for i, (t, a) in
+                           enumerate(zip(read_only, read_arrays))
+                           if t._data is not a]
                 holder["changed"] = changed
-                new_state = tuple(state_list[i]._data for i in changed)
+                new_state = tuple(
+                    t._data for t in
+                    written + [read_only[i] for i in changed])
                 # only tracer-backed concretizations become guards
                 # (constants were verified equal at trace time). One
                 # stacked int64 vector => ONE host sync per step at check
@@ -519,14 +524,30 @@ class StaticFunction:
 
     @staticmethod
     def _bind(graph: _CompiledGraph, leaves):
-        """The arrays a compiled call hands its program."""
+        """The arrays a compiled call hands its program: the written
+        state it gives away, the written state it keeps, the read-only
+        state, the call arguments."""
         arg_arrays = tuple(leaf._data for leaf in leaves
                            if isinstance(leaf, Tensor))
-        state_arrays = tuple(t._data for t in graph.state_list)
-        return state_arrays, arg_arrays
+        read_arrays = tuple(t._data for t in graph.read_only)
+        written = tuple(t._data for t in graph.written)
+        if graph.guard_log:
+            # a guarded run must be DISCARDABLE on mismatch, and a
+            # donated buffer is gone: guarded graphs give nothing away
+            return (None,) * len(written), written, read_arrays, arg_arrays
+        # a buffer can be given away only if nothing else of this call
+        # reads it: one that a call argument or a second state tensor
+        # also holds stays the caller's (returned fresh)
+        ids = list(map(id, itertools.chain(written, read_arrays,
+                                           arg_arrays)))
+        if len(set(ids)) == len(ids):
+            return written, (), read_arrays, arg_arrays
+        held = collections.Counter(ids)
+        donated = tuple(a if held[id(a)] == 1 else None for a in written)
+        kept = tuple(a for a, d in zip(written, donated) if d is None)
+        return donated, kept, read_arrays, arg_arrays
 
-    def _run_compiled(self, graph: _CompiledGraph, state_arrays,
-                      arg_arrays):
+    def _run_compiled(self, graph: _CompiledGraph, *arrays):
         if graph.call_avals is None:
             # keep a sharding only where it spans devices: one-device
             # arrays are uncommitted and follow the others, as in a call
@@ -535,14 +556,13 @@ class StaticFunction:
                     a.shape, a.dtype,
                     sharding=a.sharding
                     if len(a.sharding.device_set) > 1 else None),
-                (state_arrays, arg_arrays))
+                arrays)
+        donated, kept = arrays[:2]      # kept fills donated's Nones
         with _span("to_static/execute"):
-            new_state, out_arrays, guard_vec = graph.jitted(state_arrays,
-                                                            arg_arrays)
+            new_state, out_arrays, guard_vec = graph.jitted(*arrays)
         _c_calls.inc()
         _c_outputs.inc(len(new_state) + len(out_arrays))
-        if graph.donates:
-            _c_donated.inc(len(state_arrays))
+        _c_donated.inc(len(donated) - len(kept))
         with _span("to_static/commit"):
             return self._commit(graph, new_state, out_arrays, guard_vec)
 
@@ -556,10 +576,12 @@ class StaticFunction:
         if expect is not None and expect.size:
             if not np.array_equal(np.asarray(guard_vec), expect):
                 raise _GuardMismatch()
-        for i, a in zip(holder["changed"], new_state):
-            graph.state_list[i].set_data(a)
-            if not graph.state_list[i]._stop_gradient:
-                graph.state_list[i]._grad_stale = True
+        reassigned = graph.written + [graph.read_only[i]
+                                      for i in holder["changed"]]
+        for t, a in zip(reassigned, new_state):
+            t.set_data(a)
+            if not t._stop_gradient:
+                t._grad_stale = True
         obs = set(holder.get("obs_ret", ()))
         out_leaves = [Tensor(a) if is_t else
                       (float(a) if i in obs else a)
@@ -569,27 +591,31 @@ class StaticFunction:
 
 
 def to_static(function=None, input_spec=None, build_strategy=None,
-              backend=None, full_graph=False, donate_state=False,
-              **kwargs):
+              backend=None, full_graph=False, **kwargs):
     """Decorator/wrapper converting an imperative function or a Layer into a
     compiled whole-program (paddle.jit.to_static parity).
 
-    ``donate_state=True`` donates the captured persistable state buffers
-    (params, optimizer slots) to the compiled program — XLA aliases the
-    updated state into the input buffers instead of allocating a fresh
-    copy per step. Only guard-free graphs donate (a guarded run must be
-    discardable); the flag is a no-op otherwise."""
+    What a compiled call consumes: the persistable state the function
+    REASSIGNS (parameters and optimizer slots of a train step, an RNG
+    key) is donated to the program, which writes the new values into the
+    same device buffers, so the arrays those tensors held BEFORE the call
+    are deleted by it. The tensors themselves are rebound and stay valid.
+    ``p.detach()`` shares the parameter's buffer, as in Paddle, and reads
+    of it raise after the next compiled step; a snapshot that must
+    outlive a step is ``p.clone()`` or ``p.numpy()``. State the function
+    only reads (the weights of an inference function) and the call's
+    arguments are never donated, and graphs with guarded branches donate
+    nothing (a mispredicted run must be discardable)."""
 
     def decorate(fn):
         from ..nn.layer.layers import Layer
         if isinstance(fn, Layer):
-            static_fwd = StaticFunction(type(fn).forward, input_spec,
-                                        donate_state=donate_state)
+            static_fwd = StaticFunction(type(fn).forward, input_spec)
             static_fwd._instance = fn
             fn.forward = static_fwd
             return fn
         return StaticFunction(fn, input_spec, build_strategy, backend,
-                              full_graph, donate_state=donate_state)
+                              full_graph)
     if function is not None:
         return decorate(function)
     return decorate

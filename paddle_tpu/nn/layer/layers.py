@@ -288,7 +288,12 @@ class Layer:
                         f"shape mismatch for {key}: loaded "
                         f"{tuple(data.shape)} vs param "
                         f"{tuple(target._data.shape)}")
-                target.set_data(data.astype(target.dtype))
+                # from a tensor, a copy: it may be live state that a
+                # compiled step donates (its old buffer is then deleted)
+                target.set_data(
+                    jnp.array(data, dtype=target._data.dtype)
+                    if isinstance(src, Tensor)
+                    else data.astype(target.dtype))
             else:
                 missing.append(key)
         for key in state_dict:

@@ -245,25 +245,25 @@ class Optimizer:
         """Restore optimizer state. Accumulators are created lazily at the
         first step, so state for not-yet-created slots is stashed and
         applied on creation (resume-before-first-step works). Values are
-        snapshotted now — state_dict() hands out live tensors, and the
-        source optimizer may keep stepping before our slots materialize."""
+        COPIED now — state_dict() hands out live tensors, and the source
+        optimizer may keep stepping before our slots materialize (a
+        compiled step donates, and so deletes, the buffers it held)."""
+        def value(src):
+            return jnp.array(src._data if isinstance(src, Tensor) else src)
+
         self._pending_state = {
-            k: (Tensor(v._data) if isinstance(v, Tensor) else v)
+            k: (Tensor(value(v)) if isinstance(v, Tensor) else v)
             for k, v in state.items()}
         for store in self._accumulators.values():
             for t in store.values():
                 if t.name in state:
-                    src = state[t.name]
-                    t.set_data(src._data if isinstance(src, Tensor)
-                               else jnp.asarray(src))
+                    t.set_data(value(state[t.name]))
         for pid, t in self._master_weights.items():
             name = next((f"{self._param_key(p)}_master"
                          for p in self._parameter_list if id(p) == pid),
                         None)
             if name and name in state:
-                src = state[name]
-                t.set_data(src._data if isinstance(src, Tensor)
-                           else jnp.asarray(src))
+                t.set_data(value(state[name]))
         if "LR_Scheduler" in state and isinstance(self._learning_rate,
                                                   LRScheduler):
             self._learning_rate.set_state_dict(state["LR_Scheduler"])

@@ -89,15 +89,16 @@ class TestCompiledFitParity:
         assert m._optimizer._step_count == 8
 
     def test_donation_invalidates_old_state_buffers(self):
-        """donate=True aliases state into the compiled program: the
-        pre-step param buffer must be dead afterwards (proof the
-        donation actually engaged, not silently dropped)."""
+        """fit's compiled step gets to_static's rule: the state it
+        reassigns is aliased into the program, so the pre-step param
+        buffer must be dead afterwards (proof the donation actually
+        engaged, not silently dropped)."""
         m = _model(0)
         ds = _dataset()
-        _fit_losses(m, ds, epochs=1, compiled=True, donate=True)
+        _fit_losses(m, ds, epochs=1, compiled=True)
         p = next(iter(m.network.parameters()))
         old = p._data
-        _fit_losses(m, ds, epochs=1, compiled=True, donate=True)
+        _fit_losses(m, ds, epochs=1, compiled=True)
         with pytest.raises(RuntimeError):
             np.asarray(old) + 1   # donated buffer: deleted
         # the live tensor is fine
@@ -340,18 +341,6 @@ class TestDevicePrefetcher:
         np.testing.assert_allclose(rec, ref, rtol=1e-6)
         assert m._last_epoch_summary["h2d_mb"] >= 0
 
-    def test_donate_toggle_rebuilds_compiled_step(self):
-        m = _model(0)
-        ds = _dataset()
-        _fit_losses(m, ds, epochs=1, compiled=True, donate=True)
-        sf1 = m._compiled_train_step
-        p = next(iter(m.network.parameters()))
-        _fit_losses(m, ds, epochs=1, compiled=True, donate=False)
-        assert m._compiled_train_step is not sf1
-        old = p._data
-        _fit_losses(m, ds, epochs=1, compiled=True, donate=False)
-        np.asarray(old)    # donate=False: old buffer must stay alive
-
     def test_dataloader_prefetch_to_device_arg(self):
         loader = paddle.io.DataLoader(_dataset(8), batch_size=4,
                                       shuffle=False,
@@ -359,6 +348,196 @@ class TestDevicePrefetcher:
         it = iter(loader)
         assert isinstance(it, DevicePrefetcher)
         assert len(list(it)) == 2
+
+
+def _adamw_step(seed=0):
+    """A plain ``to_static`` AdamW step over a small MLP whose first
+    layer is frozen: state the step reads and never writes."""
+    paddle.seed(seed)
+    net = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 4))
+    net[0].weight.stop_gradient = True
+    net[0].bias.stop_gradient = True
+    trained = list(net[2].parameters())
+    opt = paddle.optimizer.AdamW(
+        learning_rate=1e-2, parameters=trained,
+        grad_clip=nn.ClipGradByGlobalNorm(1.0))
+
+    def step(x, y, *held):
+        loss = ((net(x) - y) ** 2).mean()
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    x = paddle.to_tensor(np.random.RandomState(0).randn(16, 8)
+                         .astype("float32"))
+    y = paddle.to_tensor(np.random.RandomState(1).randn(16, 4)
+                         .astype("float32"))
+    return net, opt, step, x, y
+
+
+def _donated_by(fn, *args):
+    from paddle_tpu.profiler.metrics import get_registry
+    c = get_registry().counter("jit/donated_inputs")
+    before = c.value
+    out = fn(*args)
+    return out, c.value - before
+
+
+def _latest_graph(fn):
+    entry = list(fn._graphs.values())[-1]
+    return entry.by_key[entry.latest_key]
+
+
+class TestStepDonatesWhatItReassigns:
+    """A guard-free compiled step gives its program the buffers of the
+    state it reassigns, and nothing else (ISSUE 26)."""
+
+    def test_written_buffer_is_consumed_read_only_is_not(self):
+        net, _, step, x, y = _adamw_step()
+        fn = paddle.jit.to_static(step)
+        fn(x, y)                                  # discovery: eager
+        written, read_only = net[2].weight, net[0].weight
+        old_w, old_r = written._data, read_only._data
+        fn(x, y)
+        assert old_w.is_deleted() and written._data is not old_w
+        assert read_only._data is old_r           # not even returned
+        np.asarray(old_r)
+        assert np.isfinite(written.numpy()).all()
+
+    def test_parameter_passed_as_argument_stays_the_callers(self):
+        """A written parameter that is ALSO a call argument is left out
+        of the donated group (JAX refuses ``f(donate(a), a)``), and
+        only that one."""
+        net, _, step, x, y = _adamw_step()
+        w = net[2].weight
+        fn = paddle.jit.to_static(step)
+        _, n = _donated_by(fn, x, y, w)           # discovery: eager
+        assert n == 0
+        old_w, old_b = w._data, net[2].bias._data
+        for _ in range(3):
+            _, n = _donated_by(fn, x, y, w)
+        graph = _latest_graph(fn)
+        assert n == len(graph.written) - 1
+        assert not old_w.is_deleted() and old_b.is_deleted()
+        assert w._data is not old_w               # returned fresh
+
+    def test_two_state_tensors_sharing_a_buffer(self):
+        """... is given away by neither (JAX refuses a buffer that one
+        flattened argument donates and another reads): both come back
+        fresh, and from then on each owns its own."""
+        import jax.numpy as jnp
+        _, opt, step, x, y = _adamw_step()
+        fn = paddle.jit.to_static(step)
+        fn(x, y)
+        pair = [t for t in opt.state_dict().values()
+                if isinstance(t, paddle.Tensor) and t.shape == [4]][:2]
+        shared = jnp.zeros((4,), jnp.float32)
+        for t in pair:
+            t.set_data(shared)
+        _, n = _donated_by(fn, x, y)
+        graph = _latest_graph(fn)
+        assert n == len(graph.written) - 2
+        assert not shared.is_deleted()
+        assert pair[0]._data is not pair[1]._data
+        loss, n = _donated_by(fn, x, y)
+        assert n == len(graph.written) and np.isfinite(float(loss))
+
+    def test_losses_equal_the_undonated_steps_bit_for_bit(self):
+        """Donation changes where the new state is written, not one bit
+        of it: the same step with every parameter and optimizer slot
+        ALSO handed in as an (unused) argument keeps them all, and reads
+        the same losses exactly. Against the eager loop the compiled
+        program differs in the last digit with or without donation (XLA
+        fuses the update), so that comparison has a tolerance."""
+        _, _, step, x, y = _adamw_step()
+        eager = [float(step(x, y)) for _ in range(6)]
+        _, _, step, x, y = _adamw_step()
+        fn = paddle.jit.to_static(step)
+        donated = [float(fn(x, y)) for _ in range(6)]
+        net, opt, step, x, y = _adamw_step()
+        fn = paddle.jit.to_static(step)
+        first = float(fn(x, y))                   # slots exist after it
+        held = list(net[2].parameters()) + [
+            t for t in opt.state_dict().values()
+            if isinstance(t, paddle.Tensor)]
+        kept, n_donated = [first], []
+        for _ in range(5):
+            loss, n = _donated_by(fn, x, y, *held)
+            kept.append(float(loss))
+            n_donated.append(n)
+        assert donated == kept                    # exact, not allclose
+        graph = _latest_graph(fn)
+        assert n_donated[-1] == len(graph.written) - len(held)
+        np.testing.assert_allclose(donated, eager, rtol=1e-6)
+
+    def test_detach_aliases_and_clone_snapshots(self):
+        net, _, step, x, y = _adamw_step()
+        fn = paddle.jit.to_static(step)
+        fn(x, y)
+        w = net[2].weight
+        alias, snap = w.detach(), w.clone()
+        # (not w.numpy(): on the CPU that is a view of the buffer, and
+        # a buffer with a view on it is copied instead of given away)
+        host = snap.numpy()
+        fn(x, y)
+        with pytest.raises(RuntimeError):
+            alias.numpy()                         # as Paddle: aliases
+        np.testing.assert_array_equal(snap.numpy(), host)
+        assert not np.array_equal(w.numpy(), host)
+
+    def test_set_state_dict_copies_from_live_state(self):
+        """A target network synced from the online one, and an optimizer
+        loaded from a live one, own their buffers: the online step's
+        donation must not delete them."""
+        net, opt, step, x, y = _adamw_step()
+        target, opt2 = _adamw_step(seed=1)[:2]
+        fn = paddle.jit.to_static(step)
+        fn(x, y)
+        target.set_state_dict(net.state_dict())
+        opt2.set_state_dict(opt.state_dict())
+        want = {k: v.numpy() for k, v in net.state_dict().items()}
+        for _ in range(2):
+            fn(x, y)
+        for k, v in target.state_dict().items():
+            np.testing.assert_array_equal(v.numpy(), want[k])
+        for v in opt2._pending_state.values():
+            if isinstance(v, paddle.Tensor):
+                v.numpy()
+
+    def test_rng_state_saved_before_a_compiled_call_restores(self):
+        """The generator's key is written state; a saved copy of it is
+        the caller's, before and after it was set back."""
+        paddle.seed(3)
+        drop = nn.Dropout(0.5)
+        fn = paddle.jit.to_static(lambda x: drop(x))
+        x = paddle.to_tensor(np.ones((64,), "float32"))
+        fn(x)                                     # discovery
+        saved = paddle.get_rng_state()
+        _, n = _donated_by(fn, x)
+        assert n == 1                             # the key
+        first = fn(x).numpy()
+        outs = []
+        for _ in range(2):
+            paddle.set_rng_state(saved)
+            fn(x)
+            outs.append(fn(x).numpy())
+        np.testing.assert_array_equal(outs[0], first)
+        np.testing.assert_array_equal(outs[1], first)
+
+    def test_model_average_survives_compiled_steps(self):
+        net, _, step, x, y = _adamw_step()
+        ma = paddle.incubate.ModelAverage(
+            parameters=list(net[2].parameters()))
+        fn = paddle.jit.to_static(step)
+        seen = []
+        for _ in range(4):
+            fn(x, y)
+            ma.step()
+            seen.append(net[2].weight.numpy())
+        with ma.apply():
+            np.testing.assert_allclose(net[2].weight.numpy(),
+                                       np.mean(seen, 0), rtol=1e-6)
 
 
 class TestFitPipelineSurface:
