@@ -1,0 +1,59 @@
+"""What a served model keeps between steps, declared per layer.
+
+``model.cache_spec()`` returns a list of entries in the order the model's
+``forward(caches=...)`` takes its arrays; :class:`ContinuousBatchingEngine`
+builds its pools, their reset and the page audit from it. A model without
+the method gets :func:`uniform_kv_spec`: one paged K/V pair per layer, the
+layout every dense decoder here has."""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+__all__ = ["PagedKV", "SlotState", "StepCounters", "uniform_kv_spec",
+           "spec_of"]
+
+
+@dataclass(frozen=True)
+class PagedKV:
+    """One attention layer's paged K/V: two pools ``(kv_heads, num_pages,
+    page_size, head_dim)``, and under quantized KV two float32 scales pools
+    ``(kv_heads, num_pages, page_size)`` after them."""
+    kv_heads: int
+    head_dim: int
+
+
+@dataclass(frozen=True)
+class SlotState:
+    """One array ``(num_slots, *shape)`` of per-slot recurrent state.
+    ``dtype`` None = the model's own. The MODEL keeps it right inside the
+    step program: a slot at position 0 starts from zero, an idle slot's
+    row is left as it is; the engine only allocates and rebuilds it.
+    Nothing of it is paged, so nothing of it can be shared, copied on
+    write or shipped: an engine whose spec has one serves without prefix
+    cache, speculative decoding and migration."""
+    shape: tuple
+    dtype: str | None = None
+
+
+@dataclass(frozen=True)
+class StepCounters:
+    """One int32 array ``(len(names),)`` the model ADDS to in every pass.
+    The engine hands the step program zeros and reads the sums back in the
+    step's one packed fetch, into counters ``serving/<name>``. The
+    vocabulary is the model's: its module declares each name with its help
+    text (``profiler.metrics.declare("serving/<name>", "counter", ...)``);
+    the engine knows none of them and refuses an undeclared one."""
+    names: tuple
+
+
+def uniform_kv_spec(cfg):
+    # MHA models (e.g. GPT2) carry no kv-head/head-dim fields
+    kvh = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+    d = getattr(cfg, "head_dim", cfg.hidden_size // cfg.num_attention_heads)
+    return [PagedKV(kvh, d)] * cfg.num_hidden_layers
+
+
+def spec_of(model):
+    fn = getattr(model, "cache_spec", None)
+    return list(fn()) if fn is not None else uniform_kv_spec(model.config)

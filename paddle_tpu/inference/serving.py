@@ -257,6 +257,12 @@ _pmetrics.declare("serving/kv_quant_scale_pool_bytes", "gauge",
                   "(0 when kv_quant='none') — the quantization "
                   "overhead term in the capacity math")
 
+# -- per-layer cache spec: recurrent state (a model's pass counters are
+# declared by the model: cache_spec.StepCounters)
+_pmetrics.declare("serving/state_pool_bytes", "gauge",
+                  "total bytes of the per-slot recurrent-state arrays "
+                  "(cache_spec.SlotState; 0 for a paged-KV-only model)")
+
 # -- speculative decoding: draft/verify economics (ISSUE 18)
 _pmetrics.declare("spec/steps", "counter",
                   "speculative unified-step programs dispatched "
@@ -567,48 +573,93 @@ class ContinuousBatchingEngine:
         self.greedy = bool(greedy)
         self.temperature = float(temperature)
 
-        # MHA models (e.g. GPT2) carry no kv-head/head-dim fields
-        kvh = getattr(cfg, "num_key_value_heads",
-                      cfg.num_attention_heads)
-        d = getattr(cfg, "head_dim",
-                    cfg.hidden_size // cfg.num_attention_heads)
-        # per layer: (key_pages, value_pages) — flat list like dense
-        # caches; geometry kept so step-failure containment can rebuild
-        # the pools from scratch (_reset_device_state). Quantized KV
-        # (ISSUE 20) interleaves two extra pools per layer — the
-        # page-parallel f32 scales pools (key_scales, value_scales),
-        # shape (kvh, num_pages, page_size): one scale per (token,
-        # kv head), page axis at index 1 like the data pools, so every
-        # generic pool operation (COW page copy, migration export/crc,
-        # batched import landing pads, containment rebuild) composes
-        # over the flat list unchanged.
-        self._pool_shape = (kvh, self.num_pages, self.page_size, d)
+        # the pools, from the model's cache spec (inference/cache_spec.py;
+        # a model that declares none gets one paged K/V pair per layer).
+        # A flat list like dense caches, in the model's own order;
+        # geometry kept so step-failure containment can rebuild the
+        # pools from scratch (_reset_device_state). Per PagedKV entry:
+        # (key_pages, value_pages), and under quantized KV (ISSUE 20)
+        # two extra pools after them — the page-parallel f32 scales
+        # pools (key_scales, value_scales), shape (kvh, num_pages,
+        # page_size): one scale per (token, kv head), page axis at
+        # index 1 like the data pools, so every page operation (COW page
+        # copy, migration export/crc, batched import landing pads)
+        # composes over the paged pools unchanged. Per SlotState entry:
+        # one (num_slots, ...) array the MODEL keeps right in-program.
+        # A StepCounters entry: one int32 vector the step program zeroes
+        # and reports in its packed fetch.
+        from .cache_spec import PagedKV, SlotState, StepCounters, spec_of
         self._pool_dtype = dtype if kv_quant == "none" else jnp.dtype(
             jnp.int8 if kv_quant == "int8" else jnp.float8_e4m3fn)
-        self._scale_shape = (kvh, self.num_pages, self.page_size)
-        if kv_quant == "none":
-            self._pool_shapes = [self._pool_shape] * 2
-            self._pool_dtypes = [self._pool_dtype] * 2
-        else:
-            self._pool_shapes = [self._pool_shape, self._pool_shape,
-                                 self._scale_shape, self._scale_shape]
-            self._pool_dtypes = [self._pool_dtype, self._pool_dtype,
-                                 jnp.float32, jnp.float32]
-        self._pool_shapes = self._pool_shapes * cfg.num_hidden_layers
-        self._pool_dtypes = self._pool_dtypes * cfg.num_hidden_layers
+        self._pool_shapes, self._pool_dtypes, self._pool_kinds = [], [], []
+        self._counter_names, self._counter_pool = (), None
+        for ent in spec_of(model):
+            if isinstance(ent, PagedKV):
+                shape = (ent.kv_heads, self.num_pages, self.page_size,
+                         ent.head_dim)
+                self._pool_shapes += [shape] * 2
+                self._pool_dtypes += [self._pool_dtype] * 2
+                self._pool_kinds += ["kv"] * 2
+                if kv_quant != "none":
+                    self._pool_shapes += [shape[:3]] * 2
+                    self._pool_dtypes += [jnp.float32] * 2
+                    self._pool_kinds += ["scale"] * 2
+            elif isinstance(ent, SlotState):
+                self._pool_shapes.append(
+                    (self.num_slots,) + tuple(ent.shape))
+                self._pool_dtypes.append(
+                    jnp.dtype(ent.dtype) if ent.dtype else dtype)
+                self._pool_kinds.append("state")
+            elif isinstance(ent, StepCounters):
+                if self._counter_pool is not None:
+                    raise ValueError("a cache spec holds at most one "
+                                     "StepCounters entry")
+                known = _pmetrics.catalog()
+                unknown = [n for n in ent.names
+                           if known.get("serving/" + n, ("",))[0]
+                           != "counter"]
+                if unknown:
+                    raise ValueError(
+                        f"StepCounters names {unknown} are not declared: "
+                        f"the model's module declares each as a "
+                        f"'serving/<name>' counter with its help text")
+                self._counter_names = tuple(ent.names)
+                self._counter_pool = len(self._pool_shapes)
+                self._pool_shapes.append((len(ent.names),))
+                self._pool_dtypes.append(jnp.int32)
+                self._pool_kinds.append("counters")
+            else:
+                raise TypeError(f"unknown cache spec entry {ent!r}")
         self._n_pools = len(self._pool_shapes)
+        #: pools with a page axis (index 1): what COW, export and import
+        #: walk
+        self._paged = [i for i, k in enumerate(self._pool_kinds)
+                       if k in ("kv", "scale")]
+        #: per-slot recurrent state lives outside the pages: nothing of
+        #: it can be shared, forked or shipped (cache_spec.SlotState)
+        self._has_state = "state" in self._pool_kinds
+        if self._has_state:
+            if role == "prefill":
+                raise ValueError(
+                    "role='prefill' exports finished prompt pages; this "
+                    "model keeps per-slot recurrent state beside its "
+                    "pages, which a migration does not carry yet")
+            if spec_decode or spec_k is not None or spec_draft is not None:
+                raise ValueError(
+                    "speculative decoding rolls a slot back to its last "
+                    "accepted token; this model keeps per-slot recurrent "
+                    "state, which has no rollback yet")
         self.pools = [Tensor(jnp.zeros(s, dt)) for s, dt in
                       zip(self._pool_shapes, self._pool_dtypes)]
-        # static pool-geometry facts for the kv_quant gauges
+        # static pool-geometry facts for the gauges
         self._kv_quant_bits = 8 * jnp.dtype(self._pool_dtype).itemsize
-        self._kv_pool_bytes = sum(
+        _bytes = lambda kind: sum(
             int(np.prod(s)) * jnp.dtype(dt).itemsize
-            for s, dt in zip(self._pool_shapes, self._pool_dtypes)
-            if len(s) == 4)
-        self._kv_scale_pool_bytes = sum(
-            int(np.prod(s)) * jnp.dtype(dt).itemsize
-            for s, dt in zip(self._pool_shapes, self._pool_dtypes)
-            if len(s) == 3)
+            for s, dt, k in zip(self._pool_shapes, self._pool_dtypes,
+                                self._pool_kinds) if k == kind)
+        self._kv_pool_bytes = _bytes("kv")
+        self._kv_scale_pool_bytes = _bytes("scale")
+        self._state_pool_bytes = _bytes("state")
 
         self._free_pages = deque(range(1, self.num_pages))
         # host-side slot bookkeeping (admission decisions, drain)
@@ -720,6 +771,9 @@ class ContinuousBatchingEngine:
         # or prefix_cache=False restores exclusive-page behavior.
         self._prefix_cache = _env_bool("PADDLE_TPU_PREFIX_CACHE", True) \
             if prefix_cache is None else bool(prefix_cache)
+        if self._has_state:
+            # a hit would skip tokens whose recurrent state nobody stored
+            self._prefix_cache = False
         self._pc_root = _PrefixCacheNode(None, 0, None)   # sentinel
         self._pc_nodes: dict[int, _PrefixCacheNode] = {}  # page -> node
         self._pc_clock = 0                                # LRU stamps
@@ -801,6 +855,11 @@ class ContinuousBatchingEngine:
             "serving/kv_quant_pool_bytes")
         self._g_kvq_scale_bytes = self.metrics.gauge(
             "serving/kv_quant_scale_pool_bytes")
+        self._g_state_bytes = self.metrics.gauge(
+            "serving/state_pool_bytes")
+        # the model's own pass counters (cache_spec.StepCounters)
+        self._c_model = {n: self.metrics.counter("serving/" + n)
+                         for n in self._counter_names}
         self._c_migrated_out = self.metrics.counter(
             "disagg/migrated_out")
         self._c_kv_exported = self.metrics.counter(
@@ -1010,7 +1069,7 @@ class ContinuousBatchingEngine:
             # inactive in every dispatched program (its writes are
             # trash-page-guarded), so the fetched content is the final
             # prefill output even under the pipelined driver
-            data = [np.asarray(p._data[:, page]) for p in self.pools]
+            data = [np.asarray(a[:, page]) for a in self._paged_arrays()]
             blocks.append({
                 "tokens": np.asarray(
                     eff[lvl * ps:(lvl + 1) * ps], np.int32),
@@ -1020,7 +1079,7 @@ class ContinuousBatchingEngine:
             })
         payload = {"version": 1, "rid": int(req.request_id),
                    "eff_len": int(len(eff)), "page_size": ps,
-                   "n_pools": self._n_pools,
+                   "n_pools": len(self._paged),
                    "dtype": str(self._pool_dtype),
                    "kv_quant": self.kv_quant,
                    "blocks": blocks}
@@ -1077,12 +1136,17 @@ class ContinuousBatchingEngine:
         ANY malformed/damaged block stops seeding (the chain must stay
         root-contiguous) and the request still replays correctly from
         whatever prefix landed. Returns import counts."""
+        if self._has_state:
+            raise ValueError(
+                "import_migration lands shipped prompt pages; this model "
+                "keeps per-slot recurrent state beside its pages, which "
+                "no migration carries yet (requeue() replays the tokens)")
         imported = dedup = rejected = 0
         pending = []          # (page, [per-pool np page content])
         ok = (self._prefix_cache and isinstance(payload, dict)
               and payload.get("version") == 1
               and payload.get("page_size") == self.page_size
-              and payload.get("n_pools") == self._n_pools
+              and payload.get("n_pools") == len(self._paged)
               and payload.get("dtype") == str(self._pool_dtype)
               # geometry handshake: quantized pages only land in a
               # same-kv_quant pool (a mixed pair falls back to the
@@ -1106,7 +1170,7 @@ class ContinuousBatchingEngine:
                     continue
                 data = blk.get("data") or []
                 crcs = blk.get("crc")
-                if len(data) != self._n_pools or (
+                if len(data) != len(self._paged) or (
                         crcs is not None
                         and [zlib.crc32(np.ascontiguousarray(
                                 d).tobytes()) for d in data]
@@ -1141,9 +1205,9 @@ class ContinuousBatchingEngine:
             dst = jnp.asarray([p for p, _ in padded], jnp.int32)
             stacked = [jnp.asarray(
                 np.stack([d[i] for _, d in padded], axis=1),
-                self._pool_dtypes[i]) for i in range(self._n_pools)]
-            self.pools = [Tensor(a) for a in _kv_write_pages(
-                [p._data for p in self.pools], dst, stacked)]
+                self._pool_dtypes[pi]) for i, pi in enumerate(self._paged)]
+            self._set_paged(_kv_write_pages(self._paged_arrays(), dst,
+                                            stacked))
         _t_obs = time.perf_counter()
         if imported:
             self._c_kv_imported.inc(imported)
@@ -1503,6 +1567,14 @@ class ContinuousBatchingEngine:
         self._audit_pages("containment")
         return done
 
+    def _paged_arrays(self):
+        """The pools that have a page axis, in pool order."""
+        return [self.pools[i]._data for i in self._paged]
+
+    def _set_paged(self, arrays):
+        for i, a in zip(self._paged, arrays):
+            self.pools[i] = Tensor(a)
+
     def _reset_device_state(self):
         """Rebuild the pools, the free list and all per-slot state from
         scratch — FRESH device buffers, so writes still racing out of
@@ -1586,6 +1658,7 @@ class ContinuousBatchingEngine:
         temperature = self.temperature
         C = self.prefill_chunk
         n_dec = self._n_decode
+        cpool = self._counter_pool
 
         def ustep(ids_t, nq_t, last_t, tgt_t, tok_t, ctx_t, act_t,
                   tbl_t, lim_t, eos_t, key_t, *pools):
@@ -1594,6 +1667,12 @@ class ContinuousBatchingEngine:
             def fn(ids, nq, last, tgt, tok, ctx, act, tbl, lim,
                    eos_arr, key, *pool_leaves):
                 b = tok.shape[0]
+                if cpool is not None:
+                    # the model's pass counters start every step at 0
+                    # and leave with its packed fetch
+                    pool_leaves = list(pool_leaves)
+                    pool_leaves[cpool] = jnp.zeros_like(
+                        pool_leaves[cpool])
                 # stale instant-eos guard (legacy chunk-entry contract)
                 act = act & ((eos_arr < 0) | (tok != eos_arr))
                 is_pre = nq > 0
@@ -1670,11 +1749,15 @@ class ContinuousBatchingEngine:
                     tok_f, ctx_f, act_f, key_f, leaves_f = carry0
                     toks_all = out0[:, None]
                     emit_all = fire[:, None]
-                packed_out = jnp.concatenate(
-                    [toks_all.astype(jnp.int32),
-                     emit_all.astype(jnp.int32),
-                     ctx_f[:, None].astype(jnp.int32),
-                     act_f[:, None].astype(jnp.int32)], axis=1)
+                cols = [toks_all.astype(jnp.int32),
+                        emit_all.astype(jnp.int32),
+                        ctx_f[:, None].astype(jnp.int32),
+                        act_f[:, None].astype(jnp.int32)]
+                if cpool is not None:
+                    cols.append(jnp.broadcast_to(
+                        leaves_f[cpool][None, :],
+                        (b, leaves_f[cpool].shape[0])))
+                packed_out = jnp.concatenate(cols, axis=1)
                 return (packed_out, tok_f, ctx_f, act_f, key_f) \
                     + tuple(leaves_f)
 
@@ -1857,7 +1940,13 @@ class ContinuousBatchingEngine:
             # columns (committed-draft and drafted counts per slot) past
             # the layout this method parses — fold them into the spec
             # economics counters
-            if arr.shape[1] > 2 * n_steps + 2:
+            if self._counter_names and not self._spec:
+                # the model's pass counters ride the same fetch: every
+                # row repeats them
+                base = 2 * n_steps + 2
+                for j, name in enumerate(self._counter_names):
+                    self._c_model[name].inc(int(arr[0, base + j]))
+            elif arr.shape[1] > 2 * n_steps + 2:
                 nds = arr[:, 2 * n_steps + 3]
                 accs = arr[:, 2 * n_steps + 2]
                 drafted = int(nds.sum())
@@ -2227,6 +2316,11 @@ class ContinuousBatchingEngine:
             "kv_quant_bits": int(self._kv_quant_bits),
             "kv_quant_pool_bytes": int(self._kv_pool_bytes),
             "kv_quant_scale_pool_bytes": int(self._kv_scale_pool_bytes),
+            # per-layer cache spec: paged K/V beside per-slot state,
+            # and whatever the model counts per pass
+            "kv_pool_bytes": int(self._kv_pool_bytes),
+            "state_pool_bytes": int(self._state_pool_bytes),
+            **{n: int(c.value) for n, c in self._c_model.items()},
         }
 
     def reset_gauges(self):
@@ -2237,7 +2331,8 @@ class ContinuousBatchingEngine:
         for k in self._stats:
             self._stats[k] = 0.0 if k == "run_seconds" else 0
         for c in (self._c_spec_steps, self._c_spec_drafted,
-                  self._c_spec_accepted, self._c_spec_rejected):
+                  self._c_spec_accepted, self._c_spec_rejected,
+                  *self._c_model.values()):
             c.set(0)
         self._h_ttft.reset()
         self._h_itl.reset()
@@ -2254,6 +2349,7 @@ class ContinuousBatchingEngine:
         self._g_kvq_bits.set(int(self._kv_quant_bits))
         self._g_kvq_pool_bytes.set(int(self._kv_pool_bytes))
         self._g_kvq_scale_bytes.set(int(self._kv_scale_pool_bytes))
+        self._g_state_bytes.set(int(self._state_pool_bytes))
         from ..profiler.trace import get_tracer
         tr = get_tracer()
         if tr.enabled:
@@ -2366,34 +2462,25 @@ class ContinuousBatchingEngine:
                 raise AssertionError(
                     f"prefix-cache attachment to unindexed page "
                     f"{page} at {where}")
-        # quantized-KV structural invariant (ISSUE 20): every layer
-        # carries [k, v, k_scales, v_scales] and the scales pools index
-        # the SAME page axis as their data pools — a page id is valid
-        # in all four or in none, so the single accounting above covers
-        # the scales pools too iff the geometry agrees
-        if self.kv_quant != "none":
-            if len(self.pools) != self._n_pools \
-                    or self._n_pools != 4 * self.cfg.num_hidden_layers:
+        # structural invariant: the pools are the cache spec's, in its
+        # order, shapes and dtypes. Under quantized KV (ISSUE 20) every
+        # attention layer carries [k, v, k_scales, v_scales] and the
+        # scales pools index the SAME page axis as their data pools — a
+        # page id is valid in all four or in none, so the single
+        # accounting above covers the scales pools too iff the geometry
+        # agrees
+        if len(self.pools) != self._n_pools:
+            raise AssertionError(
+                f"pool count broken at {where}: {len(self.pools)} pools, "
+                f"the cache spec gives {self._n_pools}")
+        for i, p in enumerate(self.pools):
+            if tuple(p._data.shape) != tuple(self._pool_shapes[i]) \
+                    or p._data.dtype != self._pool_dtypes[i]:
                 raise AssertionError(
-                    f"quantized pool count broken at {where}: "
-                    f"{len(self.pools)} pools, expected "
-                    f"{4 * self.cfg.num_hidden_layers}")
-            for i, p in enumerate(self.pools):
-                shape = tuple(p._data.shape)
-                want = (self._pool_shape if i % 4 < 2
-                        else self._scale_shape)
-                if shape != want:
-                    raise AssertionError(
-                        f"quantized pool geometry broken at {where}: "
-                        f"pool {i} shape {shape} != {want}")
-                if i % 4 >= 2 and p._data.dtype != jnp.float32:
-                    raise AssertionError(
-                        f"scales pool {i} dtype {p._data.dtype} at "
-                        f"{where}: scales must stay f32")
-                if shape[1] != self.num_pages:
-                    raise AssertionError(
-                        f"pool {i} page-axis length {shape[1]} != "
-                        f"num_pages {self.num_pages} at {where}")
+                    f"pool geometry broken at {where}: pool {i} "
+                    f"({self._pool_kinds[i]}) is {tuple(p._data.shape)} "
+                    f"{p._data.dtype}, the cache spec gives "
+                    f"{tuple(self._pool_shapes[i])} {self._pool_dtypes[i]}")
 
     # ---- prefix cache: radix index + COW sharing (ISSUE 12) --------------
 
@@ -2514,8 +2601,7 @@ class ContinuousBatchingEngine:
         prefix owner's completed writes and is visible to every later
         program."""
         s, d = jnp.int32(src), jnp.int32(dst)
-        self.pools = [Tensor(a) for a in _pc_copy_page(
-            [p._data for p in self.pools], s, d)]
+        self._set_paged(_pc_copy_page(self._paged_arrays(), s, d))
         self._stats.inc("prefix_cache_cow_forks")
 
     @property
@@ -2611,7 +2697,10 @@ class ContinuousBatchingEngine:
         class the identity checks exist to catch). ``device=True``
         additionally deactivates the slot's DEVICE mirrors: needed on
         eviction, where the device still believes the slot is active;
-        a drained slot already went inactive inside its program."""
+        a drained slot already went inactive inside its program.
+        Per-slot recurrent state (``cache_spec.SlotState``) is not
+        touched: the model zeroes a slot's row in-program when its next
+        occupant starts at position 0."""
         self._pc_detach(slot)        # shared pages: decref, stay cached
         self.slot_pages[slot] = []
         self.slot_req[slot] = None

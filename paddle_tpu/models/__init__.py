@@ -12,6 +12,7 @@ from .qwen2 import (Qwen2Config, Qwen2MoeConfig, Qwen2ForCausalLM,
 from .ernie import (ErnieConfig, ErnieModel, ErnieForPretraining,
                     ErnieForMaskedLM, ErnieForSequenceClassification)
 from .deepseek import DeepseekV2Config, DeepseekV2ForCausalLM
+from .nemotron_h import NemotronHConfig, NemotronHForCausalLM
 
 __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "LlamaModel", "LlamaForCausalLM", "LlamaForCausalLMPipe",
@@ -19,4 +20,6 @@ __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "Qwen2MoeConfig", "Qwen2ForCausalLM", "Qwen2MoeForCausalLM",
            "Qwen2MoeForCausalLMPipe", "Qwen2MoePretrainingCriterion",
            "ErnieConfig", "ErnieModel", "ErnieForPretraining",
-           "ErnieForMaskedLM", "ErnieForSequenceClassification", "DeepseekV2Config", "DeepseekV2ForCausalLM"]
+           "ErnieForMaskedLM", "ErnieForSequenceClassification", "DeepseekV2Config",
+           "DeepseekV2ForCausalLM", "NemotronHConfig",
+           "NemotronHForCausalLM"]

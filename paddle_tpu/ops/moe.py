@@ -32,7 +32,8 @@ from jax import lax
 
 __all__ = ["top_k_gating", "top_k_gating_idx", "moe_dispatch_combine",
            "moe_ffn_grouped", "moe_forward", "moe_forward_ep",
-           "sort_rows_by_expert", "moe_forward_dropless", "moe_ablation"]
+           "sort_rows_by_expert", "moe_forward_dropless", "moe_ablation",
+           "top_k_weights", "sigmoid_top_k_router", "moe_experts_held"]
 
 
 # -- section ablation (profiler.breakdown step-attribution harness) --------
@@ -86,6 +87,38 @@ def _ablation_gating(x, T, E, k, capacity):
     return gate_idx, gate_vals, pos, keep, zero, zero
 
 
+def top_k_weights(select, scores, k, norm_topk_prob=True, scale=1.0):
+    """The tail every router here shares: the ``k`` largest columns of
+    ``select`` per row, and for each the weight read from ``scores`` at
+    that column, normalised over the k chosen (``norm_topk_prob``) and
+    times ``scale``. The softmax gates pass their probabilities for
+    both; a router with a selection bias passes biased scores to choose
+    by and the unbiased ones to weigh by. Returns (idx [T, k], w [T, k])."""
+    if select is scores:
+        vals, idx = lax.top_k(scores, k)
+    else:
+        _, idx = lax.top_k(select, k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        vals = vals / jnp.maximum(
+            jnp.sum(vals, -1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        vals = vals * scale
+    return idx, vals
+
+
+def sigmoid_top_k_router(logits, bias, k, norm_topk_prob=True, scale=1.0):
+    """Sigmoid router with a selection bias (the DeepSeek-V3 form, one
+    group): scores ``sigmoid(logits)``; the k experts are the largest of
+    ``scores + bias``; their weights are the UNBIASED scores, normalised
+    over the k and scaled. logits [T, E] (computed in float32), bias [E].
+    Returns (idx [T, k] int32, w [T, k] float32)."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    idx, w = top_k_weights(scores + bias.astype(jnp.float32), scores, k,
+                           norm_topk_prob, scale)
+    return idx.astype(jnp.int32), w
+
+
 def top_k_gating(logits, k, capacity, norm_topk_prob=True):
     """Top-k softmax gating with capacity-bounded dispatch tensors.
 
@@ -96,10 +129,8 @@ def top_k_gating(logits, k, capacity, norm_topk_prob=True):
     T, E = logits.shape
     logits = logits.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = lax.top_k(probs, k)          # [T, k]
-    if norm_topk_prob:
-        gate_vals = gate_vals / jnp.maximum(
-            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    gate_idx, gate_vals = top_k_weights(probs, probs, k,
+                                        norm_topk_prob)   # [T, k]
 
     # one-hot per assignment: [T, k, E]
     assign = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
@@ -143,10 +174,8 @@ def top_k_gating_idx(logits, k, capacity, norm_topk_prob=True):
     T, E = logits.shape
     logits = logits.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = lax.top_k(probs, k)          # [T, k]
-    if norm_topk_prob:
-        gate_vals = gate_vals / jnp.maximum(
-            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    gate_idx, gate_vals = top_k_weights(probs, probs, k,
+                                        norm_topk_prob)   # [T, k]
 
     assign = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [T, k, E]
     flat = assign.reshape(T * k, E)
@@ -350,6 +379,56 @@ def moe_forward_dropless(x, router_w, w_gate, w_up, w_down, k=2,
     y_k = y_p[perm].reshape(T, k, d)                    # gather back
     w = gate_vals.astype(y_k.dtype)[..., None]
     return jnp.sum(y_k * w, axis=1).astype(x.dtype), aux, z
+
+
+def moe_experts_held(v, idx, weights, w1, w2, first, valid=None, bm=None):
+    """The routed part of an expert layer that HOLDS experts
+    ``[first, first + E_held)`` of a larger routed set: of the ``[T, k]``
+    pairs the router chose over ALL experts, only those whose expert is
+    held here (and whose token is ``valid``) are sorted, sent through
+    two grouped matmuls with a squared ReLU between (no gate matrix) and
+    summed with the router's weights — dropless: every held pair is
+    computed. The other pairs belong to other holders, whose outputs add
+    to this one's (everything after the selection is linear in the
+    experts' sum).
+
+    v [T, d] tokens (a latent, or the hidden state), idx [T, k] global
+    expert ids, weights [T, k], w1 [E_held, d, h], w2 [E_held, h, d].
+    The static row capacity is the worst case (all T*k pairs held); the
+    kernel skips the tiles past the live ones. Returns (out [T, d],
+    stats) with stats = int32 [local pairs, pairs of the busiest held
+    expert]."""
+    from .pallas.grouped_matmul import grouped_matmul_live
+
+    T, d = v.shape
+    k = idx.shape[1]
+    E = w1.shape[0]
+    if bm is None:
+        # a decode step sends an expert a handful of rows: the smallest
+        # bf16 row tile; a prompt block fills MXU-sized ones
+        bm = 128 if T * k >= 128 * E else 16
+    local = idx.astype(jnp.int32) - first
+    held = (local >= 0) & (local < E)
+    if valid is not None:
+        held = held & valid[:, None]
+    # group E is the layout's last: everything not computed here
+    gid = jnp.where(held, local, E)
+    perm, tile_gid, P = sort_rows_by_expert(gid, E + 1, bm=bm)
+    n_live = jnp.sum(tile_gid < E).astype(jnp.int32)
+    tile_gid = jnp.minimum(tile_gid, E - 1)
+    src = jnp.full((P,), T, jnp.int32).at[perm].set(
+        jnp.arange(T * k, dtype=jnp.int32) // k)
+    v_pad = jnp.concatenate([v, jnp.zeros((1, d), v.dtype)], axis=0)
+    a = grouped_matmul_live(v_pad[src], w1, tile_gid, n_live, act="relu2")
+    y_p = grouped_matmul_live(a, w2, tile_gid, n_live)
+    y_k = y_p[perm].reshape(T, k, d)
+    # where, not a product: rows of dead tiles were never written
+    y_k = jnp.where(held[..., None],
+                    y_k * weights.astype(y_k.dtype)[..., None], 0)
+    counts = jnp.zeros((E + 1,), jnp.int32).at[gid.reshape(-1)].add(1)
+    stats = jnp.stack([jnp.sum(held).astype(jnp.int32),
+                       jnp.max(counts[:E])])
+    return jnp.sum(y_k, axis=1).astype(v.dtype), stats
 
 
 def moe_forward_ep(x, router_w, expert_fn_local, axis_name, k=2,

@@ -46,7 +46,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._utils import interpret_mode as _interpret, no_x64 as _no_x64
 
-__all__ = ["grouped_matmul", "grouped_matmul_t", "grouped_dw"]
+__all__ = ["grouped_matmul", "grouped_matmul_t", "grouped_dw",
+           "grouped_matmul_live"]
 
 
 def _pick_block(dim, want):
@@ -62,53 +63,105 @@ def _pick_block(dim, want):
     return dim
 
 
-def _fwd_kernel(gid_ref, x_ref, w_ref, o_ref, *, transpose_rhs):
-    x = x_ref[...]
-    w = w_ref[...]  # (None, a, b) BlockSpec squeezes the expert dim
-    dn = (((1,), (1,)), ((), ())) if transpose_rhs \
-        else (((1,), (0,)), ((), ()))
-    acc = lax.dot_general(x, w, dn, preferred_element_type=jnp.float32)
-    o_ref[...] = acc.astype(o_ref.dtype)
+def _fwd_kernel(gid_ref, live_ref, x_ref, w_ref, o_ref, *, transpose_rhs,
+                act):
+    # tiles past the live ones compute and write nothing
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        x = x_ref[...]
+        w = w_ref[...]  # (None, a, b) BlockSpec squeezes the expert dim
+        dn = (((1,), (1,)), ((), ())) if transpose_rhs \
+            else (((1,), (0,)), ((), ()))
+        acc = lax.dot_general(x, w, dn, preferred_element_type=jnp.float32)
+        if act == "relu2":
+            acc = jnp.square(jnp.maximum(acc, 0.0))
+        o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _gmm_call(x, w, tile_gid, transpose_rhs, bn):
-    """y[t] = x[t] @ w[gid(t)] (or @ w[gid(t)].T when transpose_rhs).
+def _gmm_call(x, w, tile_gid, transpose_rhs, bn, n_live=None, act=None):
+    """y[t] = act(x[t] @ w[gid(t)]) (or @ w[gid(t)].T when transpose_rhs).
 
     x [P, k_dim]; w [E, d, h] contracting d (or h when transposed);
     output [P, h] (or [P, d]). bn tiles the output feature dim; the
-    contraction dim is whole (one MXU pass per tile)."""
+    contraction dim is whole (one MXU pass per tile).
+
+    ``n_live`` (int32 scalar, None = every tile): only the first
+    ``n_live`` row tiles are computed; the rows of the others are left
+    UNWRITTEN. A dead tile costs a grid step and no DMA: its input block
+    indices repeat the last live tile's, and its output block is the one
+    trash tile ``nr - 1``, which the caller's layout must keep dead
+    (``n_live <= nr - 1`` wherever a tile is dead). ``act``: None or
+    "relu2" (squared ReLU on the f32 accumulator)."""
     P, kdim = x.shape
-    E = w.shape[0]
     out_dim = w.shape[1] if transpose_rhs else w.shape[2]
     nr = tile_gid.shape[0]
     bm = P // nr
     assert bm * nr == P, (P, nr)
     bn = _pick_block(out_dim, bn)
     nj = out_dim // bn
+    live = jnp.full((1,), nr, jnp.int32) if n_live is None \
+        else jnp.reshape(n_live, (1,)).astype(jnp.int32)
+
+    def row(i, nl):
+        return jnp.maximum(jnp.minimum(i, nl[0] - 1), 0)
+
+    def col(i, j, nl):
+        return jnp.where(i < nl[0], j, nj - 1)
 
     if transpose_rhs:
-        w_spec = pl.BlockSpec((None, bn, kdim),
-                              lambda i, j, g: (g[i], j, 0))
+        w_spec = pl.BlockSpec(
+            (None, bn, kdim),
+            lambda i, j, g, nl: (g[row(i, nl)], col(i, j, nl), 0))
     else:
-        w_spec = pl.BlockSpec((None, kdim, bn),
-                              lambda i, j, g: (g[i], 0, j))
+        w_spec = pl.BlockSpec(
+            (None, kdim, bn),
+            lambda i, j, g, nl: (g[row(i, nl)], 0, col(i, j, nl)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nr, nj),
         in_specs=[
-            pl.BlockSpec((bm, kdim), lambda i, j, g: (i, 0)),
+            pl.BlockSpec((bm, kdim), lambda i, j, g, nl: (row(i, nl), 0)),
             w_spec,
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, g: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, g, nl: (
+            jnp.where(i < nl[0], i, nr - 1), jnp.where(i < nl[0], j, 0))),
     )
     with _no_x64():
         return pl.pallas_call(
-            functools.partial(_fwd_kernel, transpose_rhs=transpose_rhs),
+            functools.partial(_fwd_kernel, transpose_rhs=transpose_rhs,
+                              act=act),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((P, out_dim), x.dtype),
             name="grouped_matmul",
             interpret=_interpret(),
-        )(tile_gid, x, w)
+        )(tile_gid.astype(jnp.int32), live, x, w)
+
+
+#: the weight block of :func:`grouped_matmul_live` stays under this many
+#: bytes: two of them (double buffering) beside the row blocks fit the
+#: 16 MiB of scoped VMEM at any contraction width
+_LIVE_BLOCK_BYTES = 4 << 20
+
+
+def grouped_matmul_live(x, w, tile_gid, n_live, act=None):
+    """The forward kernel over the first ``n_live`` row tiles only (not
+    differentiable): ``y[t] = act(x[t] @ w[tile_gid(t // bm)])`` for rows
+    of live tiles; rows of the other tiles are left UNWRITTEN (the caller
+    must not read them). For a layout whose static row capacity is the
+    worst case and whose live part is usually a fraction of it — an
+    expert layer that holds a share of the experts and is sent only its
+    own pairs (:func:`_gmm_call` says what a dead tile costs).
+
+    x [P, d] sorted and group-padded (``ops.moe.sort_rows_by_expert``),
+    w [E, d, h], ``tile_gid`` [P // bm] with every entry < E, ``n_live``
+    int32 scalar <= nr - 1. The weight block is [d, bn] with bn the
+    largest lane-aligned divisor of h that keeps it under
+    ``_LIVE_BLOCK_BYTES``."""
+    kdim = x.shape[1]
+    bn = max(128, _LIVE_BLOCK_BYTES // (kdim * w.dtype.itemsize)
+             // 128 * 128)
+    return _gmm_call(x, w, tile_gid, transpose_rhs=False, bn=bn,
+                     n_live=n_live, act=act)
 
 
 def _dw_kernel(gid_ref, x_ref, dy_ref, o_ref, acc_ref, *, nr):

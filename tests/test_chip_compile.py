@@ -225,6 +225,26 @@ def test_grouped_matmul_fwd_bwd(native, one_chip):
 
 # ---- under a mesh --------------------------------------------------------
 
+@pytest.mark.parametrize("tokens", [64, 64 * 128])
+def test_held_expert_layer_at_published_widths(native, one_chip, tokens):
+    """The held-share expert layer (ops.moe.moe_experts_held) as the
+    Nemotron-3-Super cell runs it: 128 held experts of 512, latent 1024,
+    expert width 2688, 22 pairs a token — a decode step's 64 tokens (row
+    tile 16) and a mixed pass's 64 x 128 positions (row tile 128, live
+    tiles only)."""
+    from paddle_tpu.ops import moe
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(v, idx, w, w1, w2, valid):
+        return moe.moe_experts_held(v, idx, w, w1, w2, 0, valid=valid)
+
+    _compile(fn, sds((tokens, 1024), BF16), sds((tokens, 22), jnp.int32),
+             sds((tokens, 22), jnp.float32), sds((128, 1024, 2688), BF16),
+             sds((128, 2688, 1024), BF16), sds((tokens,), jnp.bool_))
+
+
 def test_kernels_under_a_2x2_mesh(native, topo):
     """Mosaic kernels cannot be partitioned automatically. Under a
     fleet mesh (sharding=2 x model=2, the ``--chips 4`` phase) the call

@@ -23,7 +23,10 @@ call, or when it is minted through the prefix-concat idiom
 a metric call whose first argument is ``"<subsystem>/" + <expr>``
 marks the prefix, and a declared name under that prefix counts as live
 iff its suffix appears as a string constant in the SAME file (the
-``_STAT_KEYS`` tuple). A declared name that matches neither is an
+``_STAT_KEYS`` tuple) or in the file that DECLARES it (a served model
+declares the pass counters it hands the engine through
+``cache_spec.StepCounters`` beside the tuple that names them). A
+declared name that matches neither is an
 error: a declared-but-never-written metric is documentation lying
 about instrumentation that does not exist. Dynamic names beyond that
 idiom (f-strings over a gauges() dict etc.) are out of scope by
@@ -101,6 +104,7 @@ def scan_file(path):
 def collect(root):
     declares, uses = {}, []   # name -> (kind, help, file, line)
     concat = []               # (prefixes, strings) per file
+    strings_of = {}           # declaring file -> its string constants
     files = []
     pkg = os.path.join(root, "paddle_tpu")
     for dirpath, dirnames, filenames in os.walk(pkg):
@@ -125,22 +129,27 @@ def collect(root):
         uses.extend((name, rel, line) for name, line in use)
         if prefixes:
             concat.append((prefixes, strings))
-    return declares, uses, errors, concat
+        if decl:
+            strings_of[rel] = strings
+    return declares, uses, errors, concat, strings_of
 
 
-def dead_metrics(declares, uses, concat):
+def dead_metrics(declares, uses, concat, strings_of):
     """Declared-but-never-written names (module docstring): not used
     as a literal metric-API arg anywhere, and not mintable through a
-    same-file prefix-concat idiom."""
+    prefix-concat idiom from a suffix constant in the minting file or
+    in the declaring one."""
     used = {n for n, _, _ in uses}
     dead = []
     for name in declares:
         if name in used:
             continue
         alive = False
+        own = strings_of.get(declares[name][2], ())
         for prefixes, strings in concat:
             for p in prefixes:
-                if name.startswith(p) and name[len(p):] in strings:
+                if name.startswith(p) and (name[len(p):] in strings
+                                           or name[len(p):] in own):
                     alive = True
                     break
             if alive:
@@ -158,7 +167,7 @@ def main(argv=None) -> int:
     root = argv[0] if argv else \
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-    declares, uses, errors, concat = collect(root)
+    declares, uses, errors, concat, strings_of = collect(root)
 
     if table:
         print("| metric | kind | meaning |")
@@ -192,7 +201,7 @@ def main(argv=None) -> int:
                 f"{DOCS} (add a `{name}` row; regenerate with "
                 "tools/check_metric_names.py --table)")
 
-    for name in sorted(dead_metrics(declares, uses, concat)):
+    for name in sorted(dead_metrics(declares, uses, concat, strings_of)):
         _, _, rel, line = declares[name]
         errors.append(
             f"{rel}:{line}: metric {name!r} is declared but never "
