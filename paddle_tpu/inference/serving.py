@@ -10,15 +10,15 @@ world. Two engine modes share the pool/slot machinery:
 
 **Unified mode (default, ``unified=True``)** — ONE compiled
 batching-step program for the whole scheduler turn, built on the ragged
-paged-attention entry point (PAPERS.md "Ragged Paged Attention"): a
-mixed ragged pass advances every slot — prefill slots stream their next
-``prefill_chunk`` prompt tokens, active decode slots ride their pending
-token as a length-1 sequence, idle slots are length 0 — through one
-``[num_slots, prefill_chunk]`` forward, samples where a prompt
-completes or a decode step fires, then chains ``decode_chunk - 1``
-in-program decode micro-steps via ``lax.scan``. Prefill→decode
+paged-attention entry point (PAPERS.md "Ragged Paged Attention"). It
+computes the positions whose result the turn uses: a loop over groups
+of prefilling slots, its trip count the turn's own data, streams each
+group's next ``prefill_chunk`` prompt tokens through one ``[group,
+prefill_chunk]`` forward whose head reads one row per slot, and samples
+where a prompt completes; then ``decode_chunk`` in-program decode
+micro-steps via ``lax.scan`` advance the decoding slots. Prefill→decode
 transition happens ON DEVICE inside the program (a slot whose prompt
-ends in the mixed pass decodes from micro-step 1), so the PR-3
+ends in the loop joins the scan after micro-step 0), so the PR-3
 prefill-wave/decode-chunk interleave, its first-token echo machinery,
 and the residual compiled-signature zoo all collapse: steady-state
 ``compiled_programs`` == 1.
@@ -149,9 +149,11 @@ _pmetrics.declare("serving/prefill_tokens", "counter",
                   "prompt tokens carried by dispatched programs (sum "
                   "of the per-slot chunk lengths)")
 _pmetrics.declare("serving/prefill_positions", "counter",
-                  "prompt positions computed by dispatched programs "
-                  "(num_slots x prefill_chunk per mixed pass or "
-                  "prefill wave, filled or not)")
+                  "prompt positions computed by dispatched programs, "
+                  "filled or not (the unified step: groups run x rows "
+                  "a group x prefill_chunk; a speculative step's or a "
+                  "legacy prefill wave's pass: num_slots x "
+                  "prefill_chunk)")
 _pmetrics.declare("serving/ttft_ms", "histogram",
                   "request arrival -> first token on host, ms (bounded "
                   "reservoir; p50/p99 exposed via gauges())")
@@ -276,6 +278,16 @@ _pmetrics.declare("spec/tokens_rejected", "counter",
                   "draft tokens rejected at verification and rolled "
                   "back (their in-flight KV writes are left "
                   "unreachable behind ctx and overwritten in place)")
+
+#: prompt positions one group of the unified step's prefill loop computes
+#: (rows a group = this // prefill_chunk, at most num_slots: 8 at chunk
+#: 128). A matmul that streams bf16 weights does T FLOP per weight byte
+#: for T positions and the v5e's ridge is 197e12 / 819e9 = 240, so 1,024
+#: positions keep every projection compute-bound with a factor of four
+#: to spare, while a turn of chat or offline traffic (5-6 prefilling
+#: slots of 64) fits one group. Not an option: the loop's trip count
+#: follows the turn, this only sizes one trip.
+PREFILL_GROUP_POSITIONS = 1024
 
 #: the historical ``_stats`` key set, preserved verbatim — now backed
 #: by ``serving/*`` registry counters
@@ -783,13 +795,18 @@ class ContinuousBatchingEngine:
         self._prefill_fn = None        # legacy: ONE prefill signature
         self._chunk_fns = {}           # legacy: chunk len -> program
         self._compiled = set()         # distinct compiled signatures
-        # unified mode: ONE batching-step program (mixed ragged pass +
-        # decode_chunk-1 in-program decode micro-steps); per-slot count
+        # unified mode: ONE batching-step program (a loop over groups
+        # of prompt rows + decode_chunk decode micro-steps); per-slot count
         # of dispatched-but-unharvested steps that may emit tokens for
         # the slot — drain defers while any are in flight
         self._unified = bool(unified)
         self._n_decode = max(0, self.decode_chunk - 1)
         self._unified_fn = None
+        # rows of one group of the unified step's prefill loop, and the
+        # length of the padded row list the step program is handed
+        self._group = max(1, min(self.num_slots, PREFILL_GROUP_POSITIONS
+                                 // self.prefill_chunk))
+        self._group_rows = -(-self.num_slots // self._group) * self._group
         self._emits_inflight = np.zeros((B,), np.int32)
         # ---- speculative decoding (ISSUE 18) -------------------------
         # a drafting decode slot rides 1 + K tokens (pending + drafts)
@@ -1638,34 +1655,57 @@ class ContinuousBatchingEngine:
                               & (self.limits > self._pred_ctx)))
 
     def _unified_static(self):
-        """The ONE compiled batching-step program: a ragged mixed pass
-        (prefill slots stream their next ``prefill_chunk`` prompt
-        tokens, active decode slots ride their pending token as a
-        length-1 sequence, idle slots are length 0 — one
-        [num_slots, prefill_chunk] forward through
-        ``ragged_paged_attention``) followed by ``decode_chunk - 1``
-        in-program decode micro-steps. A slot whose prompt completes in
-        the mixed pass samples its first token and starts decoding at
-        micro-step 1 — prefill→decode transition never leaves the
-        device, so no first-token echo machinery exists in this mode.
-        The packed output carries every emitted token of the step plus
-        the ctx/active mirrors in ONE int32 fetch."""
+        """The ONE compiled batching-step program. It computes the
+        positions whose result a turn uses, in two parts:
+
+        - a loop over GROUPS of ``self._group`` prefilling slots, its
+          trip count the turn's own (``lax.fori_loop`` with a traced
+          bound; a turn without a prompt runs none): each trip gathers
+          its rows' ids, positions, table rows and per-slot state, runs
+          one ``[group, prefill_chunk]`` forward through
+          ``ragged_paged_attention`` whose head reads ONE position a row
+          (``logits_at``), samples the first token of the rows whose
+          prompt ends in this chunk, and scatters results and state
+          back. The paged pools go through whole. Padding rows of the
+          last group name slot ``num_slots`` — out of range, so every
+          scatter drops them — and carry length 0, so their K/V lands
+          on the trash page;
+        - ``decode_chunk`` decode micro-steps (``lax.scan`` over a
+          ``[num_slots, 1]`` forward): the decoding slots ride all of
+          them; a slot whose prompt ended in the loop joins after
+          micro-step 0 with its first token — prefill→decode transition
+          never leaves the device, so no first-token echo machinery
+          exists in this mode.
+
+        The packed output carries every emitted token of the step (a
+        first token in column 0, like a decoding slot's) plus the
+        ctx/active mirrors in ONE int32 fetch. The forward is traced
+        twice: the loop's body and the scan's."""
         if self._unified_fn is not None:
             return self._unified_fn
         from ..jit import to_static
         model = self.model
         greedy = self.greedy
         temperature = self.temperature
-        C = self.prefill_chunk
-        n_dec = self._n_decode
+        n_steps = 1 + self._n_decode
         cpool = self._counter_pool
+        kinds = tuple(self._pool_kinds)
+        G = self._group
 
-        def ustep(ids_t, nq_t, last_t, tgt_t, tok_t, ctx_t, act_t,
-                  tbl_t, lim_t, eos_t, key_t, *pools):
+        def ustep(ids_t, nq_t, last_t, tgt_t, rows_t, nrows_t, tok_t,
+                  ctx_t, act_t, tbl_t, lim_t, eos_t, key_t, *pools):
             fwd = model.forward
 
-            def fn(ids, nq, last, tgt, tok, ctx, act, tbl, lim,
-                   eos_arr, key, *pool_leaves):
+            def sample(lg, key):
+                lg = lg.astype(jnp.float32)
+                if greedy:
+                    return jnp.argmax(lg, -1).astype(jnp.int32), key
+                key, sub = jax.random.split(key)
+                return jax.random.categorical(
+                    sub, lg / temperature).astype(jnp.int32), key
+
+            def fn(ids, nq, last, tgt, rows, n_rows, tok, ctx, act, tbl,
+                   lim, eos_arr, key, *pool_leaves):
                 b = tok.shape[0]
                 if cpool is not None:
                     # the model's pass counters start every step at 0
@@ -1675,43 +1715,47 @@ class ContinuousBatchingEngine:
                         pool_leaves[cpool])
                 # stale instant-eos guard (legacy chunk-entry contract)
                 act = act & ((eos_arr < 0) | (tok != eos_arr))
-                is_pre = nq > 0
-                lengths = jnp.where(
-                    is_pre, nq,
-                    jnp.where(act, 1, 0)).astype(jnp.int32)
-                # decode slots carry their device-resident pending
-                # token in stream column 0
-                ids_eff = ids.at[:, 0].set(
-                    jnp.where(is_pre, ids[:, 0], tok))
-                with no_grad():
-                    logits, npools = fwd(
-                        Tensor(ids_eff),
-                        caches=[Tensor(a) for a in pool_leaves],
-                        pos=Tensor(ctx[:, None]),
-                        tables=(Tensor(tbl), Tensor(lengths)))
-                lg = logits._data                      # [B, C, V]
-                idx = jnp.clip(lengths - 1, 0, C - 1)
-                last_lg = jnp.take_along_axis(
-                    lg, idx[:, None, None], axis=1)[:, 0]
-                last_lg = last_lg.astype(jnp.float32)
-                if greedy:
-                    sampled = jnp.argmax(last_lg, -1).astype(jnp.int32)
-                else:
-                    key, sub = jax.random.split(key)
-                    sampled = jax.random.categorical(
-                        sub, last_lg / temperature).astype(jnp.int32)
-                # a next-token fires for completing prompts and for
-                # advancing decode slots
-                fire = (is_pre & last) | (act & ~is_pre)
-                nxt = jnp.where(fire, sampled, tok)
-                ctx1 = ctx + lengths
-                hit_eos = (eos_arr >= 0) & (nxt == eos_arr)
-                still_dec = act & ~is_pre & (ctx1 < lim) & ~hit_eos
-                act_pre = is_pre & last & tgt & (ctx1 < lim) & ~hit_eos
-                act1 = jnp.where(is_pre, act_pre, still_dec)
-                out0 = jnp.where(fire, nxt, -1)
 
-                def body(carry, _):
+                def group(g, carry):
+                    tok_c, ctx_c, fire_c, act_c, key_c, leaves = carry
+                    rw = jax.lax.dynamic_slice(rows, (g * G,), (G,))
+                    real = rw < b
+                    at = jnp.minimum(rw, b - 1)      # gathers stay inside
+                    nq_g = jnp.where(real, nq[at], 0)
+                    ctx_g = ctx[at]
+                    with no_grad():
+                        logits, ncaches = fwd(
+                            Tensor(ids[at]),
+                            caches=[Tensor(a[at] if k == "state" else a)
+                                    for k, a in zip(kinds, leaves)],
+                            pos=Tensor(ctx_g[:, None]),
+                            tables=(Tensor(tbl[at]), Tensor(nq_g)),
+                            logits_at=Tensor(jnp.maximum(nq_g - 1, 0)))
+                    sampled, key_c = sample(logits._data[:, 0], key_c)
+                    fire_g = real & last[at]
+                    nxt_g = jnp.where(fire_g, sampled, tok[at])
+                    ctx1_g = ctx_g + nq_g
+                    hit_eos = (eos_arr[at] >= 0) & (nxt_g == eos_arr[at])
+                    act_g = fire_g & tgt[at] & (ctx1_g < lim[at]) \
+                        & ~hit_eos
+
+                    def put(whole, part):
+                        # a padding row's index is out of range: dropped
+                        return whole.at[rw].set(part, mode="drop")
+
+                    return (put(tok_c, nxt_g), put(ctx_c, ctx1_g),
+                            put(fire_c, fire_g), put(act_c, act_g), key_c,
+                            tuple(put(a, n._data) if k == "state"
+                                  else n._data for k, a, n in
+                                  zip(kinds, leaves, ncaches)))
+
+                none = jnp.zeros((b,), bool)
+                tok_p, ctx_p, fire_pre, act_pre, key, leaves_p = \
+                    jax.lax.fori_loop(
+                        0, (n_rows + (G - 1)) // G, group,
+                        (tok, ctx, none, none, key, tuple(pool_leaves)))
+
+                def body(carry, first):
                     tok_c, ctx_c, act_c, key_c, leaves = carry
                     with no_grad():
                         lgs, ncaches = fwd(
@@ -1719,38 +1763,27 @@ class ContinuousBatchingEngine:
                             caches=[Tensor(a) for a in leaves],
                             pos=Tensor(ctx_c[:, None]),
                             tables=(Tensor(tbl), Tensor(act_c)))
-                    lg_c = lgs[:, -1]._data.astype(jnp.float32)
-                    if greedy:
-                        nx = jnp.argmax(lg_c, -1).astype(jnp.int32)
-                    else:
-                        key_c, sub_c = jax.random.split(key_c)
-                        nx = jax.random.categorical(
-                            sub_c, lg_c / temperature).astype(jnp.int32)
+                    nx, key_c = sample(lgs[:, -1]._data, key_c)
                     ctx_n = ctx_c + act_c.astype(jnp.int32)
                     nx = jnp.where(act_c, nx, tok_c)
                     still = act_c & (ctx_n < lim) & \
                         ((eos_arr < 0) | (nx != eos_arr))
+                    # micro-step 0 is the decoding slots' alone; a slot
+                    # whose prompt ended in the loop joins after it: its
+                    # first token (the loop left it in the carry, with
+                    # its ctx) takes column 0
+                    emit = act_c | (first & fire_pre)
+                    still = still | (first & act_pre)
                     new_leaves = tuple(t._data for t in ncaches)
-                    out_tok = jnp.where(act_c, nx, -1)
                     return (nx, ctx_n, still, key_c, new_leaves), \
-                        (out_tok, act_c)
+                        (jnp.where(emit, nx, -1), emit)
 
-                carry0 = (nxt, ctx1, act1, key,
-                          tuple(t._data for t in npools))
-                if n_dec:
-                    carry, (toks, emitted) = jax.lax.scan(
-                        body, carry0, jnp.arange(n_dec))
-                    tok_f, ctx_f, act_f, key_f, leaves_f = carry
-                    toks_all = jnp.concatenate(
-                        [out0[:, None], toks.T], axis=1)
-                    emit_all = jnp.concatenate(
-                        [fire[:, None], emitted.T], axis=1)
-                else:
-                    tok_f, ctx_f, act_f, key_f, leaves_f = carry0
-                    toks_all = out0[:, None]
-                    emit_all = fire[:, None]
-                cols = [toks_all.astype(jnp.int32),
-                        emit_all.astype(jnp.int32),
+                carry0 = (tok_p, ctx_p, act & (nq == 0), key, leaves_p)
+                carry, (toks, emitted) = jax.lax.scan(
+                    body, carry0, jnp.arange(n_steps) == 0)
+                tok_f, ctx_f, act_f, key_f, leaves_f = carry
+                cols = [toks.T.astype(jnp.int32),
+                        emitted.T.astype(jnp.int32),
                         ctx_f[:, None].astype(jnp.int32),
                         act_f[:, None].astype(jnp.int32)]
                 if cpool is not None:
@@ -1762,12 +1795,12 @@ class ContinuousBatchingEngine:
                     + tuple(leaves_f)
 
             return _apply_multi(
-                fn, [ids_t, nq_t, last_t, tgt_t, tok_t, ctx_t, act_t,
-                     tbl_t, lim_t, eos_t, key_t] + list(pools),
-                n_out=5 + len(pools))
+                fn, [ids_t, nq_t, last_t, tgt_t, rows_t, nrows_t, tok_t,
+                     ctx_t, act_t, tbl_t, lim_t, eos_t, key_t]
+                + list(pools), n_out=5 + len(pools))
 
         self._unified_fn = to_static(ustep)
-        self._compiled.add(("unified", C, 1 + n_dec))
+        self._compiled.add(("unified", self.prefill_chunk, n_steps))
         return self._unified_fn
 
     def _stage_prompt_chunks(self):
@@ -1775,12 +1808,15 @@ class ContinuousBatchingEngine:
         slot (at most ``admit_batch`` of them), as the step programs
         take them: ``ids [B, C]``, ``nq`` (tokens staged per slot),
         ``last`` (the prompt ends in this chunk), ``tgt`` (the slot
-        decodes afterwards), and how many slots carry a chunk."""
+        decodes afterwards), ``rows`` (the slots that carry a chunk,
+        compacted; padded to whole groups with ``num_slots``, an index
+        no scatter lands on), and how many slots carry a chunk."""
         B, C = self.num_slots, self.prefill_chunk
         ids = np.zeros((B, C), np.int32)
         nq = np.zeros((B,), np.int32)
         last = np.zeros((B,), bool)
         tgt = np.zeros((B,), bool)
+        rows = np.full((self._group_rows,), B, np.int32)
         n_pre = 0
         for slot in range(B):
             if not self._prefilling[slot] or n_pre >= self.admit_batch:
@@ -1792,14 +1828,16 @@ class ContinuousBatchingEngine:
             nq[slot] = v
             last[slot] = off + v == len(prm)
             tgt[slot] = self._act_target[slot]
+            rows[n_pre] = slot
             n_pre += 1
-        return ids, nq, last, tgt, n_pre
+        return ids, nq, last, tgt, rows, n_pre
 
     def _count_dispatch(self, sp, mode, n_steps, n_active, n_pre,
-                        n_tok):
+                        n_tok, n_groups, group_rows):
         """The counters, the flight-recorder turn and the
         ``serving/dispatch`` span's args of one dispatched unified
-        step (plain or speculative)."""
+        step (plain or speculative). The step computed ``n_groups``
+        passes of ``group_rows x prefill_chunk`` prompt positions."""
         B = self.num_slots
         _t_obs = time.perf_counter()
         self._stats.inc("chunks")
@@ -1808,10 +1846,11 @@ class ContinuousBatchingEngine:
         if n_pre:
             self._stats.inc("prefill_waves")
         self._stats.inc("active_slot_steps", n_active * n_steps)
-        # how full the mixed pass is: it computes every slot's
+        # how full the prompt passes are: a pass computes every row's
         # prefill_chunk positions whether or not they hold a token
         self._stats.inc("prefill_tokens", n_tok)
-        self._stats.inc("prefill_positions", B * self.prefill_chunk)
+        self._stats.inc("prefill_positions",
+                        n_groups * group_rows * self.prefill_chunk)
         from ..profiler.trace import get_tracer
         _tr = get_tracer()
         if _tr.enabled:
@@ -1822,7 +1861,8 @@ class ContinuousBatchingEngine:
                            active=n_active, queued=len(self.queue),
                            prefilling=n_pre, chunk_len=n_steps)
         sp.set_args(seq=self._seq, active=n_active, prefilling=n_pre,
-                    prefill_tokens=n_tok, chunk_len=n_steps)
+                    prefill_tokens=n_tok, prefill_groups=n_groups,
+                    chunk_len=n_steps)
         self._obs_s += time.perf_counter() - _t_obs
 
     def _dispatch_step(self):
@@ -1833,9 +1873,10 @@ class ContinuousBatchingEngine:
         with _span("serving/dispatch") as sp:
             B = self.num_slots
             with _span("serving/dispatch.stage"):
-                ids, nq, last, tgt, n_pre = self._stage_prompt_chunks()
-                staged = [Tensor(jnp.asarray(a))
-                          for a in (ids, nq, last, tgt)]
+                ids, nq, last, tgt, rows, n_pre = \
+                    self._stage_prompt_chunks()
+                staged = [Tensor(jnp.asarray(a)) for a in
+                          (ids, nq, last, tgt, rows, np.int32(n_pre))]
             fn = self._unified_static()
             self._seq += 1
             self._last_fetch_dispatch_seq = self._seq
@@ -1847,7 +1888,8 @@ class ContinuousBatchingEngine:
                                    & (self.limits > self._pred_ctx))
                                   | (nq > 0)))
             self._count_dispatch(sp, "unified", n_steps, n_active, n_pre,
-                                 int(nq.sum()))
+                                 int(nq.sum()),
+                                 -(-n_pre // self._group), self._group)
             with _span("serving/dispatch.launch"):
                 res = fn(*staged,
                          Tensor(self._dev_tok), Tensor(self._dev_ctx),
@@ -1961,9 +2003,10 @@ class ContinuousBatchingEngine:
     # ---- speculative decoding (ISSUE 18) ---------------------------------
 
     def _unified_spec_static(self):
-        """The speculative batching-step program: the SAME ragged mixed
-        pass as :meth:`_unified_static` — prefill slots stream prompt
-        chunks unchanged — but an active decode slot rides ``1 + n_d``
+        """The speculative batching-step program: ONE ragged mixed pass
+        over all slots (``[num_slots, prefill_chunk]``; it has not taken
+        :meth:`_unified_static`'s group loop yet) — prefill slots stream
+        prompt chunks — in which an active decode slot rides ``1 + n_d``
         tokens (its pending token in column 0, host-proposed draft
         tokens in columns ``1..n_d``) as a short prefill-shaped chunk,
         and the ``decode_chunk - 1`` scan tail is replaced by
@@ -2142,7 +2185,8 @@ class ContinuousBatchingEngine:
         with _span("serving/dispatch") as sp:
             B, K = self.num_slots, self._spec_k
             with _span("serving/dispatch.stage"):
-                ids, nq, last, tgt, n_pre = self._stage_prompt_chunks()
+                ids, nq, last, tgt, _, n_pre = \
+                    self._stage_prompt_chunks()
                 nd = np.zeros((B,), np.int32)
                 drafting = [s for s in range(B)
                             if self.active[s] and not self._prefilling[s]
@@ -2167,8 +2211,9 @@ class ContinuousBatchingEngine:
                                    & (self.limits > self._pred_ctx))
                                   | (nq > 0)))
             self._c_spec_steps.inc()
+            # the speculative step keeps its one [B, C] mixed pass
             self._count_dispatch(sp, "spec", n_steps, n_active, n_pre,
-                                 int(nq.sum()))
+                                 int(nq.sum()), 1, B)
             with _span("serving/dispatch.launch"):
                 res = fn(*staged,
                          Tensor(self._dev_tok), Tensor(self._dev_ctx),
@@ -2230,9 +2275,11 @@ class ContinuousBatchingEngine:
           is averaged away.
         - ``prefill_tokens`` / ``prefill_positions`` / ``prefill_fill``:
           prompt tokens carried by the dispatched programs, the prompt
-          positions those programs computed (``num_slots x
-          prefill_chunk`` per mixed pass, filled or not), and their
-          ratio — how full the padded prefill compute is.
+          positions those programs computed, filled or not (the unified
+          step: groups run x rows a group x ``prefill_chunk``; a
+          speculative step's or a legacy wave's pass: ``num_slots x
+          prefill_chunk``), and their ratio — how full the prompt
+          passes are.
         - ``compiled_programs``: distinct compiled signatures this
           engine built — steady-state 1 in unified mode (the single
           batching-step program); 1 prefill + the decode-chunk-length

@@ -134,7 +134,8 @@ class GPT2Model(nn.Layer):
         self.ln_f = nn.LayerNorm(config.hidden_size,
                                  config.layer_norm_epsilon)
 
-    def forward(self, input_ids, caches=None, pos=None, tables=None):
+    def forward(self, input_ids, caches=None, pos=None, tables=None,
+                logits_at=None):
         s = input_ids.shape[1]
         positions = creation.arange(0, s, dtype="int64")
         if pos is not None:
@@ -149,6 +150,9 @@ class GPT2Model(nn.Layer):
                     x, cache=tuple(caches[stride * i:stride * (i + 1)]),
                     pos=pos, tables=tables)
                 new_caches.extend(kv)
+            if logits_at is not None:
+                from .llama import _hidden_at
+                x = _hidden_at(x, logits_at)
             return self.ln_f(x), new_caches
         x = self.drop(x)
         from ..nn.scan import scan_layers, can_scan
@@ -183,11 +187,13 @@ class GPT2ForCausalLM(nn.Layer, GenerationMixin):
                 for _ in range(2 * cfg.num_hidden_layers)]
 
     def forward(self, input_ids, labels=None, caches=None, pos=None,
-                tables=None):
+                tables=None, logits_at=None):
+        """``logits_at`` (serving path only): per row the ONE position
+        whose logits are wanted; the result is ``[B, 1, V]``."""
         from ..ops.linalg import matmul
         if caches is not None:
             hidden, caches = self.gpt2(input_ids, caches=caches, pos=pos,
-                                       tables=tables)
+                                       tables=tables, logits_at=logits_at)
             logits = matmul(hidden, self.gpt2.wte.weight, transpose_y=True)
             return logits, caches
         hidden = self.gpt2(input_ids)

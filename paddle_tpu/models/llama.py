@@ -226,6 +226,18 @@ def _paged_attention_step(attn, q, k, v, cache, pos, tables, rope=True,
     return out_proj(ctx_out), new_cache
 
 
+def _hidden_at(x, logits_at):
+    """Row ``logits_at[b]`` of each sequence's hidden states, ``[B, S, H]
+    -> [B, 1, H]``: the serving branches take it BEFORE the final norm
+    and the head, so a chunk whose one next token is wanted pays the head
+    for one position, not for S."""
+    def fn(a, at):
+        return jnp.take_along_axis(
+            a, at.astype(jnp.int32)[:, None, None], axis=1)
+
+    return apply(fn, x, logits_at, name="hidden_at", differentiable=False)
+
+
 def _alloc_kv_caches(cfg, batch_size, max_length, dtype):
     """Zero KV caches: per layer (k, v) of [B, max_len, KV, D]."""
     caches = []
@@ -495,7 +507,7 @@ class LlamaModel(nn.Layer):
         self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, input_ids, caches=None, pos=None, tables=None,
-                skip_layers=None):
+                skip_layers=None, logits_at=None):
         x = self.embed_tokens(input_ids)
         if caches is not None:
             # skip_layers (speculative decoding, ISSUE 18): the listed
@@ -515,6 +527,8 @@ class LlamaModel(nn.Layer):
                     continue
                 x, kv = layer(x, cache=lc, pos=pos, tables=tables)
                 new_caches.extend(kv)
+            if logits_at is not None:
+                x = _hidden_at(x, logits_at)
             return self.norm(x), new_caches
         if skip_layers:
             raise ValueError("skip_layers requires the caches "
@@ -630,11 +644,14 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
         return _alloc_kv_caches(self.config, batch_size, max_length, dtype)
 
     def forward(self, input_ids, labels=None, caches=None, pos=None,
-                tables=None, skip_layers=None):
+                tables=None, skip_layers=None, logits_at=None):
+        """``logits_at`` (serving path only): per row the ONE position
+        whose logits are wanted; the result is ``[B, 1, V]``."""
         if caches is not None:
             hidden, caches = self.llama(input_ids, caches=caches, pos=pos,
                                         tables=tables,
-                                        skip_layers=skip_layers)
+                                        skip_layers=skip_layers,
+                                        logits_at=logits_at)
         else:
             hidden = self.llama(input_ids)
         if labels is not None and caches is None and \

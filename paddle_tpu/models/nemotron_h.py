@@ -42,7 +42,7 @@ from ..ops import mamba2 as ssd
 from ..ops import manipulation as M
 from ..ops import moe as moe_ops
 from ..profiler import metrics as _pmetrics
-from .llama import _paged_attention_step
+from .llama import _hidden_at, _paged_attention_step
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM"]
 
@@ -501,8 +501,11 @@ class NemotronHForCausalLM(_Base, GenerationMixin):
 
     # ---- forward ---------------------------------------------------------
 
-    def forward(self, input_ids, caches=None, pos=None, tables=None):
-        """Logits [B, S, V]; with ``caches`` also the new caches.
+    def forward(self, input_ids, caches=None, pos=None, tables=None,
+                logits_at=None):
+        """Logits [B, S, V]; with ``caches`` also the new caches. With
+        ``logits_at`` (caches path only; per row the ONE position whose
+        logits are wanted) the logits are [B, 1, V].
 
         ``tables=(block_tables, gate)`` is the serving engine's paged
         convention (``gate``: per-slot valid count, or a bool active mask
@@ -567,4 +570,6 @@ class NemotronHForCausalLM(_Base, GenerationMixin):
                              *([moe_stats] if moe_stats is not None else []),
                              name="nemotron_h_counters",
                              differentiable=False))
+        if logits_at is not None:
+            x = _hidden_at(x, logits_at)
         return F.linear(self.norm_f(x), self.lm_head.weight), new

@@ -21,7 +21,7 @@ from ..distributed.parallel_layers import (ColumnParallelLinear,
                                            VocabParallelEmbedding)
 from ..incubate.distributed.models.moe import MoELayer
 from ..generation import GenerationMixin
-from .llama import (rope_with_offset, _alloc_kv_caches,
+from .llama import (rope_with_offset, _alloc_kv_caches, _hidden_at,
                     _paged_attention_step)
 
 __all__ = ["Qwen2Config", "Qwen2MoeConfig", "Qwen2ForCausalLM",
@@ -267,7 +267,9 @@ class _Qwen2Base(nn.Layer, GenerationMixin):
         return _alloc_kv_caches(self.config, batch_size, max_length, dtype)
 
     def forward(self, input_ids, labels=None, caches=None, pos=None,
-                tables=None):
+                tables=None, logits_at=None):
+        """``logits_at`` (serving path only): per row the ONE position
+        whose logits are wanted; the result is ``[B, 1, V]``."""
         if self._moe and self.training and self.config.use_recompute \
                 and self.config.router_aux_loss_coef:
             # raised here (where recompute actually wraps the layers),
@@ -290,6 +292,8 @@ class _Qwen2Base(nn.Layer, GenerationMixin):
                     x, cache=tuple(caches[stride * i:stride * (i + 1)]),
                     pos=pos, tables=tables)
                 new_caches.extend(kv)
+            if logits_at is not None:
+                x = _hidden_at(x, logits_at)
             hidden = self.norm(x)
             logits = self.lm_head(hidden) if self.lm_head is not None else \
                 matmul(hidden, self.embed_tokens.weight, transpose_y=True)

@@ -88,17 +88,9 @@ def _sds(sharding):
 
 # ---- serving: ragged paged attention -------------------------------------
 
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
-@pytest.mark.parametrize("rep", [1, 4, 7])
-@pytest.mark.parametrize("c,page", [(1, 16), (16, 16), (64, 32)],
-                         ids=["decode", "chunk16", "chunk64_page32"])
-def test_ragged_paged_attention(native, one_chip, quant, rep, c, page):
-    """GQA ratios 1 (MHA), 4 (Llama-3-8B) and 7 (Qwen2-7B), d128, pure
-    decode and a prefill chunk, plain and quantized pools."""
+def _compile_ragged(s, quant, b, c, kvh, rep, page, pps, n_pages, d=128):
     from paddle_tpu.ops.pallas.ragged_paged_attention import (
         ragged_paged_attention)
-    s = _sds(one_chip)
-    kvh, b, d, pps, n_pages = 4, 8, 128, 64, 512
     pool = s((kvh, n_pages, page, d), jnp.int8 if quant else BF16)
     args = [s((b, c, kvh * rep, d)), pool, pool,
             s((b, pps), jnp.int32), s((b,), jnp.int32),
@@ -113,6 +105,100 @@ def test_ragged_paged_attention(native, one_chip, quant, rep, c, page):
     else:
         fn = ragged_paged_attention
     _compile(fn, *args)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("rep", [1, 4, 7])
+@pytest.mark.parametrize("c,page", [(1, 16), (16, 16), (64, 32)],
+                         ids=["decode", "chunk16", "chunk64_page32"])
+def test_ragged_paged_attention(native, one_chip, quant, rep, c, page):
+    """GQA ratios 1 (MHA), 4 (Llama-3-8B) and 7 (Qwen2-7B), d128, pure
+    decode and a prefill chunk, plain and quantized pools."""
+    _compile_ragged(_sds(one_chip), quant, b=8, c=c, kvh=4, rep=rep,
+                    page=page, pps=64, n_pages=512)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("kvh,rep", [(4, 7), (2, 16)],
+                         ids=["qwen2_7b", "nemotron_h"])
+def test_ragged_paged_attention_at_the_group_shape(native, one_chip, quant,
+                                                   kvh, rep):
+    """One group of the serving step's prefill loop: 8 rows x a chunk of
+    128, page 16, over the benchmark's pools (64 slots x 2048), at the
+    two GQA ratios the serving cells run."""
+    _compile_ragged(_sds(one_chip), quant, b=8, c=128, kvh=kvh, rep=rep,
+                    page=16, pps=128, n_pages=8193)
+
+
+def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
+                                              monkeypatch):
+    """The unified step program — the loop over groups of 8 prefilling
+    slots, the 16 decode micro-steps, the head on one row per slot — of a
+    2-layer model at Qwen2-7B's widths, 64 slots x 2048, compiled whole:
+    two loops (the group loop's trip count is data), and the attention
+    kernel in both bodies."""
+    import paddle_tpu as paddle
+    from paddle_tpu.framework.core import Tensor
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.models import Qwen2Config, Qwen2ForCausalLM
+    from paddle_tpu.nn import initializer
+
+    def no_storage(self, shape, dtype):
+        # 1.6 B parameters are only shapes here
+        a = jnp.zeros(tuple(shape), BF16)
+        a.delete()
+        return a
+
+    cfg = Qwen2Config.qwen2_7b()
+    cfg.vocab_size, cfg.num_hidden_layers = 152064, 2
+    cfg.max_position_embeddings = 32768
+    cfg.scan_layers = cfg.tensor_parallel = False
+    with monkeypatch.context() as m:
+        m.setattr(initializer.Normal, "__call__", no_storage)
+        model = Qwen2ForCausalLM(cfg)
+    model.eval()
+    params = list(model.parameters())
+    for p in params:
+        if p._data.dtype != BF16:              # the norm scales
+            p._data = p._data.astype(BF16)
+    eng = ContinuousBatchingEngine(model, num_slots=64, max_len=2048,
+                                   page_size=16, greedy=True)
+    assert (eng._group, eng.prefill_chunk, eng.decode_chunk) == (8, 128, 16)
+    ustep = eng._unified_static().function
+    s = _sds(one_chip)
+    B, C, MP = eng.num_slots, eng.prefill_chunk, eng.pages_per_slot
+    i32 = jnp.int32
+    args = [s((B, C), i32), s((B,), i32), s((B,), bool), s((B,), bool),
+            s((eng._group_rows,), i32), s((), i32), s((B,), i32),
+            s((B,), i32), s((B,), bool), s((B, MP), i32), s((B,), i32),
+            s((B,), i32), s((2,), jnp.uint32)]
+    args += [s(tuple(p._data.shape), p._data.dtype) for p in eng.pools]
+    # the call sites ask which platform they run on: answer for the
+    # described chip while the program is traced
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+
+    def step(leaves, *arrays):
+        held = [p._data for p in params]
+        for p, a in zip(params, leaves):
+            p._data = a
+        try:
+            with paddle.no_grad():
+                outs = ustep(*[Tensor(a) for a in arrays])
+        finally:
+            for p, a in zip(params, held):
+                p._data = a
+        return [o._data for o in outs]
+
+    lowered = jax.jit(step).lower(
+        [s(tuple(p.shape), BF16) for p in params], *args)
+    assert lowered.as_text().count("stablehlo.while") == 2
+    text = lowered.compile().as_text()
+    assert "ragged_paged_attention" in text
+    for shape in ("bf16[8,4,896,128]", "bf16[64,4,8,128]"):
+        assert shape in text           # the kernel at 8 rows, and at 64
+    for shape in ("bf16[8192,", "bf16[64,128,152064]"):
+        assert shape not in text       # no pass at all 64 x 128 positions
 
 
 def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):

@@ -186,6 +186,69 @@ def test_engine_recomputes_state_after_a_preemption(tiny):
         assert _worst_gap(m, src, p, r.tokens) <= 1e-4
 
 
+def _grouped_engine(model, monkeypatch, slots, rows):
+    """An engine whose prefill loop runs groups of ``rows`` slots (the
+    module constant patched small; chunk 8)."""
+    from paddle_tpu.inference import serving
+    monkeypatch.setattr(serving, "PREFILL_GROUP_POSITIONS", rows * 8)
+    eng = ContinuousBatchingEngine(model, num_slots=slots, max_len=64,
+                                   page_size=4, prefill_chunk=8,
+                                   decode_chunk=4, greedy=True, audit=True)
+    assert eng._group == rows
+    return eng
+
+
+def test_engine_serves_the_reference_in_groups_through_a_preemption(
+        tiny, monkeypatch):
+    """More prompts than one group of the step's prefill loop holds (four
+    slots, groups of three: the state rows are gathered to a group and
+    scattered back), slot reuse, and a higher-priority arrival that evicts
+    a running request: every stream is the reference's, and every
+    admission (the replay too) starts exactly one slot from zero state."""
+    model, m, src = tiny
+    eng = _grouped_engine(model, monkeypatch, slots=4, rows=3)
+    rng = np.random.default_rng(8)
+    shapes = [(11, 26), (19, 30), (6, 28), (27, 24), (9, 6), (14, 5)]
+    prompts = [rng.integers(0, 128, L).astype(np.int32) for L, _ in shapes]
+    rids = [eng.add_request(p, n) for p, (_, n) in zip(prompts, shapes)]
+    for _ in range(3):
+        eng.step()
+    ph = rng.integers(0, 128, 7).astype(np.int32)
+    rids.append(eng.add_request(ph, 9, priority=5))
+    done = _serve(eng, rids)
+    g = eng.gauges()
+    assert g["preempt_evictions"] >= 1
+    assert g["state_resets"] \
+        == len(rids) + sum(r.preemptions for r in done)
+    assert g["compiled_programs"] == 1
+    for p, (_, n), r in zip(prompts + [ph], shapes + [(7, 9)], done):
+        assert r.error is None and len(r.tokens) == n
+        assert _worst_gap(m, src, p, r.tokens) <= 1e-4
+
+
+def test_a_padding_row_of_a_ragged_group_writes_no_state(tiny, monkeypatch):
+    """Four prompts on four slots in groups of three: the last slot is
+    row 0 of a ragged last group whose two padding rows GATHER that
+    slot's state (their index is clamped for the read) — written back,
+    they would put the slot's old state over its new one. Its prompt
+    spans three chunks, so each turn's state is the next one's input."""
+    model, m, src = tiny
+    eng = _grouped_engine(model, monkeypatch, slots=4, rows=3)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, 128, L).astype(np.int32)
+               for L in (5, 7, 4, 21)]
+    rids = [eng.add_request(p, 6) for p in prompts]
+    eng.step()
+    assert eng.slot_req[3] is not None \
+        and eng.slot_req[3].request_id == rids[3]
+    state = [np.asarray(eng.pools[i]._data[3]) for i, k in
+             enumerate(eng._pool_kinds) if k == "state"]
+    assert all(np.abs(a).max() > 0 for a in state)
+    for p, r in zip(prompts, _serve(eng, rids)):
+        assert r.error is None
+        assert _worst_gap(m, src, p, r.tokens) <= 1e-4
+
+
 def test_legacy_engine_pair_keeps_the_state_rules(tiny):
     """``unified=False`` (prefill waves + decode chunks) calls the same
     forward with the same ``pos`` / gate convention, so the state's rules

@@ -213,7 +213,7 @@ def test_a_turns_children_nest_and_do_not_overlap(serving_trace):
         disp, harv = kids[1], kids[2]
         assert disp[3]["seq"] == harv[3]["seq"] == step[3]["seq"]
         assert {"active", "prefilling", "prefill_tokens",
-                "chunk_len"} <= set(disp[3])
+                "prefill_groups", "chunk_len"} <= set(disp[3])
         assert "appended" in harv[3]
         inner = _children(evs, disp, ("serving/dispatch.",))
         assert [e[0] for e in inner] == ["serving/dispatch.stage",
@@ -237,10 +237,22 @@ def test_spans_outside_a_turn_are_only_add_request(serving_trace):
 
 
 def test_prefill_counters_count_tokens_and_positions(serving_trace):
-    _, _, eng, prompts = serving_trace
+    """Positions are what the step's group loop computed: groups x rows a
+    group x chunk, summed over turns; a turn whose slots all decode runs
+    no group and adds none."""
+    evs, _, eng, prompts = serving_trace
     g = eng.gauges()
     assert g["prefill_tokens"] == sum(len(p) for p in prompts)
-    assert g["prefill_positions"] == SLOTS * CHUNK * g["unified_steps"]
+    turns = [e[3] for e in evs if e[0] == "serving/dispatch"]
+    assert len(turns) == g["unified_steps"]
+    rows = eng._group
+    assert rows == SLOTS            # 1,024 positions a group > 2 x 8
+    for t in turns:
+        assert t["prefill_groups"] == -(-t["prefilling"] // rows)
+    assert 0 in {t["prefill_groups"] for t in turns}
+    assert g["prefill_positions"] == rows * CHUNK * sum(
+        t["prefill_groups"] for t in turns)
+    assert g["prefill_positions"] < SLOTS * CHUNK * g["unified_steps"]
     assert g["prefill_fill"] == pytest.approx(
         g["prefill_tokens"] / g["prefill_positions"])
     assert 0.0 < g["prefill_fill"] <= 1.0
