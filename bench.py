@@ -552,7 +552,7 @@ def _cb_bench(on_tpu, autotune=False):
     if on_tpu:
         cfg = LlamaConfig.llama_1b()
         slots, page, chunk = 8, 32, 32
-        max_len, buckets = 384, (64, 128, 256)
+        max_len, pchunk = 384, 256
         specs = [(64, 128), (128, 96), (192, 128), (64, 64),
                  (128, 128), (192, 96), (64, 128), (128, 64),
                  (96, 128), (160, 96), (64, 96), (128, 128)]
@@ -560,7 +560,7 @@ def _cb_bench(on_tpu, autotune=False):
     else:
         cfg = LlamaConfig.tiny()
         slots, page, chunk = 2, 8, 4
-        max_len, buckets = 48, (8, 16)
+        max_len, pchunk = 48, 16
         specs = [(6, 8), (12, 5), (9, 10), (4, 6)]
         reps = 1
     cfg.tensor_parallel = False
@@ -577,7 +577,7 @@ def _cb_bench(on_tpu, autotune=False):
     # region would benchmark compilation
     eng = ContinuousBatchingEngine(model, num_slots=slots, page_size=page,
                                    max_len=max_len, decode_chunk=chunk,
-                                   prompt_buckets=buckets, greedy=True)
+                                   prefill_chunk=pchunk, greedy=True)
 
     def timed_engine(e):
         """warmup (compiles prefill + chunk ladder) then best timed
@@ -640,8 +640,7 @@ def _cb_bench(on_tpu, autotune=False):
                         max_len=max_len,
                         decode_chunk=c["decode_chunk"],
                         prefill_chunk=c["prefill_chunk"],
-                        admit_batch=c["admit_batch"],
-                        prompt_buckets=buckets, greedy=True)
+                        admit_batch=c["admit_batch"], greedy=True)
                     tps, wall, _ = timed_engine(e)
                     trials.append((dict(c), wall, tps))
                 except Exception as exc:  # candidate-scoped, like the
@@ -676,24 +675,7 @@ def _cb_bench(on_tpu, autotune=False):
           f"{gauges['itl_ms_p50']:.2f}ms, {gauges['compiled_programs']} "
           f"compiled programs, {gauges['unified_steps']} unified steps)",
           file=sys.stderr)
-    # A/B the PR-3 legacy engine on the SAME workload (acceptance
-    # evidence for the unified-kernel rebuild: cb tok/s >= legacy).
-    # Same warmup + best-rep protocol, own compiled programs.
-    legacy_tps = None
-    try:
-        leg = ContinuousBatchingEngine(
-            model, num_slots=slots, page_size=page, max_len=max_len,
-            decode_chunk=chunk, prompt_buckets=buckets, greedy=True,
-            unified=False)
-        legacy_tps, _, _ = timed_engine(leg)
-        print(f"# continuous batching (legacy engine): "
-              f"{legacy_tps:.0f} tokens/s "
-              f"({leg.gauges()['compiled_programs']} compiled "
-              f"programs) -> unified is x{best / legacy_tps:.2f}",
-              file=sys.stderr)
-    except Exception as exc:  # A/B is telemetry, never fails the bench
-        print(f"# legacy-engine A/B failed: {exc!r}", file=sys.stderr)
-    return best, gauges, tuned_cb, legacy_tps
+    return best, gauges, tuned_cb
 
 
 def _cb_spec_bench(on_tpu, autotune=False):
@@ -729,12 +711,12 @@ def _cb_spec_bench(on_tpu, autotune=False):
 
     if on_tpu:
         cfg = LlamaConfig.llama_1b()
-        page, max_len, buckets = 32, 384, (64,)
+        page, max_len, pchunk = 32, 384, 64
         base_len, tile, n_new, reps = 16, 3, 96, 2
         http_req, http_conc = 12, 2
     else:
         cfg = LlamaConfig.tiny()
-        page, max_len, buckets = 8, 64, (16,)
+        page, max_len, pchunk = 8, 64, 16
         base_len, tile, n_new, reps = 4, 3, 24, 2
         http_req, http_conc = 8, 2
     spec_k = 4
@@ -751,7 +733,7 @@ def _cb_spec_bench(on_tpu, autotune=False):
         skw.update(kw)
         return ContinuousBatchingEngine(
             model, num_slots=nslots, page_size=page, max_len=max_len,
-            decode_chunk=1, prompt_buckets=buckets, greedy=True, **skw)
+            decode_chunk=1, prefill_chunk=pchunk, greedy=True, **skw)
 
     def prompts_for(nreq, seed):
         # repeated-span prompts: the generated stream re-walks its own
@@ -1149,7 +1131,7 @@ def _cb_procfleet_bench(on_tpu):
     from paddle_tpu.testing import FaultInjector
 
     eng_kw = dict(num_slots=2, page_size=8, max_len=48,
-                  decode_chunk=4, prompt_buckets=(8, 16), greedy=True)
+                  decode_chunk=4, prefill_chunk=16, greedy=True)
     spec = {"factory": "paddle_tpu.inference.worker:llama_engine",
             "kwargs": dict(model="tiny", num_hidden_layers=1, seed=0,
                            **eng_kw)}
@@ -1308,11 +1290,11 @@ def _cb_disagg_bench(on_tpu):
     # of disaggregation: prefill replicas keep the 40-wide mixed pass
     # but drop the decode tail they never use (decode_chunk=2), decode
     # replicas drop the 40-wide pass they never use (imported pages
-    # re-prefill only short suffixes -> prompt_buckets=(8,)); the
+    # re-prefill only short suffixes -> prefill_chunk=8); the
     # colocated baseline must provision one program for BOTH phases
     eng_kw = dict(num_slots=2, page_size=8, max_len=64,
                   num_pages=48, decode_chunk=4,
-                  prompt_buckets=(8, 40), greedy=True)
+                  prefill_chunk=40, greedy=True)
 
     def _spec(**over):
         kw = dict(model="tiny", num_hidden_layers=1, seed=0,
@@ -1390,7 +1372,7 @@ def _cb_disagg_bench(on_tpu):
         for _ in range(2):
             disagg.scale_up(
                 engine_factory=_spec(role="decode",
-                                     prompt_buckets=(8,)),
+                                     prefill_chunk=8),
                 warm=False, role="decode")
         tps, p99, n_ok, g = run_leg(disagg)
     finally:
@@ -1460,7 +1442,7 @@ def _cb_autoscale_bench(on_tpu):
     def factory():
         return ContinuousBatchingEngine(
             model, num_slots=2, page_size=8, max_len=48,
-            decode_chunk=4, prompt_buckets=(8, 16), greedy=True)
+            decode_chunk=4, prefill_chunk=16, greedy=True)
 
     max_r = 3
     ctl_kw = dict(min_replicas=1, max_replicas=max_r,
@@ -1719,7 +1701,7 @@ def _cb_quant_bench(on_tpu, autotune=False):
         return ContinuousBatchingEngine(
             m if m is not None else model, num_slots=nslots,
             page_size=page, max_len=max_len, num_pages=pages,
-            decode_chunk=4, prompt_buckets=(16,), greedy=True, **kw)
+            decode_chunk=4, prefill_chunk=16, greedy=True, **kw)
 
     # equal-byte provisioning from the engines' OWN pool-byte gauges
     base_eng = make_engine(pages=base_pages)
@@ -1926,7 +1908,7 @@ def _cb_http_bench(on_tpu):
     def factory():
         return ContinuousBatchingEngine(
             model, num_slots=slots, page_size=page, max_len=max_len,
-            decode_chunk=chunk, prompt_buckets=(8, 16), greedy=True)
+            decode_chunk=chunk, prefill_chunk=16, greedy=True)
 
     rng = np.random.RandomState(44)
     specs = [(rng.randint(0, cfg.vocab_size,
@@ -2468,11 +2450,11 @@ def main():
         _emit_record(record, rec_out)
 
     try:
-        cb_tok_s, cb_gauges, cb_tuned, cb_legacy = _timed_section(
+        cb_tok_s, cb_gauges, cb_tuned = _timed_section(
             "cb", lambda: _cb_bench(on_tpu, autotune=args.autotune))
     except Exception as e:
         print(f"# continuous-batching bench failed: {e!r}", file=sys.stderr)
-        cb_tok_s = cb_gauges = cb_tuned = cb_legacy = None
+        cb_tok_s = cb_gauges = cb_tuned = None
     if cb_tok_s is not None:
         record["cb_metric"] = ("llama_1B_continuous_batching_mixed_lengths"
                                + suffix)
@@ -2490,8 +2472,7 @@ def main():
         record["cb_compiles"] = cb_gauges["compiled_programs"]
         # ISSUE-7 unified-batching-step keys: the engine now runs ONE
         # compiled program per scheduler turn (cb_compiles expected
-        # ~1 steady-state), with the PR-3 engine A/B'd on the same
-        # workload as the regression reference
+        # ~1 steady-state)
         # (aliases of cb_value / cb_gauges.unified_steps so rounds
         # grep ONE name — assigned from the record, cannot diverge)
         record["cb_unified_tok_s"] = record["cb_value"]
@@ -2500,10 +2481,6 @@ def main():
         # the serving hot loop (<2% pinned by test_metrics)
         record["obs_overhead_frac"] = round(
             cb_gauges.get("obs_overhead_frac", 0.0), 6)
-        if cb_legacy:
-            record["cb_legacy_tok_s"] = round(cb_legacy, 2)
-            record["cb_unified_vs_legacy"] = round(
-                cb_tok_s / cb_legacy, 4)
         record["cb_gauges"] = {
             k: (round(v, 4) if isinstance(v, float) else v)
             for k, v in cb_gauges.items()}

@@ -385,7 +385,6 @@ def phase_serve(model_cfg, sizes, seed, dev, comp):
     def serve(tag, **kw):
         comp.mark()
         eng = ContinuousBatchingEngine(model, **eng_kw, **kw)
-        check(eng._unified, "engine default is not the unified step")
         toks, turns = _drive(eng, prompts, n_new, comp)
         g = eng.gauges()
         warm = [t for t, built in turns if not built]

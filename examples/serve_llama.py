@@ -94,13 +94,13 @@ from paddle_tpu.inference import ContinuousBatchingEngine
 
 if BIG:
     eng_kw = dict(num_slots=4, page_size=16, max_len=prompt_len + 128,
-                  decode_chunk=16, prompt_buckets=(64, 128))
+                  decode_chunk=16, prefill_chunk=128)
     req_specs = [(prompt_len, 64), (prompt_len // 2, 48),
                  (prompt_len // 4, 96), (prompt_len, 32),
                  (prompt_len // 2, 64), (prompt_len // 4, 80)]
 else:
     eng_kw = dict(num_slots=2, page_size=8, max_len=48,
-                  decode_chunk=4, prompt_buckets=(8, 16))
+                  decode_chunk=4, prefill_chunk=16)
     req_specs = [(6, 8), (12, 5), (9, 10), (4, 6), (14, 7)]
 
 engine = ContinuousBatchingEngine(model, greedy=True, **eng_kw)

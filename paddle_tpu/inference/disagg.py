@@ -257,19 +257,16 @@ class DisaggServingFleet(ServingFleet):
         the compiled program (slot activation is data, not shape), so
         prefill replicas warm with ``max_new=1`` and complete locally.
 
-        The sacrificial PROMPT is long (the widest prompt bucket the
-        engine provisions): a prefill replica exists to absorb long
-        prompts, and the base fleet's 4-token decode-shaped warm
-        request would compile only the narrowest bucket — the first
-        routed long prompt would then eat the wide bucket's XLA
-        compile inside the serving path, exactly the latency warmup
-        exists to take off it (ISSUE 19)."""
+        The sacrificial PROMPT fills one ``prefill_chunk`` where the
+        engine tells its chunk (a worker's proxy does not: two pages
+        then): a prefill replica exists to absorb long prompts, so its
+        warm request is prompt-shaped, not the base fleet's 4-token
+        decode-shaped one."""
         if self._role(rep) != "prefill":
             return super()._warm(rep)
         eng = rep.engine
-        buckets = getattr(eng, "prompt_buckets", None)
-        plen = int(max(buckets)) if buckets \
-            else 2 * int(getattr(eng, "page_size", 8))
+        plen = int(getattr(eng, "prefill_chunk", 0)) \
+            or 2 * int(getattr(eng, "page_size", 8))
         plen = max(4, min(plen, int(eng.max_len) - 2))
         wreq = ServedRequest(-1, np.zeros((plen,), np.int32), 1, None)
         wreq.t_arrive = time.perf_counter()

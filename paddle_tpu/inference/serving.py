@@ -6,29 +6,21 @@ length streams; reference mount empty, no cites — SURVEY.md §2.1
 inference row, PAPERS.md ragged-paged-attention).
 
 TPU-native design — the vLLM recipe restructured for XLA's static-shape
-world. Two engine modes share the pool/slot machinery:
+world: ONE compiled batching-step program for the whole scheduler turn,
+built on the ragged paged-attention entry point (PAPERS.md "Ragged
+Paged Attention"). It computes the positions whose result the turn
+uses: a loop over groups of prefilling slots, its trip count the turn's
+own data, streams each group's next ``prefill_chunk`` prompt tokens
+through one ``[group, prefill_chunk]`` forward whose head reads one row
+per slot, and samples where a prompt completes; then ``decode_chunk``
+in-program decode micro-steps via ``lax.scan`` advance the decoding
+slots. Prefill→decode transition happens ON DEVICE inside the program
+(a slot whose prompt ends in the loop joins the scan after micro-step
+0), so steady-state ``compiled_programs`` == 1. A speculative engine
+(``spec_decode=True``) runs a sibling program in its place: one mixed
+pass that verifies host-proposed drafts (:meth:`_unified_spec_static`).
 
-**Unified mode (default, ``unified=True``)** — ONE compiled
-batching-step program for the whole scheduler turn, built on the ragged
-paged-attention entry point (PAPERS.md "Ragged Paged Attention"). It
-computes the positions whose result the turn uses: a loop over groups
-of prefilling slots, its trip count the turn's own data, streams each
-group's next ``prefill_chunk`` prompt tokens through one ``[group,
-prefill_chunk]`` forward whose head reads one row per slot, and samples
-where a prompt completes; then ``decode_chunk`` in-program decode
-micro-steps via ``lax.scan`` advance the decoding slots. Prefill→decode
-transition happens ON DEVICE inside the program (a slot whose prompt
-ends in the loop joins the scan after micro-step 0), so the PR-3
-prefill-wave/decode-chunk interleave, its first-token echo machinery,
-and the residual compiled-signature zoo all collapse: steady-state
-``compiled_programs`` == 1.
-
-**Legacy mode (``unified=False``)** — the PR-3 two-program-family
-engine (batched prefill waves interleaved with adaptive decode chunks),
-kept as the scheduling-parity oracle for the ``serving_parity`` CI gate
-and for A/B benching.
-
-Shared structure:
+Structure:
 
 - The KV cache is a global PAGE POOL per layer ([KVH, num_pages,
   page_size, D]); each admitted request owns a page list (its block
@@ -47,40 +39,27 @@ Shared structure:
 - A fixed number of SLOTS (the batch dimension) keeps every compiled
   shape static. Admission = host-side: allocate pages from the free
   list and mark the slot PREFILLING.
-- Prefill is CHUNKED and BATCHED through the paged pool: ONE compiled
-  prefill signature ([num_slots, prefill_chunk] ids) advances every
-  prefilling slot ``prefill_chunk`` prompt tokens per program — k/v are
-  written into the slot's pages incrementally
-  (``ops.paged_attention.paged_prefill_write``) and the chunk's queries
-  attend causally over the paged history
-  (``paged_prefill_attention``). No per-bucket dense-cache forward, no
-  exact-length recompiles for prompts longer than every bucket: every
-  prompt length flows through the same program, and up to
-  ``admit_batch`` queued prompts ride one program together. Prefill
-  waves INTERLEAVE with decode chunks, so a long prompt no longer
-  stalls active decode streams.
-- Decoding runs in compiled CHUNKS: ONE program advances ALL active
-  slots ``n`` tokens via a ``lax.scan`` (per-slot positions, paged
-  attention reads, trash-page-guarded writes). The chunk length is
-  ADAPTIVE (``adaptive_chunk``): clamped to the minimum remaining token
-  budget across active slots (quantized to a power-of-two ladder under
-  ``decode_chunk`` to bound compiled signatures), so a drain wave ends
-  exactly at the chunk boundary — no overshoot slot-steps, and the
-  once-per-drain-wave wasted speculative chunk program is gone (the
-  host can prove the successor would do no work).
-- Between chunks the host scheduler drains finished slots (eos or token
+- Prefill is CHUNKED and BATCHED through the paged pool: every
+  prefilling slot (up to ``admit_batch`` of them) advances
+  ``prefill_chunk`` prompt tokens per step — k/v are written into the
+  slot's pages incrementally and the chunk's queries attend causally
+  over the paged history (``ops.paged_attention.ragged_paged_attention``).
+  Every prompt length flows through the same program, beside the
+  decoding slots, so a long prompt does not stall active streams.
+- Between steps the host scheduler drains finished slots (eos or token
   budget), frees their pages, and admits queued requests into the freed
   slots — mixed-length streams flow through without ever reshaping the
-  compiled programs.
+  compiled program.
 - Hot state (last token / context length / active mask / RNG key / page
-  pools) is DEVICE-RESIDENT between programs: prefill waves and decode
-  chunks chain device state asynchronously; each decode chunk fetches
-  one packed int32 array (emitted tokens + first-token echoes + ctx/
-  active mirrors), and prefill never fetches — a prompt's first token
-  lands in device state and is echoed through the next chunk's packed
-  fetch. Per-array uploads + a blocking scalar fetch per admission
-  cost a fixed overhead per call; round trips, not kernels, set the
-  serving throughput (not measured on current code).
+  pools) is DEVICE-RESIDENT between programs: steps chain device state
+  asynchronously, and each step's ONE packed int32 fetch carries every
+  token it emitted (a prompt's first token among them) plus the
+  ctx/active mirrors. Admission mutates per-slot device state with tiny
+  ``.at[slot].set`` dispatches.
+- Two pumps drive the step: :meth:`step` (dispatch, then harvest; what
+  ``ApiServer``, the fleet replicas and the benchmark call) and
+  :meth:`run` (the same turn, with the successor dispatched before the
+  harvest).
 - Per-request latency accounting rides the scheduler: TTFT (arrival →
   first token on host) and smoothed inter-token latency, exposed as
   p50/p99 gauges next to the occupancy/overlap counters from PR 2, plus
@@ -117,8 +96,8 @@ __all__ = ["ContinuousBatchingEngine", "ServedRequest",
 # a PRIVATE MetricsRegistry instance of these — two engines in one
 # process never cross-pollute.
 _pmetrics.declare("serving/chunks", "counter",
-                  "compiled programs dispatched (unified steps + legacy "
-                  "decode chunks)")
+                  "compiled step programs dispatched (unified and "
+                  "speculative steps)")
 _pmetrics.declare("serving/chunk_slot_steps", "counter",
                   "slot-steps dispatched (num_slots x chunk length, "
                   "active or not)")
@@ -138,8 +117,8 @@ _pmetrics.declare("serving/chunks_empty", "counter",
                   "harvested programs that delivered no tokens "
                   "(unpredictable eos stops)")
 _pmetrics.declare("serving/unified_steps", "counter",
-                  "unified batching-step programs dispatched (0 in "
-                  "legacy mode)")
+                  "batching-step programs dispatched (speculative "
+                  "steps included)")
 _pmetrics.declare("serving/requests_completed", "counter",
                   "requests finished (eos or length)")
 _pmetrics.declare("serving/run_seconds", "counter",
@@ -151,9 +130,8 @@ _pmetrics.declare("serving/prefill_tokens", "counter",
 _pmetrics.declare("serving/prefill_positions", "counter",
                   "prompt positions computed by dispatched programs, "
                   "filled or not (the unified step: groups run x rows "
-                  "a group x prefill_chunk; a speculative step's or a "
-                  "legacy prefill wave's pass: num_slots x "
-                  "prefill_chunk)")
+                  "a group x prefill_chunk; a speculative step's pass: "
+                  "num_slots x prefill_chunk)")
 _pmetrics.declare("serving/ttft_ms", "histogram",
                   "request arrival -> first token on host, ms (bounded "
                   "reservoir; p50/p99 exposed via gauges())")
@@ -493,24 +471,18 @@ def request_trace_summary(req) -> dict:
 
 class ContinuousBatchingEngine:
     """Schedules mixed-length generation streams through ONE compiled
-    unified batching-step program (ragged mixed prefill+decode; default)
-    or, with ``unified=False``, the legacy prefill-wave/decode-chunk
-    pair. Greedy or temperature sampling.
+    unified batching-step program (ragged mixed prefill+decode). Greedy
+    or temperature sampling.
 
     model: any CausalLM Layer implementing ``forward(ids, caches=, pos=,
     tables=)`` + ``init_kv_cache`` — Llama, Qwen2 (incl. MoE), and GPT2
     all qualify. num_slots is the batch size; total pool memory =
-    num_pages * page_size tokens of KV per layer.
-
-    ``prompt_buckets`` is kept for API compatibility: buckets no longer
-    select prefill signatures (there is exactly ONE), but the largest
-    bucket seeds the default ``prefill_chunk``."""
+    num_pages * page_size tokens of KV per layer."""
 
     def __init__(self, model, num_slots=4, page_size=16, num_pages=None,
-                 max_len=512, decode_chunk=None, prompt_buckets=(32, 64, 128),
+                 max_len=512, decode_chunk=None,
                  eos_token_id=None, greedy=True, temperature=1.0,
                  seed=0, prefill_chunk=None, admit_batch=None,
-                 adaptive_chunk=True, unified=True,
                  trace_sample_rate=0.01, latency_reservoir=2048,
                  max_strikes=2, max_containments=8, audit=None,
                  prefix_cache=None, role="both", spec_decode=False,
@@ -571,12 +543,8 @@ class ContinuousBatchingEngine:
         if decode_chunk is None:
             decode_chunk = int(tuned.get("decode_chunk", 0)) or 16
         self.decode_chunk = int(decode_chunk)
-        self.adaptive_chunk = bool(adaptive_chunk)
-        self.prompt_buckets = tuple(sorted(prompt_buckets)) \
-            if prompt_buckets else ()
         if prefill_chunk is None:
-            prefill_chunk = int(tuned.get("prefill_chunk", 0)) or \
-                (self.prompt_buckets[-1] if self.prompt_buckets else 32)
+            prefill_chunk = int(tuned.get("prefill_chunk", 0)) or 128
         self.prefill_chunk = max(1, min(int(prefill_chunk), self.max_len))
         if admit_batch is None:
             admit_batch = int(tuned.get("admit_batch", 0)) or self.num_slots
@@ -697,25 +665,16 @@ class ContinuousBatchingEngine:
         self._act_target = np.zeros((B,), bool)  # activate on completion
         # host prediction of device ctx (exact for length-limited slots;
         # an eos stop only ever makes it an overestimate) — drives the
-        # adaptive chunk length and the is-the-successor-worth-it test
+        # is-a-step-worth-it test (_worth_step)
         self._pred_ctx = np.zeros((B,), np.int32)
         # monotone program-dispatch counter + per-slot activation seq:
-        # a decode chunk dispatched BEFORE a slot's final prefill wave
-        # has a stale view of that slot, so its ctx/active mirrors must
-        # not be applied at harvest
+        # a step dispatched BEFORE the one a slot's prompt ended in has
+        # a stale view of that slot, so its ctx/active mirrors must not
+        # be applied at harvest
         self._seq = 0
         self._act_since = np.zeros((B,), np.int64)
-        # pending first-token echo: slots whose prefill finished but
-        # whose first token has not been appended host-side yet
-        self._pending_first = np.zeros((B,), bool)
-        # echo snapshotted into a dispatched-but-unharvested chunk: the
-        # slot must not drain until that harvest appends the token (a
-        # one-shot request admitted mid-stream would otherwise finish
-        # empty — its pending flag is cleared at dispatch, but the token
-        # only arrives with the chunk's packed fetch)
-        self._echo_inflight = np.zeros((B,), bool)
 
-        # device-resident hot state (never round-trips between chunks);
+        # device-resident hot state (never round-trips between steps);
         # admission mutates it with tiny async .at[slot].set dispatches
         self._dev_tok = jnp.zeros((B,), jnp.int32)
         self._dev_ctx = jnp.zeros((B,), jnp.int32)
@@ -792,14 +751,11 @@ class ContinuousBatchingEngine:
         #: per-slot attached cache nodes, in table-row order — the
         #: slot's block table is [shared pages..., private pages...]
         self.slot_shared: list[list] = [[] for _ in range(B)]
-        self._prefill_fn = None        # legacy: ONE prefill signature
-        self._chunk_fns = {}           # legacy: chunk len -> program
         self._compiled = set()         # distinct compiled signatures
-        # unified mode: ONE batching-step program (a loop over groups
-        # of prompt rows + decode_chunk decode micro-steps); per-slot count
-        # of dispatched-but-unharvested steps that may emit tokens for
-        # the slot — drain defers while any are in flight
-        self._unified = bool(unified)
+        # ONE batching-step program (a loop over groups of prompt rows +
+        # decode_chunk decode micro-steps); per-slot count of
+        # dispatched-but-unharvested steps that may emit tokens for the
+        # slot — drain defers while any are in flight
         self._n_decode = max(0, self.decode_chunk - 1)
         self._unified_fn = None
         # rows of one group of the unified step's prefill loop, and the
@@ -818,10 +774,6 @@ class ContinuousBatchingEngine:
         # defaults; an explicit argument always wins.
         self._spec = bool(spec_decode) or spec_k is not None \
             or spec_draft is not None
-        if self._spec and not self._unified:
-            raise ValueError("speculative decoding requires the "
-                             "unified batching-step engine "
-                             "(unified=True)")
         self._spec_k = 0
         self._spec_source = None
         self._spec_fn = None
@@ -1247,30 +1199,31 @@ class ContinuousBatchingEngine:
 
     def step(self):
         """Admit what fits, advance every slot one scheduler turn (one
-        unified batching-step program, or prefill waves + one decode
-        chunk in legacy mode), drain finished slots. Returns the
+        batching-step program), drain finished slots. Returns the
         requests completed by this step. Step failures hit the same
         containment boundary as :meth:`run`."""
         with self._turn():
             self._admit()
             try:
-                if self._unified:
-                    if self._worth_step():
-                        # spec engines speculate in step()-pumped
-                        # drivers too (ApiServer, fleet replicas), not
-                        # just run()
-                        self._harvest_step(self._dispatch_spec_step()
-                                           if self._spec else
-                                           self._dispatch_step())
-                else:
-                    self._pump_prefill()
-                    if self.active.any():
-                        self._decode_chunk()
+                rec = self._dispatch_turn()
+                if rec is not None:
+                    self._harvest_step(rec)
             except Exception as exc:  # noqa: BLE001 — containment boundary
                 if not self._containable(exc):
                     raise
                 return self._contain_step_failure(exc) + self._drain()
             return self._drain()
+
+    def _dispatch_turn(self):
+        """Launch the turn's program — the speculative step on a
+        speculative engine, else the unified step — and return its
+        in-flight record for :meth:`_harvest_step`; None when no slot
+        would advance. ``step()``, the driver's idle turn and its
+        pipelined successor all dispatch through here."""
+        if not self._worth_step():
+            return None
+        return self._dispatch_spec_step() if self._spec \
+            else self._dispatch_step()
 
     @contextlib.contextmanager
     def _turn(self):
@@ -1290,89 +1243,29 @@ class ContinuousBatchingEngine:
         """Drive until every queued request completes; returns them in
         completion order.
 
-        Pipelined: the NEXT chunk is ALWAYS dispatched before the
-        previous chunk's packed output is fetched — device state chains
-        asynchronously, so the harvest round-trip AND the whole
-        admission wave (prefill-chunk programs, slot-state updates)
-        execute while the speculative successor decodes on device: a
-        prefill wave consumes the successor's output pools, so it simply
-        joins the device stream after it, and an admitted slot starts
-        decoding in the chunk after its final prefill wave. A slot that
-        finished inside the previous chunk is inactive in the
-        speculative successor (its device active flag is already False),
-        so the overlap never decodes garbage. The successor is SKIPPED
-        when the host can prove it would do no work (every active slot's
-        predicted remaining budget is zero) — with adaptive chunk
-        lengths that proof fires exactly at each drain wave, so the
-        round-4 "one wasted chunk program per drain wave" cost is gone
-        (``chunks_empty`` measures any residue, e.g. eos stops the host
-        cannot predict).
+        Pipelined: the NEXT step is dispatched before the previous
+        step's packed output is fetched — device state chains
+        asynchronously, so the harvest round-trip and the whole
+        admission pass (slot-state updates) execute while the successor
+        runs on device. A slot that finished inside the previous step
+        is inactive in the successor (its device active flag is already
+        False), so the overlap never decodes garbage, and a slot
+        admitted meanwhile streams its prompt in the step after. The
+        successor is SKIPPED when the host can prove it would do no
+        work: no prefilling slot exists and every active slot's
+        predicted budget is exhausted (``chunks_empty`` measures the
+        residue, eos stops the host cannot predict).
 
-        Unified mode runs the SAME driver with its own hooks: the
-        speculative successor is a whole batching-step program, there
-        is no separate prefill pump (prompt streaming, activation, the
-        first-token sample and the decode tail all live inside the
-        step), and the successor is skipped when no prefilling slot
-        exists and every active slot's predicted budget is exhausted."""
-        if self._unified:
-            if self._spec:
-                # speculative decoding runs the SAME driver SERIALLY:
-                # drafts are functions of the harvested token history
-                # (n-gram lookup) or of the post-harvest device state
-                # (self-spec), so a speculative successor dispatched
-                # before harvest would draft from a stale stream. The
-                # round trip it un-hides is amortized by the ~K tokens
-                # each step emits instead of one.
-                return self._run_driver(
-                    spec_dispatch=lambda: None,
-                    harvest=self._harvest_step,
-                    after_admit=lambda: None,
-                    idle_turn=self._idle_turn_spec)
-            return self._run_driver(
-                spec_dispatch=lambda: self._dispatch_step()
-                if self._worth_step() else None,
-                harvest=self._harvest_step,
-                after_admit=lambda: None,
-                idle_turn=self._idle_turn_unified)
-        return self._run_driver(
-            spec_dispatch=lambda: self._dispatch_chunk()
-            if self._worth_dispatching() else None,
-            harvest=self._harvest_chunk,
-            # ONE prefill wave per scheduler turn: prompt streaming
-            # interleaves with decode chunks instead of stalling them
-            after_admit=lambda: self._pump_prefill(max_waves=1),
-            idle_turn=self._idle_turn_legacy)
+        A speculative engine runs the same loop SERIALLY: drafts are
+        functions of the harvested token history (n-gram lookup) or of
+        the post-harvest device state (self-spec), so a successor
+        dispatched before the harvest would draft from a stale stream.
+        The round trip it un-hides is amortized by the ~K tokens each
+        step emits instead of one."""
+        return self._run_driver()
 
-    def _idle_turn_unified(self):
-        """Nothing in flight: dispatch a step if it would advance
-        anything. Returns (progressed, inflight record or None)."""
-        if self._worth_step():
-            return True, self._dispatch_step()
-        return False, None
-
-    def _idle_turn_spec(self):
-        """Serial speculative turn: draft + dispatch one spec step if
-        it would advance anything."""
-        if self._worth_step():
-            return True, self._dispatch_spec_step()
-        return False, None
-
-    def _idle_turn_legacy(self):
-        """Nothing in flight: stream one prefill wave if prompts are
-        pending, else dispatch a decode chunk if slots are active."""
-        if self._prefilling.any():
-            self._pump_prefill(max_waves=1)
-            return True, None
-        if self.active.any():
-            return True, self._dispatch_chunk()
-        return False, None
-
-    def _run_driver(self, spec_dispatch, harvest, after_admit,
-                    idle_turn):
-        """The one scheduler loop both modes share — hooks differ, the
-        pipelining skeleton, overlap-admission accounting, the fault-
-        containment boundary and stall detection must not (a fix here
-        fixes both engines).
+    def _run_driver(self):
+        """The scheduler loop behind :meth:`run`.
 
         Reliability structure (ISSUE 10): every compiled-step
         dispatch/harvest runs inside the containment boundary — a step
@@ -1412,14 +1305,16 @@ class ContinuousBatchingEngine:
                     # scoped: another component's beats cannot mask us)
                     _frec.beat(_wd_token)
                     if inflight is not None:
-                        # speculative successor first: device never
+                        # the successor first (a speculative engine
+                        # has none: it runs serially): the device never
                         # idles while the host harvests/drains/admits.
                         # Containment wraps ONLY the compiled dispatch/
                         # harvest — a host-side scheduler bug in
                         # _admit/_drain/_reap is not a per-request fault
                         # and must surface, not be laundered into strikes
                         try:
-                            nxt = spec_dispatch()
+                            nxt = None if self._spec \
+                                else self._dispatch_turn()
                         except Exception as exc:  # noqa: BLE001
                             extra = contained(exc)
                             if extra is None:
@@ -1428,7 +1323,7 @@ class ContinuousBatchingEngine:
                             done.extend(extra)
                             continue
                         try:
-                            harvest(inflight)
+                            self._harvest_step(inflight)
                         except Exception as exc:  # noqa: BLE001
                             # blame the HARVESTED program's dispatch-time
                             # cohort (rec[1]), not whoever occupies the
@@ -1445,17 +1340,6 @@ class ContinuousBatchingEngine:
                         self._overlap_admission = nxt is not None
                         try:
                             self._admit()
-                            try:
-                                # legacy prefill waves ARE compiled
-                                # dispatches — containable; nxt is
-                                # abandoned with the rest of device state
-                                after_admit()
-                            except Exception as exc:  # noqa: BLE001
-                                extra = contained(exc)
-                                if extra is None:
-                                    raise
-                                nxt = None
-                                done.extend(extra)
                         finally:
                             self._overlap_admission = False
                         inflight = nxt
@@ -1464,7 +1348,7 @@ class ContinuousBatchingEngine:
                     self._admit()
                     done.extend(self._drain())
                     try:
-                        progressed, inflight = idle_turn()
+                        inflight = self._dispatch_turn()
                     except Exception as exc:  # noqa: BLE001
                         extra = contained(exc)
                         if extra is None:
@@ -1472,7 +1356,7 @@ class ContinuousBatchingEngine:
                         inflight = None
                         done.extend(extra)
                         continue
-                    if progressed or len(done) > n_before:
+                    if inflight is not None or len(done) > n_before:
                         # a recovered wedge must not eat the deadlock
                         # budget forever: the cap bounds CONSECUTIVE
                         # fruitless evictions, not a run's lifetime total
@@ -1626,8 +1510,6 @@ class ContinuousBatchingEngine:
         self._act_target[:] = False
         self._pred_ctx[:] = 0
         self._act_since[:] = 0
-        self._pending_first[:] = False
-        self._echo_inflight[:] = False
         self._emits_inflight[:] = 0
         self._dev_tok = jnp.zeros((B,), jnp.int32)
         self._dev_ctx = jnp.zeros((B,), jnp.int32)
@@ -1674,8 +1556,7 @@ class ContinuousBatchingEngine:
           ``[num_slots, 1]`` forward): the decoding slots ride all of
           them; a slot whose prompt ended in the loop joins after
           micro-step 0 with its first token — prefill→decode transition
-          never leaves the device, so no first-token echo machinery
-          exists in this mode.
+          never leaves the device.
 
         The packed output carries every emitted token of the step (a
         first token in column 0, like a decoding slot's) plus the
@@ -1713,7 +1594,8 @@ class ContinuousBatchingEngine:
                     pool_leaves = list(pool_leaves)
                     pool_leaves[cpool] = jnp.zeros_like(
                         pool_leaves[cpool])
-                # stale instant-eos guard (legacy chunk-entry contract)
+                # stale instant-eos guard: a slot whose last token is
+                # its stop token does not decode
                 act = act & ((eos_arr < 0) | (tok != eos_arr))
 
                 def group(g, carry):
@@ -2261,8 +2143,8 @@ class ContinuousBatchingEngine:
           the fraction of compiled slot-steps that produced a token.
         - ``active_occupancy``: slots active at dispatch / all slots —
           the drain/re-admit idle share specifically.
-        - ``prefill_overlap_frac``: admissions made while a decode chunk
-          was in flight (prefill waves then overlap its on-device run).
+        - ``prefill_overlap_frac``: admissions made while a step was in
+          flight (``run()``'s pipelined successor; 0 under ``step()``).
         - ``tokens_per_s``: emitted tokens / wall seconds inside
           scheduler turns (``serving/step`` spans), whoever pumps them:
           ``run()``, or ``step()`` from an ApiServer / fleet replica.
@@ -2277,21 +2159,16 @@ class ContinuousBatchingEngine:
           prompt tokens carried by the dispatched programs, the prompt
           positions those programs computed, filled or not (the unified
           step: groups run x rows a group x ``prefill_chunk``; a
-          speculative step's or a legacy wave's pass: ``num_slots x
-          prefill_chunk``), and their ratio — how full the prompt
-          passes are.
+          speculative step's pass: ``num_slots x prefill_chunk``), and
+          their ratio — how full the prompt passes are.
         - ``compiled_programs``: distinct compiled signatures this
-          engine built — steady-state 1 in unified mode (the single
-          batching-step program); 1 prefill + the decode-chunk-length
-          ladder in legacy mode. The compile-budget CI gate asserts on
-          this.
+          engine built — steady-state 1 (the single batching-step
+          program). The compile-budget CI gate asserts on this.
         - ``chunks_empty``: harvested programs that delivered no
-          tokens (unpredictable eos stops; structurally-wasted drain
-          wave dispatches are eliminated).
-        - ``prefill_waves``: programs that carried prompt tokens (in
-          unified mode, unified steps with ≥1 prefilling slot).
-        - ``unified_steps``: unified batching-step programs dispatched
-          (0 in legacy mode).
+          tokens (eos stops the host could not predict).
+        - ``prefill_waves``: steps that carried prompt tokens (≥1
+          prefilling slot).
+        - ``unified_steps``: batching-step programs dispatched.
         """
         s = self._stats.as_dict()
         steps = s["chunk_slot_steps"]
@@ -2762,8 +2639,6 @@ class ContinuousBatchingEngine:
         if device:
             self.active[slot] = False
             self._prefilling[slot] = False
-            self._pending_first[slot] = False
-            self._echo_inflight[slot] = False
             self._emits_inflight[slot] = 0
             self._dev_tbl = self._dev_tbl.at[slot].set(
                 jnp.zeros((self.pages_per_slot,), jnp.int32))
@@ -2891,13 +2766,13 @@ class ContinuousBatchingEngine:
     def _admit_queued(self):
         """Move queued requests into free slots: allocate pages, stage
         per-slot state, and mark the slot PREFILLING — the prompt itself
-        streams through the batched prefill-chunk program in
-        :meth:`_pump_prefill`. Admission order is priority-then-FIFO;
-        when no slot or not enough pages are free, a strictly-higher-
-        priority candidate preempts running lower-priority sequences
-        (:meth:`_preempt_for`). Requests implicated by a step failure
-        (``strikes > 0``) re-enter SOLO so the next fault implicates
-        exactly one request."""
+        streams through the step program's prompt groups
+        (:meth:`_stage_prompt_chunks`). Admission order is
+        priority-then-FIFO; when no slot or not enough pages are free,
+        a strictly-higher-priority candidate preempts running
+        lower-priority sequences (:meth:`_preempt_for`). Requests
+        implicated by a step failure (``strikes > 0``) re-enter SOLO so
+        the next fault implicates exactly one request."""
         while self.queue:
             req = self._next_candidate()
             if self._already_complete(req):
@@ -3050,349 +2925,6 @@ class ContinuousBatchingEngine:
         self._dev_eos = self._dev_eos.at[slot].set(
             int(self.slot_eos[slot]))
 
-    def _prefill_static(self):
-        """The ONE compiled prefill signature: every wave — any mix of
-        prompt lengths, any number of admitted prompts up to
-        ``admit_batch`` — runs through this [num_slots, prefill_chunk]
-        program. Writes pages incrementally, attends causally over the
-        paged history, and samples the first token for slots whose
-        prompt ends inside the chunk (it stays device-resident; the next
-        decode chunk echoes it through the packed fetch)."""
-        if self._prefill_fn is not None:
-            return self._prefill_fn
-        from ..jit import to_static
-        model = self.model
-        greedy = self.greedy
-        temperature = self.temperature
-        C = self.prefill_chunk
-
-        def prefill(ids_t, pstart_t, valid_t, last_t, tgt_t, tok_t,
-                    ctx_t, act_t, tbl_t, key_t, *pools):
-
-            def fn(ids, pstart, valid, last, tgt, tok, ctx, act, tbl,
-                   key, *pool_leaves):
-                with no_grad():
-                    logits, npools = model(
-                        Tensor(ids),
-                        caches=[Tensor(a) for a in pool_leaves],
-                        pos=Tensor(pstart[:, None]),
-                        tables=(Tensor(tbl), Tensor(valid)))
-                lg = logits._data                        # [B, C, V]
-                idx = jnp.clip(valid - 1, 0, C - 1)
-                last_lg = jnp.take_along_axis(
-                    lg, idx[:, None, None], axis=1)[:, 0]
-                last_lg = last_lg.astype(jnp.float32)    # [B, V]
-                if greedy:
-                    sampled = jnp.argmax(last_lg, -1).astype(jnp.int32)
-                else:
-                    key, sub = jax.random.split(key)
-                    sampled = jax.random.categorical(
-                        sub, last_lg / temperature).astype(jnp.int32)
-                fire = last & (valid > 0)
-                tok2 = jnp.where(fire, sampled, tok)
-                ctx2 = ctx + valid
-                act2 = jnp.where(fire, tgt, act)
-                return (tok2, ctx2, act2, key) + tuple(
-                    t._data for t in npools)
-
-            return _apply_multi(
-                fn, [ids_t, pstart_t, valid_t, last_t, tgt_t, tok_t,
-                     ctx_t, act_t, tbl_t, key_t] + list(pools),
-                n_out=4 + len(pools))
-
-        self._prefill_fn = to_static(prefill)
-        self._compiled.add(("prefill", C))
-        return self._prefill_fn
-
-    def _pump_prefill(self, max_waves=None):
-        """Dispatch batched prefill-chunk programs until every
-        prefilling slot has streamed its whole prompt (or ``max_waves``
-        waves were dispatched — the interleaving throttle). Entirely
-        async: no host fetch; completion is host-predicted (prompt
-        lengths are known)."""
-        B, C = self.num_slots, self.prefill_chunk
-        waves = 0
-        while self._prefilling.any():
-            if max_waves is not None and waves >= max_waves:
-                return
-            ids = np.zeros((B, C), np.int32)
-            pstart = np.zeros((B,), np.int32)
-            valid = np.zeros((B,), np.int32)
-            last = np.zeros((B,), bool)
-            tgt = np.zeros((B,), bool)
-            batched = []
-            for slot in range(B):
-                if not self._prefilling[slot]:
-                    continue
-                if len(batched) >= self.admit_batch:
-                    continue      # next wave picks it up
-                prm = self._slot_prompt[slot]
-                off = int(self._prefill_off[slot])
-                v = min(C, len(prm) - off)
-                ids[slot, :v] = prm[off:off + v]
-                pstart[slot] = off
-                valid[slot] = v
-                last[slot] = off + v == len(prm)
-                tgt[slot] = self._act_target[slot]
-                batched.append(slot)
-            fn = self._prefill_static()
-            self._seq += 1
-            self._stats["prefill_waves"] += 1
-            n_tok = int(valid.sum())
-            self._stats.inc("prefill_tokens", n_tok)
-            self._stats.inc("prefill_positions", B * C)
-            with _span("serving/dispatch", seq=self._seq,
-                       prefilling=len(batched), prefill_tokens=n_tok):
-                res = fn(Tensor(jnp.asarray(ids)),
-                         Tensor(jnp.asarray(pstart)),
-                         Tensor(jnp.asarray(valid)),
-                         Tensor(jnp.asarray(last)),
-                         Tensor(jnp.asarray(tgt)), Tensor(self._dev_tok),
-                         Tensor(self._dev_ctx), Tensor(self._dev_act),
-                         Tensor(self._dev_tbl), Tensor(self._key),
-                         *self.pools)
-            tok2, ctx2, act2, key2 = res[:4]
-            self.pools = list(res[4:])
-            self._dev_tok = tok2._data
-            self._dev_ctx = ctx2._data
-            self._dev_act = act2._data
-            self._key = key2._data
-            for slot in batched:
-                self._prefill_off[slot] += valid[slot]
-                if not last[slot]:
-                    continue
-                # final wave for this prompt: host-side activation —
-                # the sampled first token stays on device and is echoed
-                # through the next decode chunk's packed fetch (or the
-                # drain-time fetch for one-shot tail requests)
-                req = self.slot_req[slot]
-                tl = len(self._slot_prompt[slot])
-                req.t_prefill_done = time.perf_counter()
-                self._prefilling[slot] = False
-                self.ctx[slot] = tl
-                self._pred_ctx[slot] = tl
-                self._pending_first[slot] = True
-                self._act_since[slot] = self._seq
-                # instant-eos (first token == stop token) is detected ON
-                # DEVICE at the next chunk's entry; only the structural
-                # one-token case is known host-side now
-                self.active[slot] = bool(self._act_target[slot])
-                # prompt pages final: publish for prefix sharing
-                self._pc_insert(slot)
-            waves += 1
-
-    # ---- chunked decode --------------------------------------------------
-
-    def _worth_dispatching(self):
-        """Is there any slot a decode chunk could advance? With the
-        host's ctx prediction this is exact for length-limited slots, so
-        the structurally-wasted drain-wave dispatch never happens; an
-        eos stop the host cannot see may still yield an empty chunk
-        (counted in ``chunks_empty``)."""
-        return bool(np.any(self.active & (self.limits > self._pred_ctx)))
-
-    def _next_chunk_len(self):
-        """Adaptive chunk length: clamp to the minimum predicted
-        remaining budget across active slots so no slot oversteps its
-        limit inside a chunk, quantized to a power-of-two ladder ≤
-        ``decode_chunk`` to bound distinct compiled signatures."""
-        if not self.adaptive_chunk:
-            return self.decode_chunk
-        rem = (self.limits - self._pred_ctx)[self.active
-                                             & (self.limits
-                                                > self._pred_ctx)]
-        if rem.size == 0:
-            return self.decode_chunk
-        m = int(rem.min())
-        if m >= self.decode_chunk:
-            return self.decode_chunk
-        return 1 << (m.bit_length() - 1)
-
-    def _chunk_static(self, n_steps):
-        fn = self._chunk_fns.get(n_steps)
-        if fn is not None:
-            return fn
-        from ..jit import to_static
-        model = self.model
-        greedy = self.greedy
-        temperature = self.temperature
-
-        def chunk(tok_t, ctx_t, act_t, tbl_t, lim_t, eos_t, key_t,
-                  *pools):
-            fwd = model.forward
-
-            def fn(tok, ctx, act, tbl, lim, eos_arr, key, *pool_leaves):
-                b = tok.shape[0]
-                # a freshly admitted slot whose prefill token already hit
-                # its stop token must not decode (the host never saw the
-                # token — instant-eos is detected here, on device)
-                act = act & ((eos_arr < 0) | (tok != eos_arr))
-                init_tok = tok
-
-                def body(carry, _):
-                    tok_c, ctx_c, act_c, key_c, leaves = carry
-                    with no_grad():
-                        logits, ncaches = fwd(
-                            Tensor(tok_c.reshape(b, 1)),
-                            caches=[Tensor(a) for a in leaves],
-                            pos=Tensor(ctx_c[:, None]),
-                            tables=(Tensor(tbl), Tensor(act_c)))
-                    lg = logits[:, -1]._data.astype(jnp.float32)
-                    if greedy:
-                        nxt = jnp.argmax(lg, -1).astype(jnp.int32)
-                    else:
-                        key_c, sub = jax.random.split(key_c)
-                        nxt = jax.random.categorical(
-                            sub, lg / temperature).astype(jnp.int32)
-                    ctx_n = ctx_c + act_c.astype(jnp.int32)
-                    nxt = jnp.where(act_c, nxt, tok_c)
-                    # per-slot eos (a traced [B] array, -1 = none): each
-                    # request may carry its own stop token
-                    still = act_c & (ctx_n < lim) & \
-                        ((eos_arr < 0) | (nxt != eos_arr))
-                    new_leaves = tuple(t._data for t in ncaches)
-                    out_tok = jnp.where(act_c, nxt, -1)
-                    return (nxt, ctx_n, still, key_c, new_leaves), \
-                        (out_tok, act_c)
-
-                carry0 = (tok, ctx, act, key, tuple(pool_leaves))
-                carry, (toks, emitted) = jax.lax.scan(
-                    body, carry0, jnp.arange(n_steps))
-                tok_f, ctx_f, act_f, key_f, leaves_f = carry
-                # ONE packed int32 fetch carries everything the host
-                # scheduler needs: emitted tokens, emission mask, the
-                # first-token echo for freshly admitted slots, and the
-                # ctx/active mirrors
-                packed_out = jnp.concatenate(
-                    [toks.T.astype(jnp.int32),
-                     emitted.T.astype(jnp.int32),
-                     init_tok[:, None].astype(jnp.int32),
-                     ctx_f[:, None].astype(jnp.int32),
-                     act_f[:, None].astype(jnp.int32)], axis=1)
-                return (packed_out, tok_f, ctx_f, act_f, key_f) \
-                    + tuple(leaves_f)
-
-            return _apply_multi(fn, [tok_t, ctx_t, act_t, tbl_t, lim_t,
-                                     eos_t, key_t]
-                                + list(pools), n_out=5 + len(pools))
-
-        fn = to_static(chunk)
-        self._chunk_fns[n_steps] = fn
-        self._compiled.add(("chunk", n_steps))
-        return fn
-
-    def _dispatch_chunk(self):
-        """Launch one chunk program (async) and chain the device state.
-        Returns an in-flight record for :meth:`_harvest_chunk` — the
-        packed output is NOT fetched here, so a caller may overlap the
-        fetch with the next chunk's on-device compute."""
-        n = self._next_chunk_len()
-        fn = self._chunk_static(n)
-        self._seq += 1
-        self._last_fetch_dispatch_seq = self._seq
-        # "active" for occupancy accounting = slots this chunk can
-        # actually advance (host-active AND budget remaining); a slot
-        # that exhausted its budget but has not drained yet is idle
-        n_active = int(np.sum(self.active
-                              & (self.limits > self._pred_ctx)))
-        _t_obs = time.perf_counter()
-        self._stats.inc("chunks")
-        self._stats.inc("chunk_slot_steps", self.num_slots * n)
-        self._stats.inc("active_slot_steps", n_active * n)
-        from ..profiler.trace import get_tracer
-        _tr = get_tracer()
-        if _tr.enabled:
-            _tr.counter("serving/active_slots", n_active,
-                        queued=len(self.queue), chunk_len=n)
-        _frec.record_event("sched_turn", seq=self._seq, mode="legacy",
-                           active=n_active, queued=len(self.queue),
-                           chunk_len=n)
-        self._obs_s += time.perf_counter() - _t_obs
-        with _span("serving/dispatch", seq=self._seq, active=n_active,
-                   chunk_len=n):
-            res = fn(Tensor(self._dev_tok), Tensor(self._dev_ctx),
-                     Tensor(self._dev_act), Tensor(self._dev_tbl),
-                     Tensor(self._dev_lim), Tensor(self._dev_eos),
-                     Tensor(self._key), *self.pools)
-        packed, tok_f, ctx_f, act_f, key_f = res[:5]
-        self.pools = list(res[5:])
-        self._dev_tok = tok_f._data
-        self._dev_ctx = ctx_f._data
-        self._dev_act = act_f._data
-        self._key = key_f._data
-        self._pred_ctx = np.where(
-            self.active,
-            np.minimum(self.limits, self._pred_ctx + n),
-            self._pred_ctx).astype(np.int32)
-        # snapshot the slot->request mapping, the pending-first mask and
-        # the dispatch seq: by harvest time a drained slot may have been
-        # re-admitted (or a prefilling slot activated) — stale views
-        # must not be applied
-        rec = (packed, list(self.slot_req), self._pending_first.copy(),
-               n, self._seq)
-        self._echo_inflight |= self._pending_first
-        self._pending_first[:] = False
-        return rec
-
-    def _harvest_chunk(self, rec):
-        """Fetch one in-flight chunk's packed output and apply it."""
-        with _span("serving/harvest") as sp:
-            packed, snap_req, pending, n, seq = rec
-            with _span("serving/harvest.fetch"):
-                arr = np.asarray(packed._data)        # the ONE fetch
-            self._last_harvest_seq = max(self._last_harvest_seq, seq)
-            self._release_deferred()
-            toks_np = arr[:, :n]
-            emitted_np = arr[:, n:2 * n].astype(bool)
-            init_tok = arr[:, 2 * n]
-            ctx_m = arr[:, 2 * n + 1].astype(np.int32)
-            act_m = arr[:, 2 * n + 2].astype(bool)
-            t_now = time.perf_counter()
-            appended = 0
-            for slot in range(self.num_slots):
-                req = snap_req[slot]
-                if req is not self.slot_req[slot]:
-                    # slot evicted (its echo flag was reset by the
-                    # eviction) or re-admitted since this dispatch: the
-                    # stale pending snapshot must not clear the NEW
-                    # occupant's first-token guard — its token rides a
-                    # later, unharvested program
-                    continue
-                if pending[slot]:
-                    # this harvest delivers the slot's first-token echo;
-                    # _drain may finish the slot again from here on
-                    self._echo_inflight[slot] = False
-                if self._act_since[slot] <= seq:
-                    # the chunk's view of this slot is current (it was not
-                    # re-activated by a prefill wave after this dispatch)
-                    self.ctx[slot] = ctx_m[slot]
-                    self.active[slot] = act_m[slot]
-                if req is None:
-                    continue
-                if pending[slot]:
-                    if not req.tokens:
-                        req.t_first = t_now
-                    req.tokens.append(int(init_tok[slot]))
-                    appended += 1
-                if req.finished:
-                    continue
-                req.strikes = 0        # clean harvest exonerates (above)
-                for j in range(n):
-                    if emitted_np[slot, j]:
-                        if not req.tokens:
-                            req.t_first = t_now
-                        req.tokens.append(int(toks_np[slot, j]))
-                        appended += 1
-            _t_obs = time.perf_counter()
-            self._stats.inc("tokens_emitted", appended)
-            if appended == 0:
-                self._stats.inc("chunks_empty")
-            sp.set_args(seq=seq, appended=appended)
-            self._obs_s += time.perf_counter() - _t_obs
-
-    def _decode_chunk(self):
-        self._harvest_chunk(self._dispatch_chunk())
-
     # ---- completion ------------------------------------------------------
 
     def _record_latency(self, req):
@@ -3487,25 +3019,15 @@ class ContinuousBatchingEngine:
             if req is None:
                 continue
             if self._prefilling[slot]:
-                # prompt still streaming through prefill waves — the
-                # slot is inactive but very much occupied
+                # prompt still streaming through the step's prompt
+                # groups — the slot is inactive but very much occupied
                 continue
-            if self._echo_inflight[slot] or self._emits_inflight[slot]:
+            if self._emits_inflight[slot]:
                 # tokens for this slot ride a dispatched-but-
                 # unharvested program: finishing now would lose them
                 # (defer one loop)
                 continue
             if not self.active[slot]:
-                if self._pending_first[slot]:
-                    # finished without any chunk running after prefill
-                    # completion (one-token request at the tail of the
-                    # workload): the first token never got echoed —
-                    # fetch it now
-                    req.t_first = time.perf_counter()
-                    req.tokens.append(int(np.asarray(
-                        self._dev_tok[slot])))
-                    self._stats.inc("tokens_emitted")
-                    self._pending_first[slot] = False
                 if self._should_migrate(slot, req):
                     self._migrate_out(slot, req)
                     continue
@@ -3535,9 +3057,7 @@ def _apply_multi(fn, tensors, n_out):
 # standalone builder: `bench.py --autotune`'s cb section is the sweep
 # vehicle (it times candidate ladders on the real workload and commits
 # the winner); a recorded winner then serves every ctor call that
-# leaves the knobs as None. Candidate values are powers of two — the
-# adaptive decode ladder and the compiled-signature budget both
-# assume pow2.
+# leaves the knobs as None. Candidate values are powers of two.
 
 def _register_serving_surface():
     from ..tuner.surface import TunableSurface, register_surface
@@ -3572,8 +3092,8 @@ def _register_serving_surface():
         candidates=_candidates,
         is_valid=_is_valid,
         describe="ContinuousBatchingEngine ladder: decode chunk length, "
-                 "batched-prefill chunk, prompts admitted per prefill "
-                 "wave. Shape key: slots/max_len/page."))
+                 "batched-prefill chunk, prompts admitted per step. "
+                 "Shape key: slots/max_len/page."))
 
 
 def _register_spec_surface():

@@ -64,9 +64,6 @@ def llama_engine(model="tiny", num_hidden_layers=1, seed=0,
     if dtype:
         m.to(dtype=dtype)
     m.eval()
-    if "prompt_buckets" in engine_kw:
-        engine_kw["prompt_buckets"] = tuple(
-            engine_kw["prompt_buckets"])
     engine_kw.setdefault("greedy", True)
     return ContinuousBatchingEngine(m, **engine_kw)
 

@@ -271,7 +271,7 @@ class FaultInjector:
 
         def make(original, plan_):
             def patched(eng, rec, *a, **kw):
-                snap = rec[1]   # both harvest records carry the
+                snap = rec[1]   # the harvest record carries the
                                 # slot->request snapshot at index 1
                 if any(r is not None and r.request_id == rid
                        for r in snap) and injector._claim(plan_):
@@ -281,8 +281,7 @@ class FaultInjector:
                 return original(eng, rec, *a, **kw)
             return patched
 
-        for meth in ("_harvest_step", "_harvest_chunk"):
-            self._custom(self._SERVING + meth, plan, make)
+        self._custom(self._SERVING + "_harvest_step", plan, make)
         return plan
 
     def wedge_slot(self, slot, times=1):

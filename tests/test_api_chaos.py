@@ -60,7 +60,7 @@ def _factory():
     m, _ = _model()
     return lambda: ContinuousBatchingEngine(
         m, num_slots=2, page_size=8, max_len=48, decode_chunk=4,
-        prompt_buckets=(8, 16), greedy=True)
+        prefill_chunk=16, greedy=True)
 
 
 def _reference(prompt_ids, n_new):

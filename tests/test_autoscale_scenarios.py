@@ -59,7 +59,7 @@ def _factory(**kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("max_len", 48)
     kw.setdefault("decode_chunk", 4)
-    kw.setdefault("prompt_buckets", (8, 16))
+    kw.setdefault("prefill_chunk", 16)
     kw.setdefault("greedy", True)
 
     def make(role=None, **_ignored):
@@ -210,7 +210,7 @@ def test_tenant_hotspot_attainment_for_both_tenants():
 def test_long_prompt_flood_holds_short_chat_slo():
     sc, fleet, ctl, clock, report = _run(
         "long_prompt_flood",
-        factory_kw=dict(max_len=64, prompt_buckets=(8, 16, 48)))
+        factory_kw=dict(max_len=64, prefill_chunk=48))
     _assert_common(sc, ctl, clock, report)
     assert report["goodput_frac"] >= sc["attainment_bar"], report
 
@@ -228,7 +228,7 @@ def test_long_prompt_flood_on_disagg_picks_role_from_signals():
     schedule = load_harness.build_scenario(
         "long_prompt_flood", vocab=cfg.vocab_size, seed=0)
     fleet = DisaggServingFleet(
-        _factory(max_len=64, prompt_buckets=(8, 16, 48)),
+        _factory(max_len=64, prefill_chunk=48),
         num_prefill=1, num_decode=1, hedge_delay_s=None, seed=0,
         slo_rules=[SLORule(**d) for d in sc["slo_rules"]])
     clock = load_harness.TickClock()

@@ -28,7 +28,7 @@ pytestmark = pytest.mark.disagg
 os.environ.setdefault("PADDLE_TPU_SERVING_AUDIT", "1")
 
 _ENG_KW = dict(num_slots=2, page_size=8, max_len=64, decode_chunk=4,
-               prompt_buckets=(32,), greedy=True)
+               prefill_chunk=32, greedy=True)
 
 
 @pytest.fixture(scope="module")
@@ -116,20 +116,17 @@ def test_handoff_reattach_round_trip_single_engine(model):
     eng._audit_pages("post-reattach")
 
 
-@pytest.mark.parametrize("unified", [True, False])
-def test_migration_token_identity_single_pair(model, oracle, unified):
+def test_migration_token_identity_single_pair(model, oracle):
     """Export from a prefill-role engine, import into a decode-role
     engine: greedy streams token-identical to colocated, audits green
     both sides, single-token requests complete locally."""
     cfg, m = model
-    pre = ContinuousBatchingEngine(m, unified=unified, role="prefill",
-                                   **_ENG_KW)
-    dec = ContinuousBatchingEngine(m, unified=unified, role="decode",
-                                   **_ENG_KW)
+    pre = ContinuousBatchingEngine(m, role="prefill", **_ENG_KW)
+    dec = ContinuousBatchingEngine(m, role="decode", **_ENG_KW)
     ids = [pre.add_request(p, n) for p, n in _specs(cfg)]
     done, migrated = _drive_pair(pre, dec, len(ids))
     for i, ref in zip(ids, oracle):
-        assert done[i].tokens == ref, (unified, i)
+        assert done[i].tokens == ref, i
     assert migrated == 4        # the max_new=1 request stays local
     assert pre._c_migrated_out.value == 4
     assert dec._c_kv_imported.value > 0
@@ -300,15 +297,13 @@ def test_fleet_disagg_token_identity_and_metrics(model, oracle):
         or fleet.predicted_itl_s() > 0
 
 
-def test_prefill_scale_up_warms_the_wide_bucket(model):
+def test_prefill_scale_up_warms_the_step_program(model):
     """ISSUE-19 satellite: a warm ``scale_up(role="prefill")`` must
-    compile the WIDEST prompt bucket before the replica takes router
+    compile the engine's step program before the replica takes router
     weight — a long prompt served right after the scale-up must not
-    pay a new XLA compile inside the serving path (the base fleet's
-    4-token sacrificial request would only warm the narrowest
-    bucket)."""
+    pay an XLA compile inside the serving path."""
     cfg, m = model
-    kw = dict(_ENG_KW, prompt_buckets=(8, 32))
+    kw = dict(_ENG_KW, prefill_chunk=32)
 
     def factory(role="both"):
         return ContinuousBatchingEngine(m, role=role, **kw)
@@ -318,10 +313,10 @@ def test_prefill_scale_up_warms_the_wide_bucket(model):
     rid = fleet.scale_up(role="prefill", warm=True)
     eng = fleet.replicas[rid].engine
     assert any(sig[1] == 32 for sig in eng._compiled
-               if sig[0] in ("unified", "prefill")), eng._compiled
+               if sig[0] == "unified"), eng._compiled
     before = eng.gauges()["compiled_programs"]
-    # a long prompt straight onto the warmed engine: same bucket,
-    # zero new compiled signatures
+    # a long prompt straight onto the warmed engine: zero new
+    # compiled signatures
     prompt = np.arange(28, dtype=np.int32) % cfg.vocab_size
     eng.add_request(prompt, 1)
     for _ in range(200):

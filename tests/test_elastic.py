@@ -26,18 +26,31 @@ ENV = dict(os.environ, JAX_PLATFORMS="cpu",
 
 def test_heartbeat_and_watch(tmp_path):
     d = str(tmp_path)
-    mgr = ElasticManager(2, directory=d, timeout=0.5)
+    # the timeout that must NOT fire is 600 beat intervals (a loaded
+    # machine may starve a beat thread for a second, not for thirty);
+    # the gap that must fire is made, not slept through
+    mgr = ElasticManager(2, directory=d, timeout=30.0)
     status, missing = mgr.watch()
     assert status is ElasticStatus.INCOMPLETE and missing == [0, 1]
-    start_heartbeat(0, directory=d, interval=0.1)
+    start_heartbeat(0, directory=d, interval=0.05)
     status, missing = mgr.watch()
     assert status is ElasticStatus.INCOMPLETE and missing == [1]
-    start_heartbeat(1, directory=d, interval=0.1)  # replaces thread 0...
+    start_heartbeat(1, directory=d, interval=0.05)  # replaces thread 0...
     assert mgr.wait_all_registered(timeout=5.0)
     status, stale = mgr.watch()
     assert status is ElasticStatus.HEALTHY
-    # rank 0's thread was replaced by rank 1's: rank 0 goes stale
-    time.sleep(0.8)
+    # rank 0's thread was replaced by rank 1's, so nothing refreshes its
+    # beat: age it past the timeout, then see rank 1 beat twice more (a
+    # rank-0 thread still alive would have beaten in that time too)
+    old = time.time() - 60.0
+    os.utime(os.path.join(d, "heartbeat.0"), (old, old))
+    beat1 = os.path.join(d, "heartbeat.1")
+    seen, last, give_up = 0, os.path.getmtime(beat1), time.time() + 20.0
+    while seen < 2 and time.time() < give_up:
+        time.sleep(0.01)
+        now = os.path.getmtime(beat1)
+        seen, last = seen + (now != last), now
+    assert seen == 2
     status, stale = mgr.watch()
     assert status is ElasticStatus.STALE and stale == [0]
     stop_heartbeat()

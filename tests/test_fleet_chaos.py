@@ -50,7 +50,7 @@ def _factory(**kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("max_len", 48)
     kw.setdefault("decode_chunk", 4)
-    kw.setdefault("prompt_buckets", (8, 16))
+    kw.setdefault("prefill_chunk", 16)
     kw.setdefault("greedy", True)
     return lambda: ContinuousBatchingEngine(m, **kw)
 

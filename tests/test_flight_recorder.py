@@ -303,7 +303,7 @@ def test_engine_stall_raises_and_dumps(tmp_path):
     # first otherwise (test_serving_reliability pins that behavior)
     eng = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8,), greedy=True,
+                                   prefill_chunk=8, greedy=True,
                                    audit=False)
     eng.add_request(np.arange(5, dtype=np.int32), 4)
     eng._free_pages.clear()

@@ -240,7 +240,7 @@ def _tiny_engine(**kw):
     model.eval()
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8, 16), greedy=True,
+                                   prefill_chunk=16, greedy=True,
                                    **kw)
     return eng, cfg
 

@@ -249,24 +249,6 @@ def test_a_padding_row_of_a_ragged_group_writes_no_state(tiny, monkeypatch):
         assert _worst_gap(m, src, p, r.tokens) <= 1e-4
 
 
-def test_legacy_engine_pair_keeps_the_state_rules(tiny):
-    """``unified=False`` (prefill waves + decode chunks) calls the same
-    forward with the same ``pos`` / gate convention, so the state's rules
-    hold there too; the pass counters are the unified step's alone."""
-    model, m, src = tiny
-    eng = ContinuousBatchingEngine(model, num_slots=2, max_len=48,
-                                   page_size=4, prefill_chunk=8,
-                                   decode_chunk=4, greedy=True,
-                                   unified=False, audit=True)
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(0, 128, L).astype(np.int32) for L in (13, 5, 9)]
-    rids = [eng.add_request(p, 6) for p in prompts]
-    for p, r in zip(prompts, _serve(eng, rids)):
-        assert r.error is None and len(r.tokens) == 6
-        assert _worst_gap(m, src, p, r.tokens) <= 1e-4
-    assert eng.gauges()["moe_tokens"] == 0
-
-
 def test_held_share_model_serves_its_share_of_the_reference():
     """A model that holds experts 4..7 of 16 serves what the reference,
     given the same share, computes."""

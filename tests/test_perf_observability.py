@@ -124,7 +124,7 @@ class TestServingGauges:
         model.eval()
         eng = ContinuousBatchingEngine(
             model, num_slots=2, page_size=8, max_len=48, decode_chunk=4,
-            prompt_buckets=(8, 16), greedy=True)
+            prefill_chunk=16, greedy=True)
         rng = np.random.RandomState(0)
         for plen, n in [(6, 8), (12, 5), (9, 10), (4, 6)]:
             eng.add_request(rng.randint(0, cfg.vocab_size,

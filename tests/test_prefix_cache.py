@@ -54,7 +54,7 @@ def _engine(**kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("max_len", 64)
     kw.setdefault("decode_chunk", 4)
-    kw.setdefault("prompt_buckets", (32,))
+    kw.setdefault("prefill_chunk", 32)
     kw.setdefault("greedy", True)
     return ContinuousBatchingEngine(m, **kw)
 
@@ -78,11 +78,10 @@ def _balanced(eng):
     eng._audit_pages("test")
 
 
-@pytest.mark.parametrize("unified", [True, False])
-def test_cache_on_off_token_identical(unified):
+def test_cache_on_off_token_identical():
     """THE transparency pin: a shared-prefix batch produces bitwise
-    the same greedy streams with the cache on and off, in both engine
-    modes — and the warm run actually shares (hits, tokens saved)."""
+    the same greedy streams with the cache on and off — and the warm
+    run actually shares (hits, tokens saved)."""
     _, cfg = _model()
     rng = np.random.RandomState(7)
     shared = rng.randint(0, cfg.vocab_size, (19,)).astype(np.int32)
@@ -92,9 +91,9 @@ def test_cache_on_off_token_identical(unified):
                            (int(rng.randint(0, 6)),)).astype(np.int32)
         specs.append((np.concatenate([shared, tail]),
                       int(rng.randint(3, 7))))
-    refs = _ref_off(specs, unified=unified)
+    refs = _ref_off(specs)
 
-    eng = _engine(unified=unified)
+    eng = _engine()
     ids = [eng.add_request(p, n) for p, n in specs]
     by = {r.request_id: r for r in eng.run()}
     for rid, ref in zip(ids, refs):
@@ -229,12 +228,12 @@ def test_eviction_is_refcount_aware_lru():
     rng = np.random.RandomState(23)
     # 5 allocatable pages, 3-page requests: each run caches 2 pages,
     # so the third distinct prompt MUST evict
-    eng = _engine(num_pages=6, max_len=32, prompt_buckets=(16,))
+    eng = _engine(num_pages=6, max_len=32, prefill_chunk=16)
     refs, ids = [], []
     prompts = [rng.randint(0, cfg.vocab_size, (16,)).astype(np.int32)
                for _ in range(3)]
     refs = _ref_off([(p, 6) for p in prompts], num_pages=6,
-                    max_len=32, prompt_buckets=(16,))
+                    max_len=32, prefill_chunk=16)
     for p in prompts:
         ids.append(eng.add_request(p, 6))
         by = {r.request_id: r for r in eng.run()}
@@ -306,7 +305,7 @@ def test_fleet_prefix_affinity_hint():
     def factory():
         return ContinuousBatchingEngine(
             m, num_slots=2, page_size=8, max_len=64, decode_chunk=4,
-            prompt_buckets=(32,), greedy=True)
+            prefill_chunk=32, greedy=True)
 
     fleet = ServingFleet(factory, num_replicas=3)
     h = hash(shared[:8].tobytes())

@@ -55,7 +55,7 @@ def _factory(**kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("max_len", 64)
     kw.setdefault("decode_chunk", 4)
-    kw.setdefault("prompt_buckets", (32,))
+    kw.setdefault("prefill_chunk", 32)
     kw.setdefault("greedy", True)
     return lambda: ContinuousBatchingEngine(m, **kw)
 

@@ -38,7 +38,7 @@ pytestmark = [pytest.mark.proc_fleet, pytest.mark.fault,
               pytest.mark.slow]
 
 _ENG_KW = dict(num_slots=2, page_size=8, max_len=48, decode_chunk=4,
-               prompt_buckets=(8, 16), greedy=True)
+               prefill_chunk=16, greedy=True)
 _SPEC = {"factory": "paddle_tpu.inference.worker:llama_engine",
          "kwargs": dict(model="tiny", num_hidden_layers=1, seed=0,
                         **_ENG_KW)}

@@ -225,12 +225,13 @@ class _Spawner:
     and remembers them so tests can kill/pause a specific
     incarnation."""
 
-    def __init__(self, engine_factory=None):
+    def __init__(self, engine_factory=None, hb_interval=0.02):
         self.engine_factory = engine_factory or _FakeEngine
+        self.hb_interval = hb_interval
         self.procs = []
 
     def __call__(self, replica):
-        p = _FakeProc(self.engine_factory)
+        p = _FakeProc(self.engine_factory, self.hb_interval)
         self.procs.append(p)
         return p, p.parent_sock
 
@@ -440,17 +441,35 @@ def test_paused_worker_is_hung_not_dead():
 
 
 def test_slow_reply_with_heartbeats_is_not_hung():
-    rep, sp = _replica(rpc_deadline_s=0.02, rpc_retries=2,
-                       rpc_hard_deadline_s=5.0)
+    # the deadlines that must NOT fire (heartbeat silence 10 s, hard
+    # deadline 60 s) stand three orders above the soft deadline that
+    # must (20 ms), and the worker beats every 50 ms: slower than the
+    # parent's 20 ms receive poll, so a poll runs dry between two beats
+    # and the soft deadline is looked at
+    rep, sp = _replica(_Spawner(hb_interval=0.05), rpc_deadline_s=0.02,
+                       rpc_retries=2, rpc_hard_deadline_s=60.0,
+                       hb_timeout_s=10.0)
     try:
-        # delay every reply beyond the soft deadline: retransmits
-        # fire (deduped by the worker's reply cache), heartbeats keep
-        # flowing, and the RPC eventually lands — no hung declaration
+        retries = rep.engine.metrics.counter("proc/rpc_retries")
+        # hold every step's reply until the parent HAS retransmitted
+        # it (deduped by the worker's reply cache) and then heard a
+        # heartbeat — the events themselves, not a sleep that stands
+        # for them: the RPC lands late, no hung declaration
         orig = _FakeWorker._handle
+        held = []
+
+        def until(cond, give_up):
+            while not cond() and time.perf_counter() < give_up:
+                time.sleep(0.002)
+            return cond()
 
         def slow(self, op, msg):
             if op == "step":
-                time.sleep(0.06)
+                n0, give_up = retries.value, time.perf_counter() + 8.0
+                retried = until(lambda: retries.value > n0, give_up)
+                beat = rep.last_beat
+                held.append(retried and until(
+                    lambda: rep.last_beat > beat, give_up))
             return orig(self, op, msg)
 
         _FakeWorker._handle = slow
@@ -461,8 +480,8 @@ def test_slow_reply_with_heartbeats_is_not_hung():
             _FakeWorker._handle = orig
         assert done[0].tokens == _expected_tokens(0, 2)
         assert not rep._hung
-        reg = rep.engine.metrics
-        assert reg.counter("proc/rpc_retries").value >= 1
+        assert held and all(held)
+        assert retries.value >= len(held)
     finally:
         rep.close()
 
@@ -651,7 +670,7 @@ def test_real_worker_token_identity_and_sigkill_respawn():
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
     eng_kw = dict(num_slots=2, page_size=8, max_len=48,
-                  decode_chunk=4, prompt_buckets=(8, 16), greedy=True)
+                  decode_chunk=4, prefill_chunk=16, greedy=True)
     spec = {"factory": "paddle_tpu.inference.worker:llama_engine",
             "kwargs": dict(model="tiny", num_hidden_layers=1, seed=0,
                            **eng_kw)}

@@ -282,33 +282,6 @@ def test_run_seconds_accumulate_per_turn_whoever_pumps(pump):
     assert eng._stats["run_seconds"] == 0.0
 
 
-def test_legacy_engine_has_the_same_layer_spans(tmp_path):
-    eng, cfg = _engine(unified=False, prompt_buckets=(16,))
-    for p in _prompts(cfg, (5, 11)):
-        eng.add_request(p, 6)
-    _pump(eng)
-    prompts = _prompts(cfg, (7, 12), seed=2)
-    eng.reset_gauges()
-
-    def body():
-        for p in prompts:
-            eng.add_request(p, 6)
-        return _pump(eng)
-
-    turns = _traced(tmp_path, body)
-    evs = _host_events(str(tmp_path), ("serving/",))
-    steps = [e for e in evs if e[0] == "serving/step"]
-    assert len(steps) == turns
-    names = {e[0] for e in evs}
-    assert {"serving/admit", "serving/dispatch", "serving/harvest",
-            "serving/harvest.fetch", "serving/drain"} <= names
-    for step in steps:
-        kids = [e for e in _children(evs, step, ("serving/",))
-                if "." not in e[0]]
-        _assert_disjoint(kids)
-    assert eng.gauges()["prefill_tokens"] == sum(len(p) for p in prompts)
-
-
 def test_serving_step_donates_nothing():
     """The serving step programs read the model's weights and write no
     state (the KV pools are call ARGUMENTS), so to_static's donation

@@ -17,7 +17,7 @@ Contracts pinned here:
   oracle within explicit top-1 agreement bars on a fixed-seed model;
 - int8-KV composes with everything that moves pages: prefix-cache
   warm attach, priority preemption + recompute replay, spec decode,
-  the legacy (unified=False) engine, and disagg migration (native
+  and disagg migration (native
   quantized wire blocks, crc over codes+scales, mixed-quant pairs
   reject into the tokens-only replay) — with the page audit (which
   covers the scales pools) on for every engine;
@@ -71,7 +71,7 @@ def _engine(**kw):
     kw.setdefault("audit", True)
     return ContinuousBatchingEngine(
         m, num_slots=kw.pop("num_slots", 2), page_size=8, max_len=48,
-        decode_chunk=4, prompt_buckets=(16,), greedy=True, **kw)
+        decode_chunk=4, prefill_chunk=16, greedy=True, **kw)
 
 
 def _prompts(n, seed=7, lo=5, hi=14):
@@ -248,7 +248,7 @@ def test_accuracy_gate_weight_only_int8():
     wm.eval()
     eng = ContinuousBatchingEngine(  # ctor runs quantize_for_serving
         wm, num_slots=2, page_size=8, max_len=48, decode_chunk=4,
-        prompt_buckets=(16,), greedy=True, audit=True)
+        prefill_chunk=16, greedy=True, audit=True)
     assert isinstance(wm.lm_head, WeightOnlyLinear)
     quant = _streams(eng, prompts)
     assert _agreement(oracle, quant) >= 0.85
@@ -305,14 +305,6 @@ def test_spec_decode_composes_with_int8_kv():
     spec = _streams(_engine(kv_quant="int8", num_slots=2, spec_k=4,
                             spec_draft="ngram"), prompts, n_new=8)
     assert spec == plain
-
-
-def test_legacy_engine_composes_with_int8_kv():
-    prompts = _prompts(4)
-    uni = _streams(_engine(kv_quant="int8", num_slots=2), prompts)
-    leg = _streams(_engine(kv_quant="int8", num_slots=2,
-                           unified=False), prompts)
-    assert leg == uni
 
 
 def test_disagg_migration_ships_quantized_pages():

@@ -150,8 +150,8 @@ def test_c1_reduces_to_decode_oracle():
 
 def test_full_lengths_reduce_to_prefill_oracle():
     """lengths == C makes the ragged oracle exactly the chunked-prefill
-    oracle — the legacy engine's whole-chunk path is a special case of
-    the unified entry point."""
+    oracle — a whole chunk is a special case of the ragged entry
+    point."""
     rng = np.random.RandomState(6)
     B, H, KVH, D, page, P = 2, 4, 2, 8, 4, 6
     kp, vp, tables = _pool_case(rng, B, KVH, D, page, P, B * P + 2)

@@ -41,7 +41,7 @@ def test_paged_pool_matches_dense_generate():
 
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(16,), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     eng.add_request(prompt, n_new)
     done = eng.run()
     assert len(done) == 1
@@ -63,7 +63,7 @@ def test_mixed_length_streams_more_requests_than_slots():
 
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8, 16, 32), greedy=True)
+                                   prefill_chunk=32, greedy=True)
     ids = [eng.add_request(pr, n) for pr, (_, n) in zip(prompts, specs)]
     free_before = len(eng._free_pages)
     done = eng.run()
@@ -96,7 +96,7 @@ def test_eos_stops_stream_early():
     # engine-level eos unset: the PER-REQUEST eos alone must stop decode
     eng = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8,), greedy=True)
+                                   prefill_chunk=8, greedy=True)
     eng.add_request(prompt, 12, eos_token_id=eos)
     (req,) = eng.run()
     assert req.finish_reason == "eos"
@@ -114,7 +114,7 @@ def test_oversized_prompt_uses_exact_bucket():
     ref = _ref_greedy(model, prompt, 5)
     eng = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8, 16), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     eng.add_request(prompt, 5)
     (req,) = eng.run()
     assert req.tokens == ref, (req.tokens, ref)
@@ -128,7 +128,7 @@ def test_impossible_request_rejected():
     model, cfg = _model()
     eng = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                    num_pages=3, max_len=64,
-                                   prompt_buckets=(8,), greedy=True)
+                                   prefill_chunk=8, greedy=True)
     with _pytest.raises(ValueError, match="pages"):
         eng.add_request(np.zeros((20,), np.int32), 10)
 
@@ -144,7 +144,7 @@ def test_sampling_mode_deterministic_with_seed():
     def run(seed):
         eng = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                        max_len=64, decode_chunk=4,
-                                       prompt_buckets=(8,), greedy=False,
+                                       prefill_chunk=8, greedy=False,
                                        temperature=0.9, seed=seed)
         eng.add_request(prompt, 6)
         (req,) = eng.run()
@@ -178,7 +178,7 @@ def test_qwen2_moe_through_engine():
     ref = np.asarray(ref_out.numpy())[0].tolist()
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=48, decode_chunk=4,
-                                   prompt_buckets=(16,), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     eng.add_request(prompt, 6)
     (req,) = eng.run()
     assert req.tokens == ref, (req.tokens, ref)
@@ -202,7 +202,7 @@ def test_gpt2_through_engine():
     ref = np.asarray(ref_out.numpy())[0].tolist()
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=48, decode_chunk=4,
-                                   prompt_buckets=(16,), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     eng.add_request(prompt, 8)
     (req,) = eng.run()
     assert req.tokens == ref, (req.tokens, ref)
@@ -219,7 +219,7 @@ def test_one_shot_admitted_mid_stream():
     model, cfg = _model()
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=48, decode_chunk=4,
-                                   prompt_buckets=(8, 16), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     rng = np.random.RandomState(3)
     long_p = rng.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
     mid_p = rng.randint(0, cfg.vocab_size, (7,)).astype(np.int32)
@@ -274,7 +274,7 @@ def test_latency_gauges_schema():
     model, cfg = _model()
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8, 16), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     rng = np.random.RandomState(9)
     for plen, n in [(5, 6), (12, 4), (9, 8)]:
         eng.add_request(rng.randint(0, cfg.vocab_size,
@@ -304,32 +304,6 @@ def test_latency_gauges_schema():
 
 
 @pytest.mark.slow
-def test_adaptive_chunk_no_wasted_drain_dispatch():
-    """Adaptive decode chunks clamp to the min remaining budget across
-    active slots: an eos-free workload must finish with ZERO empty
-    chunk dispatches (the round-4 'one wasted chunk program per drain
-    wave' cost) and zero overshoot slot-steps for active slots."""
-    model, cfg = _model()
-    eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
-                                   max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8, 16), greedy=True,
-                                   adaptive_chunk=True, unified=False)
-    rng = np.random.RandomState(10)
-    specs = [(5, 7), (9, 3), (12, 6), (4, 5)]
-    for plen, n in specs:
-        eng.add_request(rng.randint(0, cfg.vocab_size,
-                                    (plen,)).astype(np.int32), n)
-    done = eng.run()
-    assert sum(len(r.tokens) for r in done) == sum(n for _, n in specs)
-    g = eng.gauges()
-    assert g["chunks_empty"] == 0, g
-    # active slots never overstep their budget inside a chunk, so every
-    # ACTIVE slot-step emits a token
-    assert g["tokens_emitted"] == eng._stats["active_slot_steps"] \
-        + len(specs)  # + the prefill first tokens (not slot-steps)
-
-
-@pytest.mark.slow
 def test_stall_detection_still_fires():
     """The page-pool-exhaustion stall guard survives ISSUE 10 as the
     true-deadlock diagnostic: a request that can never be admitted
@@ -339,7 +313,7 @@ def test_stall_detection_still_fires():
     model, cfg = _model()
     eng = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8,), greedy=True,
+                                   prefill_chunk=8, greedy=True,
                                    audit=False)
     eng.add_request(np.arange(5, dtype=np.int32), 4)
     eng._free_pages.clear()       # simulate a leaked/fragmented pool
@@ -350,7 +324,7 @@ def test_stall_detection_still_fires():
     # the stall path
     eng2 = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                     max_len=64, decode_chunk=4,
-                                    prompt_buckets=(8,), greedy=True,
+                                    prefill_chunk=8, greedy=True,
                                     audit=True)
     eng2.add_request(np.arange(5, dtype=np.int32), 4)
     eng2._free_pages.clear()
@@ -360,12 +334,8 @@ def test_stall_detection_still_fires():
 
 def test_compile_budget_mixed_length_workload():
     """Fast-tier CI gate (ISSUE 7 satellite): a mixed-length workload
-    through the unified engine must compile EXACTLY ONE program — the
-    unified batching-step signature — strictly below the PR-3
-    per-family baseline (1 batched prefill + the power-of-two
-    decode-chunk ladder: 1 + log2(4) + 1 = 4 programs for this
-    workload) and the older per-bucket baseline (5). Any second
-    signature fails this gate."""
+    through the engine must compile EXACTLY ONE program — the unified
+    batching-step signature. Any second signature fails this gate."""
     cfg = LlamaConfig.tiny()
     cfg.tensor_parallel = False
     cfg.scan_layers = False
@@ -375,7 +345,7 @@ def test_compile_budget_mixed_length_workload():
     model.eval()
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=64, decode_chunk=4,
-                                   prompt_buckets=(8, 16), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     rng = np.random.RandomState(11)
     # five DISTINCT prompt lengths, two past every bucket — the shapes
     # that exploded the per-bucket signature zoo
@@ -411,7 +381,7 @@ def test_one_token_and_instant_eos_requests():
     model, cfg = _model()
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                    max_len=48, decode_chunk=4,
-                                   prompt_buckets=(8, 16), greedy=True)
+                                   prefill_chunk=16, greedy=True)
     rng = np.random.RandomState(0)
     p1 = rng.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
     r1 = eng.add_request(p1, 1)                 # one-token request
@@ -420,7 +390,7 @@ def test_one_token_and_instant_eos_requests():
     p2 = rng.randint(0, cfg.vocab_size, (7,)).astype(np.int32)
     probe = ContinuousBatchingEngine(model, num_slots=1, page_size=8,
                                      max_len=48, decode_chunk=4,
-                                     prompt_buckets=(8, 16), greedy=True)
+                                     prefill_chunk=16, greedy=True)
     probe.add_request(p2, 2)
     first_tok = probe.run()[0].tokens[0]
     r2 = eng.add_request(p2, 5, eos_token_id=int(first_tok))

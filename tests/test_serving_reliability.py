@@ -56,7 +56,7 @@ def _build(**kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("max_len", 48)
     kw.setdefault("decode_chunk", 4)
-    kw.setdefault("prompt_buckets", (8, 16))
+    kw.setdefault("prefill_chunk", 16)
     kw.setdefault("greedy", True)
     return ContinuousBatchingEngine(m, **kw)
 
@@ -170,8 +170,7 @@ def test_cancel_mid_prefill():
     """Cancelling while the prompt is still streaming through prefill
     chunks reclaims the pages before a single token exists."""
     (pLong,) = _prompts(17, (30,))
-    eng = _build(max_len=64, prefill_chunk=8,
-                 prompt_buckets=(8,))
+    eng = _build(max_len=64, prefill_chunk=8)
     rid = eng.add_request(pLong, 8)
     eng.step()                            # first prefill chunk only
     req = eng.request(rid)
@@ -182,25 +181,6 @@ def test_cancel_mid_prefill():
     assert req.finished
     assert isinstance(req.error, RequestCancelled)
     assert req.tokens == []
-    _assert_balanced(eng)
-
-
-def test_cancel_mid_decode_legacy_engine():
-    """The legacy wave/chunk engine shares the lifecycle machinery:
-    cancel mid-decode must reclaim pages there too (echo/pending-first
-    bookkeeping included)."""
-    pA, pB = _prompts(19, (6, 7))
-    refB = _ref(pB, 4)
-    eng = _build(unified=False)
-    c1 = eng.add_request(pA, 25)
-    c2 = eng.add_request(pB, 4)
-    while not eng.request(c1).tokens:
-        eng.step()
-    eng.cancel(c1)
-    eng.run()
-    by = {r.request_id: r for r in eng.completed}
-    assert isinstance(by[c1].error, RequestCancelled)
-    assert by[c2].tokens == refB
     _assert_balanced(eng)
 
 
@@ -393,7 +373,7 @@ def test_page_leak_fails_audit_loudly():
     sup = EngineSupervisor(
         lambda: ContinuousBatchingEngine(
             m, num_slots=2, page_size=8, max_len=48, decode_chunk=4,
-            prompt_buckets=(8, 16), greedy=True), max_restarts=3)
+            prefill_chunk=16, greedy=True), max_restarts=3)
     sup.add_request(pA, 4)
     with FaultInjector() as fi:
         fi.leak_pages(n=1)

@@ -67,7 +67,7 @@ def _build(**kw):
     kw.setdefault("page_size", 8)
     kw.setdefault("max_len", 48)
     kw.setdefault("decode_chunk", 4)
-    kw.setdefault("prompt_buckets", (8, 16))
+    kw.setdefault("prefill_chunk", 16)
     kw.setdefault("greedy", True)
     return ContinuousBatchingEngine(m, **kw)
 
@@ -205,11 +205,6 @@ def test_get_draft_source_resolution():
     assert get_draft_source(src) is src
     with pytest.raises(ValueError):
         get_draft_source("medusa")
-
-
-def test_spec_requires_unified_engine():
-    with pytest.raises(ValueError):
-        _build(unified=False, spec_decode=True)
 
 
 def test_ctor_resolves_knobs_through_tuner_surface():

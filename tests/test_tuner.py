@@ -574,15 +574,14 @@ def test_serving_engine_consults_chunk_cache(gcache):
                {"decode_chunk": 8, "prefill_chunk": 16,
                 "admit_batch": 1})
     eng = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
-                                   max_len=48, prompt_buckets=(8, 16),
-                                   greedy=True)
+                                   max_len=48, greedy=True)
     assert eng.decode_chunk == 8            # cache served the ladder
     assert eng.prefill_chunk == 16
     assert eng.admit_batch == 1
     # explicit argument beats the cache
     eng2 = ContinuousBatchingEngine(model, num_slots=2, page_size=8,
                                     max_len=48, decode_chunk=4,
-                                    prompt_buckets=(8, 16), greedy=True)
+                                    greedy=True)
     assert eng2.decode_chunk == 4
     # and the tuned engine actually serves
     rng = np.random.RandomState(0)

@@ -151,11 +151,11 @@ def gate_commands(log: str, budget: float, no_budget: bool,
               "-q", "-m", "disagg",
               "-p", "no:cacheprovider"]))
     if not no_serving:
-        # serving parity: the unified ragged batching-step engine must
-        # reproduce the legacy prefill-wave/decode-chunk engine's token
-        # streams exactly AND hold the 1-compiled-program budget
-        # (1-layer tiny model on CPU — fast, inside the tier-1 budget
-        # tripwire)
+        # serving parity: the engine's greedy token streams must equal
+        # dense model.generate's exactly — every served family with a
+        # tiny preset x both pumps (run(), step()), eos stops included
+        # — AND hold the 1-compiled-program budget (tiny models on CPU
+        # — fast, inside the tier-1 budget tripwire)
         gates.append(
             ("serving_parity",
              [sys.executable, "-m", "pytest",
@@ -201,7 +201,7 @@ def gate_commands(log: str, budget: float, no_budget: bool,
         # and kernel parity, the greedy accuracy gate vs the full-
         # precision oracle on fixed-seed weights, composition with
         # everything that moves pages (prefix cache, preemption
-        # replay, spec decode, legacy engine, disagg migration +
+        # replay, spec decode, disagg migration +
         # mixed-quant reject), and the weight-only int8/int4 layers.
         # The FULL quant_serving marker; rides --no-serving with the
         # rest of the serving stack.
@@ -289,8 +289,8 @@ def main(argv=None) -> int:
                          "fleet/prefix-cache storms, process-worker "
                          "SIGKILL/SIGSTOP)")
     ap.add_argument("--no-serving", action="store_true",
-                    help="skip the unified-vs-legacy serving parity "
-                         "gate (compiles two tiny engines)")
+                    help="skip the engine-vs-dense-generate serving "
+                         "parity gate (compiles tiny engines)")
     ap.add_argument("--no-fused", action="store_true",
                     help="skip the fused training-kernel parity gate "
                          "(interpret-mode kernel suite, fused flags "
