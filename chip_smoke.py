@@ -192,12 +192,14 @@ def _ragged_case(rng, b, c, h, kvh, d, page, pps, quant):
     plan = (plan * (b // len(plan) + 1))[:b]
     ctx = jnp.asarray([p[0] for p in plan], jnp.int32)
     lens = jnp.asarray([p[1] for p in plan], jnp.int32)
-    shape = (kvh, n_pages, page, d)
+    from paddle_tpu.ops.paged_attention import kv_pool_shape, kv_scales_shape
+    shape = kv_pool_shape(kvh, n_pages, page, d)
     if quant:
+        sshape = kv_scales_shape(kvh, n_pages, page)
         kp = jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
         vp = jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
-        ks = jnp.asarray(rng.uniform(0.002, 0.02, shape[:3]), jnp.float32)
-        vs = jnp.asarray(rng.uniform(0.002, 0.02, shape[:3]), jnp.float32)
+        ks = jnp.asarray(rng.uniform(0.002, 0.02, sshape), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.002, 0.02, sshape), jnp.float32)
         return (q, kp, vp, tables, ctx, lens), {"k_scales": ks,
                                                 "v_scales": vs}
     kp = jnp.asarray(rng.randn(*shape), jnp.bfloat16)

@@ -205,15 +205,10 @@ define_flag("FLAGS_recompute_policy", "dots_saveable",
 define_flag("FLAGS_flash_attn_pallas_bwd", True,
             "Flash-attn backward via the hand-written Pallas dkv/dq "
             "kernels (False = blockwise lax.scan recompute fallback).")
-define_flag("FLAGS_use_pallas_paged_attention", 1,
-            "ops.paged_attention.paged_attention (the standalone "
-            "decode-step op + incubate API): use the jax Pallas "
-            "decode kernel on TPU (0 = jnp gather/softmax reference). "
-            "The serving engine's decode path no longer rides this op "
-            "— it goes through the unified ragged entry point, gated "
-            "by FLAGS_use_pallas_ragged_attention.")
 define_flag("FLAGS_use_pallas_ragged_attention", 1,
-            "Serving batching step: use the Pallas ragged "
+            "Every paged-attention entry point (the serving batching "
+            "step, and ops.paged_attention.paged_attention = the same "
+            "call at one query token): use the Pallas ragged "
             "paged-attention kernel (mixed prefill+decode, ONE "
             "program) on TPU (0 = jnp gather/softmax reference path).")
 # These are a tunable surface ("ragged_paged_attention",

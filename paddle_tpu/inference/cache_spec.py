@@ -16,9 +16,11 @@ __all__ = ["PagedKV", "SlotState", "StepCounters", "uniform_kv_spec",
 
 @dataclass(frozen=True)
 class PagedKV:
-    """One attention layer's paged K/V: two pools ``(kv_heads, num_pages,
-    page_size, head_dim)``, and under quantized KV two float32 scales pools
-    ``(kv_heads, num_pages, page_size)`` after them."""
+    """One attention layer's paged K/V: two pools ``(num_pages, page_size,
+    kv_heads * head_dim)`` — page axis first, a token's heads side by side
+    (``ops.paged_attention.kv_pool_shape``, the one layout the write and
+    the attention kernel share) — and under quantized KV two float32
+    scales pools ``(num_pages, kv_heads, page_size)`` after them."""
     kv_heads: int
     head_dim: int
 

@@ -22,8 +22,8 @@ pass that verifies host-proposed drafts (:meth:`_unified_spec_static`).
 
 Structure:
 
-- The KV cache is a global PAGE POOL per layer ([KVH, num_pages,
-  page_size, D]); each admitted request owns a page list (its block
+- The KV cache is a global PAGE POOL per layer ([num_pages,
+  page_size, KVH * D]); each admitted request owns a page list (its block
   table row). Page 0 is a reserved trash page for drained slots.
 - PREFIX CACHE (ISSUE 12, default on): completed prefills publish
   their full prompt pages into a radix index keyed by token blocks at
@@ -348,14 +348,14 @@ class _PrefixCacheNode:
 #: 2 x num_layers sequential dispatches on the TTFT-critical
 #: admission path.
 _pc_copy_page = jax.jit(lambda pools, src, dst:
-                        [p.at[:, dst].set(p[:, src]) for p in pools])
+                        [p.at[dst].set(p[src]) for p in pools])
 
 
 #: KV-page import (ISSUE 17): write ALL of a migrated request's
 #: accepted pages into every layer's k/v pool in ONE compiled
 #: dispatch. ``dst`` is an int32 vector of page indices and each
 #: pool's ``data`` stacks the matching page contents along the page
-#: axis ([kv_heads, n, page_size, head_dim]) — per-page dispatches put
+#: axis ([n, page_size, kv_heads * head_dim]) — per-page dispatches put
 #: ~2 x num_layers x pages_per_request sequential launches on the
 #: migration pump, the pump's dominant cost. The page count per
 #: request is bounded by max_len/page_size, so the compile set stays
@@ -363,8 +363,15 @@ _pc_copy_page = jax.jit(lambda pools, src, dst:
 #: in-flight program in the device stream exactly like the COW fork
 #: above — an import never races a dispatched step.
 _kv_write_pages = jax.jit(lambda pools, dst, data:
-                          [p.at[:, dst].set(d)
+                          [p.at[dst].set(d)
                            for p, d in zip(pools, data)])
+
+
+#: version of the migration payload (``_migrate_out``). 2: a page's
+#: exported array is the pool's own ``[page_size, kv_heads * head_dim]``
+#: (scales ``[kv_heads, page_size]``); version 1 shipped
+#: ``[kv_heads, page_size, head_dim]`` and is refused at import.
+KV_PAYLOAD_VERSION = 2
 
 
 #: the priority band EXTERNAL requests are clamped into by the HTTP
@@ -560,14 +567,16 @@ class ContinuousBatchingEngine:
         # pools from scratch (_reset_device_state). Per PagedKV entry:
         # (key_pages, value_pages), and under quantized KV (ISSUE 20)
         # two extra pools after them — the page-parallel f32 scales
-        # pools (key_scales, value_scales), shape (kvh, num_pages,
-        # page_size): one scale per (token, kv head), page axis at
-        # index 1 like the data pools, so every page operation (COW page
+        # pools (key_scales, value_scales), shape (num_pages, kvh,
+        # page_size): one scale per (token, kv head), page axis at index
+        # 0 like the data pools (ops.paged_attention.kv_pool_shape and
+        # kv_scales_shape state both), so every page operation (COW page
         # copy, migration export/crc, batched import landing pads)
         # composes over the paged pools unchanged. Per SlotState entry:
         # one (num_slots, ...) array the MODEL keeps right in-program.
         # A StepCounters entry: one int32 vector the step program zeroes
         # and reports in its packed fetch.
+        from ..ops.paged_attention import kv_pool_shape, kv_scales_shape
         from .cache_spec import PagedKV, SlotState, StepCounters, spec_of
         self._pool_dtype = dtype if kv_quant == "none" else jnp.dtype(
             jnp.int8 if kv_quant == "int8" else jnp.float8_e4m3fn)
@@ -575,13 +584,13 @@ class ContinuousBatchingEngine:
         self._counter_names, self._counter_pool = (), None
         for ent in spec_of(model):
             if isinstance(ent, PagedKV):
-                shape = (ent.kv_heads, self.num_pages, self.page_size,
-                         ent.head_dim)
-                self._pool_shapes += [shape] * 2
+                geom = (ent.kv_heads, self.num_pages, self.page_size)
+                self._pool_shapes += [kv_pool_shape(*geom,
+                                                    ent.head_dim)] * 2
                 self._pool_dtypes += [self._pool_dtype] * 2
                 self._pool_kinds += ["kv"] * 2
                 if kv_quant != "none":
-                    self._pool_shapes += [shape[:3]] * 2
+                    self._pool_shapes += [kv_scales_shape(*geom)] * 2
                     self._pool_dtypes += [jnp.float32] * 2
                     self._pool_kinds += ["scale"] * 2
             elif isinstance(ent, SlotState):
@@ -611,7 +620,7 @@ class ContinuousBatchingEngine:
             else:
                 raise TypeError(f"unknown cache spec entry {ent!r}")
         self._n_pools = len(self._pool_shapes)
-        #: pools with a page axis (index 1): what COW, export and import
+        #: pools with a page axis (index 0): what COW, export and import
         #: walk
         self._paged = [i for i, k in enumerate(self._pool_kinds)
                        if k in ("kv", "scale")]
@@ -1038,7 +1047,7 @@ class ContinuousBatchingEngine:
             # inactive in every dispatched program (its writes are
             # trash-page-guarded), so the fetched content is the final
             # prefill output even under the pipelined driver
-            data = [np.asarray(a[:, page]) for a in self._paged_arrays()]
+            data = [np.asarray(a[page]) for a in self._paged_arrays()]
             blocks.append({
                 "tokens": np.asarray(
                     eff[lvl * ps:(lvl + 1) * ps], np.int32),
@@ -1046,7 +1055,8 @@ class ContinuousBatchingEngine:
                 "crc": [zlib.crc32(np.ascontiguousarray(d).tobytes())
                         for d in data],
             })
-        payload = {"version": 1, "rid": int(req.request_id),
+        payload = {"version": KV_PAYLOAD_VERSION,
+                   "rid": int(req.request_id),
                    "eff_len": int(len(eff)), "page_size": ps,
                    "n_pools": len(self._paged),
                    "dtype": str(self._pool_dtype),
@@ -1104,7 +1114,9 @@ class ContinuousBatchingEngine:
         prefix-cache hit. Idempotent: blocks already resident dedup;
         ANY malformed/damaged block stops seeding (the chain must stay
         root-contiguous) and the request still replays correctly from
-        whatever prefix landed. Returns import counts."""
+        whatever prefix landed. A payload of another version than
+        :data:`KV_PAYLOAD_VERSION` lands nothing and the result says so
+        under ``"refused"``. Returns import counts."""
         if self._has_state:
             raise ValueError(
                 "import_migration lands shipped prompt pages; this model "
@@ -1112,8 +1124,13 @@ class ContinuousBatchingEngine:
                 "no migration carries yet (requeue() replays the tokens)")
         imported = dedup = rejected = 0
         pending = []          # (page, [per-pool np page content])
+        refused = None
+        if (isinstance(payload, dict)
+                and payload.get("version") != KV_PAYLOAD_VERSION):
+            refused = (f"kv payload version {payload.get('version')!r}: "
+                       f"this engine reads version {KV_PAYLOAD_VERSION}")
         ok = (self._prefix_cache and isinstance(payload, dict)
-              and payload.get("version") == 1
+              and refused is None
               and payload.get("page_size") == self.page_size
               and payload.get("n_pools") == len(self._paged)
               and payload.get("dtype") == str(self._pool_dtype)
@@ -1173,7 +1190,7 @@ class ContinuousBatchingEngine:
             padded = pending + [pending[-1]] * (width - len(pending))
             dst = jnp.asarray([p for p, _ in padded], jnp.int32)
             stacked = [jnp.asarray(
-                np.stack([d[i] for _, d in padded], axis=1),
+                np.stack([d[i] for _, d in padded], axis=0),
                 self._pool_dtypes[pi]) for i, pi in enumerate(self._paged)]
             self._set_paged(_kv_write_pages(self._paged_arrays(), dst,
                                             stacked))
@@ -1184,18 +1201,18 @@ class ContinuousBatchingEngine:
             self._c_kv_dedup.inc(dedup)
         if rejected:
             self._c_kv_rejects.inc(rejected)
-        _frec.record_event("migrate_in", req=req.request_id,
-                           imported=imported, dedup=dedup,
-                           rejected=rejected)
+        counts = {"imported": imported, "dedup": dedup,
+                  "rejected": rejected}
+        if refused is not None:
+            counts["refused"] = refused
+        _frec.record_event("migrate_in", req=req.request_id, **counts)
         self._obs_s += time.perf_counter() - _t_obs
         record_hop(req, "migrate_in",
                    replica=getattr(self, "_fleet_replica_id", None),
-                   imported=imported, dedup=dedup,
-                   rejected=rejected)
+                   **counts)
         self.requeue(req)
         self._audit_pages("kv_import")
-        return {"imported": imported, "dedup": dedup,
-                "rejected": rejected}
+        return counts
 
     def step(self):
         """Admit what fits, advance every slot one scheduler turn (one

@@ -7,16 +7,30 @@ UNVERIFIED — reference mount empty). The KV cache is stored as fixed-size
 table), so cache memory is allocated per-page instead of per-max-length —
 the vLLM/TPU-serving design (see PAPERS.md ragged-paged-attention).
 
-TPU-native: the fast path is the Pallas TPU paged-attention kernel that
-ships with jax (``jax.experimental.pallas.ops.tpu.paged_attention``, a
-scalar-prefetch kernel that streams only the pages named in the block
-table through VMEM). The reference path below is pure jnp (gather +
-masked softmax) — the numeric oracle and the CPU/debug fallback.
+TPU-native: the fast path is the ragged paged-attention Pallas kernel
+(``ops/pallas/ragged_paged_attention.py``, a scalar-prefetch kernel that
+streams only the pages named in the block table through VMEM). The
+reference path below is pure jnp (gather + masked softmax) — the numeric
+oracle and the CPU/debug fallback.
 
-Layouts (decode step, one query token per sequence):
+THE pool layout — one, for every function here, the kernel and the
+serving engine (:func:`kv_pool_shape`):
+
+  key_pages    [num_pages, page_size, KVH * D]
+  value_pages  [num_pages, page_size, KVH * D]
+  k/v scales   [num_pages, KVH, page_size] f32 (quantized pools only,
+               :func:`kv_scales_shape`: one scale per token and head)
+
+Page axis first, a token's heads side by side in the minor dimension
+(head ``h`` is columns ``h * D .. (h + 1) * D``). A token's k/v is ONE
+row, so the write is a row scatter at ``(page, offset)`` with no
+transpose, and a page's ``[page_size, D]`` tile of one kv head is a
+lane-aligned slice the kernel DMAs as it lies: XLA's scatter and the
+Mosaic call agree on the array's default tiling, and a compiled step
+holds no pool-sized re-layout (``tests/test_chip_compile.py`` counts).
+
+Other operands (decode step, one query token per sequence):
   q            [B, H, D]
-  key_pages    [KVH, num_pages, page_size, D]
-  value_pages  [KVH, num_pages, page_size, D]
   block_tables [B, pages_per_seq] int32 — page ids, row-padded with any
                valid id past the sequence's last page
   context_lens [B] int32 — tokens currently in cache per sequence
@@ -30,15 +44,37 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["paged_attention", "paged_attention_reference",
+__all__ = ["kv_pool_shape", "kv_scales_shape",
+           "paged_attention", "paged_attention_reference",
            "paged_prefill_attention", "paged_prefill_attention_reference",
            "ragged_paged_attention", "ragged_paged_attention_reference",
-           "paged_decode_write", "paged_prefill_write",
+           "paged_prefill_write",
            "paged_verify_write", "kv_quant_range", "quantize_kv",
            "dequantize_pages", "paged_prefill_write_quant",
            "paged_verify_write_quant"]
 
 _NEG_INF = -1e30
+
+
+def kv_pool_shape(kv_heads, num_pages, page_size, head_dim):
+    """The shape of one paged K or V pool (module docstring)."""
+    return (int(num_pages), int(page_size), int(kv_heads) * int(head_dim))
+
+
+def kv_scales_shape(kv_heads, num_pages, page_size):
+    """The shape of a quantized pool's f32 scales pool: page axis first
+    like the data, a page's offsets in the minor dimension — the order
+    the kernel's lane-dense ``[.., kv block keys]`` scale rows are
+    gathered in, so the scales too are written and read in one layout."""
+    return (int(num_pages), int(kv_heads), int(page_size))
+
+
+def _gather_heads(pages, table, kvh):
+    """One sequence's pages, head-major: pool [P, page, KVH * D] x table
+    [pages_per_seq] -> [KVH, pages_per_seq * page, D]."""
+    _, page_size, width = pages.shape
+    x = pages[table].reshape(table.shape[0] * page_size, kvh, width // kvh)
+    return jnp.swapaxes(x, 0, 1)
 
 
 def kv_quant_range(dtype):
@@ -73,24 +109,27 @@ def quantize_kv(x, dtype):
 
 
 def dequantize_pages(pages, scales):
-    """Quantized pool -> f32: pages [KVH, P, page, D] x scales
-    [KVH, P, page] (the page-parallel scales pool) -> f32 pages."""
-    return pages.astype(jnp.float32) * scales.astype(jnp.float32)[..., None]
+    """Quantized pool -> f32: pages [P, page, KVH * D] x scales
+    [P, KVH, page] (the page-parallel scales pool) -> f32 pages."""
+    p, kvh, page_size = scales.shape
+    x = pages.astype(jnp.float32).reshape(p, page_size, kvh, -1)
+    sc = jnp.swapaxes(scales.astype(jnp.float32), 1, 2)[..., None]
+    return (x * sc).reshape(pages.shape)
 
 
 def paged_attention_reference(q, key_pages, value_pages, block_tables,
                               context_lens, scale=None):
     """Pure-jnp oracle: gather each sequence's pages, mask, soft-max."""
     b, h, d = q.shape
-    kvh, _, page_size, _ = key_pages.shape
+    _, page_size, width = key_pages.shape
+    kvh = width // d
     rep = h // kvh
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     max_len = block_tables.shape[1] * page_size
 
     def one_seq(qi, table, ctx_len):
-        # [KVH, pages_per_seq, page, D] -> [KVH, max_len, D]
-        k = key_pages[:, table].reshape(kvh, max_len, d)
-        v = value_pages[:, table].reshape(kvh, max_len, d)
+        k = _gather_heads(key_pages, table, kvh)    # [KVH, max_len, D]
+        v = _gather_heads(value_pages, table, kvh)
         k = jnp.repeat(k, rep, axis=0)  # [H, max_len, D]
         v = jnp.repeat(v, rep, axis=0)
         logits = jnp.einsum("hd,hkd->hk", qi, k,
@@ -105,37 +144,16 @@ def paged_attention_reference(q, key_pages, value_pages, block_tables,
 
 def paged_attention(q, key_pages, value_pages, block_tables, context_lens,
                     scale=None):
-    """Decode-step paged attention; Pallas kernel on TPU, jnp oracle
-    elsewhere (flag ``FLAGS_use_pallas_paged_attention`` forces the
-    reference path on TPU too). The path is a rule on platform + flag:
-    a kernel that fails on TPU raises, it is never swapped for the
-    oracle."""
-    from ..framework import flags
-    platform = jax.devices()[0].platform
-    use_kernel = (platform == "tpu"
-                  and bool(int(flags.flag(
-                      "FLAGS_use_pallas_paged_attention"))))
-    if use_kernel:
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention as _kernel)
-        s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-        pages_per_seq = block_tables.shape[1]
-        ppcb = next(c for c in (8, 4, 2, 1) if pages_per_seq % c == 0)
-        # the kernel applies no softmax scale — fold it into q; it
-        # also indexes with int32 internally, so int64 tables/lens
-        # (the paddle default int dtype) must be cast AND the trace
-        # must run with x64 promotion off (kernel-internal python
-        # ints otherwise promote to i64 and its lax.div mixes
-        # dtypes) — same contract as the other pallas kernels
-        from .pallas._utils import no_x64
-        with no_x64():
-            return _kernel(q * jnp.asarray(s, q.dtype), key_pages,
-                           value_pages,
-                           context_lens.astype(jnp.int32),
-                           block_tables.astype(jnp.int32),
-                           pages_per_compute_block=ppcb)
-    return paged_attention_reference(q, key_pages, value_pages,
-                                     block_tables, context_lens, scale)
+    """Decode-step paged attention: one query token per sequence over
+    ``context_lens`` cached tokens (the new token's k/v included). It is
+    :func:`ragged_paged_attention` at ``lengths == 1`` — the one kernel
+    on TPU, the jnp oracle elsewhere — and equals
+    :func:`paged_attention_reference`."""
+    ctx = context_lens.astype(jnp.int32) - 1
+    out = ragged_paged_attention(q[:, None], key_pages, value_pages,
+                                 block_tables, ctx, jnp.ones_like(ctx),
+                                 scale)
+    return out[:, 0]
 
 
 def paged_prefill_attention_reference(q, key_pages, value_pages,
@@ -156,14 +174,15 @@ def paged_prefill_attention_reference(q, key_pages, value_pages,
     decode oracle uses, so chunked and whole-prompt prefill reduce in
     the same order — the basis of the token-parity guarantee.
 
-    ``k_scales``/``v_scales`` [KVH, num_pages, page_size] f32 mark the
+    ``k_scales``/``v_scales`` [num_pages, KVH, page_size] f32 mark the
     pools as quantized (int8/fp8): pages are dequantized to f32 right
     after the gather — the same block-table indirection, so trash-page
     routing and page sharing compose unchanged — and the output is cast
     back to q's dtype.
     """
     b, c, h, d = q.shape
-    kvh, _, page_size, _ = key_pages.shape
+    _, page_size, width = key_pages.shape
+    kvh = width // d
     quantized = k_scales is not None
     if quantized:
         key_pages = dequantize_pages(key_pages, k_scales)
@@ -173,9 +192,8 @@ def paged_prefill_attention_reference(q, key_pages, value_pages,
     max_len = block_tables.shape[1] * page_size
 
     def one_seq(qi, table, ctx_len):
-        # [KVH, pages_per_seq, page, D] -> [KVH, max_len, D]
-        k = key_pages[:, table].reshape(kvh, max_len, d)
-        v = value_pages[:, table].reshape(kvh, max_len, d)
+        k = _gather_heads(key_pages, table, kvh)    # [KVH, max_len, D]
+        v = _gather_heads(value_pages, table, kvh)
         k = jnp.repeat(k, rep, axis=0)  # [H, max_len, D]
         v = jnp.repeat(v, rep, axis=0)
         logits = jnp.einsum("chd,hkd->chk", qi, k,
@@ -256,23 +274,18 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
         scale, k_scales=k_scales, v_scales=v_scales)
 
 
-def paged_decode_write(kp, vp, k, v, block_tables, ctx, active=None):
-    """Write one decode step's k/v into the page pools.
-
-    k, v: [B, 1, KVH, D] (the step's projections, already rotated).
-    ctx: [B] int32 — current cache length per slot; the new token lands at
-    position ctx. Inactive slots (``active`` False) write to page 0 — the
-    engine reserves it as a trash page so a freed/reassigned real page is
-    never clobbered by a drained slot."""
-    page = kp.shape[2]
-    pid = jnp.take_along_axis(block_tables,
-                              (ctx // page)[:, None], axis=1)[:, 0]
-    if active is not None:
-        pid = jnp.where(active, pid, 0)
-    off = ctx % page
-    kp = kp.at[:, pid, off, :].set(jnp.swapaxes(k[:, 0], 0, 1))
-    vp = vp.at[:, pid, off, :].set(jnp.swapaxes(v[:, 0], 0, 1))
-    return kp, vp
+def _chunk_rows(pool, block_tables, ctx, valid, c):
+    """(page id, offset) [B, C] of a chunk's tokens: token j of sequence
+    b lands at global position ``ctx[b] + j`` of its block-table row;
+    tokens with ``j >= valid[b]`` go to the reserved trash page 0."""
+    page = pool.shape[1]
+    pos = ctx[:, None] + jnp.arange(c, dtype=ctx.dtype)[None, :]  # [B, C]
+    # padded positions can run past the table row — clamp the page index
+    # (the write is trash-routed anyway) so the gather stays in bounds
+    pidx = jnp.minimum(pos // page, block_tables.shape[1] - 1)
+    pid = jnp.take_along_axis(block_tables, pidx, axis=1)         # [B, C]
+    ok = jnp.arange(c)[None, :] < valid[:, None]
+    return jnp.where(ok, pid, 0), pos % page
 
 
 def paged_prefill_write(kp, vp, k, v, block_tables, ctx, valid):
@@ -280,21 +293,14 @@ def paged_prefill_write(kp, vp, k, v, block_tables, ctx, valid):
 
     k, v: [B, C, KVH, D] (the chunk's projections, already rotated).
     Token j of sequence b lands at global position ``ctx[b] + j`` in its
-    block-table row; tokens with ``j >= valid[b]`` (chunk padding, or a
-    slot not in this prefill wave) are routed to the reserved trash page
-    0 so a real page is never clobbered."""
-    c = k.shape[1]
-    page = kp.shape[2]
-    pos = ctx[:, None] + jnp.arange(c, dtype=ctx.dtype)[None, :]  # [B, C]
-    # padded positions can run past the table row — clamp the page index
-    # (the write is trash-routed anyway) so the gather stays in bounds
-    pidx = jnp.minimum(pos // page, block_tables.shape[1] - 1)
-    pid = jnp.take_along_axis(block_tables, pidx, axis=1)         # [B, C]
-    ok = jnp.arange(c)[None, :] < valid[:, None]
-    pid = jnp.where(ok, pid, 0)
-    off = pos % page
-    kp = kp.at[:, pid, off, :].set(jnp.transpose(k, (2, 0, 1, 3)))
-    vp = vp.at[:, pid, off, :].set(jnp.transpose(v, (2, 0, 1, 3)))
+    block-table row, as one row of ``KVH * D``; tokens with
+    ``j >= valid[b]`` (chunk padding, or a slot not in this prefill
+    wave) are routed to the reserved trash page 0 so a real page is
+    never clobbered. A decode step is the chunk of one token."""
+    b, c = k.shape[:2]
+    pid, off = _chunk_rows(kp, block_tables, ctx, valid, c)
+    kp = kp.at[pid, off].set(k.reshape(b, c, -1))
+    vp = vp.at[pid, off].set(v.reshape(b, c, -1))
     return kp, vp
 
 
@@ -333,31 +339,23 @@ def paged_prefill_write_quant(kp, vp, ks, vs, k, v, block_tables, ctx,
                               valid):
     """Quantize-at-write prefill chunk write for quantized KV pools.
 
-    kp, vp: [KVH, num_pages, page_size, D] int8 (or fp8) data pools;
-    ks, vs: [KVH, num_pages, page_size] f32 page-parallel scales pools.
+    kp, vp: [num_pages, page_size, KVH * D] int8 (or fp8) data pools;
+    ks, vs: [num_pages, KVH, page_size] f32 page-parallel scales pools.
     k, v: [B, C, KVH, D] float projections (already rotated). The quant
     mode rides the pool dtype (:func:`kv_quant_range`) and each token's
-    per-kv-head scale is written at the SAME (page, offset) its data
+    per-kv-head scales are written at the SAME (page, offset) its data
     lands at, so the scales ride the block-table indirection unchanged:
     trash-routed padding writes its scale to trash page 0, COW forks
     copy the scale page with the data page, and preemption replay
     rewrites both."""
-    c = k.shape[1]
-    page = kp.shape[2]
+    b, c = k.shape[:2]
     qk, sk = quantize_kv(k, kp.dtype)       # [B, C, KVH, D] / [B, C, KVH]
     qv, sv = quantize_kv(v, vp.dtype)
-    pos = ctx[:, None] + jnp.arange(c, dtype=ctx.dtype)[None, :]  # [B, C]
-    pidx = jnp.minimum(pos // page, block_tables.shape[1] - 1)
-    pid = jnp.take_along_axis(block_tables, pidx, axis=1)         # [B, C]
-    ok = jnp.arange(c)[None, :] < valid[:, None]
-    pid = jnp.where(ok, pid, 0)
-    off = pos % page
-    kp = kp.at[:, pid, off, :].set(jnp.transpose(qk, (2, 0, 1, 3)))
-    vp = vp.at[:, pid, off, :].set(jnp.transpose(qv, (2, 0, 1, 3)))
-    ks = ks.at[:, pid, off].set(jnp.transpose(sk, (2, 0, 1))
-                                .astype(ks.dtype))
-    vs = vs.at[:, pid, off].set(jnp.transpose(sv, (2, 0, 1))
-                                .astype(vs.dtype))
+    pid, off = _chunk_rows(kp, block_tables, ctx, valid, c)
+    kp = kp.at[pid, off].set(qk.reshape(b, c, -1))
+    vp = vp.at[pid, off].set(qv.reshape(b, c, -1))
+    ks = ks.at[pid, :, off].set(sk.astype(ks.dtype))
+    vs = vs.at[pid, :, off].set(sv.astype(vs.dtype))
     return kp, vp, ks, vs
 
 

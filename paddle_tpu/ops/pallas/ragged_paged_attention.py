@@ -30,7 +30,10 @@ prefetch idiom, generalized to ragged multi-token queries):
   8, or the whole row-padded chunk) whatever the GQA ratio ``rep``;
 - block tables / context lens / lengths ride scalar prefetch, so only
   the pages a sequence actually owns are streamed;
-- K/V pools stay in HBM (``ANY`` memory space); each grid step DMAs
+- K/V pools stay in HBM (``ANY`` memory space) in the one pool layout,
+  ``[num_pages, page_size, KVH * D]`` (``ops.paged_attention``'s module
+  docstring); each grid step DMAs the ``[page_size, D]`` tiles of its kv
+  head (columns ``h * D .. (h + 1) * D``, a lane-aligned slice) of
   ``kv_pages_per_block`` pages named in the block table into a
   double-buffered VMEM scratch (next block's copy overlaps the current
   block's compute) and accumulates with an online softmax in fp32.
@@ -168,14 +171,15 @@ def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
         i+1's prefetch completions satisfy a wait for block i and
         hand compute a partially-copied buffer."""
         copies = []
+        cols = pl.ds(h * d, d)     # this kv head's columns of a token row
         for gidx in range(g_pages):
             pidx = jnp.minimum(i * g_pages + gidx, pages_per_seq - 1)
             pid = tbl_ref[b * pages_per_seq + pidx]
             copies.append(pltpu.make_async_copy(
-                k_hbm_ref.at[h, pid], k_buf.at[slot, gidx],
+                k_hbm_ref.at[pid, :, cols], k_buf.at[slot, gidx],
                 sem.at[slot]))
             copies.append(pltpu.make_async_copy(
-                v_hbm_ref.at[h, pid], v_buf.at[slot, gidx],
+                v_hbm_ref.at[pid, :, cols], v_buf.at[slot, gidx],
                 sem.at[slot]))
         return copies
 
@@ -259,18 +263,18 @@ def _row_blocking(c, qb, rep):
 
 
 def _block_scales(scales, block_tables, g, page):
-    """Page-parallel scales pool [KVH, P, page] -> the sequences' own
+    """Page-parallel scales pool [P, KVH, page] -> the sequences' own
     scales, lane-dense per kv block: [B, KVH, n_kv_blocks, g * page].
     One XLA gather through the block table (the kernel DMAs only the
     data pages; a per-page scale row is 16-32 lanes, which neither the
     DMA engine nor an in-kernel (g, page) -> (1, bk) relayout
     handles)."""
-    kvh = scales.shape[0]
+    kvh = scales.shape[1]
     b, pps = block_tables.shape
     nb = -(-pps // g)
-    sc = scales[:, block_tables]                      # [KVH, B, pps, page]
-    sc = jnp.pad(sc, ((0, 0), (0, 0), (0, nb * g - pps), (0, 0)))
-    return jnp.swapaxes(sc, 0, 1).reshape(b, kvh, nb, g * page).astype(
+    sc = scales[block_tables]                         # [B, pps, KVH, page]
+    sc = jnp.pad(sc, ((0, 0), (0, nb * g - pps), (0, 0), (0, 0)))
+    return jnp.swapaxes(sc, 1, 2).reshape(b, kvh, nb, g * page).astype(
         jnp.float32)
 
 
@@ -284,19 +288,20 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
     q            [B, C, H, D] — slot b's tokens are the stream window
                  [b*C, b*C + lengths[b]); rows past lengths[b] are
                  padding (zeroed in the output)
-    key_pages /  [KVH, num_pages, page_size, D] page pools; the chunk's
+    key_pages /  [num_pages, page_size, KVH * D] page pools; the chunk's
     value_pages  k/v already written at ctx .. ctx+len-1
     block_tables [B, pages_per_seq] int32
     ctx_lens     [B] int32 — cache length BEFORE the chunk
     lengths      [B] int32 — valid stream tokens per slot (0 = idle,
                  1 = decode step, >1 = prefill chunk)
-    k_scales /   optional [KVH, num_pages, page_size] f32 page-parallel
+    k_scales /   optional [num_pages, KVH, page_size] f32 page-parallel
     v_scales     scales pools — when given, the data pools are int8/fp8
                  and the kernel applies the scales in VMEM
     Returns [B, C, H, D].
     """
     b, c, h, d = q.shape
-    kvh, _, page, _ = key_pages.shape
+    _, page, width = key_pages.shape
+    kvh = width // d
     rep = h // kvh
     pages_per_seq = block_tables.shape[1]
     quant = k_scales is not None
@@ -413,7 +418,7 @@ def ragged_attention_cost(q_shape, pool_shape, avg_ctx, lengths_sum=None,
                           pool_dtype=None):
     """Static FLOPs/bytes for one :func:`ragged_paged_attention` call
     (profiler cost-accounting surface): q [B, C, H, D], pool
-    [KVH, pages, page, D]. Attention over an average history of
+    [pages, page, KVH * D]. Attention over an average history of
     ``avg_ctx`` keys per stream token; bytes count q/pages-touched/out
     only (the kernel never materializes scores). ``pool_dtype`` makes
     the page traffic quant-aware: int8 pools stream half the bytes of
@@ -421,7 +426,7 @@ def ragged_attention_cost(q_shape, pool_shape, avg_ctx, lengths_sum=None,
     pool."""
     from ...profiler.cost import SectionCost
     b, c, h, d = (int(x) for x in q_shape)
-    _, _, page, _ = (int(x) for x in pool_shape)
+    page = int(pool_shape[1])
     toks = int(lengths_sum) if lengths_sum is not None else b * c
     flops = 4.0 * toks * h * d * float(avg_ctx)
     pages_touched = toks * -(-float(avg_ctx) // page)

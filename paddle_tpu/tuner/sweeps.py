@@ -313,15 +313,20 @@ def ragged_attention_builder(slots=8, heads=8, kv_heads=2,
         kq, kk, kv = jax.random.split(key, 3)
         q = jax.random.normal(
             kq, (slots, c, heads, d), jnp.float32).astype(dt)
+        # every token's [kv_heads, d] vectors, then the pool's own shape
+        from ..ops.paged_attention import kv_pool_shape, quantize_kv
+        pool_shape = kv_pool_shape(kv_heads, total, page, d)
         kp = jax.random.normal(
-            kk, (kv_heads, total, page, d), jnp.float32).astype(dt)
+            kk, (total, page, kv_heads, d), jnp.float32).astype(dt)
         vp = jax.random.normal(
-            kv, (kv_heads, total, page, d), jnp.float32).astype(dt)
+            kv, (total, page, kv_heads, d), jnp.float32).astype(dt)
         ks = vs = None
         if quant:
-            from ..ops.paged_attention import quantize_kv
             (kp, ks), (vp, vs) = (quantize_kv(kp, jnp.int8),
                                   quantize_kv(vp, jnp.int8))
+            # [total, page, kv_heads] -> kv_scales_shape
+            ks, vs = jnp.swapaxes(ks, 1, 2), jnp.swapaxes(vs, 1, 2)
+        kp, vp = kp.reshape(pool_shape), vp.reshape(pool_shape)
         rng = np.random.RandomState(0)
         tables = jnp.asarray(
             (rng.permutation(total - 1)[:slots * pages] + 1)

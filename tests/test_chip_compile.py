@@ -89,14 +89,17 @@ def _sds(sharding):
 # ---- serving: ragged paged attention -------------------------------------
 
 def _compile_ragged(s, quant, b, c, kvh, rep, page, pps, n_pages, d=128):
+    from paddle_tpu.ops.paged_attention import (kv_pool_shape,
+                                                kv_scales_shape)
     from paddle_tpu.ops.pallas.ragged_paged_attention import (
         ragged_paged_attention)
-    pool = s((kvh, n_pages, page, d), jnp.int8 if quant else BF16)
+    pool = s(kv_pool_shape(kvh, n_pages, page, d),
+             jnp.int8 if quant else BF16)
     args = [s((b, c, kvh * rep, d)), pool, pool,
             s((b, pps), jnp.int32), s((b,), jnp.int32),
             s((b,), jnp.int32)]
     if quant:
-        scales = s((kvh, n_pages, page), jnp.float32)
+        scales = s(kv_scales_shape(kvh, n_pages, page), jnp.float32)
         args += [scales, scales]
 
         def fn(q, k, v, t, ctx, ln, ks, vs):
@@ -130,13 +133,9 @@ def test_ragged_paged_attention_at_the_group_shape(native, one_chip, quant,
                     page=16, pps=128, n_pages=8193)
 
 
-def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
-                                              monkeypatch):
-    """The unified step program — the loop over groups of 8 prefilling
-    slots, the 16 decode micro-steps, the head on one row per slot — of a
-    2-layer model at Qwen2-7B's widths, 64 slots x 2048, compiled whole:
-    two loops (the group loop's trip count is data), and the attention
-    kernel in both bodies."""
+def _compile_serving_step(one_chip, topo, monkeypatch, kvh, kv_quant):
+    """(lowered, compiled text, engine) of the unified step program of a
+    2-layer model at Qwen2-7B's widths, 64 slots x 2048, page 16."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.core import Tensor
     from paddle_tpu.inference import ContinuousBatchingEngine
@@ -151,6 +150,7 @@ def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
 
     cfg = Qwen2Config.qwen2_7b()
     cfg.vocab_size, cfg.num_hidden_layers = 152064, 2
+    cfg.num_key_value_heads = kvh
     cfg.max_position_embeddings = 32768
     cfg.scan_layers = cfg.tensor_parallel = False
     with monkeypatch.context() as m:
@@ -162,7 +162,8 @@ def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
         if p._data.dtype != BF16:              # the norm scales
             p._data = p._data.astype(BF16)
     eng = ContinuousBatchingEngine(model, num_slots=64, max_len=2048,
-                                   page_size=16, greedy=True)
+                                   page_size=16, greedy=True,
+                                   kv_quant=kv_quant)
     assert (eng._group, eng.prefill_chunk, eng.decode_chunk) == (8, 128, 16)
     ustep = eng._unified_static().function
     s = _sds(one_chip)
@@ -192,13 +193,79 @@ def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
 
     lowered = jax.jit(step).lower(
         [s(tuple(p.shape), BF16) for p in params], *args)
+    return lowered, lowered.compile().as_text(), eng
+
+
+def _pool_copies(text, eng):
+    """Pool-shaped ``copy`` ops of a compiled step, per pool kind
+    (``"kv"`` data pools, ``"scale"`` pools): ``{kind: (in the whole
+    program, inside a ``while`` body or anything a body calls)}``. A
+    pool that the write and the kernel hold in two layouts shows here as
+    one copy per pool per pass."""
+    import re
+    names = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}
+    kind_of = {"%s[%s]" % (names[str(jnp.dtype(dt))],
+                           ",".join(map(str, sh))): kind
+               for sh, dt, kind in zip(eng._pool_shapes, eng._pool_dtypes,
+                                       eng._pool_kinds)
+               if kind in ("kv", "scale")}
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    called = re.compile(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
+    inside, todo = set(), [
+        b for ls in comps.values() for ln in ls
+        for b in re.findall(r" while\(.*body=%?([\w.\-]+)", ln)]
+    while todo:
+        n = todo.pop()
+        if n not in inside:
+            inside.add(n)
+            todo += called.findall("\n".join(comps.get(n, ())))
+    a_copy = re.compile(r"= (\S+?\])(?:\{[^}]*\})? copy\(")
+    out = {kind: [0, 0] for kind in kind_of.values()}
+    for n, ls in comps.items():
+        for ln in ls:
+            m = a_copy.search(ln)
+            if m and m.group(1) in kind_of:
+                out[kind_of[m.group(1)]][0] += 1
+                out[kind_of[m.group(1)]][1] += n in inside
+    return {k: tuple(v) for k, v in out.items()}
+
+
+@pytest.mark.parametrize("kvh,kv_quant", [(4, "none"), (4, "int8"),
+                                          (2, "none")],
+                         ids=["bf16", "int8kv", "nemotron_gqa"])
+def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
+                                              monkeypatch, kvh, kv_quant):
+    """The unified step program — the loop over groups of 8 prefilling
+    slots, the 16 decode micro-steps, the head on one row per slot — of a
+    2-layer model at Qwen2-7B's widths, 64 slots x 2048, compiled whole:
+    two loops (the group loop's trip count is data), and the attention
+    kernel in both bodies. The K/V write and the kernel share ONE pool
+    layout: no pool-sized ``copy`` inside either loop, at most one per
+    K/V pool in the whole program (the entry copy of an argument the step
+    does not donate) — bf16 and int8 pools, and Nemotron-3's GQA ratio
+    (2 kv heads). The f32 scales pools of int8 K/V (2 MB each) read 3
+    copies a pool BETWEEN the loops: XLA gives the two loops' scatters
+    two layouts of so narrow an array; none inside a loop
+    (``PERF.md`` section 7)."""
+    lowered, text, eng = _compile_serving_step(one_chip, topo, monkeypatch,
+                                               kvh, kv_quant)
     assert lowered.as_text().count("stablehlo.while") == 2
-    text = lowered.compile().as_text()
     assert "ragged_paged_attention" in text
-    for shape in ("bf16[8,4,896,128]", "bf16[64,4,8,128]"):
+    rep = 28 // kvh
+    for shape in (f"bf16[8,{kvh},{128 * rep},128]",
+                  f"bf16[64,{kvh},{-(-rep // 8) * 8},128]"):
         assert shape in text           # the kernel at 8 rows, and at 64
     for shape in ("bf16[8192,", "bf16[64,128,152064]"):
         assert shape not in text       # no pass at all 64 x 128 positions
+    copies = _pool_copies(text, eng)
+    assert all(in_loops == 0 for _, in_loops in copies.values()), copies
+    assert copies["kv"][0] <= eng._pool_kinds.count("kv"), copies
 
 
 def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):
@@ -214,8 +281,9 @@ def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):
     for cand in cands:
         assert rpa._row_blocking(48, cand["q_block"], 7)[0] \
             == cand["q_block"]
+    from paddle_tpu.ops.paged_attention import kv_pool_shape
     s = _sds(one_chip)
-    pool = s((4, 64, 16, 128))
+    pool = s(kv_pool_shape(4, 64, 16, 128))
     for cand in (cands[0], cands[-1]):
         _compile(lambda q, k, v, t, ctx, ln, _c=cand:
                  rpa.ragged_paged_attention(

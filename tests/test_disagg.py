@@ -160,6 +160,71 @@ def test_import_back_into_source_engine(model, oracle):
     eng._audit_pages("test")
 
 
+def _export_one(model, spec=1, **kw):
+    cfg, m = model
+    pre = ContinuousBatchingEngine(m, role="prefill", **_ENG_KW, **kw)
+    prompt, n_new = _specs(cfg)[spec]
+    rid = pre.add_request(prompt, n_new)
+    for _ in range(200):
+        pre.step()
+        if pre.migrations_out:
+            break
+    (req, payload), = pre.take_migrations()
+    return pre, rid, req, payload
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_exported_pages_are_the_pools_own_rows(model, kv_quant):
+    """Payload version 2: a block's arrays are the pools' pages as they
+    lie — ``[page_size, kv_heads * head_dim]``, scales ``[kv_heads,
+    page_size]`` — and an import lands them, through the wire codec,
+    bit for bit at the page axis (0) of the destination's pools."""
+    from paddle_tpu.inference.serving import KV_PAYLOAD_VERSION
+    cfg, m = model
+    pre, rid, req, payload = _export_one(model, kv_quant=kv_quant)
+    assert payload["version"] == KV_PAYLOAD_VERSION == 2
+    kvh = cfg.num_key_value_heads
+    d = cfg.hidden_size // cfg.num_attention_heads
+    want = [(8, kvh * d)] * 2 + ([(kvh, 8)] * 2 if kv_quant == "int8"
+                                 else [])
+    for blk in payload["blocks"]:
+        assert [a.shape for a in blk["data"]] \
+            == want * cfg.num_hidden_layers
+    dec = ContinuousBatchingEngine(m, role="decode", **_ENG_KW,
+                                   kv_quant=kv_quant)
+    out = dec.import_migration(req, kv_payload_from_wire(
+        kv_payload_to_wire(payload)))
+    assert out == {"imported": len(payload["blocks"]), "dedup": 0,
+                   "rejected": 0}
+    node = dec._pc_root
+    for blk in payload["blocks"]:
+        node = node.children[np.asarray(blk["tokens"], np.int32).tobytes()]
+        for pool, data in zip(dec._paged_arrays(), blk["data"]):
+            np.testing.assert_array_equal(np.asarray(pool[node.page]),
+                                          data)
+    pre.release_exported(req.request_id)
+    assert len({r.request_id: r for r in dec.run()}[rid].tokens) \
+        == _specs(cfg)[1][1]
+    dec._audit_pages("test")
+
+
+def test_an_old_payload_version_is_refused_by_name(model, oracle):
+    """A version-1 payload (pages shipped ``[kv_heads, page_size,
+    head_dim]``) lands nothing, the result names the version, and the
+    request replays from its tokens to the identical stream."""
+    cfg, m = model
+    pre, rid, req, payload = _export_one(model)
+    dec = ContinuousBatchingEngine(m, role="decode", **_ENG_KW)
+    out = dec.import_migration(req, dict(payload, version=1))
+    assert (out["imported"], out["dedup"], out["rejected"]) == (0, 0, 0)
+    assert "version 1" in out["refused"] and "version 2" in out["refused"]
+    assert dec.prefix_cache_pages == 0
+    pre.release_exported(req.request_id)
+    done = {r.request_id: r for r in dec.run()}
+    assert done[rid].tokens == oracle[1]
+    dec._audit_pages("test")
+
+
 def test_salvage_includes_parked_migrations(model):
     """An engine dying between parking a migration and its pickup
     must surface the parked request to ``salvage_unfinished`` — the

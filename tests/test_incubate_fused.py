@@ -133,8 +133,8 @@ def test_block_multihead_attention_aliases_paged():
     rng = np.random.RandomState(5)
     B, H, D, P, page = 2, 2, 4, 5, 4
     q = rng.randn(B, H, D).astype(np.float32)
-    kp = rng.randn(H, P, page, D).astype(np.float32)
-    vp = rng.randn(H, P, page, D).astype(np.float32)
+    kp = rng.randn(P, page, H * D).astype(np.float32)
+    vp = rng.randn(P, page, H * D).astype(np.float32)
     tables = np.array([[1, 2], [3, 4]], np.int32)
     lens = np.array([5, 7], np.int32)
     out = np.asarray(IF.block_multihead_attention(

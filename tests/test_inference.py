@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu import nn
 from paddle_tpu.inference import (Config, PrecisionType, create_predictor)
-from paddle_tpu.ops.paged_attention import (paged_attention,
+from paddle_tpu.ops.paged_attention import (kv_pool_shape, paged_attention,
                                             paged_attention_reference)
 from paddle_tpu.ops.pallas.flash_attention import flash_attention_reference
 
@@ -142,8 +142,10 @@ def _build_paged_case(rng, B, H, KVH, D, page, n_pages_per_seq,
     max_len = page * n_pages_per_seq
     k_dense = rng.randn(B, max_len, KVH, D).astype("float32")
     v_dense = rng.randn(B, max_len, KVH, D).astype("float32")
-    key_pages = np.zeros((KVH, total_pages, page, D), "float32")
-    value_pages = np.zeros((KVH, total_pages, page, D), "float32")
+    # the pool's one layout: [pages, page, KVH * D], a token = one row
+    key_pages = np.zeros(kv_pool_shape(KVH, total_pages, page, D),
+                         "float32")
+    value_pages = np.zeros_like(key_pages)
     perm = rng.permutation(total_pages)
     tables = np.zeros((B, n_pages_per_seq), "int32")
     pid = 0
@@ -153,8 +155,8 @@ def _build_paged_case(rng, B, H, KVH, D, page, n_pages_per_seq,
             pid += 1
             tables[b, j] = pg
             sl = slice(j * page, (j + 1) * page)
-            key_pages[:, pg] = k_dense[b, sl].transpose(1, 0, 2)
-            value_pages[:, pg] = v_dense[b, sl].transpose(1, 0, 2)
+            key_pages[pg] = k_dense[b, sl].reshape(page, KVH * D)
+            value_pages[pg] = v_dense[b, sl].reshape(page, KVH * D)
     return k_dense, v_dense, key_pages, value_pages, tables
 
 
@@ -240,8 +242,8 @@ def test_paged_prefill_write_routes_and_trashes():
     rng = np.random.RandomState(3)
     KVH, D, page, npps, B, C = 2, 4, 4, 3, 2, 5
     total = 1 + B * npps                   # page 0 = trash
-    kp = np.zeros((KVH, total, page, D), "float32")
-    vp = np.zeros((KVH, total, page, D), "float32")
+    kp = np.zeros(kv_pool_shape(KVH, total, page, D), "float32")
+    vp = np.zeros_like(kp)
     tables = np.arange(1, 1 + B * npps,
                        dtype="int32").reshape(B, npps)
     k = rng.randn(B, C, KVH, D).astype("float32")
@@ -257,16 +259,43 @@ def test_paged_prefill_write_routes_and_trashes():
         for j in range(int(valid[b])):
             pos = int(ctx[b]) + j
             pg, off = tables[b, pos // page], pos % page
-            np.testing.assert_array_equal(kp2[:, pg, off], k[b, j])
-            np.testing.assert_array_equal(vp2[:, pg, off], v[b, j])
+            np.testing.assert_array_equal(kp2[pg, off],
+                                          k[b, j].reshape(-1))
+            np.testing.assert_array_equal(vp2[pg, off],
+                                          v[b, j].reshape(-1))
     # nothing outside the written positions changed (trash page aside)
     mask = np.ones((total,), bool)
     written = {int(tables[b, (int(ctx[b]) + j) // page])
                for b in range(B) for j in range(int(valid[b]))}
     for pg in range(1, total):
         if pg not in written:
-            assert not kp2[:, pg].any() and not vp2[:, pg].any()
+            assert not kp2[pg].any() and not vp2[pg].any()
     assert mask[0]                          # page 0 absorbed the padding
+
+
+@pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2), (14, 2)])
+def test_paged_attention_is_the_ragged_entry_at_length_one(H, KVH):
+    """``paged_attention`` has no kernel of its own: it is
+    ``ragged_paged_attention`` with one query token per sequence, equal
+    to the decode reference on the jnp path and through the Pallas
+    kernel (interpret mode)."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention as kernel)
+    rng = np.random.RandomState(4)
+    B, D, page, npps = 3, 16, 8, 4
+    lens = np.array([1, 16, 27], "int32")    # 16: a page's last offset
+    _, _, kp, vp, tables = _build_paged_case(
+        rng, B, H, KVH, D, page, npps, B * npps + 2, lens)
+    q = rng.randn(B, H, D).astype("float32")
+    args = [jnp.asarray(a) for a in (kp, vp, tables)]
+    dec = np.asarray(paged_attention_reference(
+        jnp.asarray(q), *args, jnp.asarray(lens)))
+    out = paged_attention(jnp.asarray(q), *args, jnp.asarray(lens))
+    np.testing.assert_allclose(np.asarray(out), dec, rtol=1e-6, atol=1e-6)
+    out_k = kernel(jnp.asarray(q[:, None]), *args, jnp.asarray(lens - 1),
+                   jnp.ones((B,), jnp.int32))
+    np.testing.assert_allclose(np.asarray(out_k[:, 0]), dec,
+                               rtol=2e-5, atol=2e-5)
 
 
 def test_paged_attention_incubate_api():

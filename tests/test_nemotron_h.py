@@ -358,15 +358,16 @@ def _uniform(kind):
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])
 def test_uniform_kv_models_keep_their_pools(kind, kv_quant):
     """A model that declares no cache spec gets the pools it had: per
-    layer (k, v) of (kvh, pages, page, d) in the model's dtype, and under
-    quantized KV (k, v, k_scales, v_scales) — shapes, dtypes and order."""
+    layer (k, v) of (pages, page, kvh * d) in the model's dtype, and under
+    quantized KV (k, v, k_scales, v_scales), scales (pages, kvh, page) —
+    shapes, dtypes and order."""
     model = _uniform(kind)
     cfg = model.config
     eng = ContinuousBatchingEngine(model, num_slots=2, max_len=32,
                                    page_size=8, kv_quant=kv_quant)
     kvh = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
     d = getattr(cfg, "head_dim", cfg.hidden_size // cfg.num_attention_heads)
-    data, scale = (kvh, eng.num_pages, 8, d), (kvh, eng.num_pages, 8)
+    data, scale = (eng.num_pages, 8, kvh * d), (eng.num_pages, kvh, 8)
     if kv_quant == "none":
         per = [(data, jnp.float32)] * 2
     else:

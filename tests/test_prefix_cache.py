@@ -131,6 +131,30 @@ def test_cow_fork_on_fully_cached_prompt():
     _balanced(eng)
 
 
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_cow_copies_one_page_of_every_pool(kv_quant):
+    """The fork indexes the pools' page axis (0): after it ``dst`` holds
+    ``src``'s content in every K/V pool — and scales pool — and no other
+    page moved."""
+    _, cfg = _model()
+    eng = _engine(kv_quant=kv_quant)
+    eng.add_request(np.arange(1, 20, dtype=np.int32) % cfg.vocab_size, 3)
+    eng.run()
+    before = [np.asarray(a) for a in eng._paged_arrays()]
+    src = next(p for p in range(1, eng.num_pages) if before[0][p].any())
+    dst = next(p for p in range(1, eng.num_pages)
+               if p != src and not before[0][p].any())
+    eng._pc_cow(src, dst)
+    after = [np.asarray(a) for a in eng._paged_arrays()]
+    assert len(after) == (2 if kv_quant == "none" else 4) \
+        * cfg.num_hidden_layers
+    for b, a in zip(before, after):
+        assert a.shape == b.shape and a.shape[0] == eng.num_pages
+        np.testing.assert_array_equal(a[dst], b[src])
+        keep = [p for p in range(eng.num_pages) if p != dst]
+        np.testing.assert_array_equal(a[keep], b[keep])
+
+
 def test_divergence_mid_page_shares_only_full_blocks():
     """B shares A's first page then diverges INSIDE the second page:
     only the full matching block is shared (page-granular hashing),

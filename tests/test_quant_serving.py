@@ -116,26 +116,35 @@ def test_kv_roundtrip_half_step_bound(dtype):
     differently — quantization is per (token, head) vector, so a tail
     is just fewer vectors."""
     rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(2, 5, 8, 16) * 3.0, jnp.dtype(dtype))
-    q, s = PA.quantize_kv(x, jnp.int8)
-    assert q.dtype == jnp.int8 and s.shape == x.shape[:-1]
-    back = PA.dequantize_pages(q, s)
-    err = np.abs(np.asarray(back, np.float32)
-                 - np.asarray(x, np.float32))
+    # [pages, page, kv heads, d]: every token's vectors, then the pools'
+    # own shapes
+    x = jnp.asarray(rng.randn(2, 8, 5, 16) * 3.0, jnp.dtype(dtype))
+
+    def back_of(x):
+        q, s = PA.quantize_kv(x, jnp.int8)
+        assert q.dtype == jnp.int8 and s.shape == x.shape[:-1]
+        p, page, kvh, d = x.shape
+        assert q.reshape(p, page, -1).shape == PA.kv_pool_shape(
+            kvh, p, page, d)
+        assert jnp.swapaxes(s, 1, 2).shape == PA.kv_scales_shape(
+            kvh, p, page)
+        back = PA.dequantize_pages(q.reshape(p, page, -1),
+                                   jnp.swapaxes(s, 1, 2))
+        return np.asarray(back, np.float32).reshape(x.shape), s
+
+    back, s = back_of(x)
+    err = np.abs(back - np.asarray(x, np.float32))
     bound = np.asarray(s, np.float32)[..., None] * 0.5 + 1e-6
     assert (err <= bound).all()
     # a page tail (partial page) carries the same bound
-    tail = x[:, :, :3, :]
-    qt, st = PA.quantize_kv(tail, jnp.int8)
-    bt = PA.dequantize_pages(qt, st)
-    errt = np.abs(np.asarray(bt, np.float32)
-                  - np.asarray(tail, np.float32))
+    tail = x[:, :3]
+    bt, st = back_of(tail)
+    errt = np.abs(bt - np.asarray(tail, np.float32))
     assert (errt <= np.asarray(st, np.float32)[..., None] * 0.5
             + 1e-6).all()
     # all-zero vectors must round-trip to exactly zero (scale floor)
-    z, sz = PA.quantize_kv(jnp.zeros_like(x), jnp.int8)
-    assert not np.asarray(z).any()
-    assert np.asarray(PA.dequantize_pages(z, sz)).max() == 0.0
+    bz, _ = back_of(jnp.zeros_like(x))
+    assert bz.max() == 0.0 and bz.min() == 0.0
 
 
 def test_quant_write_trash_routing():
@@ -147,10 +156,10 @@ def test_quant_write_trash_routing():
     rng = np.random.RandomState(1)
     k = jnp.asarray(rng.randn(B, C, kvh, d), jnp.float32)
     v = jnp.asarray(rng.randn(B, C, kvh, d), jnp.float32)
-    kp = jnp.zeros((kvh, P, page, d), jnp.int8)
-    vp = jnp.zeros((kvh, P, page, d), jnp.int8)
-    ks = jnp.zeros((kvh, P, page), jnp.float32)
-    vs = jnp.zeros((kvh, P, page), jnp.float32)
+    kp = jnp.zeros(PA.kv_pool_shape(kvh, P, page, d), jnp.int8)
+    vp = jnp.zeros(PA.kv_pool_shape(kvh, P, page, d), jnp.int8)
+    ks = jnp.zeros(PA.kv_scales_shape(kvh, P, page), jnp.float32)
+    vs = jnp.zeros(PA.kv_scales_shape(kvh, P, page), jnp.float32)
     tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     ctx = jnp.asarray([0, 0], jnp.int32)
     valid = jnp.asarray([3, 2], jnp.int32)   # per-seq valid counts
@@ -161,15 +170,17 @@ def test_quant_write_trash_routing():
     src = np.asarray(k, np.float32)
     for b, pid in ((0, 1), (1, 3)):
         nvalid = int(np.asarray(valid)[b])
-        got = back[:, pid, :nvalid, :]
-        want = np.transpose(src[b, :nvalid], (1, 0, 2))
-        assert np.abs(got - want).max() < 0.05
+        got = back[pid, :nvalid].reshape(nvalid, kvh, d)
+        assert np.abs(got - src[b, :nvalid]).max() < 0.05
+        # a token's scales sit at [page, head, offset]
+        assert np.asarray(ks)[pid, :, :nvalid].all()
+        assert not np.asarray(ks)[pid, :, nvalid:].any()
     # invalid tokens landed on page 0, nowhere else: pages 2 and 4
     # (each seq's second table page) stay untouched
-    assert not np.asarray(kp)[:, 2].any()
-    assert not np.asarray(kp)[:, 4].any()
-    assert np.asarray(kp)[:, 0].any()          # trash took the spill
-    assert np.asarray(ks)[:, 0].any()          # scales follow the data
+    assert not np.asarray(kp)[2].any()
+    assert not np.asarray(kp)[4].any()
+    assert np.asarray(kp)[0].any()             # trash took the spill
+    assert np.asarray(ks)[0].any()             # scales follow the data
 
 
 def test_oracle_matches_bf16_and_kernel_matches_oracle():
@@ -183,16 +194,20 @@ def test_oracle_matches_bf16_and_kernel_matches_oracle():
     P, page, pages = 9, 4, 4
     rng = np.random.RandomState(3)
     q = jnp.asarray(rng.randn(B, C, H, d), jnp.float32)
-    kp = jnp.asarray(rng.randn(kvh, P, page, d), jnp.float32)
-    vp = jnp.asarray(rng.randn(kvh, P, page, d), jnp.float32)
+    # every token's [kvh, d] vectors; pools and scales in their shapes
+    kt = jnp.asarray(rng.randn(P, page, kvh, d), jnp.float32)
+    vt = jnp.asarray(rng.randn(P, page, kvh, d), jnp.float32)
+    kp, vp = kt.reshape(P, page, -1), vt.reshape(P, page, -1)
     tables = jnp.asarray(
         (np.arange(B * pages).reshape(B, pages) + 1), jnp.int32)
     ctx = jnp.asarray([5, 9], jnp.int32)
     lens = jnp.asarray([4, 2], jnp.int32)
     ref = PA.ragged_paged_attention_reference(
         q, kp, vp, tables, ctx, lens)
-    (qk, sk), (qv, sv) = (PA.quantize_kv(kp, jnp.int8),
-                          PA.quantize_kv(vp, jnp.int8))
+    (qk, sk), (qv, sv) = (PA.quantize_kv(kt, jnp.int8),
+                          PA.quantize_kv(vt, jnp.int8))
+    qk, qv = qk.reshape(kp.shape), qv.reshape(vp.shape)
+    sk, sv = jnp.swapaxes(sk, 1, 2), jnp.swapaxes(sv, 1, 2)
     ref_q = PA.ragged_paged_attention_reference(
         q, qk, qv, tables, ctx, lens, k_scales=sk, v_scales=sv)
     err_quant = np.abs(np.asarray(ref_q) - np.asarray(ref)).max()

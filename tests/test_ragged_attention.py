@@ -10,7 +10,8 @@ import pytest
 import jax.numpy as jnp
 
 from paddle_tpu.ops.paged_attention import (
-    paged_attention_reference, paged_prefill_attention_reference,
+    kv_pool_shape, paged_attention_reference,
+    paged_prefill_attention_reference, paged_prefill_write,
     ragged_paged_attention_reference)
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     force_ragged_blocks, ragged_paged_attention as kernel)
@@ -19,8 +20,9 @@ from paddle_tpu.ops.pallas.ragged_paged_attention import (
 def _pool_case(rng, B, KVH, D, page, pages_per_seq, total_pages):
     """Shuffled page pool + block tables (page 0 reserved as trash,
     the engine convention)."""
-    kp = rng.randn(KVH, total_pages, page, D).astype("float32")
-    vp = rng.randn(KVH, total_pages, page, D).astype("float32")
+    shape = kv_pool_shape(KVH, total_pages, page, D)
+    kp = rng.randn(*shape).astype("float32")
+    vp = rng.randn(*shape).astype("float32")
     perm = rng.permutation(total_pages - 1) + 1     # never page 0
     tables = perm[:B * pages_per_seq].reshape(
         B, pages_per_seq).astype("int32")
@@ -90,6 +92,66 @@ def test_page_boundary_straddling_and_one_token_sequences():
     lens = np.array([2, 7, 7, 1], "int32")
     out, ref = _run_both(q, kp, vp, tables, ctx, lens)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("rep", [1, 4, 7, 16])
+def test_write_at_a_pages_last_offset_then_read_through_the_kernel(rep):
+    """The layout's seam, end to end and against an oracle that never
+    sees a pool: dense k/v are written through ``paged_prefill_write``
+    (history, then a chunk whose tokens start at, end at, or are alone at
+    a page's LAST offset), read back through the kernel, and compared
+    with plain causal attention over the dense tensors — at the GQA
+    ratios of MHA, Llama-3, Qwen2-7B and Nemotron-3."""
+    rng = np.random.RandomState(8)
+    B, KVH, D, page, P, C = 4, 2, 8, 4, 6, 5
+    H = KVH * rep
+    total = B * P + 1
+    tables = (rng.permutation(total - 1)[:B * P] + 1).reshape(
+        B, P).astype("int32")
+    # chunk spans: 3..7 ends on offset 3; 7..9 starts on it; 11 alone on
+    # it; 0..4 runs over it
+    ctx = np.array([3, 7, 11, 0], "int32")
+    lens = np.array([5, 3, 1, 5], "int32")
+    T = int((ctx + lens).max())
+    k = rng.randn(B, T, KVH, D).astype("float32")
+    v = rng.randn(B, T, KVH, D).astype("float32")
+    q = rng.randn(B, C, H, D).astype("float32")
+    kp = jnp.zeros(kv_pool_shape(KVH, total, page, D), jnp.float32)
+    tb = jnp.asarray(tables)
+    # the history 0..ctx-1 in one chunk, then the chunk itself
+    kp, vp = paged_prefill_write(kp, kp, jnp.asarray(k), jnp.asarray(v),
+                                 tb, jnp.zeros((B,), jnp.int32),
+                                 jnp.asarray(ctx))
+    kc = np.stack([np.pad(k[b, ctx[b]:ctx[b] + C],
+                          ((0, C - min(C, T - ctx[b])), (0, 0), (0, 0)))
+                   for b in range(B)])
+    vc = np.stack([np.pad(v[b, ctx[b]:ctx[b] + C],
+                          ((0, C - min(C, T - ctx[b])), (0, 0), (0, 0)))
+                   for b in range(B)])
+    kp, vp = paged_prefill_write(kp, vp, jnp.asarray(kc), jnp.asarray(vc),
+                                 tb, jnp.asarray(ctx), jnp.asarray(lens))
+    # a token is ONE row of KVH * D at (its page, its offset)
+    for b in range(B):
+        pos = int(ctx[b] + lens[b] - 1)
+        np.testing.assert_array_equal(
+            np.asarray(kp)[tables[b, pos // page], pos % page],
+            k[b, pos].reshape(-1))
+    out = np.asarray(kernel(jnp.asarray(q), kp, vp, tb, jnp.asarray(ctx),
+                            jnp.asarray(lens)))
+    scale = 1.0 / np.sqrt(D)
+    for b in range(B):
+        for j in range(C):
+            if j >= lens[b]:
+                assert not out[b, j].any()
+                continue
+            n = int(ctx[b]) + j + 1
+            kk = np.repeat(k[b, :n], rep, axis=1).astype("float64")
+            vv = np.repeat(v[b, :n], rep, axis=1).astype("float64")
+            lg = np.einsum("hd,lhd->hl", q[b, j], kk) * scale
+            w = np.exp(lg - lg.max(-1, keepdims=True))
+            ref = np.einsum("hl,lhd->hd", w / w.sum(-1, keepdims=True), vv)
+            np.testing.assert_allclose(out[b, j], ref, rtol=2e-5,
+                                       atol=2e-5)
 
 
 @pytest.mark.parametrize("qb,g", [(1, 1), (2, 2), (4, 8), (5, 3)])
