@@ -4,14 +4,20 @@
 ``forward(caches=...)`` takes its arrays; :class:`ContinuousBatchingEngine`
 builds its pools, their reset and the page audit from it. A model without
 the method gets :func:`uniform_kv_spec`: one paged K/V pair per layer, the
-layout every dense decoder here has."""
+layout every dense decoder here has.
+
+The kinds: :class:`PagedKV` (host-managed pages, sized by ``max_len``),
+:class:`WindowKV` (a per-slot ring sized by the layer's window; its
+docstring states the ring's rule, once), :class:`SlotState` (per-slot
+recurrent state) and :class:`StepCounters` (what the model counts per
+pass)."""
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-__all__ = ["PagedKV", "SlotState", "StepCounters", "uniform_kv_spec",
-           "spec_of"]
+__all__ = ["PagedKV", "WindowKV", "SlotState", "StepCounters",
+           "uniform_kv_spec", "spec_of", "ring_pages"]
 
 
 @dataclass(frozen=True)
@@ -23,6 +29,35 @@ class PagedKV:
     scales pools ``(num_pages, kv_heads, page_size)`` after them."""
     kv_heads: int
     head_dim: int
+
+
+@dataclass(frozen=True)
+class WindowKV:
+    """One attention layer whose query ``i`` sees keys ``i - window + 1 ..
+    i`` only: two pools in :class:`PagedKV`'s layout (and its scales pools
+    under quantized KV), sized by the WINDOW and not by ``max_len``. Each
+    slot owns a ring of ``R`` pages (:func:`ring_pages`): the pools are
+    ``(num_slots * R + 1, page_size, kv_heads * head_dim)``, page 0 the
+    trash page, and logical page ``j`` of slot ``b`` is page ``1 + b * R +
+    j % R`` — a STATIC table the step program builds from that rule and
+    hands the model as ``tables[2]``, so the write and the kernel address
+    it like a global table and the host allocator never sees it. A token
+    is overwritten ``R`` pages later, after it left every window that can
+    still be asked for; a replayed request refills its ring by prefill.
+    Like :class:`SlotState` a ring belongs to its slot: nothing of it can
+    be shared, forked or shipped, so an engine whose spec has one serves
+    without prefix cache, speculative decoding and migration."""
+    kv_heads: int
+    head_dim: int
+    window: int
+
+
+def ring_pages(window, chunk, page_size):
+    """Pages of one slot's ring: enough for the keys the first query of a
+    ``chunk``-token pass sees and the chunk itself, wherever the page
+    boundaries fall (``window + chunk - 1`` consecutive tokens touch at
+    most this many pages)."""
+    return -(-(int(window) + int(chunk) - 2) // int(page_size)) + 1
 
 
 @dataclass(frozen=True)

@@ -242,6 +242,10 @@ _pmetrics.declare("serving/kv_quant_scale_pool_bytes", "gauge",
 _pmetrics.declare("serving/state_pool_bytes", "gauge",
                   "total bytes of the per-slot recurrent-state arrays "
                   "(cache_spec.SlotState; 0 for a paged-KV-only model)")
+_pmetrics.declare("serving/window_pool_bytes", "gauge",
+                  "total bytes of the window layers' ring pools "
+                  "(cache_spec.WindowKV: sized by the window, not by "
+                  "max_len; 0 for a model without window layers)")
 
 # -- speculative decoding: draft/verify economics (ISSUE 18)
 _pmetrics.declare("spec/steps", "counter",
@@ -572,27 +576,41 @@ class ContinuousBatchingEngine:
         # 0 like the data pools (ops.paged_attention.kv_pool_shape and
         # kv_scales_shape state both), so every page operation (COW page
         # copy, migration export/crc, batched import landing pads)
-        # composes over the paged pools unchanged. Per SlotState entry:
+        # composes over the paged pools unchanged. Per WindowKV entry the
+        # same pools over ``num_slots`` rings of ``_ring`` pages (+ a
+        # trash page) whatever ``max_len``, kinds "wkv"/"wscale": their
+        # table is a rule (cache_spec.WindowKV), not host state, and no
+        # page operation walks them. Per SlotState entry:
         # one (num_slots, ...) array the MODEL keeps right in-program.
         # A StepCounters entry: one int32 vector the step program zeroes
         # and reports in its packed fetch.
         from ..ops.paged_attention import kv_pool_shape, kv_scales_shape
-        from .cache_spec import PagedKV, SlotState, StepCounters, spec_of
+        from .cache_spec import (PagedKV, SlotState, StepCounters, WindowKV,
+                                 ring_pages, spec_of)
         self._pool_dtype = dtype if kv_quant == "none" else jnp.dtype(
             jnp.int8 if kv_quant == "int8" else jnp.float8_e4m3fn)
         self._pool_shapes, self._pool_dtypes, self._pool_kinds = [], [], []
         self._counter_names, self._counter_pool = (), None
-        for ent in spec_of(model):
-            if isinstance(ent, PagedKV):
-                geom = (ent.kv_heads, self.num_pages, self.page_size)
+        spec = spec_of(model)
+        #: pages of a slot's ring in every window layer's pools (0: the
+        #: spec has no window layer): the largest window's, and never more
+        #: than a slot's whole table row
+        self._ring = min(self.pages_per_slot, max(
+            (ring_pages(e.window, self.prefill_chunk, self.page_size)
+             for e in spec if isinstance(e, WindowKV)), default=0))
+        for ent in spec:
+            if isinstance(ent, (PagedKV, WindowKV)):
+                ring = isinstance(ent, WindowKV)
+                geom = (ent.kv_heads, self.num_slots * self._ring + 1
+                        if ring else self.num_pages, self.page_size)
                 self._pool_shapes += [kv_pool_shape(*geom,
                                                     ent.head_dim)] * 2
                 self._pool_dtypes += [self._pool_dtype] * 2
-                self._pool_kinds += ["kv"] * 2
+                self._pool_kinds += ["wkv" if ring else "kv"] * 2
                 if kv_quant != "none":
                     self._pool_shapes += [kv_scales_shape(*geom)] * 2
                     self._pool_dtypes += [jnp.float32] * 2
-                    self._pool_kinds += ["scale"] * 2
+                    self._pool_kinds += ["wscale" if ring else "scale"] * 2
             elif isinstance(ent, SlotState):
                 self._pool_shapes.append(
                     (self.num_slots,) + tuple(ent.shape))
@@ -624,20 +642,26 @@ class ContinuousBatchingEngine:
         #: walk
         self._paged = [i for i, k in enumerate(self._pool_kinds)
                        if k in ("kv", "scale")]
-        #: per-slot recurrent state lives outside the pages: nothing of
-        #: it can be shared, forked or shipped (cache_spec.SlotState)
-        self._has_state = "state" in self._pool_kinds
-        if self._has_state:
+        #: what a slot keeps OUTSIDE the host-managed pages — recurrent
+        #: state (cache_spec.SlotState), window rings (WindowKV) — named
+        #: for the refusals; nothing of it can be shared, forked or
+        #: shipped, so such an engine serves without prefix cache,
+        #: speculative decoding and migration
+        self._slot_owned = " and ".join(
+            what for kind, what in (("state", "per-slot recurrent state"),
+                                    ("wkv", "per-slot window rings"))
+            if kind in self._pool_kinds)
+        if self._slot_owned:
             if role == "prefill":
                 raise ValueError(
-                    "role='prefill' exports finished prompt pages; this "
-                    "model keeps per-slot recurrent state beside its "
-                    "pages, which a migration does not carry yet")
+                    f"role='prefill' exports finished prompt pages; this "
+                    f"model keeps {self._slot_owned} beside its pages, "
+                    f"which a migration does not carry yet")
             if spec_decode or spec_k is not None or spec_draft is not None:
                 raise ValueError(
-                    "speculative decoding rolls a slot back to its last "
-                    "accepted token; this model keeps per-slot recurrent "
-                    "state, which has no rollback yet")
+                    f"speculative decoding rolls a slot back to its last "
+                    f"accepted token; this model keeps {self._slot_owned}, "
+                    f"which has no rollback yet")
         self.pools = [Tensor(jnp.zeros(s, dt)) for s, dt in
                       zip(self._pool_shapes, self._pool_dtypes)]
         # static pool-geometry facts for the gauges
@@ -649,6 +673,7 @@ class ContinuousBatchingEngine:
         self._kv_pool_bytes = _bytes("kv")
         self._kv_scale_pool_bytes = _bytes("scale")
         self._state_pool_bytes = _bytes("state")
+        self._window_pool_bytes = _bytes("wkv") + _bytes("wscale")
 
         self._free_pages = deque(range(1, self.num_pages))
         # host-side slot bookkeeping (admission decisions, drain)
@@ -751,8 +776,9 @@ class ContinuousBatchingEngine:
         # or prefix_cache=False restores exclusive-page behavior.
         self._prefix_cache = _env_bool("PADDLE_TPU_PREFIX_CACHE", True) \
             if prefix_cache is None else bool(prefix_cache)
-        if self._has_state:
-            # a hit would skip tokens whose recurrent state nobody stored
+        if self._slot_owned:
+            # a hit would skip tokens whose recurrent state, or whose
+            # window layers' K/V, nobody stored
             self._prefix_cache = False
         self._pc_root = _PrefixCacheNode(None, 0, None)   # sentinel
         self._pc_nodes: dict[int, _PrefixCacheNode] = {}  # page -> node
@@ -835,6 +861,8 @@ class ContinuousBatchingEngine:
             "serving/kv_quant_scale_pool_bytes")
         self._g_state_bytes = self.metrics.gauge(
             "serving/state_pool_bytes")
+        self._g_window_bytes = self.metrics.gauge(
+            "serving/window_pool_bytes")
         # the model's own pass counters (cache_spec.StepCounters)
         self._c_model = {n: self.metrics.counter("serving/" + n)
                          for n in self._counter_names}
@@ -1117,11 +1145,11 @@ class ContinuousBatchingEngine:
         whatever prefix landed. A payload of another version than
         :data:`KV_PAYLOAD_VERSION` lands nothing and the result says so
         under ``"refused"``. Returns import counts."""
-        if self._has_state:
+        if self._slot_owned:
             raise ValueError(
-                "import_migration lands shipped prompt pages; this model "
-                "keeps per-slot recurrent state beside its pages, which "
-                "no migration carries yet (requeue() replays the tokens)")
+                f"import_migration lands shipped prompt pages; this model "
+                f"keeps {self._slot_owned} beside its pages, which no "
+                f"migration carries yet (requeue() replays the tokens)")
         imported = dedup = rejected = 0
         pending = []          # (page, [per-pool np page content])
         refused = None
@@ -1589,6 +1617,7 @@ class ContinuousBatchingEngine:
         cpool = self._counter_pool
         kinds = tuple(self._pool_kinds)
         G = self._group
+        R, MP = self._ring, self.pages_per_slot
 
         def ustep(ids_t, nq_t, last_t, tgt_t, rows_t, nrows_t, tok_t,
                   ctx_t, act_t, tbl_t, lim_t, eos_t, key_t, *pools):
@@ -1614,6 +1643,15 @@ class ContinuousBatchingEngine:
                 # stale instant-eos guard: a slot whose last token is
                 # its stop token does not decode
                 act = act & ((eos_arr < 0) | (tok != eos_arr))
+                # the window layers' table (cache_spec.WindowKV): logical
+                # page j of slot s is page 1 + s * R + j % R of its ring
+                ring = 1 + R * jnp.arange(b, dtype=jnp.int32)[:, None] \
+                    + jnp.arange(MP, dtype=jnp.int32)[None, :] % R \
+                    if R else None
+
+                def tables(rows_tbl, gate, rows_ring):
+                    return (Tensor(rows_tbl), Tensor(gate)) \
+                        + ((Tensor(rows_ring),) if R else ())
 
                 def group(g, carry):
                     tok_c, ctx_c, fire_c, act_c, key_c, leaves = carry
@@ -1628,7 +1666,8 @@ class ContinuousBatchingEngine:
                             caches=[Tensor(a[at] if k == "state" else a)
                                     for k, a in zip(kinds, leaves)],
                             pos=Tensor(ctx_g[:, None]),
-                            tables=(Tensor(tbl[at]), Tensor(nq_g)),
+                            tables=tables(tbl[at], nq_g,
+                                          ring[at] if R else None),
                             logits_at=Tensor(jnp.maximum(nq_g - 1, 0)))
                     sampled, key_c = sample(logits._data[:, 0], key_c)
                     fire_g = real & last[at]
@@ -1661,7 +1700,7 @@ class ContinuousBatchingEngine:
                             Tensor(tok_c.reshape(b, 1)),
                             caches=[Tensor(a) for a in leaves],
                             pos=Tensor(ctx_c[:, None]),
-                            tables=(Tensor(tbl), Tensor(act_c)))
+                            tables=tables(tbl, act_c, ring))
                     nx, key_c = sample(lgs[:, -1]._data, key_c)
                     ctx_n = ctx_c + act_c.astype(jnp.int32)
                     nx = jnp.where(act_c, nx, tok_c)
@@ -2261,6 +2300,7 @@ class ContinuousBatchingEngine:
             # and whatever the model counts per pass
             "kv_pool_bytes": int(self._kv_pool_bytes),
             "state_pool_bytes": int(self._state_pool_bytes),
+            "window_pool_bytes": int(self._window_pool_bytes),
             **{n: int(c.value) for n, c in self._c_model.items()},
         }
 
@@ -2291,6 +2331,7 @@ class ContinuousBatchingEngine:
         self._g_kvq_pool_bytes.set(int(self._kv_pool_bytes))
         self._g_kvq_scale_bytes.set(int(self._kv_scale_pool_bytes))
         self._g_state_bytes.set(int(self._state_pool_bytes))
+        self._g_window_bytes.set(int(self._window_pool_bytes))
         from ..profiler.trace import get_tracer
         tr = get_tracer()
         if tr.enabled:

@@ -155,13 +155,16 @@ def rope_with_offset(t, pos, max_pos, theta):
 
 
 def _paged_attention_step(attn, q, k, v, cache, pos, tables, rope=True,
-                          proj=None):
+                          proj=None, window=None):
     """Continuous-batching step over the PAGED pool, shared by the
     Llama/Qwen2/GPT2 attention layers: per-slot positions (mixed-length
     streams), trash-page routing for drained slots (serving engine
     path). ``attn`` supplies head geometry; rope=False for learned-
     position models; ``proj`` overrides the output projection
-    (defaults to attn.o_proj).
+    (defaults to attn.o_proj). ``window``: a layer that attends its
+    ``window`` newest keys only (``cache_spec.WindowKV``); its pools are
+    per-slot rings addressed through ``tables[2]``, the ring table the
+    engine hands a model whose spec has such a layer.
 
     ``tables`` is ``(block_tables, gate)``. The gate is per-slot
     validity: a boolean active mask (decode convention) or an int32
@@ -181,7 +184,9 @@ def _paged_attention_step(attn, q, k, v, cache, pos, tables, rope=True,
     / dequant-in-kernel pair instead; the quant mode rides the pool
     dtype, so this compiles the same single program shape per mode."""
     b, s = q.shape[0], q.shape[1]
-    tbl, gate = tables
+    tbl, gate = tables[:2]
+    if window is not None:
+        tbl = tables[2]
     if rope:
         q = rope_with_offset(q, pos, attn.cfg.max_position_embeddings,
                              attn.cfg.rope_theta)
@@ -197,7 +202,7 @@ def _paged_attention_step(attn, q, k, v, cache, pos, tables, rope=True,
                 kpa, vpa, ksa, vsa, ka, va, tba, ct, valid)
             out = PA.ragged_paged_attention(qa, kpa, vpa, tba, ct,
                                             valid, k_scales=ksa,
-                                            v_scales=vsa)
+                                            v_scales=vsa, window=window)
             return out, kpa, vpa, ksa, vsa
 
         ctx_out, kp2, vp2, ks2, vs2 = apply(
@@ -213,7 +218,7 @@ def _paged_attention_step(attn, q, k, v, cache, pos, tables, rope=True,
             kpa, vpa = PA.paged_prefill_write(kpa, vpa, ka, va, tba,
                                               ct, valid)
             out = PA.ragged_paged_attention(qa, kpa, vpa, tba, ct,
-                                            valid)
+                                            valid, window=window)
             return out, kpa, vpa
 
         ctx_out, kp2, vp2 = apply(

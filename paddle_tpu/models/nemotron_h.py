@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..framework.core import Parameter, apply
+from ..framework.core import apply
 from ..generation import GenerationMixin
 from ..inference.cache_spec import PagedKV, SlotState, StepCounters
 from ..nn import functional as F
@@ -42,6 +42,7 @@ from ..ops import mamba2 as ssd
 from ..ops import manipulation as M
 from ..ops import moe as moe_ops
 from ..profiler import metrics as _pmetrics
+from ._leaves import _Base, _Weight
 from .llama import _hidden_at, _paged_attention_step
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM"]
@@ -56,18 +57,7 @@ SSM_STATE_DTYPE = "float32"
 
 #: what a pass through the model counts (``StepCounters``); the vocabulary
 #: is this model's, so it is declared here and not in the engine
-COUNTERS = ("moe_tokens", "moe_local_pairs", "moe_max_expert_pairs",
-            "state_resets")
-_pmetrics.declare("serving/moe_tokens", "counter",
-                  "token-layer passes through an expert layer (valid "
-                  "tokens x expert layers), from the step program")
-_pmetrics.declare("serving/moe_local_pairs", "counter",
-                  "(token, expert) pairs whose expert this engine's "
-                  "model holds: what its grouped matmuls computed")
-_pmetrics.declare("serving/moe_max_expert_pairs", "counter",
-                  "pairs of the busiest held expert, summed over "
-                  "expert-layer passes (against moe_local_pairs / held "
-                  "experts: the load imbalance)")
+COUNTERS = moe_ops.HELD_COUNTERS + ("state_resets",)
 _pmetrics.declare("serving/state_resets", "counter",
                   "slots that started a pass from zero recurrent state "
                   "(a new or replayed request's first prompt chunk)")
@@ -156,41 +146,8 @@ class NemotronHConfig:
     @property
     def held(self):
         """(first, count) of the routed experts held here."""
-        n = self.n_routed_experts if self.n_routed_experts_held is None \
-            else int(self.n_routed_experts_held)
-        first = int(self.first_held_expert)
-        if not 0 <= first <= first + n <= self.n_routed_experts:
-            raise ValueError(
-                f"held experts [{first}, {first + n}) are not inside the "
-                f"router's {self.n_routed_experts}")
-        return first, n
-
-
-class _Base(nn.Layer):
-    """Parameters in the configuration's dtype, Normal(0, range) unless a
-    leaf says otherwise."""
-
-    def __init__(self, cfg):
-        super().__init__(dtype=cfg.dtype)
-        self.cfg = cfg
-
-    def _p(self, shape, init=None):
-        if self.cfg.empty_init:
-            data = jnp.zeros(tuple(shape), self._dtype)
-            data.delete()
-            return Parameter(data)
-        return self.create_parameter(
-            list(shape), default_initializer=init
-            or I.Normal(0.0, self.cfg.initializer_range))
-
-
-class _Weight(_Base):
-    """One leaf named ``.weight``: a bias-free projection [in, out], a
-    table, or (with ``init``) a norm scale."""
-
-    def __init__(self, cfg, *shape, init=None):
-        super().__init__(cfg)
-        self.weight = self._p(shape, init)
+        return moe_ops.held_range(self.first_held_expert,
+                          self.n_routed_experts_held, self.n_routed_experts)
 
 
 class _Norm(_Weight):

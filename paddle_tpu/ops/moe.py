@@ -30,10 +30,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..profiler import metrics as _pmetrics
+
 __all__ = ["top_k_gating", "top_k_gating_idx", "moe_dispatch_combine",
            "moe_ffn_grouped", "moe_forward", "moe_forward_ep",
            "sort_rows_by_expert", "moe_forward_dropless", "moe_ablation",
-           "top_k_weights", "sigmoid_top_k_router", "moe_experts_held"]
+           "top_k_weights", "sigmoid_top_k_router", "moe_experts_held",
+           "held_range"]
 
 
 # -- section ablation (profiler.breakdown step-attribution harness) --------
@@ -381,16 +384,49 @@ def moe_forward_dropless(x, router_w, w_gate, w_up, w_down, k=2,
     return jnp.sum(y_k * w, axis=1).astype(x.dtype), aux, z
 
 
-def moe_experts_held(v, idx, weights, w1, w2, first, valid=None, bm=None):
+#: what an expert layer over :func:`moe_experts_held` counts per pass
+#: (``inference.cache_spec.StepCounters`` names; declared here, once, for
+#: every model that has such a layer): the layer's valid tokens, then the
+#: function's two stats
+HELD_COUNTERS = ("moe_tokens", "moe_local_pairs", "moe_max_expert_pairs")
+_pmetrics.declare("serving/moe_tokens", "counter",
+                  "token-layer passes through an expert layer (valid "
+                  "tokens x expert layers), from the step program")
+_pmetrics.declare("serving/moe_local_pairs", "counter",
+                  "(token, expert) pairs whose expert this engine's "
+                  "model holds: what its grouped matmuls computed")
+_pmetrics.declare("serving/moe_max_expert_pairs", "counter",
+                  "pairs of the busiest held expert, summed over "
+                  "expert-layer passes (against moe_local_pairs / held "
+                  "experts: the load imbalance)")
+
+
+def held_range(first, held, total):
+    """(first, count) of the routed experts an instance holds of a router
+    ``total`` wide — :func:`moe_experts_held`'s ``first`` and the leading
+    axis of its expert matrices; ``held`` None = all of them."""
+    n = total if held is None else int(held)
+    first = int(first)
+    if not 0 <= first <= first + n <= total:
+        raise ValueError(f"held experts [{first}, {first + n}) are not "
+                         f"inside the router's {total}")
+    return first, n
+
+
+def moe_experts_held(v, idx, weights, w1, w2, first, valid=None, bm=None,
+                     w_gate=None):
     """The routed part of an expert layer that HOLDS experts
     ``[first, first + E_held)`` of a larger routed set: of the ``[T, k]``
     pairs the router chose over ALL experts, only those whose expert is
     held here (and whose token is ``valid``) are sorted, sent through
-    two grouped matmuls with a squared ReLU between (no gate matrix) and
-    summed with the router's weights — dropless: every held pair is
-    computed. The other pairs belong to other holders, whose outputs add
-    to this one's (everything after the selection is linear in the
-    experts' sum).
+    the experts' grouped matmuls and summed with the router's weights —
+    dropless: every held pair is computed. The other pairs belong to
+    other holders, whose outputs add to this one's (everything after the
+    selection is linear in the experts' sum).
+
+    The experts: with ``w_gate`` [E_held, d, h] SwiGLU,
+    ``(silu(x W_gate) * (x W1)) W2`` — three grouped matmuls over the same
+    tiles; without it ``relu(x W1)^2 W2``, two.
 
     v [T, d] tokens (a latent, or the hidden state), idx [T, k] global
     expert ids, weights [T, k], w1 [E_held, d, h], w2 [E_held, h, d].
@@ -419,7 +455,14 @@ def moe_experts_held(v, idx, weights, w1, w2, first, valid=None, bm=None):
     src = jnp.full((P,), T, jnp.int32).at[perm].set(
         jnp.arange(T * k, dtype=jnp.int32) // k)
     v_pad = jnp.concatenate([v, jnp.zeros((1, d), v.dtype)], axis=0)
-    a = grouped_matmul_live(v_pad[src], w1, tile_gid, n_live, act="relu2")
+    x_p = v_pad[src]
+    if w_gate is None:
+        a = grouped_matmul_live(x_p, w1, tile_gid, n_live, act="relu2")
+    else:
+        # rows of dead tiles hold whatever was there; nothing reads them
+        a = (jax.nn.silu(grouped_matmul_live(x_p, w_gate, tile_gid, n_live))
+             * grouped_matmul_live(x_p, w1, tile_gid, n_live)
+             ).astype(v.dtype)
     y_p = grouped_matmul_live(a, w2, tile_gid, n_live)
     y_k = y_p[perm].reshape(T, k, d)
     # where, not a product: rows of dead tiles were never written

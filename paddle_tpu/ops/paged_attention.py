@@ -159,7 +159,7 @@ def paged_attention(q, key_pages, value_pages, block_tables, context_lens,
 def paged_prefill_attention_reference(q, key_pages, value_pages,
                                       block_tables, context_lens,
                                       scale=None, k_scales=None,
-                                      v_scales=None):
+                                      v_scales=None, window=None):
     """Pure-jnp oracle for CHUNKED prefill over the page pool.
 
     q: [B, C, H, D] — C query tokens per sequence whose k/v have already
@@ -179,6 +179,9 @@ def paged_prefill_attention_reference(q, key_pages, value_pages,
     after the gather — the same block-table indirection, so trash-page
     routing and page sharing compose unchanged — and the output is cast
     back to q's dtype.
+
+    ``window`` (static int or None): query token j sees the ``window``
+    newest positions ``<= ctx + j`` only — its own among them.
     """
     b, c, h, d = q.shape
     _, page_size, width = key_pages.shape
@@ -198,8 +201,11 @@ def paged_prefill_attention_reference(q, key_pages, value_pages,
         v = jnp.repeat(v, rep, axis=0)
         logits = jnp.einsum("chd,hkd->chk", qi, k,
                             preferred_element_type=jnp.float32) * s
-        allow = (jnp.arange(max_len)[None, :]
-                 <= (ctx_len + jnp.arange(c))[:, None])   # [C, max_len]
+        q_pos = (ctx_len + jnp.arange(c))[:, None]
+        k_pos = jnp.arange(max_len)[None, :]
+        allow = k_pos <= q_pos                            # [C, max_len]
+        if window is not None:
+            allow = allow & (k_pos > q_pos - window)
         logits = jnp.where(allow[:, None, :], logits, _NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
         return jnp.einsum("chk,hkd->chd", probs, v)
@@ -225,7 +231,7 @@ def paged_prefill_attention(q, key_pages, value_pages, block_tables,
 def ragged_paged_attention_reference(q, key_pages, value_pages,
                                      block_tables, ctx_lens, lengths,
                                      scale=None, k_scales=None,
-                                     v_scales=None):
+                                     v_scales=None, window=None):
     """Pure-jnp oracle for the RAGGED mixed prefill+decode batching
     step: q [B, C, H, D] is the uniform-stride view of the flattened
     token stream (slot b's tokens are the ``[start=b*C, length=
@@ -243,21 +249,23 @@ def ragged_paged_attention_reference(q, key_pages, value_pages,
     c = q.shape[1]
     out = paged_prefill_attention_reference(
         q, key_pages, value_pages, block_tables, ctx_lens, scale,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, window=window)
     valid = jnp.arange(c)[None, :] < lengths[:, None]      # [B, C]
     return jnp.where(valid[:, :, None, None], out, 0).astype(out.dtype)
 
 
 def ragged_paged_attention(q, key_pages, value_pages, block_tables,
                            ctx_lens, lengths, scale=None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, window=None):
     """Mixed prefill+decode paged attention — the serving engine's ONE
     attention entry point (PAPERS.md ragged-paged-attention). Pallas
     kernel on TPU (``FLAGS_use_pallas_ragged_attention``), jnp oracle
     elsewhere; the kernel module itself always runs (interpret mode)
     in the parity tests, the flash_attention discipline. The path is a
     rule on platform + flag: a kernel that fails on TPU raises, it is
-    never swapped for the oracle."""
+    never swapped for the oracle. ``window`` (static): a layer whose
+    queries see their ``window`` newest keys only; the kernel then
+    starts at the window's first block."""
     from ..framework import flags
     platform = jax.devices()[0].platform
     use_kernel = (platform == "tpu"
@@ -268,10 +276,10 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
             ragged_paged_attention as _kernel)
         return _kernel(q, key_pages, value_pages, block_tables,
                        ctx_lens, lengths, scale,
-                       k_scales=k_scales, v_scales=v_scales)
+                       k_scales=k_scales, v_scales=v_scales, window=window)
     return ragged_paged_attention_reference(
         q, key_pages, value_pages, block_tables, ctx_lens, lengths,
-        scale, k_scales=k_scales, v_scales=v_scales)
+        scale, k_scales=k_scales, v_scales=v_scales, window=window)
 
 
 def _chunk_rows(pool, block_tables, ctx, valid, c):

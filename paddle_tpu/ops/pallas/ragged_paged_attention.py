@@ -17,7 +17,12 @@ k/v were already written into the paged pool at cache positions
 padding rides the reserved trash page 0), and query token ``j`` of
 sequence ``b`` attends every cache position ``<= ctx[b] + j`` — full
 paged history behind it, causal within the chunk. Rows ``j >=
-lengths[b]`` output zeros.
+lengths[b]`` output zeros. With a static ``window`` (a layer that
+attends its ``window`` newest keys) query ``j`` sees positions ``ctx[b]
++ j - window + 1 .. ctx[b] + j`` only, and the loop over K/V blocks
+STARTS at the block that holds the oldest key the q block's first query
+sees (:func:`first_kv_block`): what left the window is never copied.
+``window=None`` compiles the program without one.
 
 Kernel structure (the jax paged-attention decode kernel's scalar-
 prefetch idiom, generalized to ragged multi-token queries):
@@ -57,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ._utils import interpret_mode as _interpret, no_x64 as _no_x64
 
 __all__ = ["ragged_paged_attention", "force_ragged_blocks",
-           "ragged_attention_cost"]
+           "ragged_attention_cost", "first_kv_block"]
 
 _NEG_INF = -1e30
 
@@ -126,9 +131,21 @@ def _resolve_blocks(c, pages_per_seq, page, d, dtype, quant=False):
     return qb, g
 
 
+def first_kv_block(ctx, q_start, window, bk):
+    """The first K/V block (of ``bk`` keys) a q block reads whose first
+    token is chunk token ``q_start`` of a sequence with ``ctx`` cached
+    tokens: the one that holds key ``ctx + q_start - window + 1``, the
+    oldest its first query sees. THE rule of a window layer's reads: the
+    kernel's loop, its first copy and the scales it gathers all start
+    there. Without a window, block 0."""
+    if window is None:
+        return 0
+    return jnp.maximum(ctx + q_start - (window - 1), 0) // bk
+
+
 def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
                    v_hbm_ref, *rest, scale, page, q_block, rep, g_pages,
-                   pages_per_seq, quant):
+                   pages_per_seq, quant, window):
     """One program: (kv head h, sequence b, q block qi). Streams the
     sequence's pages through the double-buffered VMEM scratch and
     accumulates an online softmax over them.
@@ -189,8 +206,11 @@ def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
         # valid token at chunk offset min(q_start + q_block, length) - 1
         n_kv = ctx + jnp.minimum(q_start + q_block, length)
         n_blocks = (n_kv + bk - 1) // bk
+        # a window layer's loop starts at the block of the oldest key its
+        # first query sees; the blocks before it are never copied
+        i0 = first_kv_block(ctx, q_start, window, bk)
 
-        for c in dma_block(0, 0):
+        for c in dma_block(i0, 0 if window is None else jax.lax.rem(i0, 2)):
             c.start()
 
         q2 = q_ref[...].astype(jnp.float32) * scale      # [rows, d]
@@ -227,6 +247,11 @@ def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
             # causal over the paged history + the row-validity mask
             # (rows past `length` stay fully masked -> zero output)
             valid = ((col + (i * bk - ctx - q_start)) * rep <= row) & row_ok
+            if window is not None:
+                # k_pos > q_pos - window, division-free like the above
+                # (x > r // rep  <=>  x * rep > r)
+                valid = valid & ((col + (i * bk - ctx - q_start + window))
+                                 * rep > row)
             s = jnp.where(valid, s, _NEG_INF)
             m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
@@ -243,7 +268,7 @@ def _ragged_kernel(ctx_ref, len_ref, tbl_ref, q_ref, k_hbm_ref,
         acc0 = jnp.zeros((rows, d), jnp.float32)
         m0 = jnp.full((rows, 1), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((rows, 1), jnp.float32)
-        acc, m, l = jax.lax.fori_loop(0, n_blocks, body, (acc0, m0, l0))
+        acc, m, l = jax.lax.fori_loop(i0, n_blocks, body, (acc0, m0, l0))
         o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -281,7 +306,7 @@ def _block_scales(scales, block_tables, g, page):
 def ragged_paged_attention(q, key_pages, value_pages, block_tables,
                            ctx_lens, lengths, scale=None, q_block=None,
                            kv_pages_per_block=None, k_scales=None,
-                           v_scales=None):
+                           v_scales=None, window=None):
     """Mixed prefill+decode paged attention over the flattened token
     stream (uniform-stride view).
 
@@ -297,6 +322,10 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
     k_scales /   optional [num_pages, KVH, page_size] f32 page-parallel
     v_scales     scales pools — when given, the data pools are int8/fp8
                  and the kernel applies the scales in VMEM
+    window       optional static int: query token j sees keys ``ctx + j -
+                 window + 1 .. ctx + j`` only, and the loop over K/V
+                 blocks starts at :func:`first_kv_block`; None compiles
+                 the program without a window
     Returns [B, C, H, D].
     """
     b, c, h, d = q.shape
@@ -344,7 +373,8 @@ def ragged_paged_attention(q, key_pages, value_pages, block_tables,
         out = pl.pallas_call(
             functools.partial(
                 _ragged_kernel, scale=s, page=page, q_block=qb, rep=rep,
-                g_pages=g, pages_per_seq=pages_per_seq, quant=quant),
+                g_pages=g, pages_per_seq=pages_per_seq, quant=quant,
+                window=None if window is None else int(window)),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,   # ctx, lengths, block tables
                 grid=grid,

@@ -157,13 +157,21 @@ def _compile_serving_step(one_chip, topo, monkeypatch, kvh, kv_quant):
         m.setattr(initializer.Normal, "__call__", no_storage)
         model = Qwen2ForCausalLM(cfg)
     model.eval()
-    params = list(model.parameters())
-    for p in params:
+    for p in model.parameters():
         if p._data.dtype != BF16:              # the norm scales
             p._data = p._data.astype(BF16)
     eng = ContinuousBatchingEngine(model, num_slots=64, max_len=2048,
                                    page_size=16, greedy=True,
                                    kv_quant=kv_quant)
+    return _lower_step(model, eng, one_chip, topo, monkeypatch) + (eng,)
+
+
+def _lower_step(model, eng, one_chip, topo, monkeypatch):
+    """(lowered, compiled text) of ``eng``'s unified step program for the
+    described chip, the model's parameters as shapes."""
+    import paddle_tpu as paddle
+    from paddle_tpu.framework.core import Tensor
+    params = list(model.parameters())
     assert (eng._group, eng.prefill_chunk, eng.decode_chunk) == (8, 128, 16)
     ustep = eng._unified_static().function
     s = _sds(one_chip)
@@ -193,12 +201,13 @@ def _compile_serving_step(one_chip, topo, monkeypatch, kvh, kv_quant):
 
     lowered = jax.jit(step).lower(
         [s(tuple(p.shape), BF16) for p in params], *args)
-    return lowered, lowered.compile().as_text(), eng
+    return lowered, lowered.compile().as_text()
 
 
 def _pool_copies(text, eng):
     """Pool-shaped ``copy`` ops of a compiled step, per pool kind
-    (``"kv"`` data pools, ``"scale"`` pools): ``{kind: (in the whole
+    (``"kv"`` data pools, ``"scale"`` pools, ``"wkv"`` window rings):
+    ``{kind: (in the whole
     program, inside a ``while`` body or anything a body calls)}``. A
     pool that the write and the kernel hold in two layouts shows here as
     one copy per pool per pass."""
@@ -208,7 +217,7 @@ def _pool_copies(text, eng):
                            ",".join(map(str, sh))): kind
                for sh, dt, kind in zip(eng._pool_shapes, eng._pool_dtypes,
                                        eng._pool_kinds)
-               if kind in ("kv", "scale")}
+               if kind in ("kv", "scale", "wkv")}
     comps, cur = {}, None
     for line in text.splitlines():
         m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
@@ -266,6 +275,43 @@ def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
     copies = _pool_copies(text, eng)
     assert all(in_loops == 0 for _, in_loops in copies.values()), copies
     assert copies["kv"][0] <= eng._pool_kinds.count("kv"), copies
+
+
+def test_serving_step_program_at_k_exaone_widths(native, one_chip, topo,
+                                                 monkeypatch):
+    """The ONE step program of a 2-layer model at K-EXAONE's widths
+    (hidden 6144, 64 / 8 heads x 128, 16 held experts of 128 x 2048): a
+    window layer over per-slot rings beside a global one over host-managed
+    pages, both sparse, 64 slots x 5120. It compiles for the described
+    chip with the kernel in both loops, the window pool is sized by the
+    window — 17 pages a slot — and no pool, ring or global, is re-laid out
+    inside a loop."""
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.models import ExaoneMoeConfig, ExaoneMoeForCausalLM
+    cfg = ExaoneMoeConfig.k_exaone_236b()
+    cfg.num_hidden_layers, cfg.vocab_size = 2, 19200
+    cfg.layer_types = ("sliding_attention", "full_attention")
+    cfg.mlp_layer_types = ("sparse", "sparse")
+    cfg.num_experts_held = 16
+    cfg.dtype, cfg.empty_init = "bfloat16", True
+    model = ExaoneMoeForCausalLM(cfg)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, num_slots=64, max_len=5120,
+                                   page_size=16, greedy=True)
+    assert eng._ring == 17
+    assert [tuple(p._data.shape) for p in eng.pools] \
+        == [(64 * 17 + 1, 16, 1024)] * 2 + [(64 * 320 + 1, 16, 1024)] * 2 \
+        + [(3,)]
+    lowered, text = _lower_step(model, eng, one_chip, topo, monkeypatch)
+    # the group loop and the micro-step scan, and inside each the expert
+    # sort's searchsorted (a while of its own)
+    assert lowered.as_text().count("stablehlo.while") == 4
+    assert "ragged_paged_attention" in text and "grouped_matmul" in text
+    for shape in ("bf16[8,8,1024,128]", "bf16[64,8,8,128]"):
+        assert shape in text           # the kernel at 8 rows, and at 64
+    copies = _pool_copies(text, eng)
+    assert set(copies) == {"kv", "wkv"}
+    assert all(in_loops == 0 for _, in_loops in copies.values()), copies
 
 
 def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):

@@ -126,6 +126,65 @@ def test_the_held_quarters_add_up_to_the_whole_layer(bm):
     assert not np.asarray(total)[20:].any()
 
 
+def _gated_layer(v, idx, w, wg, w1, w2, lo, hi, valid):
+    out = np.zeros(v.shape, np.float64)
+    f64 = lambda a: np.asarray(a, np.float64)
+    for t in range(v.shape[0]):
+        if not valid[t]:
+            continue
+        for j in range(idx.shape[1]):
+            e = int(idx[t, j])
+            if lo <= e < hi:
+                gate = f64(v[t]) @ f64(wg[e - lo])
+                a = gate / (1.0 + np.exp(-gate)) * (f64(v[t]) @ f64(w1[e - lo]))
+                out[t] += float(w[t, j]) * (a @ f64(w2[e - lo]))
+    return out
+
+
+@pytest.mark.parametrize("bm", [None, 16])
+def test_gated_held_experts_are_the_dense_loop(bm):
+    """With a gate matrix the held experts are SwiGLU, ``(silu(x Wg) *
+    (x W1)) W2``: against a float64 loop over the pairs, a holder of 4 of
+    16 experts, padded tokens adding nothing; the same call without the
+    gate is still the squared-ReLU pair."""
+    T, d, h, E, k, lo = 24, 128, 256, 16, 5, 8
+    v = _rand(4, T, d)
+    wg, w1, w2 = _rand(12, 4, d, h, scale=.1), _rand(5, 4, d, h, scale=.1), \
+        _rand(6, 4, h, d, scale=.1)
+    idx, w = moe.sigmoid_top_k_router(_rand(7, T, E), _rand(8, E, scale=.5),
+                                      k, True, 2.5)
+    valid = jnp.arange(T) < 20
+    out, st = jax.jit(lambda *a: moe.moe_experts_held(
+        *a, lo, valid=valid, bm=bm, w_gate=wg))(v, idx, w, w1, w2)
+    # float32 kernel against a float64 loop over sums of 256 terms
+    np.testing.assert_allclose(
+        np.asarray(out), _gated_layer(v, idx, w, wg, w1, w2, lo, lo + 4,
+                                      valid), atol=2e-4, rtol=1e-4)
+    held = (np.asarray(idx) >= lo) & (np.asarray(idx) < lo + 4) \
+        & np.asarray(valid)[:, None]
+    assert int(st[0]) == held.sum() > 0
+    plain, st2 = moe.moe_experts_held(v, idx, w, w1, w2, lo, valid=valid,
+                                      bm=bm)
+    full1, full2 = jnp.zeros((E, d, h)).at[lo:lo + 4].set(w1), \
+        jnp.zeros((E, h, d)).at[lo:lo + 4].set(w2)
+    np.testing.assert_allclose(
+        np.asarray(plain), _dense_layer(v, idx, w, full1, full2, lo, lo + 4,
+                                        valid), atol=2e-4, rtol=1e-4)
+    assert list(np.asarray(st2)) == list(np.asarray(st))
+
+
+def test_the_held_counters_are_declared_once_where_the_function_lives():
+    from paddle_tpu.profiler import metrics
+    cat = metrics.catalog()
+    assert moe.HELD_COUNTERS == ("moe_tokens", "moe_local_pairs",
+                                 "moe_max_expert_pairs")
+    assert all(cat["serving/" + n][0] == "counter"
+               for n in moe.HELD_COUNTERS)
+    from paddle_tpu.models import exaone_moe, nemotron_h
+    assert nemotron_h.COUNTERS[:3] == exaone_moe.COUNTERS \
+        == moe.HELD_COUNTERS
+
+
 def test_a_holder_that_is_sent_nothing_returns_zero():
     T, d, h = 8, 128, 128
     v, w1, w2 = _rand(9, T, d), _rand(10, 2, d, h), _rand(11, 2, h, d)

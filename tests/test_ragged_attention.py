@@ -253,3 +253,92 @@ def test_bf16_pool_gqa_wide_case():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+# ---- a window: the loop starts at the window's first block ---------------------
+
+def _window_case(rep, C, ctx, lens, window, seed=0, **blocks):
+    rng = np.random.RandomState(seed)
+    B, KVH, D, page, P = len(ctx), 2, 16, 4, 24
+    kp, vp, tables = _pool_case(rng, B, KVH, D, page, P, B * P + 3)
+    q = rng.randn(B, C, KVH * rep, D).astype("float32")
+    args = [jnp.asarray(a) for a in (q, kp, vp, tables,
+                                     np.asarray(ctx, "int32"),
+                                     np.asarray(lens, "int32"))]
+    out = kernel(*args, window=window, **blocks)
+    ref = ragged_paged_attention_reference(*args, window=window)
+    return np.asarray(out), np.asarray(ref), args
+
+
+@pytest.mark.parametrize("rep", [1, 8])
+@pytest.mark.parametrize("C,lens", [(1, [1, 1, 1, 0, 1]),
+                                    (8, [8, 3, 8, 0, 1])])
+def test_window_kernel_matches_oracle(rep, C, lens):
+    """Decode and chunk shapes, GQA 1 and 8, with contexts below, at and
+    far above the window of 8 (and an idle slot): the kernel that STARTS
+    at the window's first block equals the oracle that masks."""
+    ctx = [3, 7, 8, 30, 77]
+    out, ref, _ = _window_case(rep, C, ctx, lens, window=8)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("qb,g", [(1, 1), (2, 2), (4, 8), (8, 3)])
+def test_window_is_block_size_invariant(qb, g):
+    """Whatever the q block and the pages per K/V block, so wherever the
+    first block falls against the window's first key."""
+    out, ref, _ = _window_case(4, 8, [5, 19, 61], [8, 5, 8], window=8,
+                               q_block=qb, kv_pages_per_block=g)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+def test_a_window_wider_than_every_context_is_no_window():
+    out, _, args = _window_case(4, 8, [5, 19, 61], [8, 5, 8], window=1000)
+    np.testing.assert_array_equal(out, np.asarray(kernel(*args)))
+
+
+def test_window_none_is_todays_output_and_a_window_changes_it():
+    """``window=None`` is the call every other model makes: the same
+    arrays as the call without the argument; the oracle likewise."""
+    _, _, args = _window_case(4, 8, [5, 19, 61], [8, 5, 8], window=None)
+    np.testing.assert_array_equal(np.asarray(kernel(*args, window=None)),
+                                  np.asarray(kernel(*args)))
+    np.testing.assert_array_equal(
+        np.asarray(ragged_paged_attention_reference(*args, window=None)),
+        np.asarray(ragged_paged_attention_reference(*args)))
+    assert np.abs(np.asarray(kernel(*args, window=8))
+                  - np.asarray(kernel(*args))).max() > 1e-3
+
+
+def test_a_key_that_left_the_window_is_never_read():
+    """Pages wholly before the window's first block hold NaN: a kernel
+    that walked them would poison the softmax; the one that starts at the
+    first block never copies them."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import first_kv_block
+    rng = np.random.RandomState(3)
+    B, KVH, D, page, P, C, g = 2, 2, 16, 4, 24, 4, 2
+    kp, vp, tables = _pool_case(rng, B, KVH, D, page, P, B * P + 3)
+    ctx, lens = np.array([50, 71], "int32"), np.array([4, 1], "int32")
+    q = rng.randn(B, C, 4, D).astype("float32")
+    want = ragged_paged_attention_reference(
+        *[jnp.asarray(a) for a in (q, kp, vp, tables, ctx, lens)], window=8)
+    for b in range(B):
+        dead = int(first_kv_block(ctx[b], 0, 8, g * page)) * g
+        assert dead > 0
+        kp[tables[b, :dead]] = np.nan
+        vp[tables[b, :dead]] = np.nan
+    out = kernel(*[jnp.asarray(a) for a in (q, kp, vp, tables, ctx, lens)],
+                 window=8, q_block=4, kv_pages_per_block=g)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_first_kv_block_holds_the_windows_oldest_key():
+    """Blocks of 8 keys, window 8: a decode token at context 0 / 30 / 100
+    sees keys 0..0 / 23..30 / 93..100, whose oldest lies in block 0 / 2 /
+    11; the fifth query of a chunk on 30 tokens sees 27..34: block 3.
+    Without a window, block 0 whatever the context."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import first_kv_block
+    ctx = jnp.asarray([0, 30, 100])
+    assert np.asarray(first_kv_block(ctx, 0, 8, 8)).tolist() == [0, 2, 11]
+    assert int(first_kv_block(jnp.asarray(30), 4, 8, 8)) == 3
+    assert first_kv_block(ctx, 0, None, 8) == 0
