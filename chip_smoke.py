@@ -177,14 +177,17 @@ def phase_device(dev, n_chips):
 
 # ---- phase: kernel-vs-oracle parity on the chip ---------------------------
 
-def _ragged_case(rng, b, c, h, kvh, d, page, pps, quant):
+def _ragged_case(rng, b, c, h, kvh, d, page, pps, quant, ring=0):
     """Seeded pools + a mixed batch (full prefill chunk, continuing
-    prefill, decode steps, an idle slot) for the ragged kernel."""
+    prefill, decode steps, an idle slot) for the ragged kernel. ``ring``:
+    a window layer's pools — a slot's table cycles through its own
+    ``ring`` pages, as the serving step builds it."""
     import jax.numpy as jnp
-    n_pages = b * pps + 1
+    n_pages = b * (ring or pps) + 1
     q = jnp.asarray(rng.randn(b, c, h, d), jnp.bfloat16)
     tables = jnp.asarray(
-        1 + rng.permutation(b * pps).reshape(b, pps), jnp.int32)
+        1 + ring * np.arange(b)[:, None] + np.arange(pps)[None, :] % ring
+        if ring else 1 + rng.permutation(b * pps).reshape(b, pps), jnp.int32)
     max_ctx = pps * page - c
     plan = [(0, c), (min(c, max_ctx), max(c // 2, 1)), (max_ctx, 1),
             (0, 0), (17, 1), (min(2 * page, max_ctx), max(c // 3, 1)),
@@ -216,7 +219,6 @@ def phase_kernels(serve, train, seed, tol=2.0 ** -6):
     import jax.numpy as jnp
 
     from paddle_tpu.nn.functional.attention import sdpa_reference
-    from paddle_tpu.ops import paged_attention as PA
     from paddle_tpu.ops.pallas import ce_chunk, rms_norm as RN
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.ragged_paged_attention import (
@@ -254,21 +256,30 @@ def phase_kernels(serve, train, seed, tol=2.0 ** -6):
         errs[name] = max(e)
 
     # serving: the engine's mixed pass (C = prefill chunk) and its
-    # in-program decode micro-steps (C = 1), plain and int8 pools
+    # in-program decode micro-steps (C = 1), plain and int8 pools — at
+    # the smoke's own shape, then at the benchmark cells': a prefill
+    # group of 8 rows and a decode step of 64 slots of every kind of
+    # attention layer they run (``window``: over a slot's ring of pages)
     s = serve
-    for quant in (False, True):
-        for c in (s["chunk"], 1):
-            args, kw = _ragged_case(rng, s["slots"], c, s["heads"],
-                                    s["kv_heads"], s["head_dim"],
-                                    s["page"], s["pages_per_slot"], quant)
-            got = jax.jit(lambda *a, _kw=kw: ragged_paged_attention(
-                *a, **_kw))(*args)
-            wide = [f32(a) for a in args]
-            want = jax.jit(
-                lambda *a, _kw=kw: PA.ragged_paged_attention_reference(
-                    *a, **_kw))(*wide)
-            errs[f"ragged{'_int8' if quant else ''}_c{c}"] = rel_err(
-                got, want)
+    shapes = [("", s["slots"], s["chunk"], s["heads"], s["kv_heads"],
+               s["pages_per_slot"], None)]
+    shapes += [(f"_{name}_{tag}", b, c, h, kvh, pps, window)
+               for name, (h, kvh, pps, window) in s["cells"].items()
+               for tag, b, c in (("group", 8, s["chunk"]), ("decode", 64, 1))]
+    from paddle_tpu.inference.cache_spec import ring_pages
+    from tools.ragged_kernel_bench import oracle_error
+    for tag, b, c0, h, kvh, pps, window in shapes:
+        ring = window and ring_pages(window, s["chunk"], s["page"])
+        for quant in (False, True):
+            for c in (c0, 1) if not tag else (c0,):
+                args, kw = _ragged_case(rng, b, c, h, kvh, s["head_dim"],
+                                        s["page"], pps, quant, ring or 0)
+                got = jax.jit(lambda *a, _kw=kw: ragged_paged_attention(
+                    *a, window=window, **_kw))(*args)
+                # against the oracle on the same values in f32 (it
+                # gathers whole tables: 8 sequences at a time)
+                errs[f"ragged{tag}{'_int8' if quant else ''}_c{c}"] = \
+                    oracle_error(got, args, kw, window)
 
     # training: flash fwd+bwd at the GPT-2 and Qwen2 head shapes,
     # rms_norm(+residual), swiglu at the Qwen2 widths, the CE pair
@@ -721,8 +732,15 @@ def real_sizes():
                  gpt_drop=0.2, gpt_calls=3, qwen_batch=1, qwen_seq=1024,
                  qwen_steps=4, qwen_calls=8)
 
+    # cells: (heads, kv heads, pages a slot, window) of the attention
+    # layers of perfbench/configs — qwen2-7b-d8, nemotron3-super-ep4-d11,
+    # k-exaone-ep8-d5's global and window layers
     kernels_serve = dict(slots=8, chunk=128, heads=28, kv_heads=4,
-                         head_dim=128, page=16, pages_per_slot=32)
+                         head_dim=128, page=16, pages_per_slot=32,
+                         cells={"qwen": (28, 4, 128, None),
+                                "nemotron": (32, 2, 128, None),
+                                "kexa_global": (64, 8, 320, None),
+                                "kexa_window": (64, 8, 320, 128)})
     kernels_train = dict(
         flash={"gpt2": (4, 1024, 12, 12, 64), "qwen2": (1, 1024, 28, 4, 128)},
         rows=1024, hidden=3584, inter=18944, ce_chunk=1024)

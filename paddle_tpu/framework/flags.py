@@ -216,8 +216,10 @@ define_flag("FLAGS_use_pallas_ragged_attention", 1,
 # tuner-cache entry, which wins over the defaults here.
 define_flag("FLAGS_ragged_attn_q_block", 16,
             "Ragged paged-attention: stream tokens per q program.")
-define_flag("FLAGS_ragged_attn_kv_pages", 4,
-            "Ragged paged-attention: KV pages per DMA compute block.")
+define_flag("FLAGS_ragged_attn_kv_pages", 0,
+            "Ragged paged-attention: KV pages per DMA compute block "
+            "(0 = sized to the static shape by the kernel's module: 512 "
+            "keys for a decode step, 128-256 for a prefill group).")
 define_flag("FLAGS_fused_linear_cross_entropy", False,
             "LM training loss: chunked fused lm_head-matmul +"
             " cross-entropy that never materializes [N, V] logits "

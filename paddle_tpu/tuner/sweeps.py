@@ -287,9 +287,14 @@ def ragged_attention_builder(slots=8, heads=8, kv_heads=2,
     """Builder for the ``ragged_paged_attention`` surface (shape
     supplies c/pages/page/d): a mixed prefill+decode batch — half the
     slots stream a full chunk, half ride one decode token over a deep
-    history — through the unified serving kernel. Candidates pin
-    through ``force_ragged_blocks`` (NOT set_flags, which would defeat
-    the override>cache>default precedence), fresh jit per candidate."""
+    history — through the unified serving kernel. A candidate pins the
+    stream tokens a q block and the pages a K/V block through
+    ``force_ragged_blocks`` (NOT set_flags, which would defeat the
+    override>cache>default precedence) around a jitted function of its
+    own: ``_resolve_blocks`` reads the pin outside the kernel's jitted
+    call and hands it the blocks as static arguments, so every candidate
+    of one shape is a program of its own. The kv heads a program owns
+    are not a candidate: every head, as in production."""
     import numpy as np
 
     import jax
@@ -339,16 +344,14 @@ def ragged_attention_builder(slots=8, heads=8, kv_heads=2,
                             for s in range(slots)], jnp.int32)
         qb = int(config["q_block"])
         g = int(config["kv_pages_per_block"])
-        if quant:
-            def step_fn(qq, kpp, vpp, tb, cx, ln, kss, vss):
-                return ragged_paged_attention(
-                    qq, kpp, vpp, tb, cx, ln,
-                    k_scales=kss, v_scales=vss)
-            step = jax.jit(step_fn)
-            operands = (q, kp, vp, tables, ctx, lens, ks, vs)
-        else:
-            step = jax.jit(ragged_paged_attention)
-            operands = (q, kp, vp, tables, ctx, lens)
+        # a function of this candidate's own: jax keeps a trace by the
+        # function it traced, so two candidates through one function
+        # object would share the first one's program
+        def step_fn(qq, kpp, vpp, tb, cx, ln, kss, vss):
+            return ragged_paged_attention(
+                qq, kpp, vpp, tb, cx, ln, k_scales=kss, v_scales=vss)
+        step = jax.jit(step_fn)
+        operands = (q, kp, vp, tables, ctx, lens, ks, vs)
 
         def fn():
             # the force context must cover the first (tracing) call —

@@ -88,7 +88,8 @@ def _sds(sharding):
 
 # ---- serving: ragged paged attention -------------------------------------
 
-def _compile_ragged(s, quant, b, c, kvh, rep, page, pps, n_pages, d=128):
+def _compile_ragged(s, quant, b, c, kvh, rep, page, pps, n_pages, d=128,
+                    window=None):
     from paddle_tpu.ops.paged_attention import (kv_pool_shape,
                                                 kv_scales_shape)
     from paddle_tpu.ops.pallas.ragged_paged_attention import (
@@ -104,9 +105,12 @@ def _compile_ragged(s, quant, b, c, kvh, rep, page, pps, n_pages, d=128):
 
         def fn(q, k, v, t, ctx, ln, ks, vs):
             return ragged_paged_attention(q, k, v, t, ctx, ln,
-                                          k_scales=ks, v_scales=vs)
+                                          k_scales=ks, v_scales=vs,
+                                          window=window)
     else:
-        fn = ragged_paged_attention
+        def fn(q, k, v, t, ctx, ln):
+            return ragged_paged_attention(q, k, v, t, ctx, ln,
+                                          window=window)
     _compile(fn, *args)
 
 
@@ -131,6 +135,69 @@ def test_ragged_paged_attention_at_the_group_shape(native, one_chip, quant,
     two GQA ratios the serving cells run."""
     _compile_ragged(_sds(one_chip), quant, b=8, c=128, kvh=kvh, rep=rep,
                     page=16, pps=128, n_pages=8193)
+
+
+# the three serving cells' layers: (kv heads, GQA ratio, pages a slot,
+# pages a pool, window). K-EXAONE's window layers read a 320-wide table
+# that cycles through a slot's ring of 17 pages (1,089 = 64 x 17 + 1)
+_CELL_LAYERS = {"qwen2_7b": (4, 7, 128, 8193, None),
+                "nemotron_h": (2, 16, 128, 8193, None),
+                "k_exaone_global": (8, 8, 320, 20481, None),
+                "k_exaone_ring": (8, 8, 320, 1089, 128)}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("c,b", [(1, 64), (128, 8)], ids=["decode", "group"])
+@pytest.mark.parametrize("layer", sorted(_CELL_LAYERS))
+def test_ragged_paged_attention_at_the_cells_shapes(native, one_chip, layer,
+                                                    c, b, quant):
+    """A decode micro-step (64 slots x one token: every kv head of a
+    sequence in one program, whole-page copies) and a prefill group (8
+    rows x 128) of every attention layer the serving cells run, at the
+    blocks their shapes resolve to."""
+    kvh, rep, pps, n_pages, window = _CELL_LAYERS[layer]
+    _compile_ragged(_sds(one_chip), quant, b=b, c=c, kvh=kvh, rep=rep,
+                    page=16, pps=pps, n_pages=n_pages, window=window)
+
+
+@pytest.mark.parametrize("c", [1, 16], ids=["decode", "chunk16"])
+def test_ragged_paged_attention_at_head_dim_64(native, one_chip, c):
+    """GPT-2's 12 heads of 64: until PR 35 Mosaic refused the kernel's
+    copy of one kv head's 64 columns ("must be aligned to tiling (128)");
+    a copy now brings 6 heads' 384 columns and a head is a slice of the
+    VMEM buffer. Compiled, not yet run on the chip (PERF.md section 7)."""
+    _compile_ragged(_sds(one_chip), False, b=8, c=c, kvh=12, rep=1,
+                    page=16, pps=64, n_pages=513, d=64)
+
+
+def test_a_stack_lowers_the_kernel_once_a_kind_of_layer(native, one_chip):
+    """What a call site costs the host, counted and not timed: a stack
+    walked in Python as K-EXAONE's is — four window layers over rings,
+    one global layer over the long table, KVH 8 — lowers to a module that
+    holds TWO kernel bodies, not five. The kernel's call is a module-level
+    jitted function with its block choices as static arguments, so the
+    calls of one signature share one jaxpr and one lowered function
+    (PERF.md section 6, PR 35; PR 34's five bodies cost the K-EXAONE
+    cell 4.3 s of set-up)."""
+    from paddle_tpu.ops.paged_attention import kv_pool_shape
+    from paddle_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    s = _sds(one_chip)
+    b, kvh, rep, d, page, pps = 8, 8, 1, 128, 16, 16
+    q = s((b, 1, kvh * rep, d))
+    ring = [s(kv_pool_shape(kvh, b * 9 + 1, page, d))] * 2
+    pool = [s(kv_pool_shape(kvh, b * pps + 1, page, d))] * 2
+    tbl, vec = s((b, pps), jnp.int32), s((b,), jnp.int32)
+
+    def stack(q, kr, vr, kg, vg, t_ring, t, ctx, ln):
+        for _ in range(4):
+            q = ragged_paged_attention(q, kr, vr, t_ring, ctx, ln,
+                                       window=128)
+        return ragged_paged_attention(q, kg, vg, t, ctx, ln)
+
+    lowered = jax.jit(stack).lower(q, *ring, *pool, tbl, tbl, vec, vec)
+    assert lowered.as_text().count("@tpu_custom_call") == 2
+    assert lowered.compile().as_text().count("tpu_custom_call") >= 5
 
 
 def _compile_serving_step(one_chip, topo, monkeypatch, kvh, kv_quant):

@@ -342,3 +342,156 @@ def test_first_kv_block_holds_the_windows_oldest_key():
     assert np.asarray(first_kv_block(ctx, 0, 8, 8)).tolist() == [0, 2, 11]
     assert int(first_kv_block(jnp.asarray(30), 4, 8, 8)) == 3
     assert first_kv_block(ctx, 0, None, 8) == 0
+
+
+# ---- every kv head of a sequence in one program, blocks sized to the shape ----
+
+def _int8_pools(rng, shape, KVH, page):
+    from paddle_tpu.ops.paged_attention import kv_scales_shape
+    sshape = kv_scales_shape(KVH, shape[0], page)
+    return (rng.randint(-127, 128, shape).astype("int8"),
+            rng.randint(-127, 128, shape).astype("int8"),
+            {"k_scales": jnp.asarray(rng.uniform(0.002, 0.02, sshape),
+                                     jnp.float32),
+             "v_scales": jnp.asarray(rng.uniform(0.002, 0.02, sshape),
+                                     jnp.float32)})
+
+
+@pytest.mark.parametrize("window", [None, 128])
+@pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8kv"])
+@pytest.mark.parametrize("C", [1, 16])
+@pytest.mark.parametrize("KVH,rep", [(4, 7), (2, 16), (8, 8), (1, 8),
+                                     (12, 1)])
+def test_kernel_matches_oracle_at_the_cells_head_geometries(KVH, rep, C,
+                                                            quant, window):
+    """The GQA geometries of the serving cells (Qwen2-7B 4 x 7, Nemotron-H
+    2 x 16, K-EXAONE 8 x 8), of one shard under a mesh (1 x 8) and of
+    more kv heads than a program owns (12: two programs of 6, each copying
+    its columns of a page), at the decode and a chunk shape, plain and
+    int8 pools, with and without a window, at the blocks the shape
+    resolves to: contexts from inside the
+    first K/V block to the fourth (a decode program's blocks are 512
+    keys), mid-page and on a page's edge, idle slots between busy ones —
+    so a program finds in its buffers what another sequence's left."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import _resolve_blocks
+    rng = np.random.RandomState(11)
+    B, D, page, P = 7, 16, 16, 100
+    ctx = np.array([1500, 0, 37, 0, 0, 1039, 511], "int32")
+    lens = np.array([C, 0, C, 0, 0, max(C // 2, 1), C], "int32")
+    shape = kv_pool_shape(KVH, B * P + 1, page, D)
+    tables = (rng.permutation(B * P) + 1).reshape(B, P).astype("int32")
+    q = rng.randn(B, C, KVH * rep, D).astype("float32")
+    if quant:
+        kp, vp, kw = _int8_pools(rng, shape, KVH, page)
+    else:
+        kp, vp, kw = (rng.randn(*shape).astype("float32"),
+                      rng.randn(*shape).astype("float32"), {})
+    qb, g, hp = _resolve_blocks(C, P, page, D, q.dtype, quant, kv_heads=KVH,
+                                rep=rep, window=window, pool_dtype=kp.dtype)
+    assert hp == (6 if KVH == 12 else KVH)
+    assert window is None or g <= window // page + 2
+    assert ctx.max() + C > (1 if window else 2) * g * page   # several blocks
+    args = [jnp.asarray(a) for a in (q, kp, vp, tables, ctx, lens)]
+    ref = np.asarray(ragged_paged_attention_reference(
+        *args, window=window, **kw))
+    if not quant:
+        # every page no query of the batch can see holds NaN: the trash
+        # page, an idle slot's pages, a busy slot's pages past its last
+        # key and before its window. A block copies none of them, and
+        # what a V buffer position keeps from an EARLIER sequence's block
+        # is finite — a kernel that copied a whole block of the table
+        # would multiply p == 0 into NaN
+        seen = np.zeros(shape[0], bool)
+        for b in range(B):
+            if lens[b]:
+                lo = max(ctx[b] - window + 1, 0) // page if window else 0
+                seen[tables[b, lo:-(-(ctx[b] + lens[b]) // page)]] = True
+        for pool in (kp, vp):
+            pool[~seen] = np.nan
+        args[1:3] = jnp.asarray(kp), jnp.asarray(vp)
+    out = np.asarray(kernel(*args, window=window, **kw))
+    np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
+    assert not out[[1, 3, 4]].any() and not out[5, lens[5]:].any()
+
+
+@pytest.mark.parametrize("layer,kvh,rep,pps,window,decode,group", [
+    ("qwen2-7b", 4, 7, 128, None, (1, 32, 4), (16, 16, 4)),
+    ("nemotron-h *", 2, 16, 128, None, (1, 32, 2), (16, 8, 2)),
+    ("k-exaone global", 8, 8, 320, None, (1, 32, 8), (16, 16, 8)),
+    ("k-exaone window", 8, 8, 320, 128, (1, 8, 8), (16, 8, 8)),
+])
+@pytest.mark.parametrize("pool", ["bfloat16", "int8"])
+def test_blocks_a_cells_shape_resolves_to(layer, kvh, rep, pps, window,
+                                          decode, group, pool):
+    """(q block, pages a K/V block, kv heads a program) of the serving
+    cells' attention layers at page 16, d 128: a decode step owns every
+    kv head and walks blocks of 512 keys; a prefill group of 112-128 rows
+    256, of 256 rows 128; a window layer the window's 128 — pinned, so a
+    change of the defaults is a change of this table (measured: PERF.md
+    section 6, PR 34)."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import _resolve_blocks
+    for c, want in ((1, decode), (128, group)):
+        assert _resolve_blocks(
+            c, pps, 16, 128, jnp.bfloat16, pool == "int8", kv_heads=kvh,
+            rep=rep, window=window, pool_dtype=jnp.dtype(pool)) == want
+
+
+def test_blocks_follow_an_explicit_choice_and_the_vmem_budget():
+    """The caller's argument and the trial hook win over the default;
+    32 kv heads are walked 8 a program; a page of 8 x 256 columns in f32
+    (128 KB) leaves the budget for 8 pages a block, not 32."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import _resolve_blocks
+    bf = jnp.bfloat16
+    assert _resolve_blocks(1, 128, 16, 128, bf, kv_heads=4, rep=7,
+                           kv_pages_per_block=4) == (1, 4, 4)
+    with force_ragged_blocks(16, 2, 1):
+        assert _resolve_blocks(128, 128, 16, 128, bf, kv_heads=4,
+                               rep=7) == (16, 2, 1)
+    with force_ragged_blocks(16, 2):
+        assert _resolve_blocks(128, 128, 16, 128, bf, kv_heads=4,
+                               rep=7) == (16, 2, 4)
+    assert _resolve_blocks(1, 128, 16, 128, bf, kv_heads=32, rep=1)[2] == 8
+    assert _resolve_blocks(1, 128, 16, 256, jnp.float32, kv_heads=8, rep=1,
+                           pool_dtype=jnp.float32)[1] == 8
+
+
+def test_two_forced_candidates_are_two_programs(monkeypatch):
+    """The kernel's call is ONE module-level jitted function whose static
+    arguments are everything ``_resolve_blocks`` decides: a second trial
+    candidate pinned through ``force_ragged_blocks`` in the same process,
+    at the same shapes, is a second program (a block choice read INSIDE
+    the jitted function would meet the first candidate's trace) and a
+    candidate's repeat is a cache hit. The tuner's builder hands every
+    candidate of one shape to that function, each as its static
+    arguments."""
+    from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
+    from paddle_tpu.tuner.sweeps import ragged_attention_builder
+    rng = np.random.RandomState(3)
+    B, KVH, D, page, P = 2, 2, 16, 8, 6
+    kp, vp, tables = _pool_case(rng, B, KVH, D, page, P, B * P + 1)
+    q = rng.randn(B, 8, 4, D).astype("float32")
+    args = (q, kp, vp, tables, np.array([30, 5], "int32"),
+            np.array([8, 1], "int32"))
+    n0 = rpa._ragged_call._cache_size()
+    for i, cand in enumerate([(8, 1), (8, 2), (8, 1), (8, 2, 1)]):
+        with force_ragged_blocks(*cand):
+            out, ref = _run_both(*args)
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+        assert rpa._ragged_call._cache_size() - n0 == (1, 2, 2, 3)[i]
+    # the sweep's own path: one builder, three candidates, one shape
+    real, got = rpa._ragged_call, []
+
+    def recording(*a, **static):
+        got.append((static["q_block"], static["kv_pages"],
+                    static["kv_heads"]))
+        return real(*a, **static)
+
+    monkeypatch.setattr(rpa, "_ragged_call", recording)
+    build = ragged_attention_builder(slots=2, heads=4, kv_heads=2,
+                                     dtype="float32")
+    shape = {"c": 8, "pages": 4, "page": 8, "d": 16}
+    outs = [np.asarray(build({"q_block": 8, "kv_pages_per_block": g},
+                             shape)()) for g in (1, 2, 4)]
+    assert got == [(8, 1, 2), (8, 2, 2), (8, 4, 2)]
+    for o in outs[1:]:
+        np.testing.assert_allclose(outs[0], o, rtol=2e-5, atol=2e-5)
