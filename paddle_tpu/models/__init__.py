@@ -5,10 +5,15 @@ paddle_tpu's nn + parallel layers + Pallas kernels.
 
 Served through ``inference.ContinuousBatchingEngine``: Llama, Qwen2 and
 GPT-2 (one paged K/V pair a layer), Nemotron-H (per-slot recurrent state
-beside paged K/V, ``cache_spec.SlotState``) and EXAONE-MoE (window layers
+beside paged K/V, ``cache_spec.SlotState``), EXAONE-MoE (window layers
 over per-slot rings beside global layers over pages,
-``cache_spec.WindowKV``; gated held-share experts). DeepSeek-V2 (MLA) and
-ERNIE run dense only."""
+``cache_spec.WindowKV``; gated held-share experts) and Qwen3-Next (gated
+delta-rule layers over per-slot float32 state beside gated full attention
+over pages; softmax-routed held-share experts beside a gated shared one).
+Of Qwen3-Next ONE benchmark cell is measured, on one chip: its share of an
+8-way expert-parallel deployment, 12 of 48 layers, serving
+(``PERF.md``); nothing of it trains. DeepSeek-V2 (MLA) and ERNIE run dense
+only."""
 
 from .gpt2 import GPT2Config, GPT2Model, GPT2ForCausalLM
 from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM,
@@ -21,6 +26,7 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForPretraining,
 from .deepseek import DeepseekV2Config, DeepseekV2ForCausalLM
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM
+from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 
 __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "LlamaModel", "LlamaForCausalLM", "LlamaForCausalLMPipe",
@@ -31,4 +37,5 @@ __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "ErnieForMaskedLM", "ErnieForSequenceClassification", "DeepseekV2Config",
            "DeepseekV2ForCausalLM", "NemotronHConfig",
            "NemotronHForCausalLM", "ExaoneMoeConfig",
-           "ExaoneMoeForCausalLM"]
+           "ExaoneMoeForCausalLM", "Qwen3NextConfig",
+           "Qwen3NextForCausalLM"]

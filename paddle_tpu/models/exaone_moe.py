@@ -150,21 +150,25 @@ class _Norm(_Weight):
         return F.rms_norm(x, self.weight, self.cfg.rms_norm_eps)
 
 
-def _qk_norm_rope(q, k, qw, kw, pos, eps, theta):
+def _qk_norm_rope(q, k, qw, kw, pos, eps, theta, rotary_dim=None,
+                  centred=False):
     """q [B, S, H, D], k [B, S, KVH, D]: the per-head RMS norm (learned
-    scale), then — ``theta`` not None — the rotary embedding over the
-    whole head at positions ``pos + [0..S)``. The angles are computed from
-    the positions at hand in float32: no table, so nothing grows with
-    ``max_position_embeddings``."""
+    scale; ``centred``: the scale is ``1 + w``), then — ``theta`` not None
+    — the rotary embedding over the first ``rotary_dim`` of the head's
+    dims (None: the whole head) at positions ``pos + [0..S)``. The angles
+    are computed from the positions at hand in float32: no table, so
+    nothing grows with ``max_position_embeddings``."""
 
     def norm(a, w):
         af = a.astype(jnp.float32)
         ms = jnp.mean(jnp.square(af), axis=-1, keepdims=True)
-        return af * jax.lax.rsqrt(ms + eps) * w.astype(jnp.float32)
+        return af * jax.lax.rsqrt(ms + eps) \
+            * (w.astype(jnp.float32) + (1.0 if centred else 0.0))
 
     qf, kf = norm(q, qw), norm(k, kw)
     if theta is not None:
-        d2 = q.shape[-1] // 2
+        rd = q.shape[-1] if rotary_dim is None else int(rotary_dim)
+        d2 = rd // 2
         inv = jnp.exp(jnp.arange(d2, dtype=jnp.float32)
                       * (-jnp.log(jnp.float32(theta)) / d2))
         at = pos.astype(jnp.int32).reshape(-1, 1) \
@@ -173,9 +177,11 @@ def _qk_norm_rope(q, k, qw, kw, pos, eps, theta):
         sin, cos = jnp.sin(ang)[:, :, None, :], jnp.cos(ang)[:, :, None, :]
 
         def rot(a):
-            a1, a2 = a[..., :d2], a[..., d2:]
-            return jnp.concatenate([a1 * cos - a2 * sin,
-                                    a2 * cos + a1 * sin], axis=-1)
+            a1, a2 = a[..., :d2], a[..., d2:rd]
+            # dims past ``rd`` pass through unrotated
+            return jnp.concatenate(
+                [a1 * cos - a2 * sin, a2 * cos + a1 * sin]
+                + ([a[..., rd:]] if rd < a.shape[-1] else []), axis=-1)
 
         qf, kf = rot(qf), rot(kf)
     return qf.astype(q.dtype), kf.astype(k.dtype)

@@ -35,8 +35,8 @@ from ..profiler import metrics as _pmetrics
 __all__ = ["top_k_gating", "top_k_gating_idx", "moe_dispatch_combine",
            "moe_ffn_grouped", "moe_forward", "moe_forward_ep",
            "sort_rows_by_expert", "moe_forward_dropless", "moe_ablation",
-           "top_k_weights", "sigmoid_top_k_router", "moe_experts_held",
-           "held_range"]
+           "top_k_weights", "sigmoid_top_k_router", "softmax_top_k_router",
+           "moe_experts_held", "held_range"]
 
 
 # -- section ablation (profiler.breakdown step-attribution harness) --------
@@ -119,6 +119,16 @@ def sigmoid_top_k_router(logits, bias, k, norm_topk_prob=True, scale=1.0):
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     idx, w = top_k_weights(scores + bias.astype(jnp.float32), scores, k,
                            norm_topk_prob, scale)
+    return idx.astype(jnp.int32), w
+
+
+def softmax_top_k_router(logits, k, norm_topk_prob=True):
+    """Softmax router without a bias (the Qwen-MoE form): probabilities
+    ``softmax(logits)`` in float32 over ALL experts; the k largest; their
+    weights the probabilities, normalised over the k. logits [T, E].
+    Returns (idx [T, k] int32, w [T, k] float32)."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    idx, w = top_k_weights(probs, probs, k, norm_topk_prob)
     return idx.astype(jnp.int32), w
 
 

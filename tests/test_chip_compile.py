@@ -64,10 +64,10 @@ def native(monkeypatch, no_persistent_cache):
     ``_interpret()`` and would lower the interpreter instead. Steered
     here, in the test — the program has no option for it."""
     from paddle_tpu.ops.pallas import (ce_chunk, flash_attention,
-                                       grouped_matmul,
+                                       gated_delta_step, grouped_matmul,
                                        ragged_paged_attention, rms_norm,
                                        swiglu)
-    for mod in (ce_chunk, flash_attention, grouped_matmul,
+    for mod in (ce_chunk, flash_attention, gated_delta_step, grouped_matmul,
                 ragged_paged_attention, rms_norm, swiglu):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
@@ -137,13 +137,15 @@ def test_ragged_paged_attention_at_the_group_shape(native, one_chip, quant,
                     page=16, pps=128, n_pages=8193)
 
 
-# the three serving cells' layers: (kv heads, GQA ratio, pages a slot,
-# pages a pool, window). K-EXAONE's window layers read a 320-wide table
-# that cycles through a slot's ring of 17 pages (1,089 = 64 x 17 + 1)
+# the serving cells' layers: (kv heads, GQA ratio, pages a slot, pages a
+# pool, window[, head_dim 128]). K-EXAONE's window layers read a 320-wide
+# table that cycles through a slot's ring of 17 pages (1,089 = 64 x 17 + 1);
+# Qwen3-Next's gated attention has heads of 256: 512 columns a token
 _CELL_LAYERS = {"qwen2_7b": (4, 7, 128, 8193, None),
                 "nemotron_h": (2, 16, 128, 8193, None),
                 "k_exaone_global": (8, 8, 320, 20481, None),
-                "k_exaone_ring": (8, 8, 320, 1089, 128)}
+                "k_exaone_ring": (8, 8, 320, 1089, 128),
+                "qwen3_next_d256": (2, 8, 320, 20481, None, 256)}
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
@@ -155,9 +157,10 @@ def test_ragged_paged_attention_at_the_cells_shapes(native, one_chip, layer,
     sequence in one program, whole-page copies) and a prefill group (8
     rows x 128) of every attention layer the serving cells run, at the
     blocks their shapes resolve to."""
-    kvh, rep, pps, n_pages, window = _CELL_LAYERS[layer]
+    kvh, rep, pps, n_pages, window, *d = _CELL_LAYERS[layer]
     _compile_ragged(_sds(one_chip), quant, b=b, c=c, kvh=kvh, rep=rep,
-                    page=16, pps=pps, n_pages=n_pages, window=window)
+                    page=16, pps=pps, n_pages=n_pages, window=window,
+                    d=d[0] if d else 128)
 
 
 @pytest.mark.parametrize("c", [1, 16], ids=["decode", "chunk16"])
@@ -273,7 +276,8 @@ def _lower_step(model, eng, one_chip, topo, monkeypatch):
 
 def _pool_copies(text, eng):
     """Pool-shaped ``copy`` ops of a compiled step, per pool kind
-    (``"kv"`` data pools, ``"scale"`` pools, ``"wkv"`` window rings):
+    (``"kv"`` data pools, ``"scale"`` pools, ``"wkv"`` window rings,
+    ``"state"`` per-slot state arrays):
     ``{kind: (in the whole
     program, inside a ``while`` body or anything a body calls)}``. A
     pool that the write and the kernel hold in two layouts shows here as
@@ -284,7 +288,7 @@ def _pool_copies(text, eng):
                            ",".join(map(str, sh))): kind
                for sh, dt, kind in zip(eng._pool_shapes, eng._pool_dtypes,
                                        eng._pool_kinds)
-               if kind in ("kv", "scale", "wkv")}
+               if kind in ("kv", "scale", "wkv", "state")}
     comps, cur = {}, None
     for line in text.splitlines():
         m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
@@ -379,6 +383,69 @@ def test_serving_step_program_at_k_exaone_widths(native, one_chip, topo,
     copies = _pool_copies(text, eng)
     assert set(copies) == {"kv", "wkv"}
     assert all(in_loops == 0 for _, in_loops in copies.values()), copies
+
+
+def test_gated_delta_step_at_the_cells_shape(native, one_chip):
+    """The one-step delta-rule kernel at a decode micro-step of the
+    Qwen3-Next cell: 64 slots x 32 heads of 128 x 128 float32 state, 16
+    heads a program, key columns sliced along the lanes, the state written
+    over its input: the compiled program holds no second copy of the 134 MB
+    state (temporaries stay under 1 MB)."""
+    from paddle_tpu.ops.pallas.gated_delta_step import gated_delta_step
+    s = _sds(one_chip)
+    f32 = jnp.float32
+    vec = s((64, 32, 128), f32)
+    compiled = jax.jit(gated_delta_step, donate_argnums=(0,)).lower(
+        s((64, 32, 128, 128), f32), vec, vec, vec, s((64, 32), f32),
+        s((64, 32), f32), s((64,), bool)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 64 * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+def test_serving_step_program_at_qwen3_next_widths(native, one_chip, topo,
+                                                   monkeypatch):
+    """The ONE step program of a 4-layer model at Qwen3-Next's widths
+    (hidden 2048; three delta-rule layers of 32 x 128 x 128 state beside one
+    gated attention layer of 16 / 2 heads x 256; 64 held experts of 512 x
+    512): per-slot float32 state beside host-managed pages, 64 slots x
+    5120. It compiles for the described chip with the attention kernel in
+    both loops, the one-step delta-rule kernel in the decode scan and the
+    chunked form (its triangular solve) in the group loop, and neither loop
+    holds a copy of a whole state array."""
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.models import Qwen3NextConfig, Qwen3NextForCausalLM
+    cfg = Qwen3NextConfig.qwen3_next_80b_a3b()
+    cfg.num_hidden_layers, cfg.vocab_size = 4, 18992
+    cfg.num_experts_held = 64
+    cfg.dtype, cfg.empty_init = "bfloat16", True
+    model = Qwen3NextForCausalLM(cfg)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, num_slots=64, max_len=5120,
+                                   page_size=16, greedy=True)
+    assert [tuple(p._data.shape) for p in eng.pools] \
+        == [(64, 32, 128, 128), (64, 3, 8192)] * 3 \
+        + [(64 * 320 + 1, 16, 512)] * 2 + [(5,)]
+    assert eng.gauges()["state_pool_bytes"] == 3 * 64 * (2_097_152 + 49_152)
+    lowered, text = _lower_step(model, eng, one_chip, topo, monkeypatch)
+    for kernel in ("ragged_paged_attention", "grouped_matmul",
+                   "gated_delta_step"):
+        assert kernel in text
+    for shape in ("bf16[8,2,1024,256]", "bf16[64,2,8,256]"):
+        assert shape in text           # the attention kernel at 8 rows, at 64
+    # ONE lowered body of the step kernel for the three delta-rule layers,
+    # called from the decode scan alone
+    assert lowered.as_text().count("gated_delta_step") == 1
+    copies = _pool_copies(text, eng)
+    assert set(copies) == {"kv", "state"} and copies["kv"][1] == 0, copies
+    # the 134 MB states: only the entry copy of an argument the step does
+    # not donate. (The 3 MB conv tails are re-laid out once a micro-step:
+    # XLA keeps ``[64, 3, 8192]`` slot-major in one loop and tap-major in
+    # the other.)
+    import re
+    loops = text.split("\nENTRY ")[0]
+    assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", loops)
 
 
 def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):
