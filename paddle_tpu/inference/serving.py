@@ -54,8 +54,13 @@ Structure:
   pools) is DEVICE-RESIDENT between programs: steps chain device state
   asynchronously, and each step's ONE packed int32 fetch carries every
   token it emitted (a prompt's first token among them) plus the
-  ctx/active mirrors. Admission mutates per-slot device state with tiny
-  ``.at[slot].set`` dispatches.
+  ctx/active mirrors.
+- What the HOST decides — block-table rows, context limits, stop tokens,
+  the turn's prompt chunks — lives in numpy arrays and rides the turn's
+  ONE upload (:class:`_TurnUpload`); admission and eviction run no
+  device program. A slot they rebind also stages a context reset, which
+  the step program applies to its chained ctx/active state before
+  anything else.
 - Two pumps drive the step: :meth:`step` (dispatch, then harvest; what
   ``ApiServer``, the fleet replicas and the benchmark call) and
   :meth:`run` (the same turn, with the successor dispatched before the
@@ -132,6 +137,14 @@ _pmetrics.declare("serving/prefill_positions", "counter",
                   "filled or not (the unified step: groups run x rows "
                   "a group x prefill_chunk; a speculative step's pass: "
                   "num_slots x prefill_chunk)")
+_pmetrics.declare("serving/step_uploads", "counter",
+                  "host arrays shipped to the device by dispatched "
+                  "steps (1 a step; a self-speculative draft ships one "
+                  "more)")
+_pmetrics.declare("serving/staged_slot_updates", "counter",
+                  "slot rows whose host-decided state (table row, "
+                  "limit, stop token, context reset) rode a step's "
+                  "upload: admissions + device clears")
 _pmetrics.declare("serving/ttft_ms", "histogram",
                   "request arrival -> first token on host, ms (bounded "
                   "reservoir; p50/p99 exposed via gauges())")
@@ -278,6 +291,7 @@ _STAT_KEYS = ("chunks", "chunk_slot_steps", "active_slot_steps",
               "prefill_waves", "chunks_empty", "unified_steps",
               "requests_completed", "run_seconds",
               "prefill_tokens", "prefill_positions",
+              "step_uploads", "staged_slot_updates",
               # ISSUE-10 reliability counters ride the same view so
               # reset_gauges()/as_dict() cover them uniformly
               "preempt_evictions", "preempt_pages_reclaimed",
@@ -319,6 +333,37 @@ class _StatsView:
 
     def as_dict(self):
         return {k: c.value for k, c in self._c.items()}
+
+
+class _TurnUpload:
+    """The ONE host array a dispatched program takes: every field the
+    host decided this turn, int32, laid end to end in a flat vector.
+    :meth:`host` hands out a fresh buffer with a writable view a field;
+    :meth:`split` is the program's prologue, the same fields sliced out
+    of the uploaded array (static offsets, so it costs the program a few
+    reshapes)."""
+
+    def __init__(self, fields):
+        self._at, self.size = {}, 0
+        for name, shape in fields:
+            n = int(np.prod(shape, dtype=np.int64))
+            self._at[name] = (self.size, n, tuple(shape))
+            self.size += n
+
+    def host(self):
+        buf = np.zeros((self.size,), np.int32)
+        return buf, self.split(buf)
+
+    def split(self, arr):
+        return {name: arr[o:o + n].reshape(shape)
+                for name, (o, n, shape) in self._at.items()}
+
+
+def _apply_slot_resets(reset, ctx, act):
+    """A step program's prologue over its chained state: a slot the
+    host rebound since the last launch (``reset >= 0``; -1 keeps) starts
+    at that context and does not decode."""
+    return jnp.where(reset >= 0, reset, ctx), act & (reset < 0)
 
 
 class _PrefixCacheNode:
@@ -708,14 +753,18 @@ class ContinuousBatchingEngine:
         self._seq = 0
         self._act_since = np.zeros((B,), np.int64)
 
-        # device-resident hot state (never round-trips between steps);
-        # admission mutates it with tiny async .at[slot].set dispatches
+        # device-resident hot state, chained through the step programs'
+        # outputs (never round-trips between steps)
         self._dev_tok = jnp.zeros((B,), jnp.int32)
         self._dev_ctx = jnp.zeros((B,), jnp.int32)
         self._dev_act = jnp.zeros((B,), bool)
-        self._dev_tbl = jnp.zeros((B, MP), jnp.int32)
-        self._dev_lim = jnp.zeros((B,), jnp.int32)
-        self._dev_eos = jnp.full((B,), -1, jnp.int32)
+        # what the host decides for a slot (tables, limits, slot_eos
+        # above) reaches the device in the next dispatch's ONE upload,
+        # and with it a per-slot reset of the chained ctx/active state
+        # (-1 keeps; admission stages its start context, an eviction 0;
+        # the last write before a launch wins)
+        self._slot_reset = np.full((B,), -1, np.int32)
+        self._staged_rows = 0     # slot rows staged since the last launch
 
         self.queue: deque[ServedRequest] = deque()
         self.completed: list[ServedRequest] = []
@@ -799,6 +848,18 @@ class ContinuousBatchingEngine:
                                  // self.prefill_chunk))
         self._group_rows = -(-self.num_slots // self._group) * self._group
         self._emits_inflight = np.zeros((B,), np.int32)
+        # the ONE upload of a turn, per step program: the prompt chunks
+        # (_stage_prompt_chunks), the slot state the host decides
+        # (_slot_fields), and the program's own (the unified step's
+        # compacted row list and its length, a speculative step's draft
+        # counts)
+        chunks = [("ids", (B, self.prefill_chunk)), ("nq", (B,)),
+                  ("last", (B,)), ("tgt", (B,))]
+        self._unified_up = _TurnUpload(
+            chunks + self._slot_fields()
+            + [("rows", (self._group_rows,)), ("n_rows", ())])
+        self._spec_up = _TurnUpload(
+            chunks + self._slot_fields() + [("nd", (B,))])
         # ---- speculative decoding (ISSUE 18) -------------------------
         # a drafting decode slot rides 1 + K tokens (pending + drafts)
         # through the SAME ragged mixed pass as a short prefill-shaped
@@ -1291,7 +1352,8 @@ class ContinuousBatchingEngine:
         Pipelined: the NEXT step is dispatched before the previous
         step's packed output is fetched — device state chains
         asynchronously, so the harvest round-trip and the whole
-        admission pass (slot-state updates) execute while the successor
+        admission pass (host bookkeeping: the slot state it decides
+        rides the next dispatch's upload) execute while the successor
         runs on device. A slot that finished inside the previous step
         is inactive in the successor (its device active flag is already
         False), so the overlap never decodes garbage, and a slot
@@ -1527,7 +1589,7 @@ class ContinuousBatchingEngine:
         an abandoned in-flight program land in orphaned arrays, never
         in state the engine will read again. Compiled programs are pure
         functions of their inputs and are kept."""
-        B, MP = self.num_slots, self.pages_per_slot
+        B = self.num_slots
         self.pools = [Tensor(jnp.zeros(s, dt)) for s, dt in
                       zip(self._pool_shapes, self._pool_dtypes)]
         self._free_pages = deque(range(1, self.num_pages))
@@ -1559,9 +1621,8 @@ class ContinuousBatchingEngine:
         self._dev_tok = jnp.zeros((B,), jnp.int32)
         self._dev_ctx = jnp.zeros((B,), jnp.int32)
         self._dev_act = jnp.zeros((B,), bool)
-        self._dev_tbl = jnp.zeros((B, MP), jnp.int32)
-        self._dev_lim = jnp.zeros((B,), jnp.int32)
-        self._dev_eos = jnp.full((B,), -1, jnp.int32)
+        self._slot_reset[:] = -1
+        self._staged_rows = 0
         # the RNG key chained through the failed program; rebuild from
         # the seed (greedy streams are unaffected; sampled streams
         # restart their key chain — documented in docs/serving.md)
@@ -1603,10 +1664,13 @@ class ContinuousBatchingEngine:
           micro-step 0 with its first token — prefill→decode transition
           never leaves the device.
 
-        The packed output carries every emitted token of the step (a
-        first token in column 0, like a decoding slot's) plus the
-        ctx/active mirrors in ONE int32 fetch. The forward is traced
-        twice: the loop's body and the scan's."""
+        Its arguments: the turn's ONE upload (``self._unified_up``:
+        prompt chunks, row list, and the tables, limits, stop tokens and
+        context resets the host decided), the chained tok/ctx/active
+        state, the key and the pools. The packed output carries every
+        emitted token of the step (a first token in column 0, like a
+        decoding slot's) plus the ctx/active mirrors in ONE int32 fetch.
+        The forward is traced twice: the loop's body and the scan's."""
         if self._unified_fn is not None:
             return self._unified_fn
         from ..jit import to_static
@@ -1619,8 +1683,9 @@ class ContinuousBatchingEngine:
         G = self._group
         R, MP = self._ring, self.pages_per_slot
 
-        def ustep(ids_t, nq_t, last_t, tgt_t, rows_t, nrows_t, tok_t,
-                  ctx_t, act_t, tbl_t, lim_t, eos_t, key_t, *pools):
+        up = self._unified_up
+
+        def ustep(up_t, tok_t, ctx_t, act_t, key_t, *pools):
             fwd = model.forward
 
             def sample(lg, key):
@@ -1631,9 +1696,14 @@ class ContinuousBatchingEngine:
                 return jax.random.categorical(
                     sub, lg / temperature).astype(jnp.int32), key
 
-            def fn(ids, nq, last, tgt, rows, n_rows, tok, ctx, act, tbl,
-                   lim, eos_arr, key, *pool_leaves):
+            def fn(upload, tok, ctx, act, key, *pool_leaves):
                 b = tok.shape[0]
+                f = up.split(upload)
+                ids, nq, rows, n_rows = (f["ids"], f["nq"], f["rows"],
+                                         f["n_rows"])
+                last, tgt = f["last"] != 0, f["tgt"] != 0
+                tbl, lim, eos_arr = f["tbl"], f["lim"], f["eos"]
+                ctx, act = _apply_slot_resets(f["reset"], ctx, act)
                 if cpool is not None:
                     # the model's pass counters start every step at 0
                     # and leave with its packed fetch
@@ -1733,42 +1803,93 @@ class ContinuousBatchingEngine:
                     + tuple(leaves_f)
 
             return _apply_multi(
-                fn, [ids_t, nq_t, last_t, tgt_t, rows_t, nrows_t, tok_t,
-                     ctx_t, act_t, tbl_t, lim_t, eos_t, key_t]
-                + list(pools), n_out=5 + len(pools))
+                fn, [up_t, tok_t, ctx_t, act_t, key_t] + list(pools),
+                n_out=5 + len(pools))
 
         self._unified_fn = to_static(ustep)
         self._compiled.add(("unified", self.prefill_chunk, n_steps))
         return self._unified_fn
 
-    def _stage_prompt_chunks(self):
-        """The next ``prefill_chunk`` prompt tokens of every prefilling
-        slot (at most ``admit_batch`` of them), as the step programs
-        take them: ``ids [B, C]``, ``nq`` (tokens staged per slot),
-        ``last`` (the prompt ends in this chunk), ``tgt`` (the slot
-        decodes afterwards), ``rows`` (the slots that carry a chunk,
-        compacted; padded to whole groups with ``num_slots``, an index
-        no scatter lands on), and how many slots carry a chunk."""
+    def _stage_prompt_chunks(self, f):
+        """Write the next ``prefill_chunk`` prompt tokens of every
+        prefilling slot (at most ``admit_batch`` of them) into the
+        upload's fields ``f``, as the step programs take them: ``ids
+        [B, C]``, ``nq`` (tokens staged per slot), ``last`` (the prompt
+        ends in this chunk), ``tgt`` (the slot decodes afterwards) and,
+        where the layout has them (the unified step's), ``rows`` (the
+        slots that carry a chunk, compacted; padded to whole groups with
+        ``num_slots``, an index no scatter lands on) and ``n_rows``.
+        Returns how many slots carry a chunk."""
         B, C = self.num_slots, self.prefill_chunk
-        ids = np.zeros((B, C), np.int32)
-        nq = np.zeros((B,), np.int32)
-        last = np.zeros((B,), bool)
-        tgt = np.zeros((B,), bool)
-        rows = np.full((self._group_rows,), B, np.int32)
-        n_pre = 0
+        staged = []
         for slot in range(B):
-            if not self._prefilling[slot] or n_pre >= self.admit_batch:
+            if not self._prefilling[slot] \
+                    or len(staged) >= self.admit_batch:
                 continue
             prm = self._slot_prompt[slot]
             off = int(self._prefill_off[slot])
             v = min(C, len(prm) - off)
-            ids[slot, :v] = prm[off:off + v]
-            nq[slot] = v
-            last[slot] = off + v == len(prm)
-            tgt[slot] = self._act_target[slot]
-            rows[n_pre] = slot
-            n_pre += 1
-        return ids, nq, last, tgt, rows, n_pre
+            f["ids"][slot, :v] = prm[off:off + v]
+            f["nq"][slot] = v
+            f["last"][slot] = off + v == len(prm)
+            f["tgt"][slot] = self._act_target[slot]
+            staged.append(slot)
+        if "rows" in f:
+            f["rows"][:] = B
+            f["rows"][:len(staged)] = staged
+            f["n_rows"][()] = len(staged)
+        return len(staged)
+
+    # ---- the turn's ONE upload -------------------------------------------
+
+    def _slot_fields(self):
+        """The per-slot fields every upload carries: what admission and
+        eviction decided on the host since the last launch."""
+        B = self.num_slots
+        return [("tbl", (B, self.pages_per_slot)), ("lim", (B,)),
+                ("eos", (B,)), ("reset", (B,))]
+
+    def _stage_reset(self, slot, ctx):
+        """Stage "this slot's device context is now ``ctx``, and it is
+        not decoding" for the next launch (the last value wins)."""
+        self._slot_reset[slot] = ctx
+        self._staged_rows += 1
+
+    def _stage_upload(self, layout):
+        """A fresh host buffer of ``layout`` and its field views, the
+        slot state filled in from the host's arrays."""
+        buf, f = layout.host()
+        f["tbl"][:] = self.tables
+        f["lim"][:] = self.limits
+        f["eos"][:] = self.slot_eos
+        f["reset"][:] = self._slot_reset
+        return buf, f
+
+    def _ship(self, buf):
+        """The upload itself: one host array to the device."""
+        self._stats.inc("step_uploads")
+        return Tensor(jnp.asarray(buf))
+
+    def _chained(self):
+        """What a step program takes after its upload: the chained
+        tok / ctx / active state, the key and the pools."""
+        return [Tensor(self._dev_tok), Tensor(self._dev_ctx),
+                Tensor(self._dev_act), Tensor(self._key), *self.pools]
+
+    def _chain(self, res):
+        """Keep a launched step program's outputs as the next one's
+        chained inputs and retire the staged resets its upload carried.
+        Returns the packed output (not fetched)."""
+        packed, tok_f, ctx_f, act_f, key_f = res[:5]
+        self.pools = list(res[5:])
+        self._dev_tok = tok_f._data
+        self._dev_ctx = ctx_f._data
+        self._dev_act = act_f._data
+        self._key = key_f._data
+        self._stats.inc("staged_slot_updates", self._staged_rows)
+        self._staged_rows = 0
+        self._slot_reset[:] = -1
+        return packed
 
     def _count_dispatch(self, sp, mode, n_steps, n_active, n_pre,
                         n_tok, n_groups, group_rows):
@@ -1811,10 +1932,10 @@ class ContinuousBatchingEngine:
         with _span("serving/dispatch") as sp:
             B = self.num_slots
             with _span("serving/dispatch.stage"):
-                ids, nq, last, tgt, rows, n_pre = \
-                    self._stage_prompt_chunks()
-                staged = [Tensor(jnp.asarray(a)) for a in
-                          (ids, nq, last, tgt, rows, np.int32(n_pre))]
+                buf, f = self._stage_upload(self._unified_up)
+                n_pre = self._stage_prompt_chunks(f)
+                upload = self._ship(buf)
+            nq, last, tgt = f["nq"], f["last"], f["tgt"]
             fn = self._unified_static()
             self._seq += 1
             self._last_fetch_dispatch_seq = self._seq
@@ -1828,18 +1949,12 @@ class ContinuousBatchingEngine:
             self._count_dispatch(sp, "unified", n_steps, n_active, n_pre,
                                  int(nq.sum()),
                                  -(-n_pre // self._group), self._group)
+            # the call stays in the dispatcher's own frame: a step traced
+            # and lowered one Python frame deeper took 0.3-1.0 s longer
+            # to build at every start (PERF.md section 6, PR 37)
             with _span("serving/dispatch.launch"):
-                res = fn(*staged,
-                         Tensor(self._dev_tok), Tensor(self._dev_ctx),
-                         Tensor(self._dev_act), Tensor(self._dev_tbl),
-                         Tensor(self._dev_lim), Tensor(self._dev_eos),
-                         Tensor(self._key), *self.pools)
-            packed, tok_f, ctx_f, act_f, key_f = res[:5]
-            self.pools = list(res[5:])
-            self._dev_tok = tok_f._data
-            self._dev_ctx = ctx_f._data
-            self._dev_act = act_f._data
-            self._key = key_f._data
+                res = fn(upload, *self._chained())
+            packed = self._chain(res)
             # host bookkeeping: prompt-stream progress is exact; decode
             # activity is a prediction refined by the harvested mirrors
             emits = np.zeros((B,), bool)
@@ -1973,13 +2088,18 @@ class ContinuousBatchingEngine:
         C = self.prefill_chunk
         K = self._spec_k
 
-        def sstep(ids_t, nq_t, last_t, tgt_t, nd_t, tok_t, ctx_t,
-                  act_t, tbl_t, lim_t, eos_t, key_t, *pools):
+        up = self._spec_up
+
+        def sstep(up_t, tok_t, ctx_t, act_t, key_t, *pools):
             fwd = model.forward
 
-            def fn(ids, nq, last, tgt, nd, tok, ctx, act, tbl, lim,
-                   eos_arr, key, *pool_leaves):
+            def fn(upload, tok, ctx, act, key, *pool_leaves):
                 b = tok.shape[0]
+                f = up.split(upload)
+                ids, nq, nd = f["ids"], f["nq"], f["nd"]
+                last, tgt = f["last"] != 0, f["tgt"] != 0
+                tbl, lim, eos_arr = f["tbl"], f["lim"], f["eos"]
+                ctx, act = _apply_slot_resets(f["reset"], ctx, act)
                 # stale instant-eos guard (same as the plain step)
                 act = act & ((eos_arr < 0) | (tok != eos_arr))
                 is_pre = nq > 0
@@ -2104,8 +2224,7 @@ class ContinuousBatchingEngine:
                     + tuple(t._data for t in npools)
 
             return _apply_multi(
-                fn, [ids_t, nq_t, last_t, tgt_t, nd_t, tok_t, ctx_t,
-                     act_t, tbl_t, lim_t, eos_t, key_t] + list(pools),
+                fn, [up_t, tok_t, ctx_t, act_t, key_t] + list(pools),
                 n_out=5 + len(pools))
 
         self._spec_fn = to_static(sstep)
@@ -2123,9 +2242,10 @@ class ContinuousBatchingEngine:
         with _span("serving/dispatch") as sp:
             B, K = self.num_slots, self._spec_k
             with _span("serving/dispatch.stage"):
-                ids, nq, last, tgt, _, n_pre = \
-                    self._stage_prompt_chunks()
-                nd = np.zeros((B,), np.int32)
+                buf, f = self._stage_upload(self._spec_up)
+                n_pre = self._stage_prompt_chunks(f)
+                ids, nq, last, tgt, nd = (f["ids"], f["nq"], f["last"],
+                                          f["tgt"], f["nd"])
                 drafting = [s for s in range(B)
                             if self.active[s] and not self._prefilling[s]
                             and self.slot_req[s] is not None
@@ -2139,8 +2259,7 @@ class ContinuousBatchingEngine:
                         if c > 0:
                             ids[s, 1:1 + c] = drafts[s, :c]
                             nd[s] = c
-                staged = [Tensor(jnp.asarray(a))
-                          for a in (ids, nq, last, tgt, nd)]
+                upload = self._ship(buf)
             fn = self._unified_spec_static()
             self._seq += 1
             self._last_fetch_dispatch_seq = self._seq
@@ -2152,18 +2271,10 @@ class ContinuousBatchingEngine:
             # the speculative step keeps its one [B, C] mixed pass
             self._count_dispatch(sp, "spec", n_steps, n_active, n_pre,
                                  int(nq.sum()), 1, B)
+            # called from this frame, as in _dispatch_step
             with _span("serving/dispatch.launch"):
-                res = fn(*staged,
-                         Tensor(self._dev_tok), Tensor(self._dev_ctx),
-                         Tensor(self._dev_act), Tensor(self._dev_tbl),
-                         Tensor(self._dev_lim), Tensor(self._dev_eos),
-                         Tensor(self._key), *self.pools)
-            packed, tok_f, ctx_f, act_f, key_f = res[:5]
-            self.pools = list(res[5:])
-            self._dev_tok = tok_f._data
-            self._dev_ctx = ctx_f._data
-            self._dev_act = act_f._data
-            self._key = key_f._data
+                res = fn(upload, *self._chained())
+            packed = self._chain(res)
             emits = np.zeros((B,), bool)
             for slot in range(B):
                 if nq[slot] > 0:
@@ -2225,6 +2336,12 @@ class ContinuousBatchingEngine:
         - ``prefill_waves``: steps that carried prompt tokens (≥1
           prefilling slot).
         - ``unified_steps``: batching-step programs dispatched.
+        - ``step_uploads`` / ``staged_slot_updates`` /
+          ``uploads_per_step``: host arrays the dispatched steps shipped
+          to the device, the slot rows (admissions + device clears)
+          whose state rode them, and uploads / steps — 1.0: a turn's
+          host-decided state reaches the device in ONE transfer and
+          nothing else runs there between a harvest and the launch.
         """
         s = self._stats.as_dict()
         steps = s["chunk_slot_steps"]
@@ -2247,6 +2364,10 @@ class ContinuousBatchingEngine:
             "chunks_empty": s["chunks_empty"],
             "prefill_waves": s["prefill_waves"],
             "unified_steps": s["unified_steps"],
+            "step_uploads": s["step_uploads"],
+            "staged_slot_updates": s["staged_slot_updates"],
+            "uploads_per_step": (s["step_uploads"] / s["unified_steps"])
+            if s["unified_steps"] else 0.0,
             "tokens_emitted": s["tokens_emitted"],
             "prefills": s["prefills"],
             "requests_completed": s["requests_completed"],
@@ -2677,9 +2798,10 @@ class ContinuousBatchingEngine:
         """The ONE per-slot teardown (drain and eviction share it —
         a field missed in a second copy is exactly the stale-state bug
         class the identity checks exist to catch). ``device=True``
-        additionally deactivates the slot's DEVICE mirrors: needed on
-        eviction, where the device still believes the slot is active;
-        a drained slot already went inactive inside its program.
+        additionally stages the deactivation of the slot's chained
+        DEVICE state (:meth:`_stage_reset`): needed on eviction, where
+        the device still believes the slot is active; a drained slot
+        already went inactive inside its program.
         Per-slot recurrent state (``cache_spec.SlotState``) is not
         touched: the model zeroes a slot's row in-program when its next
         occupant starts at position 0."""
@@ -2698,16 +2820,12 @@ class ContinuousBatchingEngine:
             self.active[slot] = False
             self._prefilling[slot] = False
             self._emits_inflight[slot] = 0
-            self._dev_tbl = self._dev_tbl.at[slot].set(
-                jnp.zeros((self.pages_per_slot,), jnp.int32))
-            self._dev_act = self._dev_act.at[slot].set(False)
-            self._dev_ctx = self._dev_ctx.at[slot].set(0)
-            self._dev_lim = self._dev_lim.at[slot].set(0)
-            self._dev_eos = self._dev_eos.at[slot].set(-1)
+            self._stage_reset(slot, 0)
 
     def _evict_slot(self, slot, requeue, reason="preempt", error=None):
         """Tear one occupied slot out of the engine mid-flight:
-        deactivate it on host AND device (an in-flight program's stale
+        deactivate it on the host now and on the device with the next
+        launch (:meth:`_stage_reset`; an in-flight program's stale
         view of the slot is discarded at harvest via the slot_req
         identity check), reclaim its pages (deferred past any fetched
         program that could still write them), and either requeue the
@@ -2918,10 +3036,12 @@ class ContinuousBatchingEngine:
 
     def _stage_slot(self, slot, req, pages, eff, remaining,
                     attach=(), start=0):
-        """Bind an admitted request to a slot: block-table row, device
-        mirrors, prefill progress. ``eff`` is the admission prompt
-        (original prompt + recompute replay tokens), ``remaining`` the
-        generation budget left. ``attach`` is the cached-prefix node
+        """Bind an admitted request to a slot, on the host alone:
+        block-table row, limit, stop token, prefill progress, and the
+        staged reset of the slot's device context to ``start`` — the
+        next dispatch's upload carries them. ``eff`` is the admission
+        prompt (original prompt + recompute replay tokens), ``remaining``
+        the generation budget left. ``attach`` is the cached-prefix node
         chain (already pinned) whose pages head the block table;
         ``start`` is the cached prefix length in tokens — prefill
         resumes there, indistinguishable from a slot that already
@@ -2935,7 +3055,6 @@ class ContinuousBatchingEngine:
         row[:len(attach)] = [n.page for n in attach]
         row[len(attach):len(attach) + len(pages)] = pages
         self.tables[slot] = row
-        self._dev_tbl = self._dev_tbl.at[slot].set(jnp.asarray(row))
         req.t_admit = time.perf_counter()
         _t_obs = req.t_admit
         if self._trace_every:
@@ -2971,17 +3090,13 @@ class ContinuousBatchingEngine:
                  or getattr(req, "no_migrate", False))
         self.ctx[slot] = start
         self._pred_ctx[slot] = start
-        self._dev_ctx = self._dev_ctx.at[slot].set(int(start))
+        self._stage_reset(slot, start)
         self.slot_eos[slot] = -1 if req.eos_token_id is None \
             else int(req.eos_token_id)
         # ctx counts CACHE entries; one generated token is always
         # pending outside the cache, so the n-th token lands when
         # ctx hits tl + n - 1 (not tl + n)
         self.limits[slot] = tl + remaining - 1
-        self._dev_lim = self._dev_lim.at[slot].set(
-            int(self.limits[slot]))
-        self._dev_eos = self._dev_eos.at[slot].set(
-            int(self.slot_eos[slot]))
 
     # ---- completion ------------------------------------------------------
 

@@ -210,7 +210,7 @@ class SelfSpecDraftSource(DraftSource):
     def __init__(self, skip_layers=None):
         self._skip = tuple(sorted(skip_layers)) \
             if skip_layers is not None else None
-        self._fns = {}          # (engine id, k) -> compiled scan
+        self._fns = {}  # (engine id, k) -> (compiled scan, its upload)
 
     def _skip_for(self, model):
         if self._skip is not None:
@@ -227,14 +227,25 @@ class SelfSpecDraftSource(DraftSource):
         import jax.numpy as jnp
         from ..framework.core import Tensor, no_grad, apply
         from ..jit import to_static
+        from .serving import _apply_slot_resets, _TurnUpload
         model = eng.model
         skip = self._skip_for(model)
+        # the draft program's ONE upload: the engine's staged slot state
+        # (block tables, context resets) and which slots draft
+        up = _TurnUpload(eng._slot_fields()
+                         + [("mask", (eng.num_slots,))])
 
-        def dstep(tok_t, ctx_t, tbl_t, mask_t, *pools):
+        def dstep(up_t, tok_t, ctx_t, *pools):
             fwd = model.forward
 
-            def fn(tok, ctx, tbl, mask, *pool_leaves):
+            def fn(upload, tok, ctx, *pool_leaves):
                 b = tok.shape[0]
+                f = up.split(upload)
+                tbl = f["tbl"]
+                # a slot the host rebound since the last launch drafts
+                # nothing, and reads its staged context like the step
+                ctx, mask = _apply_slot_resets(f["reset"], ctx,
+                                               f["mask"] != 0)
 
                 def body(carry, _):
                     tok_c, ctx_c, leaves = carry
@@ -258,30 +269,27 @@ class SelfSpecDraftSource(DraftSource):
                 # KV is never returned to the engine
                 return toks.T.astype(jnp.int32)
 
-            return apply(fn, tok_t, ctx_t, tbl_t, mask_t, *pools,
+            return apply(fn, up_t, tok_t, ctx_t, *pools,
                          n_outputs=1, differentiable=False,
                          name="spec_draft")
 
-        fn = to_static(dstep)
-        self._fns[key] = fn
+        self._fns[key] = to_static(dstep), up
         eng._compiled.add(("spec_draft", int(k)))
-        return fn
+        return self._fns[key]
 
     def propose(self, eng, slots, k):
-        import jax.numpy as jnp
         from ..framework.core import Tensor
         b = eng.num_slots
         counts = np.zeros((b,), np.int32)
         if not slots or k <= 0:
             return np.zeros((b, max(k, 1)), np.int32)[:, :k], counts
-        mask = np.zeros((b,), bool)
-        mask[list(slots)] = True
-        fn = self._draft_fn(eng, k)
-        toks = fn(Tensor(eng._dev_tok), Tensor(eng._dev_ctx),
-                  Tensor(eng._dev_tbl), Tensor(jnp.asarray(mask)),
-                  *eng.pools)
+        fn, up = self._draft_fn(eng, k)
+        buf, f = eng._stage_upload(up)
+        f["mask"][list(slots)] = 1
+        toks = fn(eng._ship(buf), Tensor(eng._dev_tok),
+                  Tensor(eng._dev_ctx), *eng.pools)
         drafts = np.asarray(toks._data).astype(np.int32)
-        counts[mask] = k
+        counts[f["mask"] != 0] = k
         return drafts, counts
 
 

@@ -245,12 +245,10 @@ def _lower_step(model, eng, one_chip, topo, monkeypatch):
     assert (eng._group, eng.prefill_chunk, eng.decode_chunk) == (8, 128, 16)
     ustep = eng._unified_static().function
     s = _sds(one_chip)
-    B, C, MP = eng.num_slots, eng.prefill_chunk, eng.pages_per_slot
-    i32 = jnp.int32
-    args = [s((B, C), i32), s((B,), i32), s((B,), bool), s((B,), bool),
-            s((eng._group_rows,), i32), s((), i32), s((B,), i32),
-            s((B,), i32), s((B,), bool), s((B, MP), i32), s((B,), i32),
-            s((B,), i32), s((2,), jnp.uint32)]
+    B, i32 = eng.num_slots, jnp.int32
+    # the turn's one upload, the chained tok / ctx / active, the key
+    args = [s((eng._unified_up.size,), i32), s((B,), i32), s((B,), i32),
+            s((B,), bool), s((2,), jnp.uint32)]
     args += [s(tuple(p._data.shape), p._data.dtype) for p in eng.pools]
     # the call sites ask which platform they run on: answer for the
     # described chip while the program is traced
@@ -346,6 +344,30 @@ def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
     copies = _pool_copies(text, eng)
     assert all(in_loops == 0 for _, in_loops in copies.values()), copies
     assert copies["kv"][0] <= eng._pool_kinds.count("kv"), copies
+
+
+def test_serving_step_takes_one_host_array(native, one_chip, topo,
+                                           monkeypatch):
+    """What the host sends a turn is ONE array: the step program compiled
+    for the described chip has, besides the weights, exactly one entry
+    parameter of host origin — the flat int32 upload that holds the prompt
+    chunks, the row list, the block tables, limits, stop tokens and context
+    resets — next to the chained tok / ctx / active state, the key and the
+    pools. An admission that shipped its own arrays, or a table kept on
+    the device, would show here as a further parameter."""
+    import re
+    _, text, eng = _compile_serving_step(one_chip, topo, monkeypatch, 4,
+                                         "none")
+    B, C, MP = eng.num_slots, eng.prefill_chunk, eng.pages_per_slot
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    shapes = re.findall(r"= (\S+?)(?:\{[^}]*\})? parameter\(\d+\)", entry)
+    n_weights = len(list(eng.model.parameters()))
+    chained = [f"s32[{B}]", f"s32[{B}]", f"pred[{B}]", "u32[2]"] + [
+        "bf16[%s]" % ",".join(map(str, p._data.shape)) for p in eng.pools]
+    upload = B * (C + MP + 6) + eng._group_rows + 1
+    assert eng._unified_up.size == upload
+    assert shapes[n_weights:] == [f"s32[{upload}]"] + chained
 
 
 def test_serving_step_program_at_k_exaone_widths(native, one_chip, topo,
