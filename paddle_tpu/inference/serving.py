@@ -533,7 +533,14 @@ class ContinuousBatchingEngine:
     model: any CausalLM Layer implementing ``forward(ids, caches=, pos=,
     tables=)`` + ``init_kv_cache`` — Llama, Qwen2 (incl. MoE), and GPT2
     all qualify. num_slots is the batch size; total pool memory =
-    num_pages * page_size tokens of KV per layer."""
+    num_pages * page_size tokens of KV per layer.
+
+    ``pools`` holds one persistable Tensor per pool for the engine's
+    lifetime. The compiled step reassigns them, so ``to_static`` donates
+    their buffers to it: a pool's array read before a step (``p._data``)
+    is deleted by that step. Read a pool through its Tensor, or keep
+    ``np.asarray(p._data)`` / ``p.clone()`` where a value must outlive
+    a step."""
 
     def __init__(self, model, num_slots=4, page_size=16, num_pages=None,
                  max_len=512, decode_chunk=None,
@@ -707,8 +714,15 @@ class ContinuousBatchingEngine:
                     f"speculative decoding rolls a slot back to its last "
                     f"accepted token; this model keeps {self._slot_owned}, "
                     f"which has no rollback yet")
+        # persistable: state the step programs reassign, so to_static
+        # donates them (the class docstring). These Tensor objects stay
+        # the engine's for its lifetime — a compiled step holds its
+        # state by identity — and everything that replaces a pool
+        # rebinds their data in place
         self.pools = [Tensor(jnp.zeros(s, dt)) for s, dt in
                       zip(self._pool_shapes, self._pool_dtypes)]
+        for p in self.pools:
+            p.persistable = True
         # static pool-geometry facts for the gauges
         self._kv_quant_bits = 8 * jnp.dtype(self._pool_dtype).itemsize
         _bytes = lambda kind: sum(
@@ -1581,17 +1595,19 @@ class ContinuousBatchingEngine:
 
     def _set_paged(self, arrays):
         for i, a in zip(self._paged, arrays):
-            self.pools[i] = Tensor(a)
+            self.pools[i].set_data(a)
 
     def _reset_device_state(self):
         """Rebuild the pools, the free list and all per-slot state from
         scratch — FRESH device buffers, so writes still racing out of
         an abandoned in-flight program land in orphaned arrays, never
         in state the engine will read again. Compiled programs are pure
-        functions of their inputs and are kept."""
+        functions of their inputs and are kept (the pool Tensors too:
+        their data is rebound)."""
         B = self.num_slots
-        self.pools = [Tensor(jnp.zeros(s, dt)) for s, dt in
-                      zip(self._pool_shapes, self._pool_dtypes)]
+        for p, s, dt in zip(self.pools, self._pool_shapes,
+                            self._pool_dtypes):
+            p.set_data(jnp.zeros(s, dt))
         self._free_pages = deque(range(1, self.num_pages))
         self._deferred_free = []
         self.tables[:] = 0
@@ -1667,10 +1683,13 @@ class ContinuousBatchingEngine:
         Its arguments: the turn's ONE upload (``self._unified_up``:
         prompt chunks, row list, and the tables, limits, stop tokens and
         context resets the host decided), the chained tok/ctx/active
-        state, the key and the pools. The packed output carries every
-        emitted token of the step (a first token in column 0, like a
-        decoding slot's) plus the ctx/active mirrors in ONE int32 fetch.
-        The forward is traced twice: the loop's body and the scan's."""
+        state and the key. The pools are not arguments: the step reads
+        ``self.pools`` and reassigns them (:func:`_keep_pools`), so
+        ``to_static`` donates their buffers and the loops update them in
+        place. The packed output carries every emitted token of the step
+        (a first token in column 0, like a decoding slot's) plus the
+        ctx/active mirrors in ONE int32 fetch. The forward is traced
+        twice: the loop's body and the scan's."""
         if self._unified_fn is not None:
             return self._unified_fn
         from ..jit import to_static
@@ -1684,8 +1703,9 @@ class ContinuousBatchingEngine:
         R, MP = self._ring, self.pages_per_slot
 
         up = self._unified_up
+        pools = self.pools
 
-        def ustep(up_t, tok_t, ctx_t, act_t, key_t, *pools):
+        def ustep(up_t, tok_t, ctx_t, act_t, key_t):
             fwd = model.forward
 
             def sample(lg, key):
@@ -1802,9 +1822,9 @@ class ContinuousBatchingEngine:
                 return (packed_out, tok_f, ctx_f, act_f, key_f) \
                     + tuple(leaves_f)
 
-            return _apply_multi(
-                fn, [up_t, tok_t, ctx_t, act_t, key_t] + list(pools),
-                n_out=5 + len(pools))
+            return _keep_pools(pools, _apply_multi(
+                fn, [up_t, tok_t, ctx_t, act_t, key_t] + pools,
+                n_out=5 + len(pools)))
 
         self._unified_fn = to_static(ustep)
         self._compiled.add(("unified", self.prefill_chunk, n_steps))
@@ -1872,16 +1892,16 @@ class ContinuousBatchingEngine:
 
     def _chained(self):
         """What a step program takes after its upload: the chained
-        tok / ctx / active state, the key and the pools."""
+        tok / ctx / active state and the key (the pools are state the
+        step reassigns, not arguments)."""
         return [Tensor(self._dev_tok), Tensor(self._dev_ctx),
-                Tensor(self._dev_act), Tensor(self._key), *self.pools]
+                Tensor(self._dev_act), Tensor(self._key)]
 
     def _chain(self, res):
         """Keep a launched step program's outputs as the next one's
         chained inputs and retire the staged resets its upload carried.
         Returns the packed output (not fetched)."""
-        packed, tok_f, ctx_f, act_f, key_f = res[:5]
-        self.pools = list(res[5:])
+        packed, tok_f, ctx_f, act_f, key_f = res
         self._dev_tok = tok_f._data
         self._dev_ctx = ctx_f._data
         self._dev_act = act_f._data
@@ -2089,8 +2109,9 @@ class ContinuousBatchingEngine:
         K = self._spec_k
 
         up = self._spec_up
+        pools = self.pools
 
-        def sstep(up_t, tok_t, ctx_t, act_t, key_t, *pools):
+        def sstep(up_t, tok_t, ctx_t, act_t, key_t):
             fwd = model.forward
 
             def fn(upload, tok, ctx, act, key, *pool_leaves):
@@ -2223,9 +2244,9 @@ class ContinuousBatchingEngine:
                 return (packed_out, tok_f, ctx_f, act_f, key) \
                     + tuple(t._data for t in npools)
 
-            return _apply_multi(
-                fn, [up_t, tok_t, ctx_t, act_t, key_t] + list(pools),
-                n_out=5 + len(pools))
+            return _keep_pools(pools, _apply_multi(
+                fn, [up_t, tok_t, ctx_t, act_t, key_t] + pools,
+                n_out=5 + len(pools)))
 
         self._spec_fn = to_static(sstep)
         self._compiled.add(("spec", C, 1 + K))
@@ -3222,6 +3243,17 @@ def _apply_multi(fn, tensors, n_out):
     from ..framework.core import apply
     return apply(fn, *tensors, n_outputs=n_out, differentiable=False,
                  name="serving_engine")
+
+
+def _keep_pools(pools, res):
+    """A step program's pools are its persistable state: write the
+    step's new pools (the outputs after the first five) back into the
+    engine's pool Tensors, so ``to_static`` records them as written and
+    donates their buffers to the compiled step. Returns the first five
+    outputs (packed, tok, ctx, act, key)."""
+    for p, r in zip(pools, res[5:]):
+        p.set_data(r._data)
+    return res[:5]
 
 
 # -- tunable surface ---------------------------------------------------------

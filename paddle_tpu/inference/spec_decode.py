@@ -234,8 +234,10 @@ class SelfSpecDraftSource(DraftSource):
         # (block tables, context resets) and which slots draft
         up = _TurnUpload(eng._slot_fields()
                          + [("mask", (eng.num_slots,))])
+        # the engine's pool Tensors: state the draft program only reads
+        pools = eng.pools
 
-        def dstep(up_t, tok_t, ctx_t, *pools):
+        def dstep(up_t, tok_t, ctx_t):
             fwd = model.forward
 
             def fn(upload, tok, ctx, *pool_leaves):
@@ -287,7 +289,7 @@ class SelfSpecDraftSource(DraftSource):
         buf, f = eng._stage_upload(up)
         f["mask"][list(slots)] = 1
         toks = fn(eng._ship(buf), Tensor(eng._dev_tok),
-                  Tensor(eng._dev_ctx), *eng.pools)
+                  Tensor(eng._dev_ctx))
         drafts = np.asarray(toks._data).astype(np.int32)
         counts[f["mask"] != 0] = k
         return drafts, counts

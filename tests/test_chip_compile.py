@@ -204,8 +204,9 @@ def test_a_stack_lowers_the_kernel_once_a_kind_of_layer(native, one_chip):
 
 
 def _compile_serving_step(one_chip, topo, monkeypatch, kvh, kv_quant):
-    """(lowered, compiled text, engine) of the unified step program of a
-    2-layer model at Qwen2-7B's widths, 64 slots x 2048, page 16."""
+    """(lowered, compiled text, memory analysis, engine) of the unified
+    step program of a 2-layer model at Qwen2-7B's widths, 64 slots x
+    2048, page 16."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.core import Tensor
     from paddle_tpu.inference import ContinuousBatchingEngine
@@ -237,8 +238,9 @@ def _compile_serving_step(one_chip, topo, monkeypatch, kvh, kv_quant):
 
 
 def _lower_step(model, eng, one_chip, topo, monkeypatch):
-    """(lowered, compiled text) of ``eng``'s unified step program for the
-    described chip, the model's parameters as shapes."""
+    """(lowered, compiled text, memory analysis) of ``eng``'s unified
+    step program for the described chip, the model's parameters as
+    shapes, bound as ``to_static`` binds it: the pools donated."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.core import Tensor
     params = list(model.parameters())
@@ -249,27 +251,55 @@ def _lower_step(model, eng, one_chip, topo, monkeypatch):
     # the turn's one upload, the chained tok / ctx / active, the key
     args = [s((eng._unified_up.size,), i32), s((B,), i32), s((B,), i32),
             s((B,), bool), s((2,), jnp.uint32)]
-    args += [s(tuple(p._data.shape), p._data.dtype) for p in eng.pools]
     # the call sites ask which platform they run on: answer for the
     # described chip while the program is traced
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    state = eng.pools + params
 
-    def step(leaves, *arrays):
-        held = [p._data for p in params]
-        for p, a in zip(params, leaves):
-            p._data = a
+    def step(pools, leaves, *arrays):
+        # as to_static binds it: the pools are the state the step
+        # reassigns (argument 0, donated), the weights the state it reads
+        held = [t._data for t in state]
+        for t, a in zip(state, list(pools) + list(leaves)):
+            t._data = a
         try:
             with paddle.no_grad():
                 outs = ustep(*[Tensor(a) for a in arrays])
+            new_pools = [p._data for p in eng.pools]
         finally:
-            for p, a in zip(params, held):
-                p._data = a
-        return [o._data for o in outs]
+            for t, a in zip(state, held):
+                t._data = a
+        return new_pools, [o._data for o in outs]
 
-    lowered = jax.jit(step).lower(
+    lowered = jax.jit(step, donate_argnums=(0,)).lower(
+        [s(tuple(p._data.shape), p._data.dtype) for p in eng.pools],
         [s(tuple(p.shape), BF16) for p in params], *args)
-    return lowered, lowered.compile().as_text()
+    compiled = lowered.compile()
+    return lowered, compiled.as_text(), compiled.memory_analysis()
+
+
+def _assert_pools_donated(text, mem, eng):
+    """The step writes every pool it reads into the buffer it was given:
+    its first parameters are the pools, each aliased to that pool's
+    output, and the aliased bytes are the pools' bytes. The step zeroes
+    its pass counters (kind ``"counters"``) instead of reading them, so
+    that pool is no parameter. The f32 scales pools of int8 K/V are laid
+    out with the page axis minor, in whole tiles of 128 pages."""
+    import re
+    read = [i for i, k in enumerate(eng._pool_kinds) if k != "counters"]
+    aliases = text[text.index("input_output_alias={"):]
+    aliases = aliases[:aliases.index(" }")]
+    assert re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases) \
+        == [(str(i), str(j)) for j, i in enumerate(read)], aliases
+
+    def nbytes(shape, dtype, kind):
+        if kind == "scale":
+            shape = (-(-shape[0] // 128) * 128,) + tuple(shape[1:])
+        return int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes == sum(
+        nbytes(eng._pool_shapes[i], eng._pool_dtypes[i], eng._pool_kinds[i])
+        for i in read)
 
 
 def _pool_copies(text, eng):
@@ -323,16 +353,16 @@ def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
     slots, the 16 decode micro-steps, the head on one row per slot — of a
     2-layer model at Qwen2-7B's widths, 64 slots x 2048, compiled whole:
     two loops (the group loop's trip count is data), and the attention
-    kernel in both bodies. The K/V write and the kernel share ONE pool
-    layout: no pool-sized ``copy`` inside either loop, at most one per
-    K/V pool in the whole program (the entry copy of an argument the step
-    does not donate) — bf16 and int8 pools, and Nemotron-3's GQA ratio
-    (2 kv heads). The f32 scales pools of int8 K/V (2 MB each) read 3
-    copies a pool BETWEEN the loops: XLA gives the two loops' scatters
-    two layouts of so narrow an array; none inside a loop
-    (``PERF.md`` section 7)."""
-    lowered, text, eng = _compile_serving_step(one_chip, topo, monkeypatch,
-                                               kvh, kv_quant)
+    kernel in both bodies. The pools are donated and the K/V write and
+    the kernel share ONE pool layout: no copy of a K/V pool anywhere in
+    the program (until the pools were donated, each was copied once at
+    entry) — bf16 and int8 pools, and Nemotron-3's GQA ratio (2 kv
+    heads). The f32 scales pools of int8 K/V (2 MB each) read 3 copies a
+    pool BETWEEN the loops: XLA gives the two loops' scatters two layouts
+    of so narrow an array; none inside a loop, and no more with the
+    pools donated (``PERF.md`` section 7)."""
+    lowered, text, mem, eng = _compile_serving_step(
+        one_chip, topo, monkeypatch, kvh, kv_quant)
     assert lowered.as_text().count("stablehlo.while") == 2
     assert "ragged_paged_attention" in text
     rep = 28 // kvh
@@ -342,32 +372,39 @@ def test_serving_step_program_at_qwen2_widths(native, one_chip, topo,
     for shape in ("bf16[8192,", "bf16[64,128,152064]"):
         assert shape not in text       # no pass at all 64 x 128 positions
     copies = _pool_copies(text, eng)
-    assert all(in_loops == 0 for _, in_loops in copies.values()), copies
-    assert copies["kv"][0] <= eng._pool_kinds.count("kv"), copies
+    assert copies["kv"] == (0, 0), copies
+    if kv_quant == "int8":
+        assert copies["scale"] == (3 * 4, 0), copies
+    _assert_pools_donated(text, mem, eng)
 
 
 def test_serving_step_takes_one_host_array(native, one_chip, topo,
                                            monkeypatch):
     """What the host sends a turn is ONE array: the step program compiled
-    for the described chip has, besides the weights, exactly one entry
-    parameter of host origin — the flat int32 upload that holds the prompt
-    chunks, the row list, the block tables, limits, stop tokens and context
-    resets — next to the chained tok / ctx / active state, the key and the
-    pools. An admission that shipped its own arrays, or a table kept on
-    the device, would show here as a further parameter."""
+    for the described chip has, besides the pools it is given (donated,
+    first) and the weights, exactly one entry parameter of host origin —
+    the flat int32 upload that holds the prompt chunks, the row list, the
+    block tables, limits, stop tokens and context resets — next to the
+    chained tok / ctx / active state and the key. An admission that
+    shipped its own arrays, or a table kept on the device, would show
+    here as a further parameter."""
     import re
-    _, text, eng = _compile_serving_step(one_chip, topo, monkeypatch, 4,
-                                         "none")
+    _, text, _, eng = _compile_serving_step(one_chip, topo, monkeypatch, 4,
+                                            "none")
     B, C, MP = eng.num_slots, eng.prefill_chunk, eng.pages_per_slot
     entry = text[text.index("\nENTRY "):]
     entry = entry[:entry.index("\n}")]
-    shapes = re.findall(r"= (\S+?)(?:\{[^}]*\})? parameter\(\d+\)", entry)
+    shapes = [sh for _, sh in sorted(
+        (int(n), sh) for sh, n in re.findall(
+            r"= (\S+?)(?:\{[^}]*\})? parameter\((\d+)\)", entry))]
     n_weights = len(list(eng.model.parameters()))
-    chained = [f"s32[{B}]", f"s32[{B}]", f"pred[{B}]", "u32[2]"] + [
-        "bf16[%s]" % ",".join(map(str, p._data.shape)) for p in eng.pools]
+    pools = ["bf16[%s]" % ",".join(map(str, p._data.shape))
+             for p in eng.pools]
+    chained = [f"s32[{B}]", f"s32[{B}]", f"pred[{B}]", "u32[2]"]
     upload = B * (C + MP + 6) + eng._group_rows + 1
     assert eng._unified_up.size == upload
-    assert shapes[n_weights:] == [f"s32[{upload}]"] + chained
+    assert shapes[:len(pools)] == pools
+    assert shapes[len(pools) + n_weights:] == [f"s32[{upload}]"] + chained
 
 
 def test_serving_step_program_at_k_exaone_widths(native, one_chip, topo,
@@ -377,8 +414,9 @@ def test_serving_step_program_at_k_exaone_widths(native, one_chip, topo,
     window layer over per-slot rings beside a global one over host-managed
     pages, both sparse, 64 slots x 5120. It compiles for the described
     chip with the kernel in both loops, the window pool is sized by the
-    window — 17 pages a slot — and no pool, ring or global, is re-laid out
-    inside a loop."""
+    window — 17 pages a slot — and no pool, ring or global, is copied
+    anywhere: the step is given them (donated) and writes them in
+    place."""
     from paddle_tpu.inference import ContinuousBatchingEngine
     from paddle_tpu.models import ExaoneMoeConfig, ExaoneMoeForCausalLM
     cfg = ExaoneMoeConfig.k_exaone_236b()
@@ -395,16 +433,16 @@ def test_serving_step_program_at_k_exaone_widths(native, one_chip, topo,
     assert [tuple(p._data.shape) for p in eng.pools] \
         == [(64 * 17 + 1, 16, 1024)] * 2 + [(64 * 320 + 1, 16, 1024)] * 2 \
         + [(3,)]
-    lowered, text = _lower_step(model, eng, one_chip, topo, monkeypatch)
+    lowered, text, mem = _lower_step(model, eng, one_chip, topo,
+                                     monkeypatch)
     # the group loop and the micro-step scan, and inside each the expert
     # sort's searchsorted (a while of its own)
     assert lowered.as_text().count("stablehlo.while") == 4
     assert "ragged_paged_attention" in text and "grouped_matmul" in text
     for shape in ("bf16[8,8,1024,128]", "bf16[64,8,8,128]"):
         assert shape in text           # the kernel at 8 rows, and at 64
-    copies = _pool_copies(text, eng)
-    assert set(copies) == {"kv", "wkv"}
-    assert all(in_loops == 0 for _, in_loops in copies.values()), copies
+    assert _pool_copies(text, eng) == {"kv": (0, 0), "wkv": (0, 0)}
+    _assert_pools_donated(text, mem, eng)
 
 
 def test_gated_delta_step_at_the_cells_shape(native, one_chip):
@@ -434,8 +472,8 @@ def test_serving_step_program_at_qwen3_next_widths(native, one_chip, topo,
     512): per-slot float32 state beside host-managed pages, 64 slots x
     5120. It compiles for the described chip with the attention kernel in
     both loops, the one-step delta-rule kernel in the decode scan and the
-    chunked form (its triangular solve) in the group loop, and neither loop
-    holds a copy of a whole state array."""
+    chunked form (its triangular solve) in the group loop. The pools are
+    donated: no copy of a K/V pool or of a 134 MB state anywhere."""
     from paddle_tpu.inference import ContinuousBatchingEngine
     from paddle_tpu.models import Qwen3NextConfig, Qwen3NextForCausalLM
     cfg = Qwen3NextConfig.qwen3_next_80b_a3b()
@@ -450,7 +488,8 @@ def test_serving_step_program_at_qwen3_next_widths(native, one_chip, topo,
         == [(64, 32, 128, 128), (64, 3, 8192)] * 3 \
         + [(64 * 320 + 1, 16, 512)] * 2 + [(5,)]
     assert eng.gauges()["state_pool_bytes"] == 3 * 64 * (2_097_152 + 49_152)
-    lowered, text = _lower_step(model, eng, one_chip, topo, monkeypatch)
+    lowered, text, mem = _lower_step(model, eng, one_chip, topo,
+                                     monkeypatch)
     for kernel in ("ragged_paged_attention", "grouped_matmul",
                    "gated_delta_step"):
         assert kernel in text
@@ -460,14 +499,15 @@ def test_serving_step_program_at_qwen3_next_widths(native, one_chip, topo,
     # called from the decode scan alone
     assert lowered.as_text().count("gated_delta_step") == 1
     copies = _pool_copies(text, eng)
-    assert set(copies) == {"kv", "state"} and copies["kv"][1] == 0, copies
-    # the 134 MB states: only the entry copy of an argument the step does
-    # not donate. (The 3 MB conv tails are re-laid out once a micro-step:
-    # XLA keeps ``[64, 3, 8192]`` slot-major in one loop and tap-major in
-    # the other.)
+    assert set(copies) == {"kv", "state"} and copies["kv"] == (0, 0), copies
+    # every state copy is of a 3 MB conv tail, none of a 134 MB state: XLA
+    # keeps ``[64, 3, 8192]`` slot-major in one loop and tap-major in the
+    # other, so the three tails are re-laid out at the loops' edges and
+    # once a micro-step (as many as before the pools were donated)
     import re
-    loops = text.split("\nENTRY ")[0]
-    assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", loops)
+    assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", text)
+    assert copies["state"] == (9, 3), copies
+    _assert_pools_donated(text, mem, eng)
 
 
 def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):

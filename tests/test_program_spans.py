@@ -282,11 +282,13 @@ def test_run_seconds_accumulate_per_turn_whoever_pumps(pump):
     assert eng._stats["run_seconds"] == 0.0
 
 
-def test_serving_step_donates_nothing():
-    """The serving step programs read the model's weights and write no
-    state (the KV pools are call ARGUMENTS), so to_static's donation
-    rule leaves them as they were: no aliased or donated parameter in
-    the unified step's program, and the counter stands still."""
+def test_serving_step_donates_its_pools():
+    """The serving step program reads the model's weights and reassigns
+    the engine's pools, which are persistable state and no call
+    argument: to_static's donation rule gives the pools, and only them,
+    to the program. Every pool parameter aliases an output, the weights
+    stay the caller's, and the counter grows by the pool count a
+    compiled call."""
     eng, cfg = _engine()
     donated = get_registry().counter("jit/donated_inputs")
     calls = get_registry().counter("jit/compiled_calls")
@@ -295,15 +297,18 @@ def test_serving_step_donates_nothing():
     eng.step()                                   # discovery turn
     before = donated.value, calls.value
     _pump(eng)
-    assert donated.value == before[0] and calls.value > before[1]
+    n_calls = calls.value - before[1]
+    assert n_calls > 0
+    assert donated.value - before[0] == n_calls * len(eng.pools)
     texts = eng._unified_fn.program_texts()
     assert texts
     for text in texts:
-        assert "tf.aliasing_output" not in text
-        assert "jax.buffer_donor" not in text
+        assert text.count("tf.aliasing_output") == len(eng.pools)
     for entry in eng._unified_fn._graphs.values():
         for graph in entry.by_key.values():
-            assert not graph.written and graph.read_only
+            assert not graph.guard_log
+            assert list(map(id, graph.written)) == list(map(id, eng.pools))
+            assert graph.read_only
 
 
 # ---- the to_static call ----------------------------------------------------
