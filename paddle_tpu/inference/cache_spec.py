@@ -9,14 +9,16 @@ layout every dense decoder here has.
 The kinds: :class:`PagedKV` (host-managed pages, sized by ``max_len``),
 :class:`WindowKV` (a per-slot ring sized by the layer's window; its
 docstring states the ring's rule, once), :class:`SlotState` (per-slot
-recurrent state) and :class:`StepCounters` (what the model counts per
-pass)."""
+recurrent state), :class:`CompressedKeys` (a per-slot index of compressed
+keys, sized by ``max_len``) and :class:`StepCounters` (what the model counts
+per pass)."""
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 
-__all__ = ["PagedKV", "WindowKV", "SlotState", "StepCounters",
+__all__ = ["PagedKV", "WindowKV", "SlotState", "CompressedKeys",
+           "StepCounters",
            "uniform_kv_spec", "spec_of", "ring_pages"]
 
 
@@ -71,6 +73,23 @@ class SlotState:
     cache, speculative decoding and migration."""
     shape: tuple
     dtype: str | None = None
+
+
+@dataclass(frozen=True)
+class CompressedKeys:
+    """One block-sparse attention layer's index (``ops.sparse_select``):
+    per slot one compressed key of ``kv_heads * head_dim`` for every
+    ``stride`` positions, an array ``(num_slots, ceil(max_len / stride),
+    kv_heads * head_dim)`` in the model's dtype. Its size follows the
+    ENGINE's ``max_len``, which a :class:`SlotState` shape fixed when the
+    model is built cannot. The model fills it inside the step program as
+    keys arrive, and an entry is read only after the request itself wrote
+    it, so a slot's entries need no reset. Like :class:`SlotState` it
+    belongs to its slot: an engine whose spec has one serves without prefix
+    cache, speculative decoding and migration."""
+    kv_heads: int
+    head_dim: int
+    stride: int
 
 
 @dataclass(frozen=True)

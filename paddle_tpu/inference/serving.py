@@ -633,12 +633,14 @@ class ContinuousBatchingEngine:
         # trash page) whatever ``max_len``, kinds "wkv"/"wscale": their
         # table is a rule (cache_spec.WindowKV), not host state, and no
         # page operation walks them. Per SlotState entry:
-        # one (num_slots, ...) array the MODEL keeps right in-program.
+        # one (num_slots, ...) array the MODEL keeps right in-program; per
+        # CompressedKeys entry the same, its rows sized by max_len.
         # A StepCounters entry: one int32 vector the step program zeroes
         # and reports in its packed fetch.
         from ..ops.paged_attention import kv_pool_shape, kv_scales_shape
-        from .cache_spec import (PagedKV, SlotState, StepCounters, WindowKV,
-                                 ring_pages, spec_of)
+        from .cache_spec import (CompressedKeys, PagedKV, SlotState,
+                                 StepCounters, WindowKV, ring_pages,
+                                 spec_of)
         self._pool_dtype = dtype if kv_quant == "none" else jnp.dtype(
             jnp.int8 if kv_quant == "int8" else jnp.float8_e4m3fn)
         self._pool_shapes, self._pool_dtypes, self._pool_kinds = [], [], []
@@ -668,6 +670,12 @@ class ContinuousBatchingEngine:
                     (self.num_slots,) + tuple(ent.shape))
                 self._pool_dtypes.append(
                     jnp.dtype(ent.dtype) if ent.dtype else dtype)
+                self._pool_kinds.append("state")
+            elif isinstance(ent, CompressedKeys):
+                self._pool_shapes.append(
+                    (self.num_slots, -(-self.max_len // ent.stride),
+                     ent.kv_heads * ent.head_dim))
+                self._pool_dtypes.append(dtype)
                 self._pool_kinds.append("state")
             elif isinstance(ent, StepCounters):
                 if self._counter_pool is not None:

@@ -9,7 +9,10 @@ beside paged K/V, ``cache_spec.SlotState``), EXAONE-MoE (window layers
 over per-slot rings beside global layers over pages,
 ``cache_spec.WindowKV``; gated held-share experts) and Qwen3-Next (gated
 delta-rule layers over per-slot float32 state beside gated full attention
-over pages; softmax-routed held-share experts beside a gated shared one).
+over pages; softmax-routed held-share experts beside a gated shared one)
+and MiniCPM-SALA (learned block-sparse attention over pages with a
+per-slot compressed-key index, ``cache_spec.CompressedKeys``, beside
+decayed linear-attention layers over per-slot float32 state).
 Of Qwen3-Next ONE benchmark cell is measured, on one chip: its share of an
 8-way expert-parallel deployment, 12 of 48 layers, serving
 (``PERF.md``); nothing of it trains. DeepSeek-V2 (MLA) and ERNIE run dense
@@ -27,6 +30,7 @@ from .deepseek import DeepseekV2Config, DeepseekV2ForCausalLM
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM
 from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
+from .minicpm_sala import MiniCpmSalaConfig, MiniCpmSalaForCausalLM
 
 __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "LlamaModel", "LlamaForCausalLM", "LlamaForCausalLMPipe",
@@ -38,4 +42,5 @@ __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "DeepseekV2ForCausalLM", "NemotronHConfig",
            "NemotronHForCausalLM", "ExaoneMoeConfig",
            "ExaoneMoeForCausalLM", "Qwen3NextConfig",
-           "Qwen3NextForCausalLM"]
+           "Qwen3NextForCausalLM", "MiniCpmSalaConfig",
+           "MiniCpmSalaForCausalLM"]

@@ -27,6 +27,12 @@ alone. Three forms of the same recurrence:
   Pallas kernel ``ops.pallas.gated_delta_step`` (one pass over the state,
   in place), plain ``jax.numpy`` elsewhere.
 
+Without its delta term the rule is decayed linear attention (lightning
+attention): ``S <- exp(g) S + k v^T``, ``o = S^T q``. Its block form is
+Mamba-2's SSD with one group per head (``ops.mamba2.ssd_chunked``), and its
+one-step form, :func:`linear_state_step`, is the same kernel under a second
+static rule.
+
 All advance NOTHING where ``g = 0`` and ``beta = 0``, which is how a caller
 masks padded positions and idle streams. All state arithmetic is float32
 whatever the activations' dtype, and the chunked form's matmuls run at
@@ -43,7 +49,7 @@ from jax import lax
 from ..profiler import metrics as _pmetrics
 
 __all__ = ["gated_delta_scan", "gated_delta_chunked", "gated_delta_step",
-           "GDN_COUNTERS"]
+           "linear_state_step", "GDN_COUNTERS"]
 
 #: what a delta-rule layer counts per pass
 #: (``inference.cache_spec.StepCounters`` names, declared here once for
@@ -194,3 +200,23 @@ def gated_delta_step(S, q, k, v, g, beta, live=None, reset=None):
         from .pallas.gated_delta_step import gated_delta_step as _kernel
         return _kernel(S, q, k, v, decay, beta, live)
     return _step(S.astype(jnp.float32), q, k, v, decay, beta)
+
+
+def linear_state_step(S, q, k, v, decay, live=None, reset=None):
+    """One position of the no-delta rule ``S <- decay S + k v^T; o = S^T q``.
+    Shapes as :func:`gated_delta_step`; ``decay`` [B, H] (not a log). On a
+    TPU the same in-place kernel under its second rule
+    (``ops.pallas.gated_delta_step.linear_state_step``); elsewhere plain
+    ``jax.numpy``. Returns (o [B, H, dv] f32, new state)."""
+    q, k, v, decay = _f32(q, k, v, decay)
+    if reset is not None:
+        decay = jnp.where(reset[:, None], 0.0, decay)
+    if jax.devices()[0].platform == "tpu":
+        from .pallas.gated_delta_step import linear_state_step as _kernel
+        return _kernel(S, q, k, v, decay, live)
+    if live is not None:
+        decay = jnp.where(live[:, None], decay, 1.0)
+        k = jnp.where(live[:, None, None], k, 0.0)
+    S = S.astype(jnp.float32) * decay[..., None, None] \
+        + k[..., None] * v[..., None, :]
+    return jnp.sum(S * q[..., None], axis=-2), S

@@ -1,15 +1,20 @@
-"""One step of the gated delta rule as ONE pass over the state (Pallas/TPU).
+"""One step of a per-head ``[d_k, d_v]`` linear-attention state as ONE pass
+over it (Pallas/TPU), under one of two static rules.
 
-``ops.gated_delta`` states the recurrence. A decode step updates, per slot
+``ops.gated_delta`` states both recurrences. A decode step updates, per slot
 and head, a ``[d_k, d_v]`` float32 state (64 KiB at 128 x 128):
 
-    S <- decay S;  m = S^T k;  d = beta (v - m);  S <- S + k d^T;  o = S^T q
+    delta rule:     S <- decay S;  m = S^T k;  d = beta (v - m);  S <- S + k d^T
+    no-delta rule:  S <- decay S + k v^T       (lightning attention)
+    both:           o = S^T q
 
 In XLA the reduction ``S^T k`` has to finish before the rank-1 update
 starts, so the state is read twice and written once. Here a program holds
-``hb`` heads' states of one slot in VMEM, does all five lines there, and
+``hb`` heads' states of one slot in VMEM, does all the lines there, and
 writes them back over the input (``input_output_aliases``): one read, one
-write.
+write. The rule is a static argument: each compiles its own body under its
+own kernel name (``gated_delta_step``, ``linear_state_step``), so a trace
+tells them apart.
 
 Layout: the state block is ``[hb, d_k, d_v]`` (d_k on sublanes, d_v on
 lanes); ``v`` and ``o`` are rows ``[hb, d_v]``; ``k`` and ``q`` come
@@ -42,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._utils import interpret_mode as _interpret, no_x64 as _no_x64
 
-__all__ = ["gated_delta_step"]
+__all__ = ["gated_delta_step", "linear_state_step"]
 
 #: heads of one slot a program holds: 16 states of 64 KiB = 1 MiB a block,
 #: in and out double-buffered 4 MiB of VMEM
@@ -50,7 +55,7 @@ _HEADS_PER_PROGRAM = 16
 
 
 def _kernel(row_ref, col_ref, live_ref, lead_ref, decay_ref, beta_ref,
-            s_ref, kt_ref, qt_ref, v_ref, so_ref, o_ref, *, hb):
+            s_ref, kt_ref, qt_ref, v_ref, so_ref, o_ref, *, hb, delta):
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(live_ref[b] == 1)
@@ -60,8 +65,10 @@ def _kernel(row_ref, col_ref, live_ref, lead_ref, decay_ref, beta_ref,
             kcol = kt_ref[:, h:h + 1]                      # [dk, 1]
             qcol = qt_ref[:, h:h + 1]
             s = s_ref[h] * decay_ref[b, head]
-            m = jnp.sum(s * kcol, axis=0, keepdims=True)   # [1, dv]
-            d = beta_ref[b, head] * (v_ref[pl.ds(h, 1), :] - m)
+            d = v_ref[pl.ds(h, 1), :]                      # [1, dv]
+            if delta:
+                m = jnp.sum(s * kcol, axis=0, keepdims=True)
+                d = beta_ref[b, head] * (d - m)
             s = s + kcol * d
             so_ref[h] = s
             o_ref[pl.ds(h, 1), :] = jnp.sum(s * qcol, axis=0,
@@ -79,14 +86,27 @@ def gated_delta_step(S, q, k, v, decay, beta, live=None):
     B, H = S.shape[:2]
     if live is None:
         live = jnp.ones((B,), bool)
-    hb = max(h for h in range(1, min(H, _HEADS_PER_PROGRAM) + 1)
-             if H % h == 0)
-    return _call(S, q, k, v, decay, beta, live, hb=hb,
+    return _call(S, q, k, v, decay, beta, live, hb=_heads(H), delta=True,
                  interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
-def _call(S, q, k, v, decay, beta, live, hb, interpret):
+def linear_state_step(S, q, k, v, decay, live=None):
+    """The no-delta rule ``S <- decay S + k v^T; o = S^T q``, shapes as
+    :func:`gated_delta_step` (no beta). Returns (o [B, H, dv] f32, S)."""
+    B, H = S.shape[:2]
+    if live is None:
+        live = jnp.ones((B,), bool)
+    return _call(S, q, k, v, decay, jnp.zeros_like(decay), live,
+                 hb=_heads(H), delta=False, interpret=_interpret())
+
+
+def _heads(H):
+    return max(h for h in range(1, min(H, _HEADS_PER_PROGRAM) + 1)
+               if H % h == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("hb", "delta", "interpret"))
+def _call(S, q, k, v, decay, beta, live, hb, delta, interpret):
     B, H, dk, dv = S.shape
     nj = H // hb
     f32 = jnp.float32
@@ -130,12 +150,12 @@ def _call(S, q, k, v, decay, beta, live, hb, interpret):
     )
     with _no_x64():
         S, o = pl.pallas_call(
-            functools.partial(_kernel, hb=hb),
+            functools.partial(_kernel, hb=hb, delta=delta),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct(S.shape, f32),
                        jax.ShapeDtypeStruct((B, H, dv), f32)],
             input_output_aliases={6: 0},
-            name="gated_delta_step",
+            name="gated_delta_step" if delta else "linear_state_step",
             interpret=interpret,
         )(row, col, live.astype(jnp.int32), lead.astype(jnp.int32),
           decay.astype(f32), beta.astype(f32), S.astype(f32),

@@ -66,9 +66,10 @@ def native(monkeypatch, no_persistent_cache):
     from paddle_tpu.ops.pallas import (ce_chunk, flash_attention,
                                        gated_delta_step, grouped_matmul,
                                        ragged_paged_attention, rms_norm,
-                                       swiglu)
+                                       sparse_paged_attention, swiglu)
     for mod in (ce_chunk, flash_attention, gated_delta_step, grouped_matmul,
-                ragged_paged_attention, rms_norm, swiglu):
+                ragged_paged_attention, rms_norm, sparse_paged_attention,
+                swiglu):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -237,14 +238,16 @@ def _compile_serving_step(one_chip, topo, monkeypatch, kvh, kv_quant):
     return _lower_step(model, eng, one_chip, topo, monkeypatch) + (eng,)
 
 
-def _lower_step(model, eng, one_chip, topo, monkeypatch):
+def _lower_step(model, eng, one_chip, topo, monkeypatch,
+                chunks=(8, 128, 16)):
     """(lowered, compiled text, memory analysis) of ``eng``'s unified
     step program for the described chip, the model's parameters as
-    shapes, bound as ``to_static`` binds it: the pools donated."""
+    shapes, bound as ``to_static`` binds it: the pools donated.
+    ``chunks``: the engine's (group rows, prefill chunk, decode chunk)."""
     import paddle_tpu as paddle
     from paddle_tpu.framework.core import Tensor
     params = list(model.parameters())
-    assert (eng._group, eng.prefill_chunk, eng.decode_chunk) == (8, 128, 16)
+    assert (eng._group, eng.prefill_chunk, eng.decode_chunk) == chunks
     ustep = eng._unified_static().function
     s = _sds(one_chip)
     B, i32 = eng.num_slots, jnp.int32
@@ -464,6 +467,50 @@ def test_gated_delta_step_at_the_cells_shape(native, one_chip):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
+def test_linear_state_step_at_the_cells_shape(native, one_chip):
+    """The one-step kernel under its no-delta rule (lightning attention) at
+    a decode micro-step of the MiniCPM-SALA cell: 16 slots x 32 heads of
+    128 x 128 float32 state written over its input, under its own kernel
+    name."""
+    from paddle_tpu.ops.pallas.gated_delta_step import linear_state_step
+    s = _sds(one_chip)
+    f32 = jnp.float32
+    vec = s((16, 32, 128), f32)
+    lowered = jax.jit(linear_state_step, donate_argnums=(0,)).lower(
+        s((16, 32, 128, 128), f32), vec, vec, vec, s((16, 32), f32),
+        s((16,), bool))
+    assert "linear_state_step" in lowered.as_text()
+    assert "gated_delta_step" not in lowered.as_text()
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 16 * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("b,s_", [(1, 2048), (16, 1)],
+                         ids=["prefill_trip", "decode"])
+def test_sparse_paged_attention_at_the_cells_shapes(native, one_chip, b, s_):
+    """The block-sparse paged attention kernel at the MiniCPM-SALA cell's
+    shapes: a prefill trip of 2,048 queries of one slot and a decode step of
+    16, 32 / 2 heads x 128, pools of 16 x 528 pages of 64 keys, 64 blocks a
+    sparse query (the block lists in SMEM, a KV group's page columns
+    copied one page at a time, the copies it starts counted in SMEM)."""
+    from paddle_tpu.ops.pallas.sparse_paged_attention import (
+        sparse_paged_attention)
+    from paddle_tpu.ops.sparse_select import SparseRule
+    rule = SparseRule(32, 16, 64, 64, 1, 2048, 8192)
+    s = _sds(one_chip)
+    pool = s((16 * 528 + 1, 64, 256))
+    text = _compile(
+        lambda q, k, v, t, c, n, bl: sparse_paged_attention(
+            q, k, v, t, c, n, bl, rule),
+        s((b, s_, 32, 128)), pool, pool, s((b, 528), jnp.int32),
+        s((b,), jnp.int32), s((b,), jnp.int32),
+        s((b, s_, 2, 64), jnp.int32))
+    assert "sparse_paged_attention" in text
+
+
 def test_serving_step_program_at_qwen3_next_widths(native, one_chip, topo,
                                                    monkeypatch):
     """The ONE step program of a 4-layer model at Qwen3-Next's widths
@@ -508,6 +555,52 @@ def test_serving_step_program_at_qwen3_next_widths(native, one_chip, topo,
     assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", text)
     assert copies["state"] == (9, 3), copies
     _assert_pools_donated(text, mem, eng)
+
+
+def test_serving_step_program_at_minicpm_sala_widths(native, one_chip, topo,
+                                                     monkeypatch):
+    """The ONE step program of a 2-layer model at MiniCPM-SALA's widths
+    (hidden 4096, a lightning layer of 32 heads x 128 x 128 float32 state
+    beside a block-sparse layer of 32 / 2 heads x 128, SwiGLU 16384, the
+    whole 73,448-row vocabulary), 16 slots x 33,792 positions, page 64,
+    prefill chunk 2,048 (one row a trip): per-slot state and a compressed-
+    key index beside host-managed pages. It compiles for the described chip
+    with the sparse kernel in both loops and the no-delta step kernel in the
+    decode scan, fits the chip with its weights and under 1 GB of
+    temporaries, and copies no K/V pool."""
+    import re
+    from paddle_tpu.inference import ContinuousBatchingEngine
+    from paddle_tpu.models import MiniCpmSalaConfig, MiniCpmSalaForCausalLM
+    cfg = MiniCpmSalaConfig.minicpm_sala_9b()
+    cfg.num_hidden_layers = 2
+    cfg.mixer_types = ("lightning-attn", "minicpm4")
+    cfg.first_published_layer = 14
+    cfg.dtype, cfg.empty_init = "bfloat16", True
+    model = MiniCpmSalaForCausalLM(cfg)
+    model.eval()
+    eng = ContinuousBatchingEngine(model, num_slots=16, max_len=33792,
+                                   page_size=64, prefill_chunk=2048,
+                                   decode_chunk=16, greedy=True)
+    assert [tuple(p._data.shape) for p in eng.pools] \
+        == [(16, 32, 128, 128)] + [(16 * 528 + 1, 64, 256)] * 2 \
+        + [(16, 2112, 256), (4,)]
+    lowered, text, mem = _lower_step(model, eng, one_chip, topo,
+                                     monkeypatch, chunks=(1, 2048, 16))
+    for kernel in ("sparse_paged_attention", "linear_state_step"):
+        assert lowered.as_text().count(kernel) >= 1 and kernel in text
+    copies = _pool_copies(text, eng)
+    assert copies["kv"] == (0, 0), copies
+    # the 33 MB state is laid out [d_v, d_k]-minor in the group loop (the
+    # block form's transposed state) and [d_k, d_v]-minor in the scan (the
+    # step kernel's): two copies a step at the loops' edges, none inside
+    assert copies["state"] == (2, 0), copies
+    assert len(re.findall(r"= f32\[16,32,128,128\]\S* copy\(", text)) == 2
+    _assert_pools_donated(text, mem, eng)
+    weights = sum(int(np.prod(p.shape)) * 2 for p in model.parameters())
+    assert weights == 2 * (285_225_216 + 253_763_840 + 601_690_112)
+    # the program's own temporaries beside weights and pools: the
+    # selection's scores are tiled, a trip's activations are 2,048 rows
+    assert mem.temp_size_in_bytes < 1 << 30, mem
 
 
 def test_ragged_surface_offers_only_accepted_blocks(native, one_chip):

@@ -177,3 +177,75 @@ def test_softmax_router_is_softmax_top_k_renormalised():
     _, raw = moe_ops.softmax_top_k_router(jnp.asarray(logits), 3,
                                           norm_topk_prob=False)
     np.testing.assert_allclose(raw, pw, rtol=1e-6)
+
+
+# ---- the no-delta rule (lightning attention) ----------------------------------
+
+def _ssd_step_args(S, q, k, v, decay):
+    """The no-delta rule as Mamba-2's one-position update: x = v, B = k,
+    C = q, dt = 1, A = log(decay); the SSD state is S transposed."""
+    from paddle_tpu.ops import mamba2
+    Bn, H, dk, dv = S.shape
+    A = jnp.log(jnp.asarray(decay[0]))
+    y, h = mamba2.ssd_step(jnp.swapaxes(jnp.asarray(S), -1, -2),
+                           jnp.asarray(v), jnp.ones((Bn, H), jnp.float32), A,
+                           jnp.asarray(k), jnp.asarray(q),
+                           jnp.zeros((H,), jnp.float32))
+    return y, jnp.swapaxes(h, -1, -2)
+
+
+@pytest.mark.parametrize("live", [None, [True, False, True]],
+                         ids=["all_live", "a_dead_row"])
+def test_linear_state_step_is_ssd_step(live):
+    """The kernel under its no-delta rule and the jnp form both give
+    Mamba-2's one-position update with one group a head; a row that does
+    not advance is neither read nor written."""
+    rng = np.random.default_rng(4)
+    d = DV
+    q = rng.normal(size=(B, H, d)).astype(np.float32)
+    k = rng.normal(size=(B, H, d)).astype(np.float32)
+    v = rng.normal(size=(B, H, d)).astype(np.float32)
+    S0 = rng.normal(size=(B, H, d, d)).astype(np.float32)
+    decay = np.broadcast_to(np.exp(-np.linspace(0.01, 0.7, H)),
+                            (B, H)).astype(np.float32)
+    want_o, want_s = _ssd_step_args(S0, q, k, v, decay)
+    lv = None if live is None else jnp.asarray(live)
+    for fn in (kernel.linear_state_step, gd.linear_state_step):
+        o, s = fn(jnp.asarray(S0), q, k, v, decay, lv)
+        rows = range(B) if live is None else [i for i in range(B)
+                                              if live[i]]
+        for i in rows:
+            np.testing.assert_allclose(o[i], want_o[i], atol=1e-5)
+            np.testing.assert_allclose(s[i], want_s[i], atol=1e-5)
+        for i in set(range(B)) - set(rows):
+            np.testing.assert_array_equal(s[i], S0[i])
+
+
+def test_linear_state_step_restarts_a_row_from_zero():
+    rng = np.random.default_rng(6)
+    d = 8
+    S0 = rng.normal(size=(2, H, d, d)).astype(np.float32)
+    q, k, v = (rng.normal(size=(2, H, d)).astype(np.float32)
+               for _ in range(3))
+    decay = np.full((2, H), 0.9, np.float32)
+    o, s = gd.linear_state_step(jnp.asarray(S0), q, k, v, decay,
+                                reset=jnp.asarray([True, False]))
+    np.testing.assert_allclose(s[0], k[0][..., :, None] * v[0][..., None, :],
+                               atol=1e-6)
+    np.testing.assert_allclose(s[1], 0.9 * S0[1] + k[1][..., :, None]
+                               * v[1][..., None, :], atol=1e-5)
+
+
+def test_the_two_rules_are_two_kernels():
+    """The rule is a static argument: the delta rule keeps its kernel name
+    (``gated_delta_step``, which the Qwen3-Next cell's roofline reads) and
+    the no-delta rule gets its own."""
+    import jax
+    S = jnp.zeros((2, H, 8, 8), jnp.float32)
+    x = jnp.zeros((2, H, 8), jnp.float32)
+    dec = jnp.ones((2, H), jnp.float32)
+    t1 = jax.jit(kernel.gated_delta_step).lower(S, x, x, x, dec,
+                                                dec).as_text()
+    t2 = jax.jit(kernel.linear_state_step).lower(S, x, x, x, dec).as_text()
+    assert "gated_delta_step" in t1 and "linear_state_step" not in t1
+    assert "linear_state_step" in t2
