@@ -252,24 +252,19 @@ def _fit_e2e_bench(on_tpu, dev, autotune=False):
     ds = paddle.io.TensorDataset([ids_t, ids_t])
 
     # (a) raw compiled step over one resident batch — no loader, no
-    # prefetch, no loss bookkeeping; scalar fetch only at the end.
-    # Runs under the SAME fused-linear-CE default as fit (fit flips it
-    # via flags.scoped_default) so the raw/fit comparison times one
-    # program, and the StaticFunction cache discovered here matches
-    # what fit reuses.
-    from paddle_tpu.framework import flags as _flags
+    # prefetch, no loss bookkeeping; scalar fetch only at the end. The
+    # StaticFunction discovered here is the one fit reuses.
     x0 = paddle.to_tensor(ids_np[:batch])
     step_fn = m._static_train_step()
-    with _flags.scoped_default("FLAGS_fused_linear_cross_entropy", True):
-        loss = step_fn(x0, x0)            # discovery
-        loss = step_fn(x0, x0)            # compile+run
-        float(np.asarray(loss._data))
-        raw_steps = 2 * n_batches
-        t0 = time.perf_counter()
-        for _ in range(raw_steps):
-            loss = step_fn(x0, x0)
-        float(np.asarray(loss._data))
-        raw_ms = (time.perf_counter() - t0) / raw_steps * 1e3
+    loss = step_fn(x0, x0)            # discovery
+    loss = step_fn(x0, x0)            # compile+run
+    float(np.asarray(loss._data))
+    raw_steps = 2 * n_batches
+    t0 = time.perf_counter()
+    for _ in range(raw_steps):
+        loss = step_fn(x0, x0)
+    float(np.asarray(loss._data))
+    raw_ms = (time.perf_counter() - t0) / raw_steps * 1e3
 
     tuned_fit = {}
     if autotune:
@@ -2227,8 +2222,6 @@ def _autotune_bench(on_tpu):
              sweeps.rms_norm_residual_builder(rows=4096), 5),
             ("swiglu", {"h": 6912},
              sweeps.swiglu_builder(rows=4096), 9),
-            ("fused_ce", {"d": 2560, "v": 32000},
-             sweeps.fused_ce_builder(rows=4096), 4),
             # the cb section's unified batching-step kernel at its v5e
             # bench geometry (llama_1b: chunk 32, 12 x 32-token pages,
             # head_dim 128, 16:8 GQA) — swept BEFORE the cb section so
@@ -2248,8 +2241,6 @@ def _autotune_bench(on_tpu):
              sweeps.rms_norm_residual_builder(rows=256), 2),
             ("swiglu", {"h": 256},
              sweeps.swiglu_builder(rows=256), 2),
-            ("fused_ce", {"d": 64, "v": 1024},
-             sweeps.fused_ce_builder(rows=256), 2),
             ("ragged_paged_attention",
              {"c": 8, "pages": 4, "page": 8, "d": 16},
              sweeps.ragged_attention_builder(slots=2, heads=4,

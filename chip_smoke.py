@@ -219,7 +219,8 @@ def phase_kernels(serve, train, seed, tol=2.0 ** -6):
     import jax.numpy as jnp
 
     from paddle_tpu.nn.functional.attention import sdpa_reference
-    from paddle_tpu.ops.pallas import ce_chunk, rms_norm as RN
+    from paddle_tpu.ops import fused_ce
+    from paddle_tpu.ops.pallas import linear_ce, rms_norm as RN
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     from paddle_tpu.ops.pallas.ragged_paged_attention import (
         ragged_paged_attention)
@@ -305,26 +306,22 @@ def phase_kernels(serve, train, seed, tol=2.0 ** -6):
     u = jnp.asarray(rng.randn(rows, inter), jnp.bfloat16)
     both("swiglu", swiglu_fused, swiglu_reference, g, u)
 
-    vc = train["ce_chunk"]
-    logits = jnp.asarray(rng.randn(rows, vc) * 3.0, jnp.float32)
-    local = jnp.asarray(rng.randint(-vc // 4, vc + vc // 4, rows),
-                        jnp.int32)
-    lo = jnp.asarray(5, jnp.int32)
-    m, ssum, t = ce_chunk.chunk_stats(logits, local, lo)
-    col = jnp.arange(vc)[None, :]
-    valid = col >= lo
-    m_ref = jnp.max(jnp.where(valid, logits, -jnp.inf), -1)
-    s_ref = jnp.sum(jnp.where(valid, jnp.exp(logits - m_ref[:, None]), 0),
-                    -1)
-    hit = valid & (col == local[:, None])
-    t_ref = jnp.sum(jnp.where(hit, logits, 0.0), -1)
-    lse = m_ref + jnp.log(s_ref)
-    scale = jnp.asarray(rng.rand(rows), jnp.float32)
-    dl = ce_chunk.chunk_dlogits(logits, lse, local, scale, lo)
-    dl_ref = (jnp.where(valid, jnp.exp(logits - lse[:, None]), 0.0)
-              - hit.astype(jnp.float32)) * scale[:, None]
-    errs["ce_chunk"] = max(rel_err(m, m_ref), rel_err(ssum, s_ref),
-                           rel_err(t, t_ref), rel_err(dl, dl_ref))
+    # the fused head + CE kernels against the jnp scan, on the same bf16
+    # operands (f32 accumulation in both), the f32 weight in both layouts
+    n, d, v = train["ce"]
+    h = jnp.asarray(rng.randn(n, d), jnp.bfloat16)
+    lab = jnp.asarray(rng.randint(-1, v, n), jnp.int32)
+    scale = jnp.where(lab >= 0, 1.0 / n, 0.0).astype(jnp.float32)
+    for vf in (True, False):
+        w = jnp.asarray(0.02 * rng.randn(*((v, d) if vf else (d, v))),
+                        jnp.float32)
+        lse, tgt = linear_ce.lse_and_target(h, w, lab, vf)
+        lse_r, tgt_r = fused_ce._scan_fwd(h, w, lab, vf)
+        dh, dw = linear_ce.head_grads(h, w, lab, lse_r, scale, vf)
+        dh_r, dw_r = fused_ce._scan_bwd(h, w, lab, lse_r, scale, vf)
+        errs["linear_ce" + ("_vd" if vf else "_dv")] = max(
+            rel_err(lse, lse_r), rel_err(tgt, tgt_r), rel_err(dh, dh_r),
+            rel_err(dw, dw_r))
 
     say("kernels", tolerance=f"{tol:.4f}(rel-to-max)",
         **{k: f"{v:.2e}" for k, v in errs.items()})
@@ -743,7 +740,7 @@ def real_sizes():
                                 "kexa_window": (64, 8, 320, 128)})
     kernels_train = dict(
         flash={"gpt2": (4, 1024, 12, 12, 64), "qwen2": (1, 1024, 28, 4, 128)},
-        rows=1024, hidden=3584, inter=18944, ce_chunk=1024)
+        rows=1024, hidden=3584, inter=18944, ce=(1024, 768, 50257))
 
     def mesh_model(parallel):
         # TinyLlama/TinyLlama-1.1B-Chat-v1.0 config.json widths, depth

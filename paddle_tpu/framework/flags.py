@@ -24,8 +24,7 @@ import os
 import threading
 from typing import Any, Callable
 
-__all__ = ["define_flag", "get_flags", "set_flags", "flag", "flag_source",
-           "scoped_default"]
+__all__ = ["define_flag", "get_flags", "set_flags", "flag", "flag_source"]
 
 _lock = threading.Lock()
 _registry: dict[str, dict] = {}
@@ -104,54 +103,6 @@ def set_flags(flags: dict[str, Any]) -> None:
             cb(v)
 
 
-class scoped_default:
-    """Context manager: give ``name`` a different DEFAULT for the scope.
-
-    The new value applies only while the flag's current value came from
-    the ``define_flag`` literal — an explicit env var or ``set_flags``
-    call always wins (the module-docstring precedence), and the source
-    stays ``"default"`` so tuner-cache resolution is unaffected. Value
-    and source are restored on exit. This is how ``Model.fit`` turns
-    ``FLAGS_fused_linear_cross_entropy`` on for the compiled hot path
-    without overriding an operator's explicit choice."""
-
-    def __init__(self, name: str, value: Any):
-        self._name = name if name.startswith("FLAGS_") else \
-            "FLAGS_" + name
-        self._value = value
-        self._applied = False
-
-    def __enter__(self):
-        cb = val = None
-        with _lock:
-            ent = _registry[self._name]
-            self._prev = ent["value"]
-            if ent["source"] == "default":
-                ent["value"] = val = ent["type"](self._value)
-                self._applied = True
-                cb = ent["on_change"]
-        # fire on_change outside the lock, same contract as set_flags —
-        # callback-maintained state must track the scoped value too
-        if self._applied and cb is not None:
-            cb(val)
-        return self
-
-    def __exit__(self, *exc):
-        cb = None
-        restored = False
-        with _lock:
-            ent = _registry[self._name]
-            # only roll back our own write: a set_flags inside the scope
-            # is an explicit user choice and must survive
-            if self._applied and ent["source"] == "default":
-                ent["value"] = self._prev
-                restored = True
-                cb = ent["on_change"]
-        if restored and cb is not None:
-            cb(self._prev)
-        return False
-
-
 # -- core flags (mirroring commonly-used FLAGS_* names where sensible) ------
 define_flag("FLAGS_check_nan_inf", False,
             "Check outputs for NaN/Inf after each op (debug).")
@@ -220,27 +171,6 @@ define_flag("FLAGS_ragged_attn_kv_pages", 0,
             "Ragged paged-attention: KV pages per DMA compute block "
             "(0 = sized to the static shape by the kernel's module: 512 "
             "keys for a decode step, 128-256 for a prefill group).")
-define_flag("FLAGS_fused_linear_cross_entropy", False,
-            "LM training loss: chunked fused lm_head-matmul +"
-            " cross-entropy that never materializes [N, V] logits "
-            "(ops/fused_ce.py); the labeled forward then returns "
-            "(None, loss). Module default OFF for the bare labeled "
-            "forward, but hapi.Model.fit(compiled=True) turns it on "
-            "for the compiled hot path via flags.scoped_default (the "
-            "memory headroom is what buys bigger per-chip batches "
-            "there); an explicit env/set_flags value wins either way. "
-            "fit(compiled=False) stays the eager UNFUSED parity "
-            "oracle.")
-define_flag("FLAGS_fused_ce_chunk_v", 1024,
-            "Fused linear+CE vocab-chunk width. This is a tunable "
-            "surface ('fused_ce', paddle_tpu.tuner): an explicit env/"
-            "set_flags value wins over a tuner-cache entry, which wins "
-            "over this default (flag_source distinguishes).")
-define_flag("FLAGS_fused_ce_pallas_inner", True,
-            "Fused linear+CE: run the per-chunk softmax stats and "
-            "backward dlogits through the Pallas inner kernels "
-            "(ops/pallas/ce_chunk.py) on TPU, keeping the scan body's "
-            "elementwise work in VMEM (0 = pure jnp scan body).")
 define_flag("FLAGS_fused_rmsnorm_residual", True,
             "Decoder hot path: fuse each residual-add with the "
             "following RMSNorm (ops/pallas/rms_norm.rms_norm_residual "

@@ -155,20 +155,14 @@ class Model:
         raise RuntimeError("prepare(loss=...) first")
 
     def _fused_network_loss(self):
-        """True when the compiled steps should route labels INTO the
-        network and take its fused linear+cross-entropy loss
-        (ops/fused_ce.py — never materializes [N, V] logits) instead of
-        running the criterion over materialized logits. Requires BOTH
-        the flag (fit turns it on by default for the compiled path via
-        flags.scoped_default) and a criterion that certifies the
-        network's labeled loss is numerics-identical
-        (``fuses_with_network_loss`` — e.g. LlamaPretrainingCriterion).
-        The eager ``train_batch`` loop never takes this path: it stays
-        the unfused parity oracle."""
-        from ..framework import flags
-        return (flags.flag("FLAGS_fused_linear_cross_entropy")
-                and getattr(self._loss, "fuses_with_network_loss",
-                            False))
+        """True when the compiled steps route labels INTO the network and
+        take its labeled loss — the fused head + cross-entropy tail
+        (ops/fused_ce.py, no [N, V] logits) — in place of the criterion
+        over logits: when the criterion certifies that the two are the
+        same loss (``fuses_with_network_loss``, e.g.
+        LlamaPretrainingCriterion). The eager ``train_batch`` loop runs
+        the criterion over logits: the parity oracle."""
+        return getattr(self._loss, "fuses_with_network_loss", False)
 
     def _backward_and_step(self, loss):
         """Backward + optimizer update, through the GradScaler when one
@@ -228,14 +222,6 @@ class Model:
         no host fetch; the fit loop resolves values at log boundaries.
         ``train_batch`` stays the eager parity oracle."""
         sf = getattr(self, "_compiled_train_step", None)
-        # the fused-loss branch is decided at TRACE time; if the flag
-        # state changed since this step was built (e.g. an explicit
-        # set_flags OFF after a fused fit), the cached program is stale
-        # — rebuild so the explicit choice actually wins
-        fused_now = self._fused_network_loss()
-        if sf is not None and \
-                getattr(self, "_compiled_train_fused", None) != fused_now:
-            sf = None
         if sf is None:
             def train_step(*args):
                 *xs, y = args
@@ -243,8 +229,8 @@ class Model:
 
                 def fwd_loss():
                     if self._fused_network_loss():
-                        # labeled forward: the network's fused lm_head
-                        # +CE tail (returns (None|logits, loss))
+                        # labeled forward: the network's fused head + CE
+                        # tail, (None, loss)
                         return self.network(*xs, labels=y)[1]
                     return self._compute_loss(self.network(*xs), y)
 
@@ -260,17 +246,10 @@ class Model:
             from ..jit.to_static_api import StaticFunction
             sf = StaticFunction(train_step)
             self._compiled_train_step = sf
-            self._compiled_train_fused = fused_now
         return sf
 
     def _static_eval_step(self):
         sf = getattr(self, "_compiled_eval_step", None)
-        # same staleness rule as the train step: the fused-loss branch
-        # bakes in at trace time, so a flag-state change rebuilds
-        fused_now = self._fused_network_loss()
-        if sf is not None and \
-                getattr(self, "_compiled_eval_fused", None) != fused_now:
-            sf = None
         if sf is None:
             def eval_step(*args):
                 *xs, y = args
@@ -285,7 +264,6 @@ class Model:
             from ..jit.to_static_api import StaticFunction
             sf = StaticFunction(eval_step)
             self._compiled_eval_step = sf
-            self._compiled_eval_fused = fused_now
         return sf
 
     def _resolve_fit_pipeline(self, batch_size, prefetch_depth,
@@ -581,22 +559,7 @@ class Model:
             from ..distributed.fleet.elastic import PreemptionGuard
             guard = PreemptionGuard().install()
             own_guard = True
-        # the compiled hot path defaults the fused linear+CE tail ON
-        # (the [N, V] logits buffer is what caps per-chip batch there);
-        # scoped_default only applies while the flag is untouched — an
-        # explicit env/set_flags OFF (or ON) wins — and is restored on
-        # exit, so eager code outside fit stays the unfused oracle.
-        # Entered BEFORE the step is built: _static_train_step keys its
-        # cache on the fused-loss state, which must match what the
-        # trace inside the epoch loop will see; the try/finally below
-        # owns the scope, so no error path can leak the default.
-        import contextlib
-        from ..framework import flags as _flags
-        _scope = contextlib.ExitStack()
         try:
-            if compiled:
-                _scope.enter_context(_flags.scoped_default(
-                    "FLAGS_fused_linear_cross_entropy", True))
             step_fn = self._static_train_step() if compiled else None
             for epoch in range(start_epoch, epochs):
                 epoch_t0 = time.perf_counter()
@@ -675,7 +638,6 @@ class Model:
             # later must not book the idle gap as productive time
             ledger.close()
             _persist_ledger(ledger)
-            _scope.close()
             if own_guard:
                 guard.uninstall()
 

@@ -31,6 +31,7 @@ from ..framework.core import Tensor, apply
 from ..nn import functional as F
 from ..ops import manipulation as M
 from ..ops.linalg import matmul
+from ..distributed.communication import in_traced_collective
 from ..distributed.parallel_layers import (ColumnParallelLinear,
                                            RowParallelLinear,
                                            VocabParallelEmbedding)
@@ -410,18 +411,28 @@ class DeepseekV2ForCausalLM(nn.Layer, GenerationMixin):
             else:
                 x = layer(x)
         hidden = self.norm(x)
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
+        tied = self.lm_head is None
+        if labels is not None and not in_traced_collective():
+            # the head fused with the shifted CE: no [N, V] logits
+            # (ops/fused_ce.py); the labeled training forward returns
+            # (None, loss). Inside a traced collective the head is
+            # vocab-parallel and takes the plain tail below.
+            from ..ops.fused_ce import lm_head_loss
+            logits = None
+            loss = lm_head_loss(hidden, self.embed_tokens.weight if tied
+                                else self.lm_head.weight, labels,
+                                vocab_first=tied)
         else:
             logits = matmul(hidden, self.embed_tokens.weight,
-                            transpose_y=True)
-        if labels is None:
-            return logits
-        shift_logits = logits[:, :-1, :]
-        shift_labels = labels[:, 1:]
-        loss = F.cross_entropy(
-            M.reshape(shift_logits, [-1, self.config.vocab_size]),
-            M.reshape(shift_labels, [-1]))
+                            transpose_y=True) if tied \
+                else self.lm_head(hidden)
+            if labels is None:
+                return logits
+            shift_logits = logits[:, :-1, :]
+            shift_labels = labels[:, 1:]
+            loss = F.cross_entropy(
+                M.reshape(shift_logits, [-1, self.config.vocab_size]),
+                M.reshape(shift_labels, [-1]))
         coef = self.config.router_aux_loss_coef
         if coef:
             # stored aux tracers cannot cross a jax.checkpoint boundary;

@@ -188,8 +188,10 @@ class GPT2ForCausalLM(nn.Layer, GenerationMixin):
 
     def forward(self, input_ids, labels=None, caches=None, pos=None,
                 tables=None, logits_at=None):
-        """``logits_at`` (serving path only): per row the ONE position
-        whose logits are wanted; the result is ``[B, 1, V]``."""
+        """Logits; with ``labels`` (training), ``(None, loss)``: the mean
+        next-token cross-entropy. ``logits_at`` (serving path only): per
+        row the ONE position whose logits are wanted; the result is
+        ``[B, 1, V]``."""
         from ..ops.linalg import matmul
         if caches is not None:
             hidden, caches = self.gpt2(input_ids, caches=caches, pos=pos,
@@ -197,13 +199,10 @@ class GPT2ForCausalLM(nn.Layer, GenerationMixin):
             logits = matmul(hidden, self.gpt2.wte.weight, transpose_y=True)
             return logits, caches
         hidden = self.gpt2(input_ids)
-        logits = matmul(hidden, self.gpt2.wte.weight, transpose_y=True)
-        if labels is None:
-            return logits
-        # shift: predict token t+1 from prefix ≤ t
-        shift_logits = logits[:, :-1, :]
-        shift_labels = labels[:, 1:]
-        loss = F.cross_entropy(
-            M.reshape(shift_logits, [-1, self.config.vocab_size]),
-            M.reshape(shift_labels, [-1]))
-        return logits, loss
+        if labels is not None:
+            # the tied head [V, D] as stored, fused with the shifted CE:
+            # no [N, V] logits (ops/fused_ce.py)
+            from ..ops.fused_ce import lm_head_loss
+            return None, lm_head_loss(hidden, self.gpt2.wte.weight, labels,
+                                      vocab_first=True)
+        return matmul(hidden, self.gpt2.wte.weight, transpose_y=True)

@@ -660,20 +660,17 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
         else:
             hidden = self.llama(input_ids)
         if labels is not None and caches is None and \
-                self.lm_head is not None and \
-                flags.flag("FLAGS_fused_linear_cross_entropy") and \
                 not in_traced_collective():
-            # chunked fused lm_head+CE: never materializes [N, V] logits
-            # (~0.8GB of HBM traffic at N=4k, V=32k). Logits are not
-            # computed on this path — the labeled training forward
-            # returns (None, loss).
-            from ..ops.fused_ce import fused_linear_cross_entropy as flce
-            h2 = M.reshape(hidden[:, :-1, :],
-                           [-1, self.config.hidden_size])
-            l2 = M.reshape(labels[:, 1:], [-1])
-            loss = apply(flce, h2, self.lm_head.weight, l2,
-                         name="fused_linear_xent")
-            return None, loss
+            # the head fused with the shifted CE: no [N, V] logits
+            # (ops/fused_ce.py); the labeled training forward returns
+            # (None, loss). Inside a traced collective the head is
+            # vocab-parallel and takes the plain tail below.
+            from ..ops.fused_ce import lm_head_loss
+            if self.lm_head is not None:
+                return None, lm_head_loss(hidden, self.lm_head.weight,
+                                          labels, vocab_first=False)
+            return None, lm_head_loss(hidden, self.llama.embed_tokens.weight,
+                                      labels, vocab_first=True)
         if self.lm_head is not None:
             logits = self.lm_head(hidden)
         else:
@@ -741,8 +738,8 @@ class LlamaPretrainingCriterion(nn.Layer):
     ``fuses_with_network_loss`` certifies exactly that contract to
     ``hapi.Model``: ``network(x, labels=y)[1]`` equals
     ``criterion(network(x), y)``, so the compiled fit step may route
-    labels into the network and let the fused linear+cross-entropy
-    path (FLAGS_fused_linear_cross_entropy) skip the [N, V] logits."""
+    labels into the network and let its fused head + cross-entropy
+    tail (ops/fused_ce.py) skip the [N, V] logits."""
 
     fuses_with_network_loss = True
 

@@ -6,8 +6,8 @@ Each module exposes a jnp reference implementation (used on CPU and as the
 numerics oracle in tests) and a Pallas kernel used on TPU when
 FLAGS_enable_pallas_kernels is set."""
 
-from . import (ce_chunk, flash_attention, ragged_paged_attention,
+from . import (flash_attention, linear_ce, ragged_paged_attention,
                rms_norm, rope, swiglu)
 
-__all__ = ["ce_chunk", "flash_attention", "ragged_paged_attention",
+__all__ = ["flash_attention", "linear_ce", "ragged_paged_attention",
            "rms_norm", "rope", "swiglu"]

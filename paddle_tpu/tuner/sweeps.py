@@ -29,14 +29,13 @@ __all__ = ["ensure_builtin_surfaces", "auto_builder",
            "grouped_matmul_builder", "flash_attention_builder",
            "rms_norm_builder", "ragged_attention_builder",
            "rms_norm_residual_builder", "swiglu_builder",
-           "fused_ce_builder", "BENCH_PRESETS"]
+           "BENCH_PRESETS"]
 
 
 def ensure_builtin_surfaces():
     """Import every module that registers a built-in surface (imports
     are the registration mechanism — registrations live next to their
     knobs)."""
-    from ..ops import fused_ce  # noqa: F401
     from ..ops.pallas import flash_attention  # noqa: F401
     from ..ops.pallas import grouped_matmul  # noqa: F401
     from ..ops.pallas import ragged_paged_attention  # noqa: F401
@@ -241,47 +240,6 @@ def swiglu_builder(rows=4096, dtype="bfloat16", train=True):
     return builder
 
 
-def fused_ce_builder(rows=4096, dtype="bfloat16", train=True):
-    """Builder for the ``fused_ce`` surface (shape supplies d/v): the
-    chunked lm_head+CE tail at the train geometry. Candidates pin the
-    chunk width through ``force_chunk_v`` (NOT set_flags — that would
-    mark FLAGS_fused_ce_chunk_v user-explicit and defeat the
-    override > cache > default precedence), fresh jit per candidate."""
-    import jax
-    import jax.numpy as jnp
-
-    def builder(config, shape):
-        from ..ops.fused_ce import (force_chunk_v,
-                                    fused_linear_cross_entropy)
-        d, v = int(shape["d"]), int(shape["v"])
-        n = int(rows)
-        dt = jnp.dtype(dtype)
-        key = jax.random.PRNGKey(0)
-        kh, kw, kl = jax.random.split(key, 3)
-        h = jax.random.normal(kh, (n, d), jnp.float32).astype(dt)
-        w = (jax.random.normal(kw, (d, v), jnp.float32) * 0.02).astype(dt)
-        labels = jax.random.randint(kl, (n,), 0, v, jnp.int32)
-        cv = int(config["chunk_v"])
-
-        if train:
-            step = jax.jit(jax.grad(
-                lambda hh, ww: fused_linear_cross_entropy(hh, ww,
-                                                          labels),
-                argnums=(0, 1)))
-        else:
-            step = jax.jit(lambda hh, ww: fused_linear_cross_entropy(
-                hh, ww, labels))
-
-        def fn():
-            # the force context must cover the first (tracing) call;
-            # later calls hit this candidate's own jit cache
-            with force_chunk_v(cv):
-                return _trial(step, h, w)
-        return fn
-
-    return builder
-
-
 def ragged_attention_builder(slots=8, heads=8, kv_heads=2,
                              dtype="bfloat16"):
     """Builder for the ``ragged_paged_attention`` surface (shape
@@ -373,7 +331,6 @@ _AUTO_BUILDERS = {
     "rms_norm_residual":
         lambda dtype: rms_norm_residual_builder(dtype=dtype),
     "swiglu": lambda dtype: swiglu_builder(dtype=dtype),
-    "fused_ce": lambda dtype: fused_ce_builder(dtype=dtype),
     "ragged_paged_attention":
         lambda dtype: ragged_attention_builder(dtype=dtype),
 }
@@ -402,7 +359,6 @@ BENCH_PRESETS = {
         # geometry (hidden 2560, intermediate 6912, vocab 32000)
         ("rms_norm_residual", {"d": 2560}),
         ("swiglu", {"h": 6912}),
-        ("fused_ce", {"d": 2560, "v": 32000}),
     ],
     "serving": [
         # the v5e llama_1b cb-bench geometry: chunk 32, 12-page rows of
@@ -424,7 +380,6 @@ BENCH_PRESETS = {
         ("rms_norm", {"d": 128}),
         ("rms_norm_residual", {"d": 128}),
         ("swiglu", {"h": 256}),
-        ("fused_ce", {"d": 64, "v": 1024}),
         ("ragged_paged_attention",
          {"c": 8, "pages": 4, "page": 8, "d": 16}),
         ("ragged_paged_attention",

@@ -63,13 +63,13 @@ def native(monkeypatch, no_persistent_cache):
     """Compile the kernels natively: off-TPU each kernel module asks
     ``_interpret()`` and would lower the interpreter instead. Steered
     here, in the test — the program has no option for it."""
-    from paddle_tpu.ops.pallas import (ce_chunk, flash_attention,
-                                       gated_delta_step, grouped_matmul,
+    from paddle_tpu.ops.pallas import (flash_attention, gated_delta_step,
+                                       grouped_matmul, linear_ce,
                                        ragged_paged_attention, rms_norm,
                                        sparse_paged_attention, swiglu)
-    for mod in (ce_chunk, flash_attention, gated_delta_step, grouped_matmul,
-                ragged_paged_attention, rms_norm, sparse_paged_attention,
-                swiglu):
+    for mod in (flash_attention, gated_delta_step, grouped_matmul,
+                linear_ce, ragged_paged_attention, rms_norm,
+                sparse_paged_attention, swiglu):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -684,20 +684,86 @@ def test_swiglu_fwd_bwd(native, one_chip):
     _compile(_grad_sum(swiglu_fused), g, g)
 
 
-@pytest.mark.parametrize("chunk", [1024, 8192])
-def test_ce_chunk_pair(native, one_chip, chunk):
-    """The stats/dlogits pair at the default chunk and at the widest
-    one the ``fused_ce`` surface can select."""
-    from paddle_tpu.ops.pallas.ce_chunk import chunk_dlogits, chunk_stats
+#: the training cell's loss tail: 4 x 1024 positions (4 x 1023 with a
+#: target), GPT-2's width and vocabulary
+_CE_N, _CE_D, _CE_V = 4092, 768, 50257
+
+
+def _big_buffers(text, elements):
+    """The f32/bf16 buffer shapes in ``text`` of ``elements`` or more."""
+    import re
+    return {m.group(0) for m in re.finditer(r"(f32|bf16)\[([\d,]+)\]", text)
+            if np.prod([int(x) for x in m.group(2).split(",")]) >= elements}
+
+
+@pytest.mark.parametrize("layout", ["vd", "dv"])
+def test_linear_ce_fwd_bwd(native, one_chip, layout):
+    """The fused head + CE pair at the training cell's shape, the f32
+    master weight in either layout (``[V, D]`` tied, ``[D, V]``): the
+    forward writes only [N] vectors, the backward one bf16 dlogits."""
+    from paddle_tpu.ops.pallas.linear_ce import (_resolve_tiles, head_grads,
+                                                 lse_and_target)
     s = _sds(one_chip)
-    n = 8192
-    logits = s((n, chunk), jnp.float32)
-    vec_i, vec_f = s((n,), jnp.int32), s((n,), jnp.float32)
-    lo = s((), jnp.int32)
-    _compile(chunk_stats, logits, vec_i, lo)
-    _compile(lambda lg, lse, loc, sc, lo_: chunk_dlogits(
-        lg, lse, loc, sc, lo_, out_dtype=BF16),
-        logits, vec_f, vec_i, vec_f, lo)
+    vf = layout == "vd"
+    h = s((_CE_N, _CE_D))
+    w = s((_CE_V, _CE_D) if vf else (_CE_D, _CE_V), jnp.float32)
+    vec_i, vec_f = s((_CE_N,), jnp.int32), s((_CE_N,), jnp.float32)
+    weight = {f"f32[{w.shape[0]},{w.shape[1]}]"}
+    text = _compile(lambda h_, w_, lab: lse_and_target(h_, w_, lab, vf),
+                    h, w, vec_i)
+    assert "linear_ce_fwd" in text
+    assert _big_buffers(text, _CE_N * 1024) == weight
+    text = _compile(lambda h_, w_, lab, lse, sc: head_grads(
+        h_, w_, lab, lse, sc, vf), h, w, vec_i, vec_f, vec_f)
+    assert "linear_ce_dlogits" in text
+    # besides the weight and dW: the dlogits, once, and the weight in
+    # bf16 for XLA's dh matmul
+    tm = _resolve_tiles(_CE_N, _CE_V)[0]
+    dlogits = f"bf16[{-(-_CE_N // tm) * tm},{_CE_V}]"
+    assert _big_buffers(text, _CE_N * 1024) <= weight | {
+        dlogits, "bf16" + next(iter(weight))[3:]}
+
+
+def test_gpt2_training_step_keeps_logits_in_vmem(native, one_chip,
+                                                 monkeypatch):
+    """GPT-2's labeled forward and backward under AMP O1 at the training
+    cell's shapes (batch 4 x 1024, vocabulary 50,257, two layers): the
+    fused kernels run, and no f32 array of 4,092 x 50,257 elements or
+    more is left in the program."""
+    import paddle_tpu as paddle
+    from paddle_tpu.framework.core import Tensor
+    from paddle_tpu.models import GPT2Config, GPT2ForCausalLM
+
+    cfg = GPT2Config(num_hidden_layers=2, hidden_dropout_prob=0.0,
+                     attention_dropout_prob=0.0)
+    assert cfg.vocab_size == _CE_V and cfg.hidden_size == _CE_D
+    model = GPT2ForCausalLM(cfg)
+    model.train()
+    params = list(model.parameters())
+    # the call sites ask which platform they run on: answer for the
+    # described chip while the program is traced
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss_fn(leaves, ids):
+        held = [p._data for p in params]
+        for p, a in zip(params, leaves):
+            p._data = a
+        try:
+            with paddle.no_grad(), paddle.amp.auto_cast(level="O1",
+                                                        dtype="bfloat16"):
+                _, loss = model(Tensor(ids), labels=Tensor(ids))
+            return loss._data
+        finally:
+            for p, a in zip(params, held):
+                p._data = a
+
+    s = _sds(one_chip)
+    text = _compile(jax.value_and_grad(loss_fn),
+                    [s(tuple(p.shape), p._data.dtype) for p in params],
+                    s((4, 1024), jnp.int32))
+    assert "linear_ce_fwd" in text and "linear_ce_dlogits" in text
+    big = _big_buffers(text, _CE_N * _CE_V)
+    assert not {b for b in big if b.startswith("f32")}, big
 
 
 def test_grouped_matmul_fwd_bwd(native, one_chip):

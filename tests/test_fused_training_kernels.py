@@ -1,10 +1,11 @@
 """ISSUE-8 tentpole: the fused training-kernel suite.
 
-Interpret-mode kernel-vs-oracle parity + gradient checks for the three
-new kernels (fused RMSNorm+residual, fused SwiGLU, the fused-CE Pallas
-chunk kernels), the fused decoder wiring's bit-parity against the
-unfused stack, and the compiled-fit fused-linear-CE path against the
-eager unfused oracle at pinned rtol.
+Interpret-mode kernel-vs-oracle parity + gradient checks for the fused
+training kernels (fused RMSNorm+residual, fused SwiGLU, the fused head +
+cross-entropy kernels), the fused decoder wiring's bit-parity against
+the unfused stack, the labeled forwards' fused loss tail against the
+cross-entropy over their logits, and the compiled fit's labeled loss
+against the eager criterion-over-logits oracle at pinned rtol.
 
 The ``fused_parity`` marker selects the kernel-parity subset the
 ``tools/run_gates.py fused_parity`` gate runs with fused flags forced
@@ -27,9 +28,7 @@ from paddle_tpu.framework import flags
 def flag_guard():
     """Snapshot/restore the fused-suite flags (value AND source) so a
     test's set_flags can't leak user-explicit state into the session."""
-    names = ["FLAGS_fused_linear_cross_entropy",
-             "FLAGS_fused_rmsnorm_residual", "FLAGS_fused_swiglu",
-             "FLAGS_fused_ce_chunk_v", "FLAGS_fused_ce_pallas_inner"]
+    names = ["FLAGS_fused_rmsnorm_residual", "FLAGS_fused_swiglu"]
     saved = {n: dict(flags._registry[n]) for n in names}
     yield flags
     for n, ent in saved.items():
@@ -178,12 +177,13 @@ class TestSwigluKernel:
 
 
 # ===========================================================================
-# fused linear + cross-entropy (chunk resolution, pallas inner, edges)
+# fused head + cross-entropy: the TPU kernels (interpreted here) and the
+# jnp scan, both against the plain CE over materialized logits
 # ===========================================================================
 
 
-def _plain_ce(h, w, labels, ignore_index=-100):
-    logits = h @ w
+def _plain_ce(h, w, labels, ignore_index=-100, vocab_first=False):
+    logits = h @ (w.T if vocab_first else w)
     lp = jax.nn.log_softmax(logits, axis=-1)
     valid = labels != ignore_index
     safe = jnp.where(valid, labels, 0)
@@ -192,94 +192,177 @@ def _plain_ce(h, w, labels, ignore_index=-100):
         / jnp.maximum(jnp.sum(valid), 1)
 
 
+def _ce_path(monkeypatch, path, tiles):
+    """Steer ``ops.fused_ce`` to one of its two implementations: the
+    kernels (interpreted off the TPU) at ``tiles`` = (rows, vocab), or
+    the jnp scan at ``tiles`` vocab columns a chunk."""
+    from paddle_tpu.ops import fused_ce
+    from paddle_tpu.ops.pallas import linear_ce
+    if path == "kernel":
+        monkeypatch.setattr(fused_ce, "_use_kernel", lambda: True)
+        monkeypatch.setattr(linear_ce, "_ROW_TILE", tiles[0])
+        monkeypatch.setattr(linear_ce, "_VOCAB_TILE", tiles[1])
+    else:
+        monkeypatch.setattr(fused_ce, "_CHUNK_V", tiles)
+
+
 @pytest.mark.fused_parity
 class TestFusedCE:
-    def _data(self, n=24, d=16, v=50, seed=0):
+    def _data(self, n=24, d=16, v=50, vocab_first=False, seed=0):
         rng = np.random.RandomState(seed)
         h = jnp.asarray(rng.randn(n, d).astype("float32"))
-        w = jnp.asarray(rng.randn(d, v).astype("float32") * 0.1)
+        w = jnp.asarray(rng.randn(*((v, d) if vocab_first else (d, v)))
+                        .astype("float32") * 0.1)
         labels = jnp.asarray(rng.randint(0, v, (n,)).astype("int32"))
         return h, w, labels
 
-    @pytest.mark.parametrize("inner", ["jnp", "pallas"])
-    @pytest.mark.parametrize("v,cv", [
-        (50, 8),       # V % chunk != 0: clamped tail chunk overlaps
-        (48, 8),       # dividing
-        (50, 64),      # single chunk wider than the vocab
+    @pytest.mark.parametrize("layout", ["dv", "vd"])
+    @pytest.mark.parametrize("path,n,v,tiles", [
+        ("scan", 24, 50, 8),      # V % chunk != 0: clamped tail overlaps
+        ("scan", 24, 48, 8),      # dividing
+        ("scan", 24, 50, 1024),   # one chunk wider than the vocab
+        ("kernel", 37, 300, (16, 128)),   # neither N nor V divides a tile
+        ("kernel", 32, 256, (16, 128)),   # dividing
+        ("kernel", 24, 50, (1024, 512)),  # one tile larger than both
     ])
-    def test_pad_vocab_parity_and_grads(self, inner, v, cv):
-        """Loss + dh/dW parity against the plain CE at every chunk
-        shape, targets planted in the tail/overlap region, one ignored
-        row — through BOTH scan-body implementations."""
-        import contextlib
-
-        from paddle_tpu.ops.fused_ce import (force_chunk_v,
-                                             force_pallas_inner,
-                                             fused_linear_cross_entropy)
-        h, w, labels = self._data(v=v)
+    def test_parity_and_grads(self, monkeypatch, layout, path, n, v, tiles):
+        """Loss + dh/dW parity against the plain CE, a target in the last
+        vocab column (the tail tile / the overlap), one ignored row, the
+        weight in either layout: ``[D, V]`` or ``[V, D]`` (a tied
+        embedding), dW returned in it."""
+        from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
+        vf = layout == "vd"
+        h, w, labels = self._data(n=n, v=v, vocab_first=vf)
         labels = labels.at[3].set(-100).at[0].set(v - 1)
-        ctx = force_pallas_inner() if inner == "pallas" \
-            else contextlib.nullcontext()
-        ref = float(_plain_ce(h, w, labels))
-        g_ref = jax.grad(lambda a, b: _plain_ce(a, b, labels),
-                         argnums=(0, 1))(h, w)
-        with ctx, force_chunk_v(cv):
-            out = float(fused_linear_cross_entropy(h, w, labels))
-            np.testing.assert_allclose(out, ref, rtol=1e-5)
-            g = jax.jit(jax.grad(
-                lambda a, b: fused_linear_cross_entropy(a, b, labels),
-                argnums=(0, 1)))(h, w)
+        _ce_path(monkeypatch, path, tiles)
+
+        def fused(a, b):
+            return fused_linear_cross_entropy(a, b, labels, -100, vf)
+
+        def plain(a, b):
+            return _plain_ce(a, b, labels, vocab_first=vf)
+
+        np.testing.assert_allclose(float(fused(h, w)), float(plain(h, w)),
+                                   rtol=1e-5)
+        g = jax.jit(jax.grad(fused, argnums=(0, 1)))(h, w)
+        g_ref = jax.grad(plain, argnums=(0, 1))(h, w)
+        assert g[1].shape == w.shape
         for a, b in zip(g, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-6)
 
-    @pytest.mark.parametrize("inner", ["jnp", "pallas"])
-    def test_all_ignored_rows_zero_not_nan(self, inner):
+    @pytest.mark.parametrize("path,tiles", [("scan", 8),
+                                            ("kernel", (16, 128))])
+    def test_all_ignored_rows_zero_not_nan(self, monkeypatch, path, tiles):
         """An ignore_index-heavy batch degrading to ALL-masked must
         yield loss exactly 0 and zero (not NaN) grads."""
-        import contextlib
-
-        from paddle_tpu.ops.fused_ce import (force_chunk_v,
-                                             force_pallas_inner,
-                                             fused_linear_cross_entropy)
-        h, w, _ = self._data()
+        from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
+        h, w, _ = self._data(v=200)
         labels = jnp.full((h.shape[0],), -100, jnp.int32)
-        ctx = force_pallas_inner() if inner == "pallas" \
-            else contextlib.nullcontext()
-        with ctx, force_chunk_v(8):
-            assert float(fused_linear_cross_entropy(h, w, labels)) == 0.0
-            g = jax.grad(
-                lambda a, b: fused_linear_cross_entropy(a, b, labels),
-                argnums=(0, 1))(h, w)
+        _ce_path(monkeypatch, path, tiles)
+        assert float(fused_linear_cross_entropy(h, w, labels)) == 0.0
+        g = jax.grad(
+            lambda a, b: fused_linear_cross_entropy(a, b, labels),
+            argnums=(0, 1))(h, w)
         for t in g:
             arr = np.asarray(t)
             assert not np.isnan(arr).any()
             assert np.abs(arr).max() == 0.0
 
-    def test_mostly_ignored_batch(self):
+    def test_mostly_ignored_batch(self, monkeypatch):
         """ignore-heavy (not fully masked): mean over the 2 surviving
         rows only."""
-        from paddle_tpu.ops.fused_ce import (force_chunk_v,
-                                             fused_linear_cross_entropy)
+        from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
         h, w, labels = self._data()
         mask = np.full(h.shape[0], True)
         mask[[4, 9]] = False
         labels = jnp.where(jnp.asarray(mask), -100, labels)
-        with force_chunk_v(8):
-            out = float(fused_linear_cross_entropy(h, w, labels))
+        _ce_path(monkeypatch, "scan", 8)
+        out = float(fused_linear_cross_entropy(h, w, labels))
         np.testing.assert_allclose(out, float(_plain_ce(h, w, labels)),
                                    rtol=1e-5)
 
-    def test_chunk_v_resolution_precedence(self, flag_guard):
-        """explicit flag (set_flags) > default; forced (trials) beats
-        everything — the standard surface precedence."""
-        from paddle_tpu.ops import fused_ce
-        assert fused_ce._resolve_chunk_v(64, 4096, "float32") \
-            == fused_ce._CHUNK_V
-        flag_guard.set_flags({"FLAGS_fused_ce_chunk_v": 2048})
-        assert fused_ce._resolve_chunk_v(64, 4096, "float32") == 2048
-        with fused_ce.force_chunk_v(256):
-            assert fused_ce._resolve_chunk_v(64, 4096, "float32") == 256
+    def test_tiles_follow_the_shape(self):
+        """ONE rule gives both kernels their tiles: the largest tiles at
+        a training cell's shape, a small operand's rounded up to the
+        (16, 128) bf16 tiling."""
+        from paddle_tpu.ops.pallas import linear_ce as K
+        assert K._resolve_tiles(4092, 50257) == (K._ROW_TILE,
+                                                 K._VOCAB_TILE)
+        assert K._resolve_tiles(37, 300) == (min(48, K._ROW_TILE),
+                                             min(384, K._VOCAB_TILE))
+        for n, v in [(1, 1), (37, 300), (4092, 50257), (8192, 152064)]:
+            tm, tv = K._resolve_tiles(n, v)
+            assert tm % 16 == 0 and tv % 128 == 0
+
+
+# ===========================================================================
+# the labeled training forwards: the fused tail against the CE over the
+# unlabeled forward's logits
+# ===========================================================================
+
+
+def _labeled_model(kind):
+    from paddle_tpu.models import (DeepseekV2Config, DeepseekV2ForCausalLM,
+                                   GPT2Config, GPT2ForCausalLM,
+                                   LlamaConfig, LlamaForCausalLM,
+                                   Qwen2Config, Qwen2ForCausalLM)
+    paddle.seed(0)
+    if kind == "gpt2":
+        return GPT2ForCausalLM(GPT2Config.tiny())
+    if kind == "qwen2":
+        cfg = Qwen2Config.tiny()
+        cfg.tensor_parallel = False
+        return Qwen2ForCausalLM(cfg)
+    if kind == "deepseek":
+        cfg = DeepseekV2Config.tiny()
+        cfg.tensor_parallel = False
+        cfg.router_aux_loss_coef = 0.0     # the unlabeled forward has none
+        return DeepseekV2ForCausalLM(cfg)
+    cfg = LlamaConfig.tiny()
+    cfg.tensor_parallel = False
+    cfg.tie_word_embeddings = kind == "llama_tied"
+    return LlamaForCausalLM(cfg)
+
+
+@pytest.mark.parametrize("path,tiles", [("scan", 1024),
+                                        ("kernel", (16, 128))])
+@pytest.mark.parametrize("kind", ["gpt2", "llama", "llama_tied", "qwen2",
+                                  "deepseek"])
+def test_labeled_forward_matches_ce_over_logits(monkeypatch, kind, path,
+                                                tiles):
+    """``model(ids, labels=ids)`` is ``(None, loss)``: its loss equals
+    ``F.cross_entropy`` over the shifted logits of ``model(ids)`` (1e-5)
+    and so do its gradients (1e-4), in f32 — the tied head ``[V, D]``
+    (GPT-2's ``wte``, a tied Llama) and the untied ``[D, V]`` alike."""
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu.ops import manipulation as M
+    m = _labeled_model(kind)
+    v = m.config.vocab_size
+    ids = paddle.to_tensor(np.random.RandomState(0).randint(
+        0, v, (2, 16)).astype("int64"))
+    _ce_path(monkeypatch, path, tiles)
+
+    def grads(loss):
+        loss.backward()
+        out = {n: np.asarray(p.grad._data).copy()
+               for n, p in m.named_parameters() if p.grad is not None}
+        for p in m.parameters():
+            p.clear_grad()
+        return out
+
+    none, loss = m(ids, labels=ids)
+    assert none is None
+    g = grads(loss)
+    logits = m(ids)
+    ref = F.cross_entropy(M.reshape(logits[:, :-1, :], [-1, v]),
+                          M.reshape(ids[:, 1:], [-1]))
+    g_ref = grads(ref)
+    np.testing.assert_allclose(float(loss), float(ref), rtol=1e-5)
+    assert set(g) == set(g_ref) and len(g) > 0
+    for n in g:
+        np.testing.assert_allclose(g[n], g_ref[n], rtol=1e-4, atol=1e-6,
+                                   err_msg=n)
 
 
 # ===========================================================================
@@ -377,12 +460,12 @@ class TestFusedDecoderWiring:
 
 
 # ===========================================================================
-# compiled fit: fused linear+CE default-on vs the eager unfused oracle
+# compiled fit: the network's labeled loss vs the eager criterion oracle
 # ===========================================================================
 
 
 class TestFitFusedCE:
-    def _model(self):
+    def _model(self, certified=True):
         from paddle_tpu.hapi import Model
         from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
                                        LlamaPretrainingCriterion)
@@ -392,9 +475,12 @@ class TestFitFusedCE:
         paddle.seed(0)
         net = LlamaForCausalLM(cfg)
         m = Model(net)
+        crit = LlamaPretrainingCriterion(cfg)
+        if not certified:
+            crit.fuses_with_network_loss = False
         m.prepare(paddle.optimizer.SGD(1e-4,
                                        parameters=net.parameters()),
-                  LlamaPretrainingCriterion(cfg))
+                  crit)
         return m, cfg
 
     def _ds(self, cfg, rows=8, s=32):
@@ -402,97 +488,49 @@ class TestFitFusedCE:
             0, cfg.vocab_size, (rows, s + 1)).astype("int64"))
         return paddle.io.TensorDataset([ids, ids])
 
-    def test_compiled_fused_matches_eager_unfused_oracle(
-            self, monkeypatch, flag_guard):
-        """The acceptance pin: fit(compiled=True) — which defaults the
-        fused linear+CE tail ON — must match fit(compiled=False)'s
-        eager UNFUSED loop at rtol 1e-5, and the fused op must actually
-        have run (spy), with the flag restored afterwards."""
+    def _spy(self, monkeypatch):
         from paddle_tpu.ops import fused_ce as fmod
         calls = {"n": 0}
         real = fmod.fused_linear_cross_entropy
 
-        def spy(h, w, labels, ignore_index=-100):
+        def spy(*args, **kw):
             calls["n"] += 1
-            return real(h, w, labels, ignore_index)
+            return real(*args, **kw)
 
         monkeypatch.setattr(fmod, "fused_linear_cross_entropy", spy)
+        return calls
+
+    def test_compiled_fused_matches_eager_unfused_oracle(self, monkeypatch):
+        """The acceptance pin: fit(compiled=True) — which routes labels
+        into the network, so its fused head + CE tail runs — must match
+        fit(compiled=False)'s eager criterion-over-logits loop at rtol
+        1e-5, and the fused op must actually have run (spy)."""
+        calls = self._spy(monkeypatch)
         m, cfg = self._model()
-        ds = self._ds(cfg)
-        m.fit(ds, batch_size=4, epochs=1, verbose=0, shuffle=False,
-              log_freq=1_000_000)
+        m.fit(self._ds(cfg), batch_size=4, epochs=1, verbose=0,
+              shuffle=False, log_freq=1_000_000)
         fused = m._last_epoch_summary
         assert calls["n"] > 0, "fused linear+CE never engaged"
-        assert flags.flag("FLAGS_fused_linear_cross_entropy") is False
-        monkeypatch.setattr(fmod, "fused_linear_cross_entropy", real)
+        calls["n"] = 0
 
         m2, cfg2 = self._model()          # fresh model, same seed
         m2.fit(self._ds(cfg2), batch_size=4, epochs=1, verbose=0,
                shuffle=False, log_freq=1_000_000, compiled=False)
         eager = m2._last_epoch_summary
+        assert calls["n"] == 0            # the oracle runs the criterion
         np.testing.assert_allclose(fused["mean_loss"],
                                    eager["mean_loss"], rtol=1e-5)
 
     @pytest.mark.slow
-    def test_explicit_flag_off_is_respected(self, monkeypatch,
-                                            flag_guard):
-        """A user's explicit set_flags OFF must beat fit's scoped
-        default — the compiled step then runs the criterion over
-        materialized logits — INCLUDING on a Model whose cached
-        compiled step was already traced fused (the step cache keys on
-        the fused-loss state, not just the input signature)."""
-        from paddle_tpu.ops import fused_ce as fmod
-        calls = {"n": 0}
-        real = fmod.fused_linear_cross_entropy
-
-        def spy(h, w, labels, ignore_index=-100):
-            calls["n"] += 1
-            return real(h, w, labels, ignore_index)
-
-        monkeypatch.setattr(fmod, "fused_linear_cross_entropy", spy)
-        flag_guard.set_flags(
-            {"FLAGS_fused_linear_cross_entropy": False})
-        m, cfg = self._model()
+    def test_uncertified_criterion_runs_over_logits(self, monkeypatch):
+        """A criterion that does not certify ``fuses_with_network_loss``
+        is what the compiled step runs: over the unlabeled forward's
+        logits, never through the network's labeled tail."""
+        calls = self._spy(monkeypatch)
+        m, cfg = self._model(certified=False)
         m.fit(self._ds(cfg), batch_size=4, epochs=1, verbose=0,
               shuffle=False, log_freq=1_000_000)
         assert calls["n"] == 0
-
-    @pytest.mark.slow
-    def test_late_explicit_off_rebuilds_cached_step(self, monkeypatch,
-                                                    flag_guard):
-        """Trace fused first, THEN set_flags OFF on the SAME Model: the
-        cached compiled step must not keep serving the fused program."""
-        from paddle_tpu.ops import fused_ce as fmod
-        calls = {"n": 0}
-        real = fmod.fused_linear_cross_entropy
-
-        def spy(h, w, labels, ignore_index=-100):
-            calls["n"] += 1
-            return real(h, w, labels, ignore_index)
-
-        monkeypatch.setattr(fmod, "fused_linear_cross_entropy", spy)
-        m, cfg = self._model()
-        ds = self._ds(cfg)
-        m.fit(ds, batch_size=4, epochs=1, verbose=0, shuffle=False,
-              log_freq=1_000_000)
-        assert calls["n"] > 0            # fused traced + cached
-        flag_guard.set_flags(
-            {"FLAGS_fused_linear_cross_entropy": False})
-        calls["n"] = 0
-        m.fit(ds, batch_size=4, epochs=1, verbose=0, shuffle=False,
-              log_freq=1_000_000)
-        assert calls["n"] == 0           # stale fused program rebuilt
-
-    def test_scoped_default_restores_value_and_source(self):
-        assert flags.flag_source(
-            "FLAGS_fused_linear_cross_entropy") == "default"
-        with flags.scoped_default("FLAGS_fused_linear_cross_entropy",
-                                  True):
-            assert flags.flag(
-                "FLAGS_fused_linear_cross_entropy") is True
-            assert flags.flag_source(
-                "FLAGS_fused_linear_cross_entropy") == "default"
-        assert flags.flag("FLAGS_fused_linear_cross_entropy") is False
 
 
 # ===========================================================================
@@ -506,8 +544,7 @@ class TestSurfacesAndCosts:
         from paddle_tpu.tuner.surface import get_surface
         sweeps.ensure_builtin_surfaces()
         for name, shape in [("rms_norm_residual", {"d": 128}),
-                            ("swiglu", {"h": 256}),
-                            ("fused_ce", {"d": 64, "v": 1024})]:
+                            ("swiglu", {"h": 256})]:
             s = get_surface(name)
             grid = s.grid(shape)
             assert grid and grid[0] == s.default
@@ -522,8 +559,6 @@ class TestSurfacesAndCosts:
              {"block_rows": 16}, {"d": 32}),
             (sweeps.swiglu_builder(rows=64, dtype="float32"),
              {"block_rows": 16, "block_cols": 128}, {"h": 128}),
-            (sweeps.fused_ce_builder(rows=32, dtype="float32"),
-             {"chunk_v": 128}, {"d": 16, "v": 200}),
         ]
         for builder, config, shape in jobs:
             fn = builder(config, shape)

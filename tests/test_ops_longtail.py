@@ -385,47 +385,14 @@ class TestReviewRegressions:
 
 
 class TestFusedLinearCrossEntropy:
-    def test_matches_plain_ce_and_grads(self):
-        import jax
-        import jax.numpy as jnp
-        import scipy.special
-        from paddle_tpu.ops.fused_ce import fused_linear_cross_entropy
-
-        rng = np.random.RandomState(0)
-        N, D, V = 24, 16, 50
-        h = jnp.asarray(rng.randn(N, D).astype("float32"))
-        w = jnp.asarray(rng.randn(D, V).astype("float32") * 0.1)
-        labels = jnp.asarray(rng.randint(0, V, (N,)).astype("int32"))
-        labels = labels.at[3].set(-100)   # ignored row
-
-        def plain(h, w):
-            logits = h @ w
-            lp = jax.nn.log_softmax(logits, axis=-1)
-            valid = labels != -100
-            safe = jnp.where(valid, labels, 0)
-            per = -jnp.take_along_axis(lp, safe[:, None], -1)[:, 0]
-            return jnp.sum(jnp.where(valid, per, 0.0)) / jnp.sum(valid)
-
-        ref = float(plain(h, w))
-        out = float(fused_linear_cross_entropy(h, w, labels))
-        np.testing.assert_allclose(out, ref, rtol=1e-5)
-
-        g_ref = jax.grad(plain, argnums=(0, 1))(h, w)
-        g_out = jax.grad(
-            lambda hh, ww: fused_linear_cross_entropy(hh, ww, labels),
-            argnums=(0, 1))(h, w)
-        np.testing.assert_allclose(np.asarray(g_out[0]),
-                                   np.asarray(g_ref[0]), rtol=1e-4,
-                                   atol=1e-6)
-        np.testing.assert_allclose(np.asarray(g_out[1]),
-                                   np.asarray(g_ref[1]), rtol=1e-4,
-                                   atol=1e-6)
+    # the op's own parity cases (both paths, both layouts, ragged tiles)
+    # are tests/test_fused_training_kernels.py::TestFusedCE
 
     @pytest.mark.slow
     def test_llama_paths_agree(self):
         import paddle_tpu as paddle
-        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-        from paddle_tpu.framework import flags
+        from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+                                       LlamaPretrainingCriterion)
 
         cfg = LlamaConfig.tiny()
         cfg.tensor_parallel = False
@@ -433,57 +400,11 @@ class TestFusedLinearCrossEntropy:
         m = LlamaForCausalLM(cfg)
         ids = paddle.to_tensor(np.random.RandomState(0).randint(
             0, cfg.vocab_size, (2, 16)).astype("int64"))
-        try:
-            flags.set_flags({"FLAGS_fused_linear_cross_entropy": True})
-            none_logits, loss_f = m(ids, labels=ids)
-        finally:
-            flags.set_flags({"FLAGS_fused_linear_cross_entropy": False})
-        assert none_logits is None     # fused path skips logits
-        logits, loss_p = m(ids, labels=ids)   # default: plain path
-        assert logits is not None
+        none_logits, loss_f = m(ids, labels=ids)
+        assert none_logits is None     # the labeled forward skips logits
+        logits = m(ids)
+        loss_p = LlamaPretrainingCriterion(cfg)(logits, ids)
         np.testing.assert_allclose(float(loss_f), float(loss_p), rtol=1e-5)
-
-
-class TestFusedCEMultiChunk:
-    def test_multi_vocab_chunk_parity(self, monkeypatch):
-        """Exercise the cross-chunk machinery (online-lse carry,
-        in-chunk target pick, stacked-dW transpose/unpad) by shrinking
-        the chunk width so V=50 spans 7 chunks including a padded one."""
-        import jax
-        import jax.numpy as jnp
-
-        from paddle_tpu.ops import fused_ce
-
-        monkeypatch.setattr(fused_ce, "_CHUNK_V", 8)
-        rng = np.random.RandomState(1)
-        N, D, V = 24, 16, 50   # 7 chunks of 8, last padded by 6
-        h = jnp.asarray(rng.randn(N, D).astype("float32"))
-        w = jnp.asarray(rng.randn(D, V).astype("float32") * 0.1)
-        labels = jnp.asarray(rng.randint(0, V, (N,)).astype("int32"))
-        labels = labels.at[5].set(-100)
-        labels = labels.at[0].set(V - 1)   # target in the padded chunk
-
-        def plain(h, w):
-            logits = h @ w
-            lp = jax.nn.log_softmax(logits, axis=-1)
-            valid = labels != -100
-            safe = jnp.where(valid, labels, 0)
-            per = -jnp.take_along_axis(lp, safe[:, None], -1)[:, 0]
-            return jnp.sum(jnp.where(valid, per, 0.0)) / jnp.sum(valid)
-
-        ref = float(plain(h, w))
-        out = float(fused_ce.fused_linear_cross_entropy(h, w, labels))
-        np.testing.assert_allclose(out, ref, rtol=1e-5)
-        g_ref = jax.grad(plain, argnums=(0, 1))(h, w)
-        g_out = jax.grad(
-            lambda hh, ww: fused_ce.fused_linear_cross_entropy(
-                hh, ww, labels), argnums=(0, 1))(h, w)
-        np.testing.assert_allclose(np.asarray(g_out[0]),
-                                   np.asarray(g_ref[0]), rtol=1e-4,
-                                   atol=1e-6)
-        np.testing.assert_allclose(np.asarray(g_out[1]),
-                                   np.asarray(g_ref[1]), rtol=1e-4,
-                                   atol=1e-6)
 
 
 class TestTopLevelApiFills:

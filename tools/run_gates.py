@@ -219,10 +219,8 @@ def gate_commands(log: str, budget: float, no_budget: bool,
         # precedence contract), so the gate exercises exactly the
         # configuration the compiled fit hot path runs — CPU-cheap,
         # inside the tier-1 budget tripwire
-        fused_env = {"FLAGS_fused_linear_cross_entropy": "1",
-                     "FLAGS_fused_rmsnorm_residual": "1",
-                     "FLAGS_fused_swiglu": "1",
-                     "FLAGS_fused_ce_pallas_inner": "1"}
+        fused_env = {"FLAGS_fused_rmsnorm_residual": "1",
+                     "FLAGS_fused_swiglu": "1"}
         gates.append(
             ("fused_parity",
              ["env", *[f"{k}={v}" for k, v in fused_env.items()],
