@@ -15,7 +15,10 @@ per-slot compressed-key index, ``cache_spec.CompressedKeys``, beside
 decayed linear-attention layers over per-slot float32 state).
 Of Qwen3-Next ONE benchmark cell is measured, on one chip: its share of an
 8-way expert-parallel deployment, 12 of 48 layers, serving
-(``PERF.md``); nothing of it trains. DeepSeek-V2 (MLA) and ERNIE run dense
+(``PERF.md``); nothing of it trains. Mellum 2 (window layers beside
+YaRN full layers, softmax-routed held-share experts) TRAINS: its labeled
+forward returns the loss, through the flash kernel's window and the
+held-share experts' backward. DeepSeek-V2 (MLA) and ERNIE run dense
 only."""
 
 from .gpt2 import GPT2Config, GPT2Model, GPT2ForCausalLM
@@ -31,6 +34,7 @@ from .nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from .exaone_moe import ExaoneMoeConfig, ExaoneMoeForCausalLM
 from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 from .minicpm_sala import MiniCpmSalaConfig, MiniCpmSalaForCausalLM
+from .mellum import MellumConfig, MellumForCausalLM
 
 __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "LlamaModel", "LlamaForCausalLM", "LlamaForCausalLMPipe",
@@ -43,4 +47,4 @@ __all__ = ["GPT2Config", "GPT2Model", "GPT2ForCausalLM", "LlamaConfig",
            "NemotronHForCausalLM", "ExaoneMoeConfig",
            "ExaoneMoeForCausalLM", "Qwen3NextConfig",
            "Qwen3NextForCausalLM", "MiniCpmSalaConfig",
-           "MiniCpmSalaForCausalLM"]
+           "MiniCpmSalaForCausalLM", "MellumConfig", "MellumForCausalLM"]

@@ -62,8 +62,13 @@ def sdpa_reference(q, k, v, attn_mask=None, dropout_p=0.0, is_causal=False,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None):
-    """Inputs [batch, seq, num_heads, head_dim] (paddle convention)."""
+                                 training=True, name=None, window=None):
+    """Inputs [batch, seq, num_heads, head_dim] (paddle convention).
+    ``window`` (with ``is_causal``): a query sees its ``window`` newest
+    keys, itself among them."""
+    if window is not None and not is_causal:
+        raise ValueError("scaled_dot_product_attention: a window needs "
+                         "is_causal=True")
     q, k, v = as_tensor(query), as_tensor(key), as_tensor(value)
     from ...amp.auto_cast import maybe_cast_matmul
     q, k = maybe_cast_matmul(q, k)
@@ -86,7 +91,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
             # per shard under a fleet mesh (ops/pallas/_mesh.py)
             out = sharded_heads(
                 lambda a, b, c: fa.flash_attention(a, b, c,
-                                                   causal=is_causal),
+                                                   causal=is_causal,
+                                                   window=window),
                 mesh, qq, kk, vv)
             # name the kernel output so the opt-in remat policy
             # FLAGS_recompute_policy='dots_and_flash_saveable' can save
@@ -101,7 +107,13 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         key_rng = fr.default_generator.next_key()
 
     def fn(qq, kk, vv, *m):
-        return sdpa_reference(qq, kk, vv, m[0] if m else None,
+        mask = m[0] if m else None
+        if window is not None:
+            sq, sk = qq.shape[1], kk.shape[1]
+            k_pos = jnp.arange(sk)[None, :]
+            near = k_pos > jnp.arange(sq)[:, None] + (sk - sq) - window
+            mask = near[None, None] if mask is None else mask & near
+        return sdpa_reference(qq, kk, vv, mask,
                               dropout_p if key_rng is not None else 0.0,
                               is_causal, dropout_key=key_rng)
     return apply(fn, *args, name="sdpa")

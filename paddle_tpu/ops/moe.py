@@ -411,6 +411,47 @@ _pmetrics.declare("serving/moe_max_expert_pairs", "counter",
                   "experts: the load imbalance)")
 
 
+#: what a TRAINING step's held-share expert layers did, booked on the
+#: process registry (the one ``to_static``'s counters use) from the
+#: device-side sums a model keeps (:func:`track_train_stats`), when
+#: :func:`book_train_stats` is called: no fetch in a step
+TRAIN_COUNTERS = ("moe/train_tokens", "moe/train_local_pairs",
+                  "moe/train_busiest_pairs")
+_pmetrics.declare("moe/train_tokens", "counter",
+                  "token-layer passes through a held-share expert layer "
+                  "in training steps, summed on the device")
+_pmetrics.declare("moe/train_local_pairs", "counter",
+                  "(token, expert) pairs of training steps whose expert "
+                  "is held here: what the grouped matmuls computed")
+_pmetrics.declare("moe/train_busiest_pairs", "counter",
+                  "pairs of the busiest held expert of each expert layer "
+                  "of training steps, summed (against train_local_pairs / "
+                  "held experts: the load imbalance)")
+_train_sums = []        # [(tensor of the three sums, what is booked)]
+
+
+def track_train_stats(sums):
+    """Register a model's device-side ``[tokens, local pairs, busiest
+    pairs]`` sums (a Tensor the training step reassigns) for
+    :func:`book_train_stats`."""
+    _train_sums.append([sums, (0.0, 0.0, 0.0)])
+
+
+def book_train_stats():
+    """Fetch every tracked model's sums once and add what they gained
+    since the last booking to the ``moe/train_*`` counters."""
+    import numpy as np
+    reg = _pmetrics.get_registry()
+    counters = (reg.counter("moe/train_tokens"),
+                reg.counter("moe/train_local_pairs"),
+                reg.counter("moe/train_busiest_pairs"))
+    for entry in _train_sums:
+        now = tuple(float(v) for v in np.asarray(entry[0]._data))
+        for c, a, b in zip(counters, now, entry[1]):
+            c.inc(a - b)
+        entry[1] = now
+
+
 def held_range(first, held, total):
     """(first, count) of the routed experts an instance holds of a router
     ``total`` wide — :func:`moe_experts_held`'s ``first`` and the leading
@@ -443,7 +484,12 @@ def moe_experts_held(v, idx, weights, w1, w2, first, valid=None, bm=None,
     The static row capacity is the worst case (all T*k pairs held); the
     kernel skips the tiles past the live ones. Returns (out [T, d],
     stats) with stats = int32 [local pairs, pairs of the busiest held
-    expert]."""
+    expert].
+
+    Differentiable in ``v``, the expert matrices and ``weights`` (so in
+    a router that made them): the backward runs the grouped kernels'
+    own over the live tiles alone (``grouped_matmul_live``), at the same
+    worst-case capacity, so it too drops no pair."""
     from .pallas.grouped_matmul import grouped_matmul_live
 
     T, d = v.shape
@@ -475,9 +521,10 @@ def moe_experts_held(v, idx, weights, w1, w2, first, valid=None, bm=None,
              ).astype(v.dtype)
     y_p = grouped_matmul_live(a, w2, tile_gid, n_live)
     y_k = y_p[perm].reshape(T, k, d)
-    # where, not a product: rows of dead tiles were never written
-    y_k = jnp.where(held[..., None],
-                    y_k * weights.astype(y_k.dtype)[..., None], 0)
+    # where, not a product: rows of dead tiles were never written (and
+    # before the weights, so their gradient never reads such a row)
+    y_k = jnp.where(held[..., None], y_k, 0) \
+        * weights.astype(y_k.dtype)[..., None]
     counts = jnp.zeros((E + 1,), jnp.int32).at[gid.reshape(-1)].add(1)
     stats = jnp.stack([jnp.sum(held).astype(jnp.int32),
                        jnp.max(counts[:E])])

@@ -13,7 +13,16 @@ on v5e, 256/512 beats 512/512 by ~2 MFU points under remat at hidden
 2560, while 512/512 won at the 0.89B sweet spot (see BASELINE.md for
 the current tuning record).
 
-GQA/MQA (fewer kv heads than q heads) is handled by repeating kv heads."""
+GQA/MQA (fewer kv heads than q heads) is handled by repeating kv heads.
+
+A static ``window`` (causal only) limits query i to the keys
+``(i - window, i]``. Such a call, and a causal one whose whole-sequence
+operands would not fit scoped VMEM, runs the BLOCKED programs: a third grid
+axis walks exactly the K/V blocks a q block can see (forward, dq) or the q
+blocks that can see a K/V block (dkv); the block index of a step past the
+last one repeats, so a block outside the window is neither copied nor
+computed. ``window=None`` at a shape that fits compiles the resident
+programs above it, as before."""
 
 from __future__ import annotations
 
@@ -23,6 +32,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ._utils import interpret_mode as _interpret, no_x64 as _no_x64
 
@@ -159,21 +169,369 @@ def _round_up(n, m):
     return -(-n // m) * m
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, causal=False, scale=None):
-    out, _ = _flash_fwd(q, k, v, causal, scale)
+# -- blocked programs: a window, or a sequence too long to keep resident ----
+
+#: scoped VMEM the resident programs may take (double-buffered
+#: whole-sequence operands of the dkv kernel, the largest of the three);
+#: above it a call takes the blocked programs
+_RESIDENT_VMEM = 12 << 20
+#: lanes of the per-row statistics (lse, delta) of the blocked programs:
+#: the whole last dim of their arrays, so a block may be this narrow
+_STAT_LANES = 8
+
+
+def _takes_blocked(sq, sk, d, itemsize, window):
+    if window is not None:
+        return True
+    dkv = 2 * (2 * _round_up(sq, 128) * d * itemsize
+               + 2 * _round_up(sq, 128) * 128 * 4)
+    fwd = 2 * 2 * _round_up(sk, 128) * d * itemsize
+    return max(dkv, fwd) > _RESIDENT_VMEM
+
+
+def _min(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.minimum(a, b)
+
+
+def _max(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
+        else jnp.maximum(a, b)
+
+
+def _visible_keys(i, block_q, off, window, seq_len_k):
+    """First and last key position q block ``i`` can see (python ints or
+    int32 scalars alike); the last below the first where it sees none."""
+    hi = _min((i + 1) * block_q - 1 + off, seq_len_k - 1)
+    lo = 0 if window is None else _max(i * block_q + off - window + 1, 0)
+    return lo, hi
+
+
+def _seeing_queries(j, block_k, off, window, seq_len_q):
+    """First and last query position that can see a key of K/V block
+    ``j``."""
+    lo = _max(j * block_k - off, 0)
+    hi = seq_len_q - 1 if window is None else _min(
+        (j + 1) * block_k - 1 - off + window - 1, seq_len_q - 1)
+    return lo, hi
+
+
+def _block_span(n_outer, inner_blk, span):
+    """For a block of the outer axis, the first and the last block of the
+    inner axis that its ``span`` of positions touches (the last below the
+    first where it touches none), as index-map functions; and the most
+    inner blocks any outer block touches (the static trip count of the
+    inner grid axis). A step's block index is ``_clamped``."""
+    def first(i):
+        return _max(span(i)[0], 0) // inner_blk
+
+    def last(i):
+        lo, hi = span(i)
+        none = _max(lo, 0) // inner_blk - 1
+        if isinstance(hi, int) and isinstance(lo, int):
+            return hi // inner_blk if hi >= lo else none
+        return jnp.where(hi >= lo, hi // inner_blk, none)
+
+    trips = 1
+    for i in range(n_outer):
+        lo, hi = span(i)
+        if hi >= lo:
+            trips = max(trips, hi // inner_blk - lo // inner_blk + 1)
+    return first, last, trips
+
+
+def _clamped(first, last, i, j):
+    """The inner block of step ``j``: past the last it repeats the last (no
+    copy), and a walk that touches none stays on its first."""
+    return jnp.minimum(first(i) + j, jnp.maximum(last(i), first(i)))
+
+
+def _mask(q0, k0, shape, off, window, seq_len_q, seq_len_k):
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    valid = (q_pos < seq_len_q) & (k_pos < seq_len_k) & (q_pos + off
+                                                          >= k_pos)
+    if window is not None:
+        valid = valid & (k_pos > q_pos + off - window)
+    return valid
+
+
+def _fwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+                        l_ref, *, scale, window, block_q, block_k, seq_len_q,
+                        seq_len_k, kv_first, kv_last, trips):
+    i, j = pl.program_id(1), pl.program_id(2)
+    off = seq_len_k - seq_len_q
+    kb = kv_first(i) + j
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(kb <= kv_last(i))
+    def _():
+        # operands in the input dtype, f32 accumulation: the logits as the
+        # backward kernels recompute them
+        s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(_mask(i * block_q, kb * block_k, s.shape, off, window,
+                            seq_len_q, seq_len_k), s, _NEG_INF)
+        m_prev = m_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == trips - 1)
+    def _():
+        l = jnp.maximum(l_ref[:, 0:1], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = jnp.broadcast_to(m_ref[:, 0:1] + jnp.log(l),
+                                        lse_ref.shape)
+
+
+def _dq_blocked_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
+                       acc_ref, *, scale, window, block_q, block_k, seq_len_q,
+                       seq_len_k, kv_first, kv_last, trips):
+    i, j = pl.program_id(1), pl.program_id(2)
+    off = seq_len_k - seq_len_q
+    kb = kv_first(i) + j
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(kb <= kv_last(i))
+    def _():
+        q, k = q_ref[...], k_ref[...]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        valid = _mask(i * block_q, kb * block_k, s.shape, off, window,
+                      seq_len_q, seq_len_k)
+        p = jnp.where(valid, jnp.exp(s - lse_ref[:, 0:1]), 0.0)
+        dp = jax.lax.dot_general(g_ref[...], v_ref[...],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[:, 0:1]) * scale).astype(q.dtype)
+        acc_ref[...] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == trips - 1)
+    def _():
+        dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _dkv_blocked_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                        dk_ref, dv_ref, dk_acc, dv_acc, *, scale, window,
+                        block_q, block_k, seq_len_q, seq_len_k, q_first,
+                        q_last, trips):
+    kj, j = pl.program_id(1), pl.program_id(2)
+    off = seq_len_k - seq_len_q
+    qb = q_first(kj) + j
+
+    @pl.when(j == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(qb <= q_last(kj))
+    def _():
+        q, k, g = q_ref[...], k_ref[...], g_ref[...]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        valid = _mask(qb * block_q, kj * block_k, s.shape, off, window,
+                      seq_len_q, seq_len_k)
+        p = jnp.where(valid, jnp.exp(s - lse_ref[:, 0:1]), 0.0)
+        dv_acc[...] += jax.lax.dot_general(
+            p.astype(k.dtype), g, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(g, v_ref[...], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[:, 0:1]) * scale).astype(k.dtype)
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == trips - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _blocked_blocks(sq, sk, d, dtype):
+    bq, bkv = _resolve_blocks(sq, sk, d, dtype)
+    return (min(_round_up(bq, 128), _round_up(sq, 128)),
+            min(_round_up(bkv, 128), _round_up(sk, 128)))
+
+
+def _to_bh(x, s_pad):
+    b, s, h, d = x.shape
+    x = x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    if s_pad != s:
+        x = jnp.pad(x, ((0, 0), (0, s_pad - s), (0, 0)))
+    return x
+
+
+def _from_bh(x, b, s):
+    bh, _, d = x.shape
+    return x[:, :s].reshape(b, bh // b, s, d).transpose(0, 2, 1, 3)
+
+
+def _kv_walk(sq, sk, block_q, block_k, window):
+    """The forward's and dq's walk: per q block the K/V blocks it sees."""
+    off = sk - sq
+    return _block_span(-(-sq // block_q), block_k,
+                       lambda i: _visible_keys(i, block_q, off, window, sk))
+
+
+def _flash_fwd_blocked(q, k, v, scale, window):
+    """[B, S, H, D] (K/V already at H heads) -> (out, lse [B*H, S])."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    block_q, block_k = _blocked_blocks(sq, sk, d, q.dtype)
+    sq_p, sk_p = _round_up(sq, block_q), _round_up(sk, block_k)
+    kv_first, kv_last, trips = _kv_walk(sq, sk, block_q, block_k, window)
+
+    def kv_index(bh, i, j):
+        return bh, _clamped(kv_first, kv_last, i, j), 0
+
+    kw = dict(scale=scale, window=window, block_q=block_q, block_k=block_k,
+              seq_len_q=sq, seq_len_k=sk, kv_first=kv_first,
+              kv_last=kv_last, trips=trips)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=0,
+        grid=(b * h, sq_p // block_q, trips),
+        in_specs=[
+            pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((None, block_k, d), kv_index),
+            pl.BlockSpec((None, block_k, d), kv_index),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((None, block_q, _STAT_LANES),
+                         lambda bh, i, j: (bh, i, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32),
+                        pltpu.VMEM((block_q, 128), jnp.float32)],
+    )
+    with _no_x64():
+        out, lse = pl.pallas_call(
+            functools.partial(_fwd_blocked_kernel, **kw),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+                jax.ShapeDtypeStruct((b * h, sq_p, _STAT_LANES),
+                                     jnp.float32),
+            ],
+            name="flash_attention_fwd",
+            interpret=_interpret(),
+        )(_to_bh(q, sq_p), _to_bh(k, sk_p), _to_bh(v, sk_p))
+    return _from_bh(out, b, sq), lse[:, :sq, 0]
+
+
+def _flash_bwd_blocked(q, k_full, v_full, out, lse, g, scale, window):
+    """dq, dk, dv of :func:`_flash_fwd_blocked` (K/V at H heads)."""
+    b, sq, h, d = q.shape
+    sk = k_full.shape[1]
+    block_q, block_k = _blocked_blocks(sq, sk, d, q.dtype)
+    sq_p, sk_p = _round_up(sq, block_q), _round_up(sk, block_k)
+    bh = b * h
+    qh, gh = _to_bh(q, sq_p), _to_bh(g.astype(q.dtype), sq_p)
+    kh, vh = _to_bh(k_full, sk_p), _to_bh(v_full, sk_p)
+    delta = jnp.sum(gh.astype(jnp.float32)
+                    * _to_bh(out, sq_p).astype(jnp.float32), -1)
+    lse_p = jnp.pad(lse, ((0, 0), (0, sq_p - sq)))
+    stat = lambda x: jnp.broadcast_to(x[..., None], (bh, sq_p, _STAT_LANES))
+    lse_p, delta = stat(lse_p), stat(delta)
+    off = sk - sq
+    kw = dict(scale=scale, window=window, block_q=block_q, block_k=block_k,
+              seq_len_q=sq, seq_len_k=sk)
+
+    q_first, q_last, q_trips = _block_span(
+        sk_p // block_k, block_q,
+        lambda j: _seeing_queries(j, block_k, off, window, sq))
+
+    def q_index(n, kj, j):
+        return n, _clamped(q_first, q_last, kj, j), 0
+
+    q_spec = pl.BlockSpec((None, block_q, d), q_index)
+    stat_q = pl.BlockSpec((None, block_q, _STAT_LANES), q_index)
+    own_kv = pl.BlockSpec((None, block_k, d), lambda n, kj, j: (n, kj, 0))
+    with _no_x64():
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_blocked_kernel, q_first=q_first,
+                              q_last=q_last, trips=q_trips, **kw),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=0,
+                grid=(bh, sk_p // block_k, q_trips),
+                in_specs=[q_spec, own_kv, own_kv, q_spec, stat_q, stat_q],
+                out_specs=[own_kv, own_kv],
+                scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                                pltpu.VMEM((block_k, d), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct((bh, sk_p, d), k_full.dtype),
+                       jax.ShapeDtypeStruct((bh, sk_p, d), v_full.dtype)],
+            name="flash_attention_dkv",
+            interpret=_interpret(),
+        )(qh, kh, vh, gh, lse_p, delta)
+
+    kv_first, kv_last, kv_trips = _kv_walk(sq, sk, block_q, block_k, window)
+
+    def kv_index(n, i, j):
+        return n, _clamped(kv_first, kv_last, i, j), 0
+
+    own_q = pl.BlockSpec((None, block_q, d), lambda n, i, j: (n, i, 0))
+    own_stat = pl.BlockSpec((None, block_q, _STAT_LANES),
+                            lambda n, i, j: (n, i, 0))
+    kv_spec = pl.BlockSpec((None, block_k, d), kv_index)
+    with _no_x64():
+        dq = pl.pallas_call(
+            functools.partial(_dq_blocked_kernel, kv_first=kv_first,
+                              kv_last=kv_last, trips=kv_trips, **kw),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=0,
+                grid=(bh, sq_p // block_q, kv_trips),
+                in_specs=[own_q, kv_spec, kv_spec, own_q, own_stat,
+                          own_stat],
+                out_specs=[own_q],
+                scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype)],
+            name="flash_attention_dq",
+            interpret=_interpret(),
+        )(qh, kh, vh, gh, lse_p, delta)[0]
+    return _from_bh(dq, b, sq), _from_bh(dk, b, sk), _from_bh(dv, b, sk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention(q, k, v, causal=False, scale=None, window=None):
+    """[B, S, H, D] attention; ``window`` (static, causal only): query i
+    sees the keys ``(i - window, i]`` (bottom-right aligned as ``causal``
+    is)."""
+    out, _ = _flash_fwd(q, k, v, causal, scale, window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale):
+def _flash_fwd(q, k, v, causal, scale, window=None):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     hk = k.shape[2]
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
     if hk != h:  # GQA: repeat kv heads
         rep = h // hk
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    if causal and _takes_blocked(sq, sk, d, q.dtype.itemsize, window):
+        return _flash_fwd_blocked(q, k, v, s, window)
     bq, bkv = _resolve_blocks(sq, sk, d, q.dtype)
     block_q = min(bq, _round_up(sq, 8))
     block_k = min(bkv, _round_up(sk, 128))
@@ -219,8 +577,8 @@ def _flash_fwd(q, k, v, causal, scale):
     return out4, lse[:, :sq, 0]
 
 
-def _fwd_rule(q, k, v, causal, scale):
-    out, lse = _flash_fwd(q, k, v, causal, scale)
+def _fwd_rule(q, k, v, causal, scale, window):
+    out, lse = _flash_fwd(q, k, v, causal, scale, window)
     return out, (q, k, v, out, lse)
 
 
@@ -419,19 +777,26 @@ def _flash_bwd_pallas(q, k_full, v_full, out, lse, g, causal, s):
     return dq4, dk4, dv4
 
 
-def _bwd_rule(causal, scale, res, g):
+def _bwd_rule(causal, scale, res, g, window=None):
     q, k, v, out, lse = res
     from ...framework import flags
-    if flags.flag("FLAGS_flash_attn_pallas_bwd"):
-        b, sq, h, d = q.shape
+    b, sq, h, d = q.shape
+    blocked = causal and _takes_blocked(sq, k.shape[1], d,
+                                        q.dtype.itemsize, window)
+    if blocked or flags.flag("FLAGS_flash_attn_pallas_bwd"):
         hk = k.shape[2]
         rep = h // hk
         k_full = jnp.repeat(k, rep, axis=2) if rep != 1 else k
         v_full = jnp.repeat(v, rep, axis=2) if rep != 1 else v
         s = scale if scale is not None else 1.0 / math.sqrt(d)
-        dq4, dk4, dv4 = _flash_bwd_pallas(q, k_full, v_full, out,
-                                          lse.reshape(b * h, sq), g,
-                                          causal, s)
+        if blocked:
+            dq4, dk4, dv4 = _flash_bwd_blocked(q, k_full, v_full, out,
+                                               lse.reshape(b * h, sq), g, s,
+                                               window)
+        else:
+            dq4, dk4, dv4 = _flash_bwd_pallas(q, k_full, v_full, out,
+                                              lse.reshape(b * h, sq), g,
+                                              causal, s)
         if rep != 1:
             sk = k.shape[1]
             dk4 = dk4.reshape(b, sk, hk, rep, d).sum(3)
@@ -512,7 +877,10 @@ def _bwd_rule_scan(causal, scale, res, g):
     return dq4, dk4, dv4
 
 
-flash_attention.defvjp(_fwd_rule, _bwd_rule)
+flash_attention.defvjp(
+    _fwd_rule,
+    lambda causal, scale, window, res, g: _bwd_rule(causal, scale, res, g,
+                                                    window))
 
 
 # -- tunable surface ---------------------------------------------------------
@@ -554,14 +922,20 @@ def _register_flash_surface():
 _register_flash_surface()
 
 
-def flash_attention_cost(q_shape, kv_seq=None, causal=False, train=False):
+def flash_attention_cost(q_shape, kv_seq=None, causal=False, train=False,
+                         window=None):
     """Static FLOPs/bytes for one :func:`flash_attention` call (profiler
     cost-accounting surface): q [B, Sq, H, D]. Flash never materializes
     the [Sq, Sk] score matrix, so bytes count only q/k/v in + out —
     exactly why the kernel moves attention to the compute-bound side of
     the roofline. ``train=True`` multiplies by 3.5 (bwd recomputes the
-    logits once on top of the 2x grad matmuls)."""
-    from ...profiler.cost import attention_cost
+    logits once on top of the 2x grad matmuls). ``window`` (causal
+    self-attention): query i scores min(i + 1, window) keys."""
+    from ...profiler.cost import SectionCost, attention_cost
     b, sq, h, d = (int(s) for s in q_shape)
     c = attention_cost(b, sq, h, d, kv_len=kv_seq, causal=causal)
+    if window is not None:
+        w = min(int(window), sq)
+        keys = w * (w + 1) / 2.0 + (sq - w) * w
+        c = SectionCost(flops=4.0 * b * h * d * keys, bytes=c.bytes)
     return c * 3.5 if train else c

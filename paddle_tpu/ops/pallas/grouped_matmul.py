@@ -143,48 +143,84 @@ def _gmm_call(x, w, tile_gid, transpose_rhs, bn, n_live=None, act=None):
 _LIVE_BLOCK_BYTES = 4 << 20
 
 
+def _live_bn(kdim, w):
+    return max(128, _LIVE_BLOCK_BYTES // (kdim * w.dtype.itemsize)
+               // 128 * 128)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def grouped_matmul_live(x, w, tile_gid, n_live, act=None):
-    """The forward kernel over the first ``n_live`` row tiles only (not
-    differentiable): ``y[t] = act(x[t] @ w[tile_gid(t // bm)])`` for rows
-    of live tiles; rows of the other tiles are left UNWRITTEN (the caller
-    must not read them). For a layout whose static row capacity is the
-    worst case and whose live part is usually a fraction of it — an
-    expert layer that holds a share of the experts and is sent only its
-    own pairs (:func:`_gmm_call` says what a dead tile costs).
+    """The forward kernel over the first ``n_live`` row tiles only:
+    ``y[t] = act(x[t] @ w[tile_gid(t // bm)])`` for rows of live tiles;
+    rows of the other tiles are left UNWRITTEN (the caller must not read
+    them). For a layout whose static row capacity is the worst case and
+    whose live part is usually a fraction of it — an expert layer that
+    holds a share of the experts and is sent only its own pairs
+    (:func:`_gmm_call` says what a dead tile costs).
 
     x [P, d] sorted and group-padded (``ops.moe.sort_rows_by_expert``),
     w [E, d, h], ``tile_gid`` [P // bm] with every entry < E, ``n_live``
     int32 scalar <= nr - 1. The weight block is [d, bn] with bn the
     largest lane-aligned divisor of h that keeps it under
-    ``_LIVE_BLOCK_BYTES``."""
-    kdim = x.shape[1]
-    bn = max(128, _LIVE_BLOCK_BYTES // (kdim * w.dtype.itemsize)
-             // 128 * 128)
-    return _gmm_call(x, w, tile_gid, transpose_rhs=False, bn=bn,
-                     n_live=n_live, act=act)
+    ``_LIVE_BLOCK_BYTES``.
+
+    Differentiable in x and w without ``act``: the backward skips the dead
+    tiles as the forward does (dx through :func:`grouped_matmul_t`, whose
+    dead rows come back as zeros, dw through :func:`grouped_dw`); the
+    cotangent of a dead row is never read."""
+    return _gmm_call(x, w, tile_gid, transpose_rhs=False,
+                     bn=_live_bn(x.shape[1], w), n_live=n_live, act=act)
 
 
-def _dw_kernel(gid_ref, x_ref, dy_ref, o_ref, acc_ref, *, nr):
+def _live_fwd(x, w, tile_gid, n_live, act):
+    return grouped_matmul_live(x, w, tile_gid, n_live, act), \
+        (x, w, tile_gid, n_live)
+
+
+def _live_bwd(act, res, dy):
+    x, w, tile_gid, n_live = res
+    nr = tile_gid.shape[0]
+    bm = x.shape[0] // nr
+    live_row = (jnp.arange(x.shape[0], dtype=jnp.int32) // bm) < n_live
+    # a dead row's cotangent holds whatever its unwritten row held
+    dy = jnp.where(live_row[:, None], dy, 0).astype(x.dtype)
+    if act is not None:
+        raise NotImplementedError(f"grouped_matmul_live: the {act!r} "
+                                  f"epilogue has no backward")
+    dx = grouped_matmul_t(dy, w, tile_gid, n_live=n_live)
+    dw = grouped_dw(x, dy, tile_gid, w.shape[0], n_live=n_live)
+    zero = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (jnp.where(live_row[:, None], dx, 0), dw.astype(w.dtype),
+            zero(tile_gid), zero(n_live))
+
+
+grouped_matmul_live.defvjp(_live_fwd, _live_bwd)
+
+
+def _dw_kernel(gid_ref, live_ref, x_ref, dy_ref, o_ref, acc_ref, *, nr):
     r = pl.program_id(2)
+    end = live_ref[0] - 1           # the last tile that is computed
     gid = gid_ref[r]
     first = (r == 0) | (gid != gid_ref[jnp.maximum(r - 1, 0)])
-    last = (r == nr - 1) | (gid != gid_ref[jnp.minimum(r + 1, nr - 1)])
+    last = (r == end) | (gid != gid_ref[jnp.minimum(r + 1, nr - 1)])
 
-    @pl.when(first)
+    @pl.when(first & (r <= end))
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # [bm, bd].T @ [bm, bh] -> [bd, bh], f32 accumulation on the MXU
-    acc_ref[...] += lax.dot_general(
-        x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    @pl.when(r <= end)
+    def _():
+        # [bm, bd].T @ [bm, bh] -> [bd, bh], f32 accumulation on the MXU
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(last)
+    @pl.when(last & (r <= end))
     def _():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _dw_call(x, dy, tile_gid, n_experts, bd, bh):
+def _dw_call(x, dy, tile_gid, n_experts, bd, bh, n_live=None):
     """dw[e] = x[group e].T @ dy[group e]  -> [E, d, h].
 
     Grid (nd, nh, nr) with the row sweep innermost: the [bd, bh] f32
@@ -192,7 +228,12 @@ def _dw_call(x, dy, tile_gid, n_experts, bd, bh):
     (gid, jd, jh) output block at its last — group tiles are contiguous,
     so the revisited output block is written exactly once before Pallas
     pages it out. Every expert owns >= 1 tile (zero rows for empty
-    groups), so all E blocks get written."""
+    groups), so all E blocks get written.
+
+    ``n_live`` (int32 scalar, None = every tile): the tiles past the first
+    ``n_live`` are skipped as in :func:`_gmm_call` — no DMA (their block
+    indices repeat the last live tile's, whose group they must carry) and
+    no compute; the last live tile flushes its group."""
     P, d = x.shape
     h = dy.shape[1]
     nr = tile_gid.shape[0]
@@ -200,16 +241,22 @@ def _dw_call(x, dy, tile_gid, n_experts, bd, bh):
     bd = _pick_block(d, bd)
     bh = _pick_block(h, bh)
     nd, nh = d // bd, h // bh
+    live = jnp.full((1,), nr, jnp.int32) if n_live is None \
+        else jnp.reshape(n_live, (1,)).astype(jnp.int32)
+
+    def row(r, nl):
+        return jnp.maximum(jnp.minimum(r, nl[0] - 1), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nd, nh, nr),
         in_specs=[
-            pl.BlockSpec((bm, bd), lambda jd, jh, r, g: (r, jd)),
-            pl.BlockSpec((bm, bh), lambda jd, jh, r, g: (r, jh)),
+            pl.BlockSpec((bm, bd), lambda jd, jh, r, g, nl: (row(r, nl), jd)),
+            pl.BlockSpec((bm, bh), lambda jd, jh, r, g, nl: (row(r, nl), jh)),
         ],
         out_specs=pl.BlockSpec((None, bd, bh),
-                               lambda jd, jh, r, g: (g[r], jd, jh)),
+                               lambda jd, jh, r, g, nl: (g[row(r, nl)], jd,
+                                                         jh)),
         scratch_shapes=[pltpu.VMEM((bd, bh), jnp.float32)],
     )
     with _no_x64():
@@ -219,7 +266,7 @@ def _dw_call(x, dy, tile_gid, n_experts, bd, bh):
             out_shape=jax.ShapeDtypeStruct((n_experts, d, h), x.dtype),
             name="grouped_matmul_dw",
             interpret=_interpret(),
-        )(tile_gid, x, dy)
+        )(tile_gid, live, x, dy)
 
 
 # static defaults — the pre-tuner tiles (VERDICT r5: "no recorded
@@ -241,19 +288,21 @@ def _tile_config(w_shape, dtype) -> dict:
     return cfg
 
 
-def grouped_matmul_t(dy, w, tile_gid, bn=None):
-    """dx for the grouped matmul: dy [P, h] @ w[gid].T -> [P, d]."""
+def grouped_matmul_t(dy, w, tile_gid, bn=None, n_live=None):
+    """dx for the grouped matmul: dy [P, h] @ w[gid].T -> [P, d]
+    (``n_live``: :func:`_gmm_call`'s, rows of dead tiles unwritten)."""
     if bn is None:
         bn = _tile_config(w.shape, dy.dtype)["bn"]
-    return _gmm_call(dy, w, tile_gid, transpose_rhs=True, bn=bn)
+    return _gmm_call(dy, w, tile_gid, transpose_rhs=True, bn=bn,
+                     n_live=n_live)
 
 
-def grouped_dw(x, dy, tile_gid, n_experts, bd=None, bh=None):
+def grouped_dw(x, dy, tile_gid, n_experts, bd=None, bh=None, n_live=None):
     if bd is None or bh is None:
         cfg = _tile_config((n_experts, x.shape[1], dy.shape[1]), x.dtype)
         bd = cfg["bd"] if bd is None else bd
         bh = cfg["bh"] if bh is None else bh
-    return _dw_call(x, dy, tile_gid, n_experts, bd=bd, bh=bh)
+    return _dw_call(x, dy, tile_gid, n_experts, bd=bd, bh=bh, n_live=n_live)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

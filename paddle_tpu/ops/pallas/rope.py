@@ -12,7 +12,8 @@ import functools
 
 import jax.numpy as jnp
 
-__all__ = ["build_sin_cos", "apply_rope", "rope_reference"]
+__all__ = ["build_sin_cos", "apply_rope", "rope_reference",
+           "rope_inv_freq"]
 
 
 @functools.lru_cache(maxsize=32)
@@ -22,6 +23,51 @@ def _sin_cos_np(seq_len: int, dim: int, base: float):
     t = np.arange(seq_len, dtype=np.float64)
     freqs = np.outer(t, inv)  # [S, D/2]
     return np.sin(freqs), np.cos(freqs)
+
+
+def rope_inv_freq(dim, params):
+    """(inv_freq [dim // 2] float32 numpy, attention_factor) of one rotary
+    embedding as an HF ``rope_parameters`` group states it: ``rope_type``
+    ``default`` (``theta ** (-2i / dim)``, factor 1) or ``yarn`` (HF's
+    ``_compute_yarn_parameters``: the frequencies interpolated by
+    ``factor`` below the correction range of ``beta_fast`` /
+    ``beta_slow`` rotations over ``original_max_position_embeddings``,
+    kept above it, a linear ramp between; cos and sin are multiplied by
+    ``attention_factor``, ``0.1 ln(factor) + 1`` where not given)."""
+    import math
+
+    import numpy as np
+    theta = float(params["rope_theta"])
+    kind = params.get("rope_type", "default")
+    pos_freqs = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if kind == "default":
+        return (1.0 / pos_freqs).astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: only default and yarn are "
+                         f"built")
+    factor = float(params["factor"])
+    att = params.get("attention_factor")
+    if att is None:
+        att = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+    orig = float(params["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = correction_dim(params.get("beta_fast") or 32)
+    high = correction_dim(params.get("beta_slow") or 1)
+    if params.get("truncate", True):
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                  # 1: extrapolate (the fast dims)
+    inv = (1.0 / (factor * pos_freqs)) * (1.0 - keep) \
+        + (1.0 / pos_freqs) * keep
+    return inv.astype(np.float32), float(att)
 
 
 def build_sin_cos(seq_len, dim, base=10000.0, dtype=jnp.float32):

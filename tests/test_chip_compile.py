@@ -652,6 +652,21 @@ def test_flash_attention_fwd_bwd(native, one_chip, d, heads):
     assert text.count("tpu_custom_call") >= 3       # fwd, dkv, dq
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "causal"])
+def test_flash_attention_at_8k_tokens(native, one_chip, window):
+    """The Mellum training cell's attention: 2 x 8,192 tokens, 32 query
+    heads over 4 KV heads of 128. Both take the blocked programs (the
+    resident dkv would need 25 MB of scoped VMEM at this length)."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    s = _sds(one_chip)
+    text = _compile(_grad_sum(
+        lambda q, k, v: flash_attention(q, k, v, True, None, window)),
+        s((2, 8192, 32, 128)), s((2, 8192, 4, 128)), s((2, 8192, 4, 128)))
+    for name in ("flash_attention_fwd", "flash_attention_dq",
+                 "flash_attention_dkv"):
+        assert name in text
+
+
 @pytest.mark.parametrize("h", [3584, 4096])
 def test_rms_norm_residual_fwd_bwd(native, one_chip, h):
     """Default row block at the widths where 256 rows overflow scoped
@@ -798,6 +813,29 @@ def test_held_expert_layer_at_published_widths(native, one_chip, tokens):
     _compile(fn, sds((tokens, 1024), BF16), sds((tokens, 22), jnp.int32),
              sds((tokens, 22), jnp.float32), sds((128, 1024, 2688), BF16),
              sds((128, 2688, 1024), BF16), sds((tokens,), jnp.bool_))
+
+
+def test_held_expert_layer_backward_at_mellum_widths(native, one_chip):
+    """The held-share layer differentiated as the Mellum training cell runs
+    it: 16 held experts of 64, hidden 2304, expert width 896, 8 pairs a
+    token over 2 x 8,192 tokens: forward, dx and dW over the live tiles."""
+    from paddle_tpu.ops import moe
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(v, idx, w, wg, w1, w2):
+        def loss(v_, w_, wg_, w1_, w2_):
+            out, _ = moe.moe_experts_held(v_, idx, w_, w1_, w2_, 0,
+                                          w_gate=wg_)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(v, w, wg, w1, w2)
+
+    t = 2 * 8192
+    text = _compile(fn, sds((t, 2304), BF16), sds((t, 8), jnp.int32),
+                    sds((t, 8), jnp.float32), sds((16, 2304, 896), BF16),
+                    sds((16, 2304, 896), BF16), sds((16, 896, 2304), BF16))
+    assert "grouped_matmul_dw" in text
 
 
 def test_kernels_under_a_2x2_mesh(native, topo):
