@@ -17,12 +17,14 @@ GQA/MQA (fewer kv heads than q heads) is handled by repeating kv heads.
 
 A static ``window`` (causal only) limits query i to the keys
 ``(i - window, i]``. Such a call, and a causal one whose whole-sequence
-operands would not fit scoped VMEM, runs the BLOCKED programs: a third grid
-axis walks exactly the K/V blocks a q block can see (forward, dq) or the q
-blocks that can see a K/V block (dkv); the block index of a step past the
-last one repeats, so a block outside the window is neither copied nor
-computed. ``window=None`` at a shape that fits compiles the resident
-programs above it, as before."""
+operands would not fit scoped VMEM, runs the BLOCKED programs. Their grid
+walks a static schedule (``_schedule``, scalar-prefetched tables): each
+(q block, K/V block) pair is not visible, wholly visible or cut by the
+diagonal, the window or padding (``_pair_kind``); the forward and dq take
+one step for each K/V block a q block sees, dkv one for each q block that
+sees a K/V block. A block outside the window is neither copied nor
+computed, and only a cut pair builds the mask. ``window=None`` at a
+shape that fits compiles the resident programs above it, as before."""
 
 from __future__ import annotations
 
@@ -31,9 +33,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...profiler import metrics as _pmetrics
 from ._utils import interpret_mode as _interpret, no_x64 as _no_x64
 
 
@@ -189,133 +193,181 @@ def _takes_blocked(sq, sk, d, itemsize, window):
     return max(dkv, fwd) > _RESIDENT_VMEM
 
 
-def _min(a, b):
-    return min(a, b) if isinstance(a, int) and isinstance(b, int) \
-        else jnp.minimum(a, b)
+#: the kind of a (q block, K/V block) pair: no query of the q block sees a
+#: key of the K/V block; the mask keeps some logits and not others; every
+#: logit is kept (no padded row or key, none past the diagonal or the
+#: window)
+_NONE, _CUT, _FULL = 0, 1, 2
+#: a step's flags beside its kind: the first and the last step of its row
+_FIRST, _LAST = 4, 8
+
+_pmetrics.declare("flash/blocks_full", "counter",
+                  "(q block, K/V block) pairs the blocked flash programs "
+                  "walk with every logit kept (no mask), summed over the "
+                  "heads of each traced call")
+_pmetrics.declare("flash/blocks_cut", "counter",
+                  "(q block, K/V block) pairs the blocked flash programs "
+                  "walk that the causal diagonal, the window or padding "
+                  "cuts (masked), summed over the heads of each traced "
+                  "call")
+_pmetrics.declare("flash/grid_steps", "counter",
+                  "grid steps of the blocked flash programs: the visible "
+                  "pairs, and one step for a row that sees none, summed "
+                  "over the heads of each traced call")
 
 
-def _max(a, b):
-    return max(a, b) if isinstance(a, int) and isinstance(b, int) \
-        else jnp.maximum(a, b)
+def _pair_kind(i, j, block_q, block_k, sq, sk, window):
+    """The kind of (q block ``i``, K/V block ``j``). Query q sees key k
+    where ``q + off - window < k <= q + off`` (``off = sk - sq``, the
+    bottom-right alignment); over a block's real rows and keys ``k - q``
+    takes every value of one range, so the pair is visible where that
+    range meets the band, and whole where the band holds it."""
+    off = sk - sq
+    q0, k0 = i * block_q, j * block_k
+    q1, k1 = min(q0 + block_q, sq) - 1, min(k0 + block_k, sk) - 1
+    lo = -math.inf if window is None else off - window + 1
+    if q1 < q0 or k1 < k0 or k0 - q1 > off or k1 - q0 < lo:
+        return _NONE
+    whole = q1 == q0 + block_q - 1 and k1 == k0 + block_k - 1
+    return _FULL if whole and k1 - q0 <= off and k0 - q1 >= lo else _CUT
 
 
-def _visible_keys(i, block_q, off, window, seq_len_k):
-    """First and last key position q block ``i`` can see (python ints or
-    int32 scalars alike); the last below the first where it sees none."""
-    hi = _min((i + 1) * block_q - 1 + off, seq_len_k - 1)
-    lo = 0 if window is None else _max(i * block_q + off - window + 1, 0)
-    return lo, hi
+@functools.lru_cache(maxsize=None)
+def _schedule(sq, sk, block_q, block_k, window, kv_major):
+    """The steps one head of a blocked program walks, as three int32
+    tables: the row's block, the walked block, and the pair's kind with
+    ``_FIRST`` / ``_LAST``. Rows are q blocks walking their K/V blocks
+    (forward, dq) or, ``kv_major``, K/V blocks walking the q blocks that
+    see them (dkv); each row's visible pairs in ascending order, and one
+    step of kind ``_NONE`` for a row that sees nothing, which still writes
+    its zeros."""
+    n_q, n_k = -(-sq // block_q), -(-sk // block_k)
+    rows, cols = (n_k, n_q) if kv_major else (n_q, n_k)
+    row, col, flags = [], [], []
+    for r in range(rows):
+        run = []
+        for c in range(cols):
+            i, j = (c, r) if kv_major else (r, c)
+            kind = _pair_kind(i, j, block_q, block_k, sq, sk, window)
+            if kind != _NONE:
+                run.append((c, kind))
+        # an empty row stays on the block the walk is on: no copy
+        run = run or [(col[-1] if col else 0, _NONE)]
+        for n, (c, kind) in enumerate(run):
+            row.append(r)
+            col.append(c)
+            flags.append(kind | (_FIRST if n == 0 else 0)
+                         | (_LAST if n == len(run) - 1 else 0))
+    tables = tuple(np.asarray(x, np.int32) for x in (row, col, flags))
+    for x in tables:        # cached: every caller gets these arrays
+        x.setflags(write=False)
+    return tables
 
 
-def _seeing_queries(j, block_k, off, window, seq_len_q):
-    """First and last query position that can see a key of K/V block
-    ``j``."""
-    lo = _max(j * block_k - off, 0)
-    hi = seq_len_q - 1 if window is None else _min(
-        (j + 1) * block_k - 1 - off + window - 1, seq_len_q - 1)
-    return lo, hi
-
-
-def _block_span(n_outer, inner_blk, span):
-    """For a block of the outer axis, the first and the last block of the
-    inner axis that its ``span`` of positions touches (the last below the
-    first where it touches none), as index-map functions; and the most
-    inner blocks any outer block touches (the static trip count of the
-    inner grid axis). A step's block index is ``_clamped``."""
-    def first(i):
-        return _max(span(i)[0], 0) // inner_blk
-
-    def last(i):
-        lo, hi = span(i)
-        none = _max(lo, 0) // inner_blk - 1
-        if isinstance(hi, int) and isinstance(lo, int):
-            return hi // inner_blk if hi >= lo else none
-        return jnp.where(hi >= lo, hi // inner_blk, none)
-
-    trips = 1
-    for i in range(n_outer):
-        lo, hi = span(i)
-        if hi >= lo:
-            trips = max(trips, hi // inner_blk - lo // inner_blk + 1)
-    return first, last, trips
-
-
-def _clamped(first, last, i, j):
-    """The inner block of step ``j``: past the last it repeats the last (no
-    copy), and a walk that touches none stays on its first."""
-    return jnp.minimum(first(i) + j, jnp.maximum(last(i), first(i)))
+def _book(tables, heads):
+    """What a traced blocked call walks, on the process registry."""
+    kinds = tables[2] & 3
+    reg = _pmetrics.get_registry()
+    reg.counter("flash/blocks_full").inc(int((kinds == _FULL).sum()) * heads)
+    reg.counter("flash/blocks_cut").inc(int((kinds == _CUT).sum()) * heads)
+    reg.counter("flash/grid_steps").inc(len(kinds) * heads)
 
 
 def _mask(q0, k0, shape, off, window, seq_len_q, seq_len_k):
-    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    valid = (q_pos < seq_len_q) & (k_pos < seq_len_k) & (q_pos + off
-                                                          >= k_pos)
+    """The logits kept in the block whose first row is query ``q0`` and
+    first column key ``k0``: written over ``k - q`` (one iota difference),
+    with a padding compare only where the sequence pads its last block."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    rel = cols - rows + (k0 - q0)
+    valid = rel <= off
     if window is not None:
-        valid = valid & (k_pos > q_pos + off - window)
+        valid = valid & (rel > off - window)
+    if seq_len_q % shape[0]:
+        valid = valid & (rows < seq_len_q - q0)
+    if seq_len_k % shape[1]:
+        valid = valid & (cols < seq_len_k - k0)
     return valid
 
 
-def _fwd_blocked_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                        l_ref, *, scale, window, block_q, block_k, seq_len_q,
-                        seq_len_k, kv_first, kv_last, trips):
-    i, j = pl.program_id(1), pl.program_id(2)
-    off = seq_len_k - seq_len_q
-    kb = kv_first(i) + j
+def _walk_step(flag_ref, start, visit, finish):
+    """One grid step of a blocked program: ``start()`` where the step
+    opens its row, ``visit(masked)`` for its pair (without the mask where
+    every logit is kept, with it where the mask cuts, not at all for a row
+    that sees nothing), ``finish()`` where it closes its row."""
+    flags = flag_ref[pl.program_id(1)]
+    pl.when((flags & _FIRST) != 0)(start)
+    pl.when((flags & 3) == _FULL)(lambda: visit(False))
+    pl.when((flags & 3) == _CUT)(lambda: visit(True))
+    pl.when((flags & _LAST) != 0)(finish)
 
-    @pl.when(j == 0)
-    def _():
+
+def _lanes(x, n):
+    """A ``[rows, 128]`` statistic whose lanes are copies, as ``n``
+    lanes."""
+    return jnp.tile(x, (1, -(-n // 128)))[:, :n]
+
+
+def _fwd_blocked_kernel(row_ref, col_ref, flag_ref, q_ref, k_ref, v_ref,
+                        o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale,
+                        window, block_q, block_k, seq_len_q, seq_len_k):
+    t = pl.program_id(1)
+
+    def start():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(kb <= kv_last(i))
-    def _():
+    def visit(masked):
         # operands in the input dtype, f32 accumulation: the logits as the
         # backward kernels recompute them
         s = jax.lax.dot_general(q_ref[...], k_ref[...],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        s = jnp.where(_mask(i * block_q, kb * block_k, s.shape, off, window,
-                            seq_len_q, seq_len_k), s, _NEG_INF)
-        m_prev = m_ref[:, 0:1]
+        if masked:
+            s = jnp.where(_mask(row_ref[t] * block_q, col_ref[t] * block_k,
+                                s.shape, seq_len_k - seq_len_q, window,
+                                seq_len_q, seq_len_k), s, _NEG_INF)
+        # m and l ride 128 lanes, each lane a copy: the rescale and the
+        # subtraction tile them, where a column would broadcast across
+        # lanes at every step
+        m_prev, l_prev = m_ref[...], l_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, acc_ref.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    @pl.when(j == trips - 1)
-    def _():
-        l = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[...] = jnp.broadcast_to(m_ref[:, 0:1] + jnp.log(l),
-                                        lse_ref.shape)
+    def finish():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / _lanes(l, o_ref.shape[1])).astype(
+            o_ref.dtype)
+        lse_ref[...] = (m_ref[...] + jnp.log(l))[:, :lse_ref.shape[1]]
+
+    _walk_step(flag_ref, start, visit, finish)
 
 
-def _dq_blocked_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
-                       acc_ref, *, scale, window, block_q, block_k, seq_len_q,
-                       seq_len_k, kv_first, kv_last, trips):
-    i, j = pl.program_id(1), pl.program_id(2)
-    off = seq_len_k - seq_len_q
-    kb = kv_first(i) + j
+def _dq_blocked_kernel(row_ref, col_ref, flag_ref, q_ref, k_ref, v_ref,
+                       g_ref, lse_ref, delta_ref, dq_ref, acc_ref, *, scale,
+                       window, block_q, block_k, seq_len_q, seq_len_k):
+    t = pl.program_id(1)
 
-    @pl.when(j == 0)
-    def _():
+    def start():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(kb <= kv_last(i))
-    def _():
+    def visit(masked):
         q, k = q_ref[...], k_ref[...]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        valid = _mask(i * block_q, kb * block_k, s.shape, off, window,
-                      seq_len_q, seq_len_k)
-        p = jnp.where(valid, jnp.exp(s - lse_ref[:, 0:1]), 0.0)
+        p = jnp.exp(s - lse_ref[:, 0:1])
+        if masked:
+            p = jnp.where(_mask(row_ref[t] * block_q, col_ref[t] * block_k,
+                                s.shape, seq_len_k - seq_len_q, window,
+                                seq_len_q, seq_len_k), p, 0.0)
         dp = jax.lax.dot_general(g_ref[...], v_ref[...],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -324,32 +376,31 @@ def _dq_blocked_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == trips - 1)
-    def _():
+    def finish():
         dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
+    _walk_step(flag_ref, start, visit, finish)
 
-def _dkv_blocked_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                        dk_ref, dv_ref, dk_acc, dv_acc, *, scale, window,
-                        block_q, block_k, seq_len_q, seq_len_k, q_first,
-                        q_last, trips):
-    kj, j = pl.program_id(1), pl.program_id(2)
-    off = seq_len_k - seq_len_q
-    qb = q_first(kj) + j
 
-    @pl.when(j == 0)
-    def _():
+def _dkv_blocked_kernel(row_ref, col_ref, flag_ref, q_ref, k_ref, v_ref,
+                        g_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
+                        dv_acc, *, scale, window, block_q, block_k,
+                        seq_len_q, seq_len_k):
+    t = pl.program_id(1)
+
+    def start():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(qb <= q_last(kj))
-    def _():
+    def visit(masked):
         q, k, g = q_ref[...], k_ref[...], g_ref[...]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        valid = _mask(qb * block_q, kj * block_k, s.shape, off, window,
-                      seq_len_q, seq_len_k)
-        p = jnp.where(valid, jnp.exp(s - lse_ref[:, 0:1]), 0.0)
+        p = jnp.exp(s - lse_ref[:, 0:1])
+        if masked:
+            p = jnp.where(_mask(col_ref[t] * block_q, row_ref[t] * block_k,
+                                s.shape, seq_len_k - seq_len_q, window,
+                                seq_len_q, seq_len_k), p, 0.0)
         dv_acc[...] += jax.lax.dot_general(
             p.astype(k.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -360,10 +411,11 @@ def _dkv_blocked_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == trips - 1)
-    def _():
+    def finish():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    _walk_step(flag_ref, start, visit, finish)
 
 
 def _blocked_blocks(sq, sk, d, dtype):
@@ -385,11 +437,16 @@ def _from_bh(x, b, s):
     return x[:, :s].reshape(b, bh // b, s, d).transpose(0, 2, 1, 3)
 
 
-def _kv_walk(sq, sk, block_q, block_k, window):
-    """The forward's and dq's walk: per q block the K/V blocks it sees."""
-    off = sk - sq
-    return _block_span(-(-sq // block_q), block_k,
-                       lambda i: _visible_keys(i, block_q, off, window, sk))
+def _own(rows, lanes):
+    """The block of a walk's operand that follows the step's row."""
+    return pl.BlockSpec((None, rows, lanes),
+                        lambda n, t, row, col, flags: (n, row[t], 0))
+
+
+def _walked(rows, lanes):
+    """The block of a walk's operand that follows the block it walks."""
+    return pl.BlockSpec((None, rows, lanes),
+                        lambda n, t, row, col, flags: (n, col[t], 0))
 
 
 def _flash_fwd_blocked(q, k, v, scale, window):
@@ -398,34 +455,23 @@ def _flash_fwd_blocked(q, k, v, scale, window):
     sk = k.shape[1]
     block_q, block_k = _blocked_blocks(sq, sk, d, q.dtype)
     sq_p, sk_p = _round_up(sq, block_q), _round_up(sk, block_k)
-    kv_first, kv_last, trips = _kv_walk(sq, sk, block_q, block_k, window)
-
-    def kv_index(bh, i, j):
-        return bh, _clamped(kv_first, kv_last, i, j), 0
-
-    kw = dict(scale=scale, window=window, block_q=block_q, block_k=block_k,
-              seq_len_q=sq, seq_len_k=sk, kv_first=kv_first,
-              kv_last=kv_last, trips=trips)
+    tables = _schedule(sq, sk, block_q, block_k, window, False)
+    _book(tables, b * h)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(b * h, sq_p // block_q, trips),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((None, block_k, d), kv_index),
-            pl.BlockSpec((None, block_k, d), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((None, block_q, _STAT_LANES),
-                         lambda bh, i, j: (bh, i, 0)),
-        ],
+        num_scalar_prefetch=3,
+        grid=(b * h, len(tables[0])),
+        in_specs=[_own(block_q, d), _walked(block_k, d),
+                  _walked(block_k, d)],
+        out_specs=[_own(block_q, d), _own(block_q, _STAT_LANES)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, 128), jnp.float32),
                         pltpu.VMEM((block_q, 128), jnp.float32)],
     )
     with _no_x64():
         out, lse = pl.pallas_call(
-            functools.partial(_fwd_blocked_kernel, **kw),
+            functools.partial(_fwd_blocked_kernel, scale=scale,
+                              window=window, block_q=block_q,
+                              block_k=block_k, seq_len_q=sq, seq_len_k=sk),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
@@ -434,7 +480,8 @@ def _flash_fwd_blocked(q, k, v, scale, window):
             ],
             name="flash_attention_fwd",
             interpret=_interpret(),
-        )(_to_bh(q, sq_p), _to_bh(k, sk_p), _to_bh(v, sk_p))
+        )(*map(jnp.asarray, tables), _to_bh(q, sq_p), _to_bh(k, sk_p),
+          _to_bh(v, sk_p))
     return _from_bh(out, b, sq), lse[:, :sq, 0]
 
 
@@ -452,61 +499,48 @@ def _flash_bwd_blocked(q, k_full, v_full, out, lse, g, scale, window):
     lse_p = jnp.pad(lse, ((0, 0), (0, sq_p - sq)))
     stat = lambda x: jnp.broadcast_to(x[..., None], (bh, sq_p, _STAT_LANES))
     lse_p, delta = stat(lse_p), stat(delta)
-    off = sk - sq
     kw = dict(scale=scale, window=window, block_q=block_q, block_k=block_k,
               seq_len_q=sq, seq_len_k=sk)
 
-    q_first, q_last, q_trips = _block_span(
-        sk_p // block_k, block_q,
-        lambda j: _seeing_queries(j, block_k, off, window, sq))
-
-    def q_index(n, kj, j):
-        return n, _clamped(q_first, q_last, kj, j), 0
-
-    q_spec = pl.BlockSpec((None, block_q, d), q_index)
-    stat_q = pl.BlockSpec((None, block_q, _STAT_LANES), q_index)
-    own_kv = pl.BlockSpec((None, block_k, d), lambda n, kj, j: (n, kj, 0))
+    kv_major = _schedule(sq, sk, block_q, block_k, window, True)
+    _book(kv_major, bh)
     with _no_x64():
         dk, dv = pl.pallas_call(
-            functools.partial(_dkv_blocked_kernel, q_first=q_first,
-                              q_last=q_last, trips=q_trips, **kw),
+            functools.partial(_dkv_blocked_kernel, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=0,
-                grid=(bh, sk_p // block_k, q_trips),
-                in_specs=[q_spec, own_kv, own_kv, q_spec, stat_q, stat_q],
-                out_specs=[own_kv, own_kv],
+                num_scalar_prefetch=3,
+                grid=(bh, len(kv_major[0])),
+                in_specs=[_walked(block_q, d), _own(block_k, d),
+                          _own(block_k, d), _walked(block_q, d),
+                          _walked(block_q, _STAT_LANES),
+                          _walked(block_q, _STAT_LANES)],
+                out_specs=[_own(block_k, d), _own(block_k, d)],
                 scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                                 pltpu.VMEM((block_k, d), jnp.float32)]),
             out_shape=[jax.ShapeDtypeStruct((bh, sk_p, d), k_full.dtype),
                        jax.ShapeDtypeStruct((bh, sk_p, d), v_full.dtype)],
             name="flash_attention_dkv",
             interpret=_interpret(),
-        )(qh, kh, vh, gh, lse_p, delta)
+        )(*map(jnp.asarray, kv_major), qh, kh, vh, gh, lse_p, delta)
 
-    kv_first, kv_last, kv_trips = _kv_walk(sq, sk, block_q, block_k, window)
-
-    def kv_index(n, i, j):
-        return n, _clamped(kv_first, kv_last, i, j), 0
-
-    own_q = pl.BlockSpec((None, block_q, d), lambda n, i, j: (n, i, 0))
-    own_stat = pl.BlockSpec((None, block_q, _STAT_LANES),
-                            lambda n, i, j: (n, i, 0))
-    kv_spec = pl.BlockSpec((None, block_k, d), kv_index)
+    q_major = _schedule(sq, sk, block_q, block_k, window, False)
+    _book(q_major, bh)
     with _no_x64():
         dq = pl.pallas_call(
-            functools.partial(_dq_blocked_kernel, kv_first=kv_first,
-                              kv_last=kv_last, trips=kv_trips, **kw),
+            functools.partial(_dq_blocked_kernel, **kw),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=0,
-                grid=(bh, sq_p // block_q, kv_trips),
-                in_specs=[own_q, kv_spec, kv_spec, own_q, own_stat,
-                          own_stat],
-                out_specs=[own_q],
+                num_scalar_prefetch=3,
+                grid=(bh, len(q_major[0])),
+                in_specs=[_own(block_q, d), _walked(block_k, d),
+                          _walked(block_k, d), _own(block_q, d),
+                          _own(block_q, _STAT_LANES),
+                          _own(block_q, _STAT_LANES)],
+                out_specs=[_own(block_q, d)],
                 scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
             out_shape=[jax.ShapeDtypeStruct((bh, sq_p, d), q.dtype)],
             name="flash_attention_dq",
             interpret=_interpret(),
-        )(qh, kh, vh, gh, lse_p, delta)[0]
+        )(*map(jnp.asarray, q_major), qh, kh, vh, gh, lse_p, delta)[0]
     return _from_bh(dq, b, sq), _from_bh(dk, b, sk), _from_bh(dv, b, sk)
 
 

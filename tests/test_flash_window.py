@@ -1,11 +1,15 @@
 """The flash kernel's window (``ops/pallas/flash_attention.py``, interpret
 mode): forward, dq and dkv against a dense masked ``jax.numpy`` reference at
 windows that are and are not multiples of the block; K/V blocks wholly
-outside every window held as NaN, which only the loop bounds keep out;
+outside every window held as NaN, which only the walk keeps out;
 ``window=None`` at GPT-2's shape taking the resident programs bit for bit;
-the blocked programs for a long causal call; the cost surface."""
+the blocked programs for a long causal call; their schedule (each pair's
+kind against the explicit mask, the walk, the counters) and their unmasked
+whole pairs against the masked walk; the cost surface."""
 
+import importlib.util
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -155,23 +159,216 @@ def test_a_long_causal_call_takes_the_blocked_programs():
                                    rtol=2e-5)
 
 
+def _explicit_kinds(sq, sk, block_q, block_k, window):
+    """{(q block, K/V block): kind} from the explicit mask over the padded
+    blocks (a padded row or key is masked): the brute force the schedule
+    is held to."""
+    nq, nk = -(-sq // block_q), -(-sk // block_k)
+    q = np.arange(nq * block_q)[:, None]
+    k = np.arange(nk * block_k)[None, :]
+    m = (q < sq) & (k < sk) & (k <= q + sk - sq)
+    if window is not None:
+        m &= k > q + sk - sq - window
+    m = m.reshape(nq, block_q, nk, block_k)
+    seen, every = m.any((1, 3)), m.all((1, 3))
+    return {(i, j): fa._FULL if every[i, j] else fa._CUT if seen[i, j]
+            else fa._NONE for i in range(nq) for j in range(nk)}
+
+
+def _walked(sq, sk, block_q, block_k, window, kv_major):
+    """{(q block, K/V block): kind} of the pairs a schedule visits."""
+    row, col, flags = fa._schedule(sq, sk, block_q, block_k, window,
+                                   kv_major)
+    got = {}
+    for r, c, f in zip(row.tolist(), col.tolist(), flags.tolist()):
+        if f & 3 != fa._NONE:
+            got[(c, r) if kv_major else (r, c)] = f & 3
+    return got
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 512)],
+                         ids=["b128x128", "b256x512"])
+@pytest.mark.parametrize("sq,sk,window", [
+    (384, 384, 128), (384, 384, 200), (384, 384, 1000), (384, 384, None),
+    (640, 640, 128), (640, 640, 200), (640, 640, 1000), (640, 640, None),
+    (256, 768, 256), (320, 704, None), (320, 704, 200), (640, 384, 200)],
+    ids=lambda x: "none" if x is None else str(x))
+def test_schedule_kinds_match_the_explicit_mask(sq, sk, window, blocks):
+    """Every pair's kind against a brute-force classification of the
+    explicit mask (windows of one block, of no multiple of it, past the
+    sequence, none; more keys than queries, fewer, padded tails); the
+    forward's walk and dkv's visit exactly the visible pairs, each under
+    its kind."""
+    bq, bk = blocks
+    want = _explicit_kinds(sq, sk, bq, bk, window)
+    for (i, j), kind in want.items():
+        assert fa._pair_kind(i, j, bq, bk, sq, sk, window) == kind, (i, j)
+    visible = {p: kind for p, kind in want.items() if kind != fa._NONE}
+    assert _walked(sq, sk, bq, bk, window, False) == visible
+    assert _walked(sq, sk, bq, bk, window, True) == visible
+
+
+@pytest.mark.parametrize("window,full,cut", [(1024, 30, 60),
+                                             (None, 240, 32)],
+                         ids=["w1024", "causal"])
+def test_schedule_counts_at_the_cells_shape(window, full, cut):
+    """S 8,192, blocks 256 / 512: a window layer walks 30 whole pairs and
+    60 cut ones a head, the full layer 240 and 32 — over Mellum2's three
+    window layers and one full layer 330 whole + 212 cut, 542 grid steps
+    a head where the clamped walk took 800 (3 x 96 + 512). The kernel
+    timer's count from the explicit mask reads the same."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "flash_bench.py")
+    spec = importlib.util.spec_from_file_location("flash_bench", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    assert bench.pair_counts(8192, 8192, 256, 512, window) == (full, cut)
+    for kv_major in (False, True):
+        kinds = fa._schedule(8192, 8192, 256, 512, window, kv_major)[2] & 3
+        assert (int((kinds == fa._FULL).sum()),
+                int((kinds == fa._CUT).sum()), len(kinds)) \
+            == (full, cut, full + cut)
+
+
 def test_the_walks_cover_exactly_the_visible_blocks():
-    """The forward's walk per q block and dkv's per K/V block, against a
-    count of the blocks holding a visible key: at S 8192, window 1024 and
-    blocks 256 / 512 a q block walks at most 3 K/V blocks of 16, and a K/V
-    block is seen by at most 6 q blocks of 32."""
-    sq = sk = 8192
-    first, last, trips = fa._kv_walk(sq, sk, 256, 512, 1024)
-    assert trips == 3
-    for i in range(sq // 256):
-        seen = {kp // 512 for qp in range(i * 256, (i + 1) * 256)
-                for kp in (max(qp - 1023, 0), qp)}
-        assert int(first(i)) == min(seen) and int(last(i)) == max(seen)
-    q_first, q_last, q_trips = fa._block_span(
-        sk // 512, 256, lambda j: fa._seeing_queries(j, 512, 0, 1024, sq))
-    assert q_trips == 6
-    assert (int(q_first(0)), int(q_last(0))) == (0, 5)
-    assert (int(q_first(15)), int(q_last(15))) == (30, 31)
+    """Each row's steps are consecutive, in ascending order, the first
+    flagged ``_FIRST`` and the last ``_LAST``; a row that sees nothing
+    takes one step of kind ``_NONE`` (both flags) that stays on the block
+    the walk is on. At S 8192, window 1024, blocks 256 / 512 a q block
+    walks at most 3 K/V blocks of 16 and a K/V block at most 6 q blocks
+    of 32; with more queries than keys (640 over 384, window 200) the
+    first q block sees nothing."""
+    for sq, sk, window, kv_major in [(8192, 8192, 1024, False),
+                                     (8192, 8192, 1024, True),
+                                     (640, 384, 200, False),
+                                     (640, 384, 200, True)]:
+        row, col, flags = fa._schedule(sq, sk, 256 if sq > 640 else 128,
+                                       512 if sq > 640 else 128, window,
+                                       kv_major)
+        runs = {}
+        for t, r in enumerate(row.tolist()):
+            runs.setdefault(r, []).append(t)
+        assert sorted(runs) == list(range(len(runs)))
+        for r, steps in runs.items():
+            assert steps == list(range(steps[0], steps[-1] + 1))
+            cols = col[steps].tolist()
+            assert cols == sorted(cols)
+            f = flags[steps].tolist()
+            assert [bool(x & fa._FIRST) for x in f] == \
+                [True] + [False] * (len(f) - 1)
+            assert [bool(x & fa._LAST) for x in f] == \
+                [False] * (len(f) - 1) + [True]
+            if f[0] & 3 == fa._NONE:
+                assert len(f) == 1 and (steps[0] == 0
+                                        or col[steps[0] - 1] == cols[0])
+        if sq == 8192:
+            assert max(map(len, runs.values())) == (6 if kv_major else 3)
+    row, _, flags = fa._schedule(640, 384, 128, 128, 200, False)
+    assert flags[0] & 3 == fa._NONE and row[1] == 1
+
+
+@pytest.mark.parametrize("sq,sk,window,block", [
+    (640, 640, 300, (128, 128)), (320, 704, None, (128, 128)),
+    (576, 576, 200, (128, 256)), (8192, 8192, 1024, (256, 512))],
+    ids=["w300", "sk_gt_sq_padded", "w200_padded", "cell"])
+def test_mask_keeps_the_explicit_logits(sq, sk, window, block):
+    """``_mask`` of every pair (written over ``k - q``, with padding
+    compares only where a block is padded) against the mask written over
+    both positions; at the cell's shape, the pairs of the first rows."""
+    bq, bk = block
+    nq = min(-(-sq // bq), 6)
+    k = np.arange(-(-sk // bk) * bk)[None, :]
+    for i in range(nq):
+        q = np.arange(i * bq, (i + 1) * bq)[:, None]
+        want = (q < sq) & (k < sk) & (k <= q + sk - sq)
+        if window is not None:
+            want &= k > q + sk - sq - window
+        for j in range(k.shape[1] // bk):
+            got = fa._mask(i * bq, j * bk, (bq, bk), sk - sq, window, sq, sk)
+            assert np.array_equal(np.asarray(got),
+                                  want[:, j * bk:(j + 1) * bk]), (i, j)
+
+
+#: shapes whose pairs hold all three kinds: a window, plain causal, more
+#: keys than queries with padded tails, padded tails under a window, and
+#: blocks 256 / 512
+THREE_KINDS = [(640, 640, 300, 128, 128), (384, 384, None, 128, 128),
+               (320, 704, None, 128, 128), (576, 576, 300, 128, 128),
+               (1536, 1536, 1000, 256, 512)]
+THREE_KIND_IDS = ["w300", "causal", "sk_gt_sq_padded", "w300_padded",
+                  "w1000_b256x512"]
+
+
+def _blocked(sq, sk, window, bq, bk, monkeypatch, seed=1):
+    q, k, v, g = _inputs(sq, sk=sk, seed=seed)
+    kinds = set(_explicit_kinds(sq, sk, bq, bk, window).values())
+    assert kinds == {fa._NONE, fa._CUT, fa._FULL}
+    monkeypatch.setattr(fa, "_takes_blocked", lambda *a: True)
+    with fa.force_blocks(bq, bk):
+        got = _fwd_bwd(lambda a, b, c: fa.flash_attention(
+            a, b, c, True, None, window), q, k, v, g)
+    return got, (q, k, v, g)
+
+
+@pytest.mark.parametrize("sq,sk,window,bq,bk", THREE_KINDS,
+                         ids=THREE_KIND_IDS)
+def test_unmasked_pairs_match_the_masked_walk(sq, sk, window, bq, bk,
+                                              monkeypatch):
+    """fwd, dq and dkv against the same walk with every visible pair
+    masked — each pair's work as the programs did it before whole pairs
+    went unmasked: equal to float32 rounding (a few ulps of the largest
+    value), not merely to a tolerance against the reference."""
+    got, args = _blocked(sq, sk, window, bq, bk, monkeypatch)
+    real = fa._schedule
+
+    def all_cut(*a):
+        row, col, flags = real(*a)
+        whole = (flags & 3) == fa._FULL
+        return row, col, np.where(whole, flags - fa._FULL + fa._CUT,
+                                  flags).astype(np.int32)
+
+    monkeypatch.setattr(fa, "_schedule", all_cut)
+    with fa.force_blocks(bq, bk):
+        masked = _fwd_bwd(lambda a, b, c: fa.flash_attention(
+            a, b, c, True, None, window), *args[:3], args[3])
+    for name, x, y in zip(("out", "dq", "dk", "dv"), got, masked):
+        x, y = np.asarray(x), np.asarray(y)
+        assert np.abs(x - y).max() <= 8 * np.finfo(np.float32).eps \
+            * np.abs(y).max(), name
+
+
+@pytest.mark.parametrize("sq,sk,window,bq,bk", THREE_KINDS,
+                         ids=THREE_KIND_IDS)
+def test_three_kinds_match_dense_under_gqa(sq, sk, window, bq, bk,
+                                           monkeypatch):
+    """fwd, dq and dkv of the blocked programs (4 query heads over 2 K/V
+    heads) against the dense masked reference."""
+    got, (q, k, v, g) = _blocked(sq, sk, window, bq, bk, monkeypatch)
+    want = _fwd_bwd(lambda a, b, c: dense(a, b, c, window), q, k, v, g)
+    for name, x, y in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5,
+                                   rtol=2e-5, err_msg=name)
+
+
+def test_counters_read_the_schedule(monkeypatch):
+    """A traced forward books the forward's walk, its backward dkv's and
+    dq's, summed over the call's heads (batch x query heads)."""
+    from paddle_tpu.profiler import metrics
+    sq, sk, window, bq, bk = THREE_KINDS[0]
+    reg = metrics.get_registry()
+    names = ("flash/blocks_full", "flash/blocks_cut", "flash/grid_steps")
+    before = [reg.counter(n).value for n in names]
+    _blocked(sq, sk, window, bq, bk, monkeypatch)
+    booked = [reg.counter(n).value - b for n, b in zip(names, before)]
+    heads = 1 * 4
+    want = [0, 0, 0]
+    for kv_major in (False, False, True):      # fwd, dq, dkv
+        kinds = fa._schedule(sq, sk, bq, bk, window, kv_major)[2] & 3
+        for n, x in enumerate(((kinds == fa._FULL).sum(),
+                               (kinds == fa._CUT).sum(), len(kinds))):
+            want[n] += int(x) * heads
+    assert booked == want
+    assert want[0] > 0 and want[1] > 0
 
 
 def test_cost_counts_the_windowed_work():
